@@ -8,7 +8,7 @@ checkpoint — and finish training.
 
 Run (CPU simulation, 2 ranks):
 
-    python examples/inprocess_restart_train.py --world 2 --kill-rank 1 --kill-step 6
+    python examples/inprocess_restart_train.py --cpu --world 2 --kill-rank 1 --kill-step 6
 
 Each rank process:
   - wraps ``train`` with :class:`tpu_resiliency.inprocess.Wrapper`
@@ -62,9 +62,6 @@ def rank_main(rank: int, world: int, port: int, args, result_q) -> None:
     kept.append(f"--xla_force_host_platform_device_count={args.devices_per_rank}")
     os.environ["XLA_FLAGS"] = " ".join(kept)
     import jax
-
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
 
@@ -183,7 +180,11 @@ def main() -> int:
     ap.add_argument("--kill-rank", type=int, default=1)
     ap.add_argument("--kill-step", type=int, default=6)
     ap.add_argument("--step-time", type=float, default=0.25)
-    ap.add_argument("--cpu", action="store_true", default=True)
+    ap.add_argument(
+        "--cpu", action="store_true",
+        help="simulate: every rank process runs on virtual CPU devices (N rank "
+        "processes on one host cannot share one chip)",
+    )
     ap.add_argument("--ckpt-root", default=None)
     ap.add_argument(
         "--devices-per-rank", type=int, default=1,

@@ -16,7 +16,9 @@ Both layers narrate their state machines via the machine-parseable
 ``[NestedRestarter] name=[InJob|InProcess] state=...`` log-line contract
 (reference ``rank_monitor_state_machine.py:127-145``, ``nested_restarter.py:34-107``).
 
-Run (CPU simulation, 2 ranks)::
+Run (CPU simulation, 2 ranks — export ``JAX_PLATFORMS=cpu`` yourself: the two
+worker processes of this simulation cannot share one chip, and nothing here
+picks a platform in code)::
 
     TPU_RESILIENCY_LOG_LEVEL=INFO JAX_PLATFORMS=cpu \\
         tpu-ft-launcher --nproc-per-node 2 --max-restarts 2 --no-ft-monitors \\
@@ -34,14 +36,7 @@ _REPO_ROOT = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
 if _REPO_ROOT not in _sys.path:
     _sys.path.insert(0, _REPO_ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
-
-from tpu_resiliency.platform.device import apply_platform_env
-
-apply_platform_env()  # the env var alone does not override the TPU plugin's boot config
-
 import jax.numpy as jnp
 
 from tpu_resiliency.inprocess import CallWrapper, Wrapper

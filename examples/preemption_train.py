@@ -11,7 +11,7 @@ No reference analogue — this is TPU-first lifecycle the reference lacks.
 
 Run (CPU simulation, 2 ranks; the parent SIGTERMs rank 1 after ~3 s):
 
-    python examples/preemption_train.py --world 2
+    python examples/preemption_train.py --cpu --world 2
 """
 
 from __future__ import annotations
@@ -49,10 +49,6 @@ CHILD = textwrap.dedent(
     rank = int(sys.argv[1]); world = int(sys.argv[2])
     jd_port = sys.argv[3]; ckpt_root = sys.argv[4]
     import jax
-
-    from tpu_resiliency.platform.device import apply_platform_env
-
-    apply_platform_env()  # parent exports JAX_PLATFORMS for the simulation
 
     from tpu_resiliency.platform import distributed as jdist
 
@@ -116,8 +112,9 @@ def main() -> int:
     ap.add_argument("--world", type=int, default=2)
     ap.add_argument("--ckpt-root", default=None)
     ap.add_argument(
-        "--platform", default="cpu",
-        help="JAX platform for the rank processes (default: cpu simulation)",
+        "--cpu", action="store_true",
+        help="simulate: every rank process runs on 2 virtual CPU devices (N "
+        "rank processes on one host cannot share one chip)",
     )
     args = ap.parse_args()
     ckpt_root = args.ckpt_root or tempfile.mkdtemp(prefix="preempt-example-")
@@ -126,8 +123,13 @@ def main() -> int:
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     child_env = {
         **os.environ,
-        "JAX_PLATFORMS": args.platform,
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+        **(
+            {
+                "JAX_PLATFORMS": "cpu",
+                "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+            }
+            if args.cpu else {}
+        ),
         # uninstalled checkouts: children run from a temp dir
         "PYTHONPATH": repo_root + os.pathsep + os.environ.get("PYTHONPATH", ""),
     }

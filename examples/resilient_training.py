@@ -20,6 +20,10 @@ Run::
 
 (``--warm-spares 1`` parks a pre-imported interpreter so the post-crash
 respawn promotes it in tens of milliseconds instead of paying jax import.)
+
+``$JAX_PLATFORMS`` decides where the worker runs; unset, it takes whatever JAX
+finds (the chip on a TPU host). Keep ``--nproc-per-node 1`` on a TPU host: every
+local worker is handed the same devices, and a chip belongs to one process.
 """
 
 from __future__ import annotations
@@ -33,12 +37,7 @@ _REPO_ROOT = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
 if _REPO_ROOT not in _sys.path:
     _sys.path.insert(0, _REPO_ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
-
-jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"].split(",")[0])
-
 import jax.numpy as jnp
 
 from tpu_resiliency.checkpoint.local_manager import LocalCheckpointManager

@@ -220,10 +220,6 @@ def main() -> int:
     ap.add_argument("--configs", default="1,2,3")
     args = ap.parse_args()
 
-    from tpu_resiliency.platform.device import apply_platform_env
-
-    apply_platform_env()
-
     os.makedirs(args.out_dir, exist_ok=True)
     runners = {1: config1, 2: config2, 3: config3}
     ok = True

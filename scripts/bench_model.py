@@ -4,7 +4,9 @@ Llama-style transformer's full train step (fwd + bwd + adamw), bf16 activations.
 Not the driver's headline metric (that's bench.py's telemetry hot loop) — this
 validates the model/parallelism stack on real hardware and gives the resiliency
 overhead a denominator: a telemetry push at ~0.03 ms/step is noise against a real
-step. Prints one JSON line.
+step. Prints one JSON line — on a TPU or not at all: off one it exits non-zero
+with no result line, and MFU is printed only for a ``device_kind`` whose peak is
+written down in :data:`PEAK_BF16_FLOPS`.
 
     python scripts/bench_model.py [--layers 8] [--d-model 1024] [--batch 8] [--seq 1024]
 """
@@ -20,6 +22,11 @@ import time
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
+
+#: bf16 peak FLOP/s of one chip, by ``device_kind`` as JAX reports it. Source:
+#: Google Cloud documentation, "TPU v5e" (197 TFLOP/s). A device that is not
+#: here gets no MFU — never another device's peak.
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
 
 
 def main() -> int:
@@ -40,9 +47,17 @@ def main() -> int:
     import numpy as np
 
     from tpu_resiliency.models import transformer as tfm
-    from tpu_resiliency.platform.device import apply_platform_env
+    from tpu_resiliency.platform.device import apply_compile_cache_env
 
-    apply_platform_env()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(
+            f"bench_model.py measures on a TPU; JAX found platform "
+            f"{dev.platform!r} ({dev.device_kind}). No result.",
+            file=sys.stderr,
+        )
+        return 1
+    apply_compile_cache_env()
 
     cfg = tfm.TransformerConfig(
         vocab_size=args.vocab,
@@ -67,9 +82,8 @@ def main() -> int:
     jax.block_until_ready(loss)
     compile_s = time.perf_counter() - t0
 
-    # Device-true per-step time via the framework's profiler: wall-clock loops
-    # under-report ~500x on remote-dispatch runtimes (BASELINE.md measurement-
-    # integrity note).
+    # Per-step device time from the framework's profiler (the program's
+    # duration on the device plane; a TPU trace without one raises).
     from tpu_resiliency.telemetry.device_profiler import DeviceTimeProfiler
 
     prof = DeviceTimeProfiler()
@@ -77,17 +91,12 @@ def main() -> int:
         for _ in range(args.iters):
             params, opt_state, loss = step(params, opt_state, tokens)
         jax.block_until_ready(loss)
-    per_step = None
-    for name, st in prof.get_stats().items():
-        if "train_step" in name:
-            per_step = st["med"]
-    if per_step is None:
-        # No device plane (CPU simulation): fall back to blocking wall clock.
-        t0 = time.perf_counter()
-        for _ in range(args.iters):
-            params, opt_state, loss = step(params, opt_state, tokens)
-            loss.block_until_ready()
-        per_step = (time.perf_counter() - t0) / args.iters
+    steps = [st["med"] for name, st in prof.get_stats().items() if "train_step" in name]
+    if not steps:
+        raise RuntimeError(
+            f"profiler window has no train_step program: {sorted(prof.get_stats())}"
+        )
+    per_step = steps[-1]
     tokens_per_s = args.batch * args.seq / per_step
 
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
@@ -103,11 +112,15 @@ def main() -> int:
                 "unit": "tokens/s",
                 "ms_per_step": round(per_step * 1e3, 2),
                 "final_loss": round(float(loss), 4),
-                "backend": jax.default_backend(),
-                "mfu_vs_v5e_peak": round(
-                    # 6*N*tokens/s FLOPs vs v5e bf16 peak 197 TFLOP/s — only
-                    # meaningful when backend == tpu.
-                    6 * n_params * tokens_per_s / 197e12, 4
+                "backend": dev.platform,
+                "device_kind": dev.device_kind,
+                "device_count": len(jax.devices()),
+                # 6*N*tokens/s FLOPs over the chip's written-down bf16 peak
+                **(
+                    {"mfu": round(
+                        6 * n_params * tokens_per_s / PEAK_BF16_FLOPS[dev.device_kind], 4
+                    )}
+                    if dev.device_kind in PEAK_BF16_FLOPS else {}
                 ),
             }
         )

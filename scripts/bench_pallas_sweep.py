@@ -1,6 +1,5 @@
 """Sweep the Pallas fused-median kernels vs XLA's sort lowering over (W, R) —
-the measured data behind ``scoring_pallas`` auto-selection (VERDICT r3 item 5 /
-r4 item 3).
+the measurement ``scoring_pallas`` auto-selection rests on.
 
 Three kernel formulations are measured: ``loop`` (rank-counting, O(W²)),
 ``pairwise`` (all-pairs block, O(W²) VMEM-heavy; the product gate caps it at
@@ -19,8 +18,8 @@ auto-select boundary from the measurements:
   contradict each other on a sub-noise tie (the use_pallas gate
   justification).
 
-Run on a real TPU (device-true per-program times via the framework's own
-DeviceTimeProfiler; wall clocks lie on remote-dispatch runtimes):
+Runs on a TPU or not at all (per-program device-plane times via the framework's
+own DeviceTimeProfiler): off one it exits non-zero with no result line.
 
     python scripts/bench_pallas_sweep.py [--ws 32,64,128,256] [--rs 256,1024,4096]
 """
@@ -76,23 +75,15 @@ def measure(r, w, variant):
     fn = jax.jit(program)
     out = fn(data, counts, ewma, hist)
     jax.block_until_ready(out)
-    if jax.default_backend() == "tpu":
-        prof = DeviceTimeProfiler()
-        with prof:
-            for _ in range(ITERS):
-                out = fn(data, counts, out.ewma, hist)
-            jax.block_until_ready(out)
-        for name, st in prof.get_stats().items():
-            if "program" in name:
-                return st["med"] * 1e3
-        raise RuntimeError(f"profiler missed program: {sorted(prof.get_stats())}")
-    import time
-
-    t0 = time.perf_counter()
-    for _ in range(ITERS):
-        out = fn(data, counts, out.ewma, hist)
+    prof = DeviceTimeProfiler()  # a TPU trace without a device plane raises
+    with prof:
+        for _ in range(ITERS):
+            out = fn(data, counts, out.ewma, hist)
         jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / ITERS * 1e3
+    for name, st in prof.get_stats().items():
+        if "program" in name:
+            return st["med"] * 1e3
+    raise RuntimeError(f"profiler missed program: {sorted(prof.get_stats())}")
 
 
 VARIANTS = ("pallas-loop", "pallas-pairwise", "pallas-radix", "xla")
@@ -108,11 +99,16 @@ def main():
 
     import jax
 
-    from tpu_resiliency.platform.device import apply_platform_env
+    from tpu_resiliency.platform.device import apply_compile_cache_env
 
-    apply_platform_env()
-
-    backend = jax.default_backend()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"bench_pallas_sweep.py measures on a TPU; JAX found platform "
+            f"{dev.platform!r} ({dev.device_kind}). No result."
+        )
+    apply_compile_cache_env()
+    backend = dev.platform
     print(f"backend: {backend} {jax.devices()}", file=sys.stderr)
     results = {}
     loop_best_by_w = {w: True for w in ws}
@@ -177,6 +173,8 @@ def main():
         json.dumps(
             {
                 "backend": backend,
+                "device_kind": dev.device_kind,
+                "device_count": len(jax.devices()),
                 "signals": S,
                 "results_ms": results,
                 "loop_max_window": loop_max_window,
@@ -185,10 +183,6 @@ def main():
                     str(w): pallas_wins_by_w[w] for w in sorted(ws)
                 },
                 "export": f"TPU_RESILIENCY_PALLAS_MAX_WINDOW={loop_max_window}",
-                # Stable schema with the merge flow that annotates a wedged
-                # run's artifact (BASELINE.md references these fields).
-                "carried_cells": [],
-                "note": "",
             }
         )
     )

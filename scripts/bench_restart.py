@@ -196,8 +196,8 @@ def bench_injob(warm_spares: int = 0, fast_path: bool = True) -> dict:
       ``worker_promoted`` → the promoted worker's first Python statement
       (cold runs report the combined segment as ``spawn_and_startup_ms``).
 
-    The interpreter/plugin startup tax is measured separately as a
-    median-of-3 floor with the same env."""
+    The interpreter startup tax is measured separately as a median-of-3
+    floor with the same env."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
     floors = []
@@ -358,7 +358,7 @@ def bench_rendezvous_fastpath(nodes: int = 16, rounds: int = 8) -> dict:
 JIT_WORKER = """
 import json, os, sys, time
 from tpu_resiliency.platform import device
-device.apply_platform_env()  # applies the compile cache + records its event
+device.apply_compile_cache_env()  # integrity sweep + the compile_cache event
 import jax, jax.numpy as jnp
 count = int(os.environ.get("TPU_FT_RESTART_COUNT", "0"))
 t0 = time.monotonic()
@@ -377,7 +377,9 @@ def bench_compile_cache() -> dict:
     persistent compilation cache warm (outcome=hit) and re-jit cheaper."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # The cold leg needs an EMPTY cache, so this child alone is denied an
+    # outside $JAX_COMPILATION_CACHE_DIR (which would win over the flag below).
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     with tempfile.TemporaryDirectory() as td:
         worker = os.path.join(td, "worker.py")
         with open(worker, "w") as f:

@@ -3,7 +3,7 @@
 #
 # The engine's premise is that injection is safe: a healthy rank must NEVER die
 # because a RankShouldRestart landed outside the wrapped fn (the round-2 delivery
-# race, VERDICT r2 weak #1). This loop is the regression gate: run the multi-rank
+# race, review round 2 weak #1). This loop is the regression gate: run the multi-rank
 # restart tests N times (default 50) and fail on the first non-green run.
 #
 #   ./scripts/stress_inprocess.sh [N]

@@ -397,7 +397,7 @@ class TestGroupSequenceFor:
 
 class TestRebuildAfterReassignment:
     def test_rebuild_remirrors_and_next_save_covers(self, tmp_path, make_store):
-        """VERDICT r3 item 7: world 4 saves with cliques [0,1],[2,3]; rank 3 dies;
+        """review round 3 item 7: world 4 saves with cliques [0,1],[2,3]; rank 3 dies;
         survivors rebuild over [0,1,2], the orphaned rank-2 shard gets re-mirrored,
         a wiped rank still recovers, and the next save is coverage-complete."""
         world = 4

@@ -453,3 +453,50 @@ class TestStripedEdgeCases:
         (hint_file,) = tmp_path.glob("d.b.*.ckpt")
         _, meta_hint = AsyncCheckpointer.load(str(hint_file))
         assert meta_main == {"it": 4} and meta_hint == {"it": 4}
+
+
+# -- async snapshots vs a step that donates the saved state --------------------
+
+def _donating_step():
+    return jax.jit(lambda t: jax.tree.map(lambda x: x + 1, t), donate_argnums=(0,))
+
+
+def test_undetached_snapshot_dies_with_the_donated_arrays():
+    """The hazard itself: donation deletes the arrays under a snapshot that has
+    not copied them out yet, whatever ``copy_to_host_async`` had enqueued."""
+    tree = {"w": jnp.arange(64.0).reshape(8, 8), "b": jnp.ones((8,))}
+    sd = PyTreeStateDict(tree)
+    sd.pop_tensors()
+    snapshot = sd.copy_tensors_to_host_async()
+    _donating_step()(tree)
+    with pytest.raises(RuntimeError, match="deleted"):
+        snapshot.resolve_all()
+
+
+def test_detach_device_makes_the_snapshot_survive_donation():
+    from tpu_resiliency.checkpoint.staging import HostStagingPool
+    from tpu_resiliency.utils import events
+
+    tree = {"w": jnp.arange(64.0).reshape(8, 8), "b": jnp.ones((8,))}
+    # Built apart from the device arrays: a numpy view of one would pin its
+    # buffer and keep the CPU backend from donating it. Leaves pop sorted: b, w.
+    want = [np.ones((8,), np.float32), np.arange(64, dtype=np.float32).reshape(8, 8)]
+    sd = PyTreeStateDict(tree)
+    sd.pop_tensors()
+    snapshot = sd.copy_tensors_to_host_async(pool=HostStagingPool())
+    snapshot.resolve(0)  # the background writer got this far
+    seen = []
+    events.add_sink(seen.append)
+    try:
+        assert sd.detach_device() == 1  # only the leaf still on the device
+        assert sd.detach_device() == 0  # idempotent, and silent
+    finally:
+        events.remove_sink(seen.append)
+    stalls = [e.payload for e in seen if e.kind == "ckpt_foreground_blocked"]
+    assert [(p["engine"], p["leaves"]) for p in stalls] == [("detach", 1)]
+    new = _donating_step()(tree)
+    assert all(x.is_deleted() for x in jax.tree.leaves(tree))
+    for got, ref in zip(snapshot.resolve_all(), want):
+        np.testing.assert_array_equal(got, ref)
+    snapshot.release()
+    np.testing.assert_array_equal(np.asarray(new["b"]), 2.0)

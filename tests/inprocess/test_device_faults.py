@@ -1,5 +1,5 @@
 """Device-fault injection kinds driving the device detectors end to end
-(VERDICT r3 item 9; reference analogue: GPU_ERROR / GPU_SLEEP in
+(review round 3 item 9; reference analogue: GPU_ERROR / GPU_SLEEP in
 ``inprocess/tools/inject_fault.py:34-47``, which exist to test the device-health
 detectors specifically):
 

@@ -14,6 +14,7 @@ def test_moe_pipeline_example_restarts_and_resumes(tmp_path):
         [
             sys.executable,
             os.path.join(REPO, "examples", "moe_pipeline_training.py"),
+            "--cpu",
             "--steps", "8",
             "--fault-step", "3",
             "--ckpt-root", str(tmp_path),

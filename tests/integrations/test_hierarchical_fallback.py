@@ -1,4 +1,4 @@
-"""The hierarchical-tier recovery story, end to end (VERDICT r4 item 5): train
+"""The hierarchical-tier recovery story, end to end (review round 4 item 5): train
 with BOTH tiers, lose the entire local tier in the crash, restart — the same
 callback seam restores from the Orbax global tier — and the rebuilt replication
 group repopulates the local tier with coverage-complete saves.

@@ -381,3 +381,92 @@ def test_straggler_report_emits_structured_event():
                                "stragglers_by_section"}
     assert ev.payload["perf_scores"].get("0") == 1.0  # single healthy rank (str keys: on-disk schema)
     assert ev.payload["stragglers_by_perf"] == []
+
+
+@pytest.mark.parametrize("use_device_mesh", [True, False], ids=["mesh", "local"])
+def test_one_worker_report_says_which_path_scored_it(use_device_mesh):
+    """One worker is one JAX process per rank: asked for the mesh path, it gets
+    the compiled mesh scorer (no store, nobody to agree columns with), and both
+    the report and its event say so, beside the profiler windows' own source."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_resiliency.utils import events
+
+    if Detector.initialized:
+        Detector.shutdown()
+    seen = []
+    events.add_sink(seen.append)
+    try:
+        cb = StragglerDetectionCallback(
+            report_time_interval=0.0, profile_programs_every=3,
+            use_device_mesh=use_device_mesh,
+        )
+        work = jax.jit(lambda x: jnp.tanh(x * 2.0).sum())
+
+        def step(state, i):
+            jax.block_until_ready(work(jnp.full((32,), float(i))))
+            return state + 1
+
+        run_training(step, 0, 24, callbacks=[cb])
+    finally:
+        events.remove_sink(seen.append)
+    want = "mesh" if use_device_mesh else "local"
+    assert cb.last_report.source == want
+    assert cb.last_report.perf_scores == {0: 1.0}
+    payloads = [e.payload for e in seen if e.kind == "straggler_report"]
+    assert len(payloads) >= 2
+    assert {p["report_source"] for p in payloads} == {want}
+    last = payloads[-1]
+    assert any(s.startswith("prog/") for s in last["signals"]), last["signals"]
+    # The CPU backend's windows are host dispatch times, and say so.
+    assert last["profile_source"] == "host" and last["profile_windows"] >= 2
+    assert (last["profile_skipped"], last["profile_dropped"]) == (0, 0)
+
+
+def test_profiler_faults_are_counted_not_fatal(monkeypatch):
+    """A window that cannot start is skipped, one that cannot be parsed is
+    dropped; training goes on and the counts reach the report event."""
+    from tpu_resiliency.telemetry import device_profiler
+    from tpu_resiliency.utils import events
+
+    if Detector.initialized:
+        Detector.shutdown()
+
+    def no_plane(*a, **k):
+        raise device_profiler.NoDevicePlane("no 'XLA Modules' line")
+
+    monkeypatch.setattr(device_profiler, "extract_program_times", no_plane)
+    seen = []
+    events.add_sink(seen.append)
+    try:
+        cb = StragglerDetectionCallback(report_time_interval=0.0, profile_programs_every=4)
+        ctx = run_training(lambda s, i: s + 1, 0, 20, callbacks=[cb])
+    finally:
+        events.remove_sink(seen.append)
+    assert ctx.state == 20
+    last = [e.payload for e in seen if e.kind == "straggler_report"][-1]
+    assert last["profile_dropped"] == 5 and last["profile_windows"] == 0
+    assert not any(s.startswith("prog/") for s in last["signals"])
+    assert not cb._program_profiler.active
+
+
+def test_async_saves_survive_a_step_that_donates_the_state(tmp_path):
+    """The loop a chip forces: the step donates its state (a second copy does
+    not fit), and async saves of that state still finalize and restore."""
+    import numpy as np
+
+    from tpu_resiliency.checkpoint.local_manager import LocalCheckpointManager
+
+    step = jax.jit(
+        lambda s: jax.tree.map(lambda x: x + 1.0, s), donate_argnums=(0,)
+    )
+    state = {"w": jnp.zeros((256, 1024)), "m": jnp.zeros((64, 1024))}
+    mgr = LocalCheckpointManager(str(tmp_path / "ckpt"), rank=0)
+    cb = HierarchicalCheckpointCallback(local_manager=mgr, local_every=2)
+    ctx = run_training(lambda s, i: step(s), state, 7, callbacks=[cb])
+    assert mgr.find_latest() == 6
+    tree, _ = mgr.load_tree(6)
+    np.testing.assert_array_equal(np.asarray(tree["w"]), 6.0)
+    np.testing.assert_array_equal(np.asarray(ctx.state["m"]), 7.0)
+    cb.close()

@@ -330,13 +330,15 @@ class TestWarmupPhase:
     def test_promoted_worker_env_and_sys_path_match_cold_spawn(self, tmp_path):
         """Promotion parity THROUGH the warmup phase: a runtime-warmed spare's
         promoted worker must see byte-identical os.environ and sys.path to a
-        cold `python script.py` with the same round env (modulo the two
-        promotion-marker vars, which exist by design)."""
+        cold `python script.py` that makes the same imports with the same round
+        env (modulo the two promotion-marker vars, which exist by design) —
+        what `import jax` itself writes into the environment included."""
         script = tmp_path / "dump.py"
         script.write_text(
             textwrap.dedent(
                 """
                 import json, os, sys
+                import jax  # what the runtime warmup imported in the spare
                 with open(sys.argv[1], "w") as f:
                     json.dump({"env": dict(os.environ), "path": sys.path}, f)
                 """
@@ -363,6 +365,58 @@ class TestWarmupPhase:
         markers = {PROMOTED_ENV, "TPU_FT_WARM_SPARE_DEPTH"}
         assert {k: v for k, v in warm["env"].items() if k not in markers} == cold["env"]
         assert warm["path"] == cold["path"]
+
+    def test_what_a_preload_writes_into_the_environment_survives_promotion(
+        self, tmp_path, monkeypatch
+    ):
+        """`import jax` on a TPU host writes LIBTPU_INIT_ARGS and more into
+        os.environ; a cold worker's own import does the same. A stand-in module
+        makes that deterministic here: the promoted worker (whose import ran
+        long before its environment was replaced) ends up where the cold one does."""
+        (tmp_path / "envwriter.py").write_text(
+            "import os\n"
+            "os.environ['WRITTEN_BY_IMPORT'] = 'yes'\n"
+            "os.environ['INIT_ARGS'] = os.environ.get('INIT_ARGS', '') + ' --flag'\n"
+        )
+        script = tmp_path / "dump.py"
+        script.write_text(
+            "import json, os, sys, envwriter\n"
+            "json.dump(dict(os.environ), open(sys.argv[1], 'w'))\n"
+        )
+        round_env = dict(os.environ)
+        round_env["PYTHONPATH"] = os.pathsep.join(
+            [str(tmp_path), round_env.get("PYTHONPATH", "")])
+        round_env["INIT_ARGS"] = "--users-own"
+        cold_out, warm_out = tmp_path / "cold.json", tmp_path / "warm.json"
+        subprocess.run([sys.executable, str(script), str(cold_out)],
+                       env=round_env, timeout=60, check=True)
+        for key in ("PYTHONPATH", "INIT_ARGS"):  # the spare inherits the launcher's
+            monkeypatch.setenv(key, round_env[key])
+        spare = spawn_spare(str(tmp_path), 0, preload="envwriter")
+        try:
+            self._wait_warm(spare, timeout=120.0)
+            proc = spare.unpark([str(script), str(warm_out)], round_env)
+            assert proc.wait(timeout=60) == 0
+        finally:
+            spare.kill()
+        cold, warm = json.loads(cold_out.read_text()), json.loads(warm_out.read_text())
+        assert cold["WRITTEN_BY_IMPORT"] == "yes" and cold["INIT_ARGS"] == "--users-own --flag"
+        markers = {PROMOTED_ENV, "TPU_FT_WARM_SPARE_DEPTH"}
+        assert {k: v for k, v in warm.items() if k not in markers} == cold
+
+    def test_round_environ_replaces_but_keeps_what_imports_wrote(self):
+        from tpu_resiliency.launcher.park import _round_environ
+
+        park = {"KEEP": "1", "DROPPED": "x", "FLAGS": "a", "LAUNCHER_CHANGED": "old"}
+        now = {**park, "FLAGS": "a --set-by-import", "WROTE": "jax",
+               "LAUNCHER_CHANGED": "old --set-by-import"}
+        round_env = {"KEEP": "1", "FLAGS": "a", "LAUNCHER_CHANGED": "new", "ROUND": "3"}
+        assert _round_environ(round_env, park, now) == {
+            "KEEP": "1", "ROUND": "3",
+            "FLAGS": "a --set-by-import",  # as a cold worker's import would make it
+            "WROTE": "jax",
+            "LAUNCHER_CHANGED": "new",  # the launcher's newer word wins
+        }  # and DROPPED is gone
 
 
 def test_restart_round_promoted_from_warm_spare(tmp_path):

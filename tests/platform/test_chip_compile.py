@@ -1,0 +1,181 @@
+"""Compile the main path's kernels and programs for a v5e chip that is described,
+not attached: what Mosaic or the TPU compiler would refuse on the chip (a slice off
+the tiling, too much VMEM, a program that does not fit HBM) is refused here, at no
+chip time. Nothing runs, so this says nothing about results or speed.
+
+The topology is described inside a module-scoped fixture and nowhere else: only one
+process may load the TPU's library, the suite runs under several xdist workers, and
+every worker imports this file. All cases stay in THIS file so one worker owns them.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+R, S = 4096, 64  # the north-star telemetry width (BASELINE.json, bench.py)
+HBM_BYTES = 16e9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` sees the CPU here and takes its
+    CPU branch (interpret mode, the XLA sort): steer it from the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def windows(w, sharding):
+    return sds((R, S, w), jnp.float32, sharding), sds((R, S), jnp.int32, sharding)
+
+
+@pytest.mark.parametrize(
+    "mode,window", [("loop", 32), ("loop", 128), ("radix", 256)],
+    ids=["loop-W32", "loop-W128", "radix-W256"],
+)
+def test_median_kernel_compiles_for_v5e(one_chip, mode, window):
+    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
+
+    data, counts = windows(window, one_chip)
+    compiled = fused_median_weights.lower(
+        data, counts, mode=mode, interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_score_program_compiles_for_v5e(one_chip):
+    """bench.py's whole scoring round: the kernel plus the cross-rank scoring math."""
+    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
+    from tpu_resiliency.telemetry import scoring
+
+    def score_program(d, c, e, h):
+        mw = fused_median_weights(d, c, mode="loop", interpret=False)
+        return scoring.score_round(d, c, e, h, medians_and_weights=mw)
+
+    data, counts = windows(32, one_chip)
+    compiled = jax.jit(score_program).lower(
+        data, counts, sds((R,), jnp.float32, one_chip), sds((R, S), jnp.float32, one_chip)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def telemetry_state(mesh, window):
+    from tpu_resiliency.telemetry.sharded import TelemetryState
+
+    rows = NamedSharding(mesh, P("rank"))
+    return TelemetryState(
+        data=sds((window, R, S), jnp.float32, NamedSharding(mesh, P(None, "rank"))),
+        counts=sds((R, S), jnp.int32, rows),
+        cursor=sds((), jnp.int32, NamedSharding(mesh, P())),
+        ewma=sds((R,), jnp.float32, rows),
+        hist_min=sds((R, S), jnp.float32, rows),
+    )
+
+
+def test_ring_push_compiles_for_v5e(topo):
+    from tpu_resiliency.telemetry.sharded import MeshTelemetry
+
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("rank",))
+    mt = MeshTelemetry(mesh, "rank", n_ranks=R, window=32, use_pallas=False,
+                       signal_names=tuple(f"s{j}" for j in range(S)))
+    compiled = mt._push.lower(
+        telemetry_state(mesh, 32), sds((R, S), jnp.float32, NamedSharding(mesh, P("rank")))
+    ).compile()
+    # The donated ring is updated in place: the program holds no second copy.
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 32 * R * S * 4
+
+
+def test_sharded_scorer_compiles_for_four_chips_with_the_kernel(topo, as_on_a_tpu):
+    """4096 ranks over four chips: the kernel runs per shard inside shard_map, the
+    cross-rank reductions are collectives, and auto-selection picks the kernel."""
+    from tpu_resiliency.telemetry.sharded import MeshTelemetry
+
+    mesh = Mesh(np.asarray(topo.devices), ("rank",))
+    mt = MeshTelemetry(mesh, "rank", n_ranks=R, window=32,
+                       signal_names=tuple(f"s{j}" for j in range(S)))
+    assert mt.use_pallas is True  # what use_pallas=None resolves to on a TPU
+    text = mt._score_reset.lower(telemetry_state(mesh, 32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text and "all-reduce" in text
+
+
+def flagship():
+    import chip_smoke
+    from tpu_resiliency.models import transformer as tfm
+
+    cfg = chip_smoke.model_config(tiny=False)
+    train_step, init_opt = tfm.make_train_step(cfg)
+    params = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    opt = jax.eval_shape(init_opt, params)
+    size = chip_smoke.SIZES["full"]
+    return cfg, train_step, init_opt, params, opt, (size["batch"], size["seq"])
+
+
+def placed(tree, shardings):
+    return jax.tree.map(lambda x, s: sds(x.shape, x.dtype, s), tree, shardings)
+
+
+def test_flagship_train_step_fits_one_chip(one_chip):
+    """8L x 1024d, batch 8 x seq 1024, AdamW, donated: it fits alone, and not
+    beside a second copy of its 1.9 GB state."""
+    _, train_step, _, params, opt, batch = flagship()
+    on_chip = lambda tree: placed(tree, jax.tree.map(lambda _: one_chip, tree))  # noqa: E731
+    compiled = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt), sds(batch, jnp.int32, one_chip)
+    ).compile()
+    mem = compiled.memory_analysis()
+    state = mem.argument_size_in_bytes
+    assert 1.8e9 < state < 2.0e9
+    assert state + mem.temp_size_in_bytes < HBM_BYTES
+    assert 2 * state + mem.temp_size_in_bytes > HBM_BYTES
+
+
+def test_flagship_train_step_compiles_for_the_dp_tp_mesh(topo):
+    """What ``chip_smoke.py --chips 4`` runs: dp=2 x tp=2 on the 2x2 host."""
+    from tpu_resiliency.parallel import mesh as pmesh
+
+    cfg, train_step, init_opt, params, opt, batch = flagship()
+    mesh = pmesh.build_mesh(devices=topo.devices, **pmesh.default_split(4))
+    pshard = pmesh.tree_shardings(mesh, pmesh.param_specs(cfg))
+    oshard = pmesh.opt_state_shardings(init_opt, params, pshard)
+    compiled = jax.jit(
+        train_step, donate_argnums=(0, 1), out_shardings=(pshard, oshard, None)
+    ).lower(
+        placed(params, pshard), placed(opt, oshard),
+        sds(batch, jnp.int32, NamedSharding(mesh, pmesh.batch_spec())),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES  # per chip
+    assert "all-reduce" in compiled.as_text()

@@ -15,7 +15,7 @@ from tpu_resiliency.utils.metrics import MetricsRegistry, observe_record
 JIT_SNIPPET = """
 import json, os, sys, time
 from tpu_resiliency.platform import device
-device.apply_platform_env()
+device.apply_compile_cache_env()
 import jax, jax.numpy as jnp
 t0 = time.monotonic()
 f = jax.jit(lambda x: jnp.tanh(x @ x.T).sum())
@@ -140,3 +140,76 @@ def test_outcome_classification():
     assert compile_cache.outcome_of({"entries": 0, "purged": 0}) == "miss"
     assert compile_cache.outcome_of({"entries": 3, "purged": 0}) == "hit"
     assert compile_cache.outcome_of({"entries": 3, "purged": 1}) == "miss_corrupt"
+
+
+# -- the placement rule: $JAX_COMPILATION_CACHE_DIR, set from outside, wins ----
+
+LAUNCHED_WORKER = """
+import json, os, sys
+from tpu_resiliency.platform import device
+device.apply_compile_cache_env()
+import jax, jax.numpy as jnp
+jax.block_until_ready(jax.jit(lambda x: jnp.tanh(x @ x.T).sum())(jnp.ones((16, 16))))
+with open(sys.argv[1], "w") as fh:
+    json.dump({"env": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+               "jax": jax.config.jax_compilation_cache_dir}, fh)
+"""
+
+
+@pytest.mark.parametrize("outside_set", [True, False], ids=["outside-wins", "flag-fills"])
+def test_launcher_and_worker_follow_the_one_variable(tmp_path, outside_set):
+    """Set outside: launcher and worker keep the cache there and the flag's
+    directory is never created. Unset: the flag supplies the variable."""
+    worker = tmp_path / "worker.py"
+    worker.write_text(LAUNCHED_WORKER)
+    outside, flag = tmp_path / "outside", tmp_path / "flag"
+    events, out = tmp_path / "events.jsonl", tmp_path / "out.json"
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["TPU_RESILIENCY_LOG_LEVEL"] = "INFO"
+    if outside_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(outside)
+    r = subprocess.run(
+        [
+            sys.executable, "-m", "tpu_resiliency.launcher.launch",
+            "--standalone", "--nproc-per-node", "1", "--max-restarts", "0",
+            "--no-ft-monitors", "--events-file", str(events),
+            "--compile-cache-dir", str(flag),
+            "--run-dir", str(tmp_path / "run"), str(worker), str(out),
+        ],
+        env=env, capture_output=True, text=True, timeout=240, cwd=str(tmp_path),
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    used, unused = (outside, flag) if outside_set else (flag, outside)
+    got = json.loads(out.read_text())
+    assert got == {"env": str(used), "jax": str(used)}
+    cc = [
+        json.loads(ln) for ln in events.read_text().splitlines()
+        if '"compile_cache"' in ln
+    ]
+    assert [e["dir"] for e in cc] == [str(used)], cc
+    assert _entries(used) and not unused.exists()
+    assert ("is set outside and wins" in r.stderr) == outside_set
+
+
+def test_unset_entry_programs_share_one_fixed_checkout_path(tmp_path):
+    """Two processes derive the same in-checkout directory, it is git-ignored,
+    and the library itself leaves caching off while the variable is unset."""
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    snippet = (
+        "from tpu_resiliency.platform import compile_cache as cc;"
+        f"print(cc.checkout_cache_dir({repo!r}));"
+        "print(cc.apply_from_env())"
+    )
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    outs = [
+        subprocess.run(
+            [sys.executable, "-c", snippet], env=env, capture_output=True,
+            text=True, timeout=60, cwd=str(tmp_path), check=True,
+        ).stdout.split()
+        for _ in range(2)
+    ]
+    assert outs[0] == outs[1] == [os.path.join(repo, ".jax_cache"), "None"]
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -3,17 +3,27 @@ the capture-window contract (start/stop/drain/get_stats/reset), and the Detector
 integration that turns program times into scored ``prog/...`` signals."""
 
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpu_resiliency.telemetry.detector import Detector
 from tpu_resiliency.telemetry.device_profiler import (
     DeviceTimeProfiler,
+    NoDevicePlane,
+    extract_op_times,
     extract_program_times,
     normalize_program_name,
+    trace_source,
 )
+
+#: one profiler window recorded on a v5e chip (jax 0.9.0, libtpu 0.0.34, PR 21):
+#: five ``_push_impl`` executions and one ``_score_reset_impl`` with the Pallas
+#: median kernel inside — the plane and line names the extraction depends on
+V5E_TRACE = os.path.join(os.path.dirname(__file__), "data", "v5e_window.xplane.pb")
 
 
 # --- xplane extraction on a stub object graph (device-plane case) -------------
@@ -64,6 +74,7 @@ def test_extract_prefers_device_plane():
     times = extract_program_times(pd)
     assert set(times) == {"jit_train_step", "jit_eval"}  # host fallback NOT mixed in
     np.testing.assert_allclose(times["jit_train_step"], [1.5e-3, 1.6e-3])
+    assert trace_source(pd) == "device"
 
 
 def test_extract_falls_back_to_host_pjit_events():
@@ -78,6 +89,38 @@ def test_extract_falls_back_to_host_pjit_events():
     times = extract_program_times(pd)
     assert set(times) == {"pjit_step"}
     np.testing.assert_allclose(times["pjit_step"], [2e-3])
+    assert trace_source(pd) == "host"
+
+
+@pytest.mark.parametrize("extract", [extract_program_times, extract_op_times])
+def test_no_device_plane_is_an_error_where_a_device_is_required(extract):
+    """What a TPU backend asks for: host events never stand in for device times."""
+    pd = _PD(
+        planes=[
+            _Plane("/host:CPU", [_Line("python", [_Ev("PjitFunction(step)", 2e6)])]),
+            _Plane("/device:CUSTOM:Megascale Trace", [_Line("XLA Modules", [])]),
+        ]
+    )
+    with pytest.raises(NoDevicePlane, match="/host:CPU"):
+        extract(pd, require_device=True)
+
+
+def test_recorded_v5e_trace_reads_device_planes():
+    """The real thing, not a stub: today's profiler names the chip's plane
+    ``/device:TPU:0`` and its lines ``XLA Modules`` / ``XLA Ops``."""
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(V5E_TRACE)
+    assert trace_source(pd) == "device"
+    times = extract_program_times(pd, require_device=True)
+    assert {k: len(v) for k, v in times.items()} == {
+        "jit__push_impl": 5, "jit__score_reset_impl": 1,
+    }
+    assert 1e-6 < min(times["jit__push_impl"]) < 1e-5
+    ops = extract_op_times(pd, require_device=True)
+    # XLA Ops events are named by whole HLO instructions and carry no tf_op.
+    assert set(ops) == {"add", "copy", "fused_median_weights"}
+    assert ops["fused_median_weights"][0] < times["jit__score_reset_impl"][0]
 
 
 def test_normalize_strips_fingerprint():
@@ -100,6 +143,7 @@ def test_capture_window_end_to_end(tmp_path):
         for _ in range(3):
             jax.block_until_ready(work(x))
 
+    assert (prof.source, prof.windows) == ("host", 1)  # the CPU backend's answer
     fresh = prof.drain()
     assert fresh, "no program samples captured"
     name = next(iter(fresh))
@@ -114,6 +158,27 @@ def test_capture_window_end_to_end(tmp_path):
     prof.reset()
     assert prof.get_stats() == {}
     # The window's trace dir is cleaned up.
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_window_faults_raise_instead_of_vanishing(tmp_path, monkeypatch):
+    """A window that cannot start, and a TPU-backend window without a device
+    plane, are errors a caller sees — and neither leaks a trace or a dir."""
+    prof = DeviceTimeProfiler(trace_root=str(tmp_path))
+    other = DeviceTimeProfiler(trace_root=str(tmp_path))
+    prof.start()
+    try:
+        with pytest.raises(Exception):
+            other.start()  # the process-global profiler is taken
+        assert not other.active
+    finally:
+        prof.stop()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prof.start()
+    jax.block_until_ready(jnp.ones((8,)) + 1)
+    with pytest.raises(NoDevicePlane):
+        prof.stop()  # a CPU trace under a backend that calls itself a TPU
+    assert not prof.active and prof.windows == 1
     assert list(tmp_path.iterdir()) == []
 
 
@@ -155,14 +220,22 @@ def test_op_scope_key_mapping():
     # hlo_op fallback (CPU client line events).
     assert op_scope_key("dot_general.2", {"hlo_op": "dot_general.2"}) == "dot_general"
     assert op_scope_key("wrapped_tanh", {}) == "wrapped_tanh"
+    # v5e "XLA Ops" events: the whole HLO instruction is the name, no stats.
+    assert (
+        op_scope_key(
+            "%fused_median_weights.1 = (f32[1024,64]{1,0:T(8,128)S(1)}, "
+            "f32[1024,64]{1,0:T(8,128)S(1)}) custom-call(f32[1024,64,32] %copy)",
+            {"device_duration_ps": 999151250},
+        )
+        == "fused_median_weights"
+    )
+    assert op_scope_key("%copy = f32[8]{0} copy(f32[8]{0} %d.1)", {}) == "copy"
     # Bookkeeping events are dropped.
     assert op_scope_key("end: dot_general.2", {}) is None
     assert op_scope_key("ThreadpoolListener::StartRegion", {}) is None
 
 
 def test_extract_op_times_prefers_device_ops_line():
-    from tpu_resiliency.telemetry.device_profiler import extract_op_times
-
     @dataclasses.dataclass
     class _EvS:
         name: str

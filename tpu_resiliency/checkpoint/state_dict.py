@@ -19,11 +19,14 @@ layout after a restart.
 from __future__ import annotations
 
 import dataclasses
+import threading
+import time
 from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
 
 from tpu_resiliency.exceptions import CheckpointError
+from tpu_resiliency.utils.events import record as record_event
 
 
 @dataclasses.dataclass
@@ -83,8 +86,10 @@ class HostSnapshot:
     blocks only until leaf ``i``'s transfer lands (the analogue of the
     reference's per-tensor pinned-memory D2H events), stages it into the
     pooled lease when one is attached, and drops the device reference so
-    device memory frees as the pipeline advances. Single-consumer: the
-    background writer resolves leaves in order; no internal locking.
+    device memory frees as the pipeline advances. The background writer
+    resolves leaves in order; :meth:`detach` lets the train loop pull whatever
+    is still on the device before a step that donates those arrays, so
+    ``resolve`` is serialized by a lock.
     """
 
     def __init__(self, tensors: Sequence[Any], pool: Any = None):
@@ -99,6 +104,7 @@ class HostSnapshot:
         self._lease = None
         self._released = False
         self._resolved: list[Optional[np.ndarray]] = [None] * len(self._tensors)
+        self._lock = threading.Lock()
 
     def __len__(self) -> int:
         return len(self._resolved)
@@ -110,17 +116,29 @@ class HostSnapshot:
 
     def resolve(self, i: int) -> np.ndarray:
         """Materialize leaf ``i`` on host (blocking only on ITS transfer)."""
-        out = self._resolved[i]
-        if out is None:
-            t = self._tensors[i]
-            lease = self._ensure_lease()
-            if lease is not None:
-                out = lease.fill(i, t)
-            else:
-                out = np.asarray(t)
-            self._resolved[i] = out
-            self._tensors[i] = None
-        return out
+        with self._lock:
+            out = self._resolved[i]
+            if out is None:
+                t = self._tensors[i]
+                lease = self._ensure_lease()
+                if lease is not None:
+                    out = lease.fill(i, t)
+                else:
+                    out = np.asarray(t)
+                self._resolved[i] = out
+                self._tensors[i] = None
+            return out
+
+    def detach(self) -> int:
+        """Resolve every leaf that still lives only on the device; returns how
+        many there were. After it the snapshot holds no device array: a jitted
+        step may donate (delete) the arrays this save was handed without
+        failing it. Their transfers were enqueued with the save, so this waits
+        for DMAs already in flight, not for the writer."""
+        pending = [i for i, t in enumerate(self._tensors) if t is not None]
+        for i in pending:
+            self.resolve(i)
+        return len(pending)
 
     def resolve_view(self, i: int) -> memoryview:
         """Leaf ``i`` as the flat uint8 window writers and senders consume."""
@@ -164,6 +182,7 @@ class PyTreeStateDict:
         self._hollow = False
         self._tensors: Optional[list] = None
         self._shardings: Optional[list] = None
+        self._snapshots: list[HostSnapshot] = []
 
     @classmethod
     def from_hollow(
@@ -181,6 +200,7 @@ class PyTreeStateDict:
         sd._hollow = True
         sd._tensors = list(tensors)
         sd._shardings = None
+        sd._snapshots = []
         sd.restore_tensor_device(shardings=shardings, device=device)
         sd.insert_tensors(sd._tensors)
         return sd
@@ -296,7 +316,13 @@ class PyTreeStateDict:
         allocate nothing large; the lease is acquired lazily at first resolve
         (on the background thread) and the snapshot owns it — ``release()``
         when the background half is done. ``self`` keeps its device tensors
-        untouched (shardings are recorded for a later restore)."""
+        untouched (shardings are recorded for a later restore).
+
+        Until a leaf resolves, the snapshot needs its device array alive. A
+        train step that DONATES the saved state deletes those arrays under the
+        background writer ("Array has been deleted", on the CPU and on a v5e
+        alike — chip run, PR 21): call :meth:`detach_device` before such a
+        step (``HierarchicalCheckpointCallback`` does, every step start)."""
         if self._tensors is None:
             raise CheckpointError("pop_tensors() before copy_tensors_to_host_async()")
         self._shardings = [getattr(t, "sharding", None) for t in self._tensors]
@@ -309,7 +335,25 @@ class PyTreeStateDict:
                     # Enqueue is an optimization; resolve() still blocks
                     # correctly on backends without the async entry point.
                     pass
-        return HostSnapshot(self._tensors, pool=pool)
+        snapshot = HostSnapshot(self._tensors, pool=pool)
+        self._snapshots.append(snapshot)
+        return snapshot
+
+    def detach_device(self) -> int:
+        """Make every async snapshot taken from this state dict independent of
+        the device arrays (:meth:`HostSnapshot.detach`); returns the number of
+        leaves that had to be pulled. The wait is a train-loop stall caused by
+        the save, so it is recorded as ``ckpt_foreground_blocked``."""
+        t0 = time.perf_counter()
+        pulled = sum(s.detach() for s in self._snapshots)
+        self._snapshots.clear()
+        if pulled:
+            record_event(
+                "checkpoint", "ckpt_foreground_blocked",
+                duration_s=time.perf_counter() - t0, engine="detach",
+                leaves=pulled,
+            )
+        return pulled
 
     def _align_shardings_pytree(self, shardings) -> list:
         """Flatten a shardings pytree that mirrors the saved tree's structure into a

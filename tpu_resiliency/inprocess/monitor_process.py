@@ -85,16 +85,23 @@ class MonitorProcess:
             try:
                 parent_sock.close()
                 os.setsid()
+                # Drop every other inherited fd — most critically rank 0's
+                # KVServer listening socket: holding it would keep the store
+                # port bound (EADDRINUSE on relaunch) and park peers'
+                # reconnects in a dead socket's backlog after the rank dies.
+                # It is also what keeps this daemon off the chip when the rank
+                # already held one at fork time: on a v5e a forked child that
+                # kept the inherited fds kept /dev/vfio/0 busy after its parent
+                # was SIGKILLed, and one that closed them did not (chip run,
+                # PR 21). Done HERE, before the second fork: this intermediate
+                # child takes a while to exit out of a rank's large address
+                # space, and was seen holding the chip's fds meanwhile.
+                _close_fds_except({child_sock.fileno(), 0, 1, 2})
                 second = os.fork()
                 if second == 0:
                     try:
                         _detach_stdio(self.cfg.log_file)
-                        # Drop every other inherited fd — most critically rank 0's
-                        # KVServer listening socket: holding it would keep the store
-                        # port bound (EADDRINUSE on relaunch) and park peers'
-                        # reconnects in a dead socket's backlog after the rank dies.
-                        _close_fds_except({child_sock.fileno(), 0, 1, 2})
-                        _monitor_loop(self.cfg, child_sock, main_pid)
+                        _monitor_loop(self.cfg, child_sock, main_pid)  # never imports jax
                     finally:
                         os._exit(0)
             finally:

@@ -52,6 +52,7 @@ from tpu_resiliency.inprocess.rank_assignment import (
     ShiftRanks,
 )
 from tpu_resiliency.inprocess.state import Mode, State
+from tpu_resiliency.platform.shardstore import connect_store
 from tpu_resiliency.platform.store import host_store, store_addr_from_env
 from tpu_resiliency.utils import flight_recorder, location
 from tpu_resiliency.utils.events import record as record_event
@@ -174,8 +175,6 @@ class CallWrapper:
             # Factory, not the constructor: under a launcher-hosted store
             # CLIQUE ($TPU_RESILIENCY_STORE_SHARDS) every key must route
             # through the same shard map the launcher's clients use.
-            from tpu_resiliency.platform.shardstore import connect_store
-
             self.store = connect_store(host, port, prefix=prefix)
             self.server = None
         else:
@@ -195,6 +194,16 @@ class CallWrapper:
         )
         self._store_prefix = prefix
         self.coord = RestartCoordinator(self.store, self.state.world_size)
+        # The monitor thread parks in a long-poll, one ``monitor_interval`` per
+        # get, back to back. On the shared client that poll holds the socket
+        # lock nearly all the time, and every coordination call of the main
+        # thread queued behind it for up to an interval (at the default 1 s a
+        # one-rank restart + completion took 5-6 s idle and two minutes under
+        # load). So the monitor has a connection of its own.
+        self._monitor_store = connect_store(*self._store_addr, prefix=prefix)
+        self._monitor_coord = RestartCoordinator(
+            self._monitor_store, self.state.world_size
+        )
 
         self.monitor_process: Optional[MonitorProcess] = None
         if wrapper.enable_monitor_process:
@@ -343,6 +352,7 @@ class CallWrapper:
         self.watchdog.shutdown()
         if self.monitor_process is not None:
             self.monitor_process.shutdown()
+        self._monitor_store.close()
         self.store.close()
         if self.server is not None:
             # All ranks are past the completion barrier. The server lingers briefly
@@ -361,8 +371,6 @@ class CallWrapper:
         declared dead during a completion round; stand down). ``False`` — server
         reachable, job not done (transient hiccup). ``None`` — coordinator
         unreachable (genuinely lost; surface loudly)."""
-        from tpu_resiliency.platform.shardstore import connect_store
-
         host, port = self._store_addr
         try:
             probe = connect_store(
@@ -391,6 +399,7 @@ class CallWrapper:
         self.watchdog.shutdown()
         if self.monitor_process is not None:
             self.monitor_process.shutdown()
+        self._monitor_store.close()
         self.store.close()
         if self.server is not None:
             self.server.close()
@@ -510,7 +519,7 @@ class CallWrapper:
                 (lambda: self._chain(w.abort, state.freeze())) if w.abort else None
             )
             monitor = MonitorThread(
-                coord,
+                self._monitor_coord,
                 iteration,
                 threading.main_thread().ident,
                 self._atomic_lock,

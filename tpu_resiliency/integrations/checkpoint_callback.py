@@ -58,6 +58,9 @@ class HierarchicalCheckpointCallback(Callback):
         )
         self.rank = rank
         self.driven_by_loop = driven_by_loop
+        #: the last save's state dict while its async snapshots may still need
+        #: the device arrays (see on_step_start)
+        self._saving: Optional[PyTreeStateDict] = None
 
     def rebuild_group(self, comm, remirror: bool = True) -> None:
         """After a restart round changed the active world: adopt the new rank
@@ -106,6 +109,16 @@ class HierarchicalCheckpointCallback(Callback):
         if global_due:
             path = os.path.join(self.global_dir, f"step_{step:08d}")
             self.global_ckpt.async_save(sd, path, rank=self.rank)
+        self._saving = sd
+
+    def on_step_start(self, ctx: LoopContext) -> None:
+        # The step about to run may donate ctx.state — with the state filling
+        # the chip it has to — and a donated array is deleted under an async
+        # save that has not yet copied it out. Pull what is still on the device
+        # first; the write, the CRCs and the replication stay in the background.
+        if self._saving is not None:
+            self._saving.detach_device()
+            self._saving = None
 
     def on_step_end(self, ctx: LoopContext) -> None:
         if not self.driven_by_loop:

@@ -62,10 +62,13 @@ class StragglerDetectionCallback(Callback):
         ``use_device_mesh``: route report rounds through the mesh-sharded scoring
         path (:class:`~tpu_resiliency.telemetry.sharded.MeshTelemetry`) instead of
         the per-rank store gather. Requires one JAX process per rank
-        (``jax.process_count() == world_size``, i.e. each worker called
-        ``jax.distributed.initialize``); outside that configuration the callback
-        logs once and falls back to the store path. ``mesh_signal_capacity`` caps
-        the number of distinct timed signals the compiled scorer carries."""
+        (``jax.process_count() == world_size``: each worker of a multi-rank job
+        called ``jax.distributed.initialize``; a one-worker job qualifies as it
+        is); outside that configuration the callback logs once and falls back to
+        the store path. ``mesh_signal_capacity`` caps the number of distinct
+        timed signals the compiled scorer carries. Which path scored a report is
+        its ``source`` (``Report.source``), also on the ``straggler_report``
+        event beside the profiler windows' own ``profile_source``."""
         self.threshold = threshold
         self.stop_if_detected = stop_if_detected
         self.export_metrics = export_metrics
@@ -76,6 +79,10 @@ class StragglerDetectionCallback(Callback):
         self.profile_programs_every = profile_programs_every
         self.profile_ops = profile_ops
         self._program_profiler = None
+        #: profiler windows that could not start / could not be parsed: a
+        #: profiling fault never breaks a step, and never goes uncounted
+        self.profile_skipped = 0
+        self.profile_dropped = 0
         self._step_count = 0
         self._init_kwargs = dict(
             scores_to_compute=(
@@ -100,7 +107,7 @@ class StragglerDetectionCallback(Callback):
 
         from tpu_resiliency.telemetry.sharded import MeshTelemetry
 
-        if ctx.world_size <= 1 or jax.process_count() != ctx.world_size:
+        if jax.process_count() != ctx.world_size:
             log.info(
                 "use_device_mesh requested but job is not one-JAX-process-per-rank "
                 f"(process_count={jax.process_count()}, world={ctx.world_size}); "
@@ -139,7 +146,12 @@ class StragglerDetectionCallback(Callback):
                     collect_ops=self.profile_ops
                 )
             if self._step_count % self.profile_programs_every == 0:
-                self._program_profiler.start()
+                try:
+                    self._program_profiler.start()
+                except Exception:
+                    # e.g. the process-global profiler is taken by user tracing
+                    self.profile_skipped += 1
+                    log.warning("profiler window skipped", exc_info=True)
         self._section = Detector.detection_section(self.section_name)
         self._section.__enter__()
 
@@ -149,17 +161,25 @@ class StragglerDetectionCallback(Callback):
             self._section = None
         self._step_count += 1
         if self._program_profiler is not None and self._program_profiler.active:
-            self._program_profiler.stop()
-            Detector.record_program_samples(self._program_profiler.drain())
-            if self.profile_ops:
-                Detector.record_op_samples(self._program_profiler.drain_ops())
+            if self._close_profiler_window():
+                Detector.record_program_samples(self._program_profiler.drain())
+                if self.profile_ops:
+                    Detector.record_op_samples(self._program_profiler.drain_ops())
         report = Detector.generate_report_if_interval_elapsed()
         if report is not None:
             self._handle_report(ctx, report)
 
-    def _close_profiler_window(self) -> None:
+    def _close_profiler_window(self) -> bool:
+        """Stop an open window; False when its trace had to be dropped (no
+        device plane on a TPU backend, unparseable, no trace written)."""
         if self._program_profiler is not None and self._program_profiler.active:
-            self._program_profiler.stop()
+            try:
+                self._program_profiler.stop()
+            except Exception:
+                self.profile_dropped += 1
+                log.warning("profiler window dropped", exc_info=True)
+                return False
+        return True
 
     def on_exception(self, ctx: LoopContext, exc: BaseException) -> None:
         # A step that dies mid-window must not leak the process-global JAX trace:
@@ -215,6 +235,20 @@ class StragglerDetectionCallback(Callback):
                 name: sorted(s.rank for s in ids)
                 for name, ids in stragglers.by_section.items()
             },
+            # Which path scored it and what it scored: a job that asked for
+            # the mesh path, or for device-plane program times, reads here
+            # whether it got them.
+            report_source=report.source,
+            signals=list(report.section_names),
+            **(
+                {
+                    "profile_source": self._program_profiler.source,
+                    "profile_windows": self._program_profiler.windows,
+                    "profile_skipped": self.profile_skipped,
+                    "profile_dropped": self.profile_dropped,
+                }
+                if self._program_profiler is not None else {}
+            ),
         )
         if self.health_policy is not None:
             self.health_policy.observe(report)

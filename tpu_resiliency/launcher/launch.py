@@ -158,11 +158,12 @@ def build_parser() -> argparse.ArgumentParser:
         "--compile-cache-dir",
         default=None,
         help="persistent XLA compilation cache shared across restart rounds "
-        "(exports $JAX_COMPILATION_CACHE_DIR + "
-        "$TPU_RESILIENCY_COMPILE_CACHE_DIR to workers): a respawned worker's "
-        "first step loads the previous round's executables instead of "
-        "re-tracing/re-compiling; corrupt entries are swept to a cold "
-        "compile, never a crash",
+        "(exported to workers as $JAX_COMPILATION_CACHE_DIR): a respawned "
+        "worker's first step loads the previous round's executables instead "
+        "of re-tracing/re-compiling; corrupt entries are swept to a cold "
+        "compile, never a crash. Where the environment already sets "
+        "$JAX_COMPILATION_CACHE_DIR, that outside setting wins and this "
+        "flag only logs so",
     )
     p.add_argument(
         "--no-rdzv-fast-path",
@@ -576,17 +577,25 @@ def main(argv: Optional[list[str]] = None) -> int:
             os.environ[COLD_KEEP_ENV] = str(args.cold_keep)
     elif args.cold_keep is not None:
         log.warning("--cold-keep has no effect without --cold-dir")
-    if args.compile_cache_dir:
-        from tpu_resiliency.platform import compile_cache
+    from tpu_resiliency.platform import compile_cache
 
-        cache_dir = os.path.abspath(args.compile_cache_dir)
-        # Both exports on purpose: TPU_RESILIENCY_* drives this package's
-        # integrity sweep + compile_cache event in workers that import it;
-        # JAX_COMPILATION_CACHE_DIR makes plain-JAX workers (no tpu_resiliency
-        # import) cache too. Sweep HERE, before any worker starts, so a cache
-        # corrupted between jobs is purged exactly once up front.
-        os.environ[compile_cache.CACHE_DIR_ENV] = cache_dir
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    # One rule (platform/compile_cache.py): $JAX_COMPILATION_CACHE_DIR places
+    # the cache. The flag only supplies a value where the environment has none.
+    outside = os.environ.get(compile_cache.CACHE_DIR_ENV, "")
+    if args.compile_cache_dir and outside:
+        log.info(
+            f"--compile-cache-dir {args.compile_cache_dir} ignored: "
+            f"${compile_cache.CACHE_DIR_ENV}={outside} is set outside and wins"
+        )
+    elif args.compile_cache_dir:
+        os.environ[compile_cache.CACHE_DIR_ENV] = os.path.abspath(
+            args.compile_cache_dir
+        )
+    cache_dir = os.environ.get(compile_cache.CACHE_DIR_ENV, "")
+    if cache_dir:
+        # Sweep HERE, before any worker starts, so a cache corrupted between
+        # jobs is purged exactly once up front. Workers (and plain-JAX ones
+        # that never import this package) read the same variable.
         os.makedirs(cache_dir, exist_ok=True)
         swept = compile_cache.sweep(cache_dir)
         if swept.get("purged"):

@@ -43,10 +43,19 @@ not present at park time into ``sys.path``. The warmup phase is bound by the
 same contract: it must not mutate ``os.environ`` or ``sys.path``, and a
 warmup that raises kills the spare *before* its ready file exists, so the
 pool counts it as a startup death (doomed warmups disable the pool instead of
-respawning forever). One caveat remains by design: an env var that a
+respawning forever). What a *preloaded import* itself wrote into ``os.environ``
+is part of that parity too, because a cold worker's own import writes the same:
+``import jax`` on a TPU host sets ``LIBTPU_INIT_ARGS`` (a runtime flag),
+``TPU_ML_PLATFORM`` and others. Those survive the replacement wherever the
+launcher has not changed the variable since the park (:func:`_round_environ`);
+wiped, the promoted worker started its TPU runtime without the flag and
+computed compile-cache keys no cold worker shares, so round 1 compiled the
+whole step again beside a warm cache (chip run, PR 21). One caveat remains by
+design: an env var that a
 *preloaded* module reads at import time must already be present in the
-launcher's environment (true for ``JAX_PLATFORMS`` workflows here: workers
-re-select platforms at runtime via ``platform.device.apply_platform_env``).
+launcher's environment (true for ``JAX_PLATFORMS`` and
+``JAX_COMPILATION_CACHE_DIR``: the launcher exports both before it parks a
+spare, and jax reads them when it is imported).
 
 Pool discipline (the restart hot path): ``acquire()`` only *selects* — it
 reaps the dead, prefers the deepest-warmed spare, and never spawns. Top-up is
@@ -99,12 +108,24 @@ def _run_warmup(spec: str) -> None:
     fn()
 
 
-def _apply_spec_and_run(spec: dict) -> None:
-    # Replace — not merge — the environment: a var the launcher dropped since
-    # the spare was parked must not survive into the worker (cold workers get
-    # Popen(env=...) replacement semantics; promoted workers must match).
+def _round_environ(round_env: dict, park_env: dict, now_env: dict) -> dict:
+    """The environment a COLD worker would have after the same imports: the
+    round env (replace, not merge — a var the launcher dropped since the park
+    must not survive), plus what this interpreter's preloads wrote since it
+    started (``now_env`` against ``park_env``), for every variable the launcher
+    left as it was at park time — there the import's result is what a cold
+    worker's import would compute again."""
+    env = dict(round_env)
+    for key, value in now_env.items():
+        if park_env.get(key) != value and round_env.get(key) == park_env.get(key):
+            env[key] = value
+    return env
+
+
+def _apply_spec_and_run(spec: dict, park_env: dict) -> None:
+    env = _round_environ(spec.get("env", {}), park_env, dict(os.environ))
     os.environ.clear()
-    os.environ.update(spec.get("env", {}))
+    os.environ.update(env)
     for stream_name, fd in (("stdout", 1), ("stderr", 2)):
         path = spec.get(stream_name)
         if path:
@@ -157,6 +178,7 @@ def _serve_parked(go_fd: int, ready_file: str, preload: str, warmup: str) -> Non
     """Import the expensive modules, run the optional warmup phase, announce
     readiness (with the achieved park depth), then block on the launcher's
     pipe until a round spec arrives (or EOF: launcher gone)."""
+    park_env = dict(os.environ)  # before any preload can write to it
     for mod in filter(None, preload.split(",")):
         __import__(mod)
     depth = 1
@@ -172,7 +194,7 @@ def _serve_parked(go_fd: int, ready_file: str, preload: str, warmup: str) -> Non
         line = go.readline()  # blocks; zero CPU while parked
     if not line.strip():
         sys.exit(0)  # EOF/blank: the launcher is gone or released us
-    _apply_spec_and_run(json.loads(line))
+    _apply_spec_and_run(json.loads(line), park_env)
 
 
 def main(argv: Optional[list[str]] = None) -> int:

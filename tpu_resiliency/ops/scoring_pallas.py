@@ -17,16 +17,16 @@ The downstream scoring math (cross-rank min, weighted perf score, robust-z, EWMA
 plain ``jnp`` in ``telemetry/scoring.py`` — it is O(R·S) and XLA fuses it into a couple
 of reductions.
 
-Measured on v5e-1 (4096×64×32) by **on-device program duration** (the only trustworthy
-methodology here — BASELINE.md "measurement-integrity note"): this kernel's scoring
-round runs in **4.31 ms vs 8.43 ms** for XLA's sort-based ``masked_median`` lowering —
-a 2.0× win, identical F1. It is therefore the **default window reduction on TPU** for
-the mesh scoring path (``MeshTelemetry(use_pallas=None)`` auto-selects by backend and
-shape via :func:`pallas_supported`); non-TPU backends use the XLA lowering. Earlier
-rounds' conclusions ("loses 100×", then "parity") were wall-clock measurement
-artifacts. Rank-counting is O(W²), so auto-selection caps it at the measured
-window crossover and switches to the O(32·W) radix-select kernel beyond it
-(``auto_mode``); ``scripts/bench_pallas_sweep.py`` measures all three variants.
+Measured on one v5e chip (4096×64×32) by **on-device program duration**: a 2026-07-31
+capture read this kernel's scoring round at 4.31 ms against 8.43 ms for XLA's
+sort-based ``masked_median`` lowering, identical F1, and PR 21's chip run read 4.309 ms
+for the same round on today's jax 0.9 / libtpu 0.0.34 (the XLA side was not measured
+again). It is therefore the **default window reduction on TPU** for the mesh scoring
+path (``MeshTelemetry(use_pallas=None)`` auto-selects by backend and shape via
+:func:`pallas_supported`); non-TPU backends use the XLA lowering. Rank-counting is
+O(W²), so auto-selection caps it at a window crossover and switches to the O(32·W)
+radix-select kernel beyond it (``auto_mode``); ``scripts/bench_pallas_sweep.py``
+measures all three variants.
 """
 
 from __future__ import annotations
@@ -165,35 +165,33 @@ def _median_weights_radix_kernel(data_ref, counts_ref, med_ref, weight_ref):
 
 #: Largest window the O(W²) kernels (loop / pairwise) are auto-selected for;
 #: beyond it auto-selection switches to the radix kernel (O(32·W), no cap)
-#: instead of falling back to the XLA sort. MEASURED on v5e
-#: (``BENCH_pallas_sweep.json``, device-true, W∈{32..256} × R∈{256..4096}):
-#: the loop kernel beats both the XLA sort and the radix kernel at every
-#: tested R for W≤128 (up to 2.0×; the only counter-reads are ≤0.8%
-#: small-R ties at W=64, within noise, against a 25% loop win at R=4096),
-#: and loses hard at W=256 (XLA 2.2–3.7× faster) — so the measured cap
-#: is 128. Operators re-derive it per device via
-#: ``scripts/bench_pallas_sweep.py`` → ``$TPU_RESILIENCY_PALLAS_MAX_WINDOW``.
+#: instead of falling back to the XLA sort. The value comes from a sweep on
+#: one v5e chip on 2026-07-31 (W∈{32..256} × R∈{256..4096}, an older jax and
+#: compiler; its record is gone): the loop kernel beat both the XLA sort and
+#: the radix kernel at every tested R for W≤128 and lost at W=256. Not measured
+#: on today's installation — ROADMAP queues that. Operators re-derive it per
+#: device via ``scripts/bench_pallas_sweep.py`` →
+#: ``$TPU_RESILIENCY_PALLAS_MAX_WINDOW``.
 DEFAULT_MAX_WINDOW = 128
 MAX_WINDOW_ENV = "TPU_RESILIENCY_PALLAS_MAX_WINDOW"
 
 #: Opt-in for AUTO-selecting the radix kernel past the loop cap (explicit
-#: ``mode="radix"`` always works). Default off, now on measurement rather
-#: than absence of it (``BENCH_pallas_sweep.json``): radix's pass cost is
-#: flat in W but loses to the loop kernel at every W≤128 (where the loop is
-#: auto-selected anyway) and to the XLA sort at W=128 (19.7 vs 18.0 ms at
-#: R=4096); at W=256 — the one regime it could win (projected ~20 vs
-#: 22.8 ms) — it currently fails to Mosaic-compile on v5e. Flip only once a
-#: sweep shows it compiling AND beating the sort past the loop cap.
+#: ``mode="radix"`` always works). Default off on that same 2026-07-31 sweep:
+#: radix lost to the loop kernel at every W≤128 and to the XLA sort at W=128,
+#: and at W=256 — the one regime it could win — it did not compile then. It
+#: does now: at R=4096, S=64, W=256 it compiles to a ``tpu_custom_call`` for a
+#: described v5e (``tests/platform/test_chip_compile.py``, PR 21). Whether it
+#: beats the sort there is not measured; flip only once a sweep shows it.
 RADIX_ENV = "TPU_RESILIENCY_PALLAS_RADIX"
 DEFAULT_RADIX_AUTO = False
 
 #: Modes whose work grows quadratically with the window (subject to the cap).
 _QUADRATIC_MODES = ("loop", "pairwise")
 
-#: Pairwise has its own, smaller bound: the sweep measured it compiling only
-#: at W=32 on v5e (S-folded; Mosaic rejects its 4-D blocks at W=64 even
-#: folded) and losing to the loop kernel 4-5x where it runs — the shared
-#: loop cap must not re-open a gate the measurement closed.
+#: Pairwise has its own, smaller bound: the 2026-07-31 sweep had it compiling
+#: only at W=32 on v5e (S-folded; Mosaic rejected its 4-D blocks at W=64 even
+#: folded) and losing to the loop kernel 4-5x where it ran — the shared
+#: loop cap must not re-open a gate that measurement closed.
 PAIRWISE_MAX_WINDOW = 32
 
 
@@ -229,13 +227,15 @@ def default_rank_tile(mode: str) -> int:
 
 
 #: Largest [RT, S, W] element count a default block may hold, per mode —
-#: each set to the largest block PROVEN to Mosaic-compile on v5e by the live
-#: sweep. The radix kernel carries more concurrent W-sized temporaries than
-#: the loop kernel (x, int32 key, candidate mask, plus the selection
-#: carries): its compile fails at 32·64·256-element blocks (≈2 MB/array, ~6
-#: live arrays brushes VMEM) while every 32·64·128 block is proven. The loop
-#: kernel compiled and ran at 32·64·256 (the W=256 sweep column), so its
-#: budget is 2× radix's. Default tiles halve until the block fits the
+#: each set to the largest block that Mosaic-compiled on v5e in the
+#: 2026-07-31 sweep. The radix kernel carries more concurrent W-sized
+#: temporaries than the loop kernel (x, int32 key, candidate mask, plus the
+#: selection carries): then its compile failed at 32·64·256-element blocks
+#: (≈2 MB/array, ~6 live arrays brushes VMEM) while every 32·64·128 block
+#: passed, and the loop kernel compiled and ran at 32·64·256, so its budget
+#: is 2× radix's. Under this budget radix at W=256 takes a 16-rank tile and
+#: compiles on today's compiler (PR 21); whether the budget can grow is
+#: queued with the window caps. Default tiles halve until the block fits the
 #: budget. Halving preserves the gate-checked divisibility only when 32 | R;
 #: for other admitted rank counts :func:`_snap_tile` snaps to the largest
 #: divisor of R within budget (and both the gate and the kernel reject the

@@ -158,6 +158,24 @@ def tree_shardings(mesh, specs):
     )
 
 
+def opt_state_shardings(init_opt, params, param_shardings):
+    """Shardings for the optimizer state ``init_opt(params)``: every params-shaped
+    subtree (AdamW's moments) laid out like the params, the rest (step counts)
+    replicated. Pass the result as ``out_shardings`` of ``jax.jit(init_opt)``:
+    left to itself the compiler puts the all-zero moments, which depend on no
+    sharded input, unsharded on device 0."""
+    import jax
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    mesh = jax.tree.leaves(param_shardings)[0].mesh
+    replicated = NamedSharding(mesh, PartitionSpec())
+    return optax.tree_map_params(
+        init_opt, lambda _, s: s, jax.eval_shape(init_opt, params), param_shardings,
+        transform_non_params=lambda _: replicated,
+    )
+
+
 def axis_sizes(mesh) -> dict[str, int]:
     """``{axis name: size}`` of a Mesh — the form the elastic reshard layout
     (``checkpoint/reshard.py``) consumes."""

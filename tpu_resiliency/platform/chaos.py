@@ -378,9 +378,11 @@ def disk_peer(path: str) -> str:
 
 def _deterministic_rng(plan: ChaosPlan, inj: Injection) -> random.Random:
     """Fault parameters (bit offsets, truncation points) come from an RNG
-    seeded by ``(seed, file, injection index)`` — NOT the plan's shared RNG,
-    whose draw order is racy across threads. Same seed → same corruption."""
-    return random.Random((plan.seed, inj.peer, inj.index))
+    seeded by ``seed:file:injection index`` — NOT the plan's shared RNG,
+    whose draw order is racy across threads. Same seed → same corruption,
+    in every process: a ``str`` seed is hashed with SHA-512 by ``random``,
+    never with the per-process salted ``hash()``."""
+    return random.Random(f"{plan.seed}:{inj.peer}:{inj.index}")
 
 
 def _on_storage_write(channel: str, peer: str, path: str, data):

@@ -3,17 +3,24 @@
 Every restarted worker used to re-trace and re-compile its step function from
 scratch — on real models that is the dominant residual cost of a warm-spare
 respawn (the interpreter floor is already paid, the XLA compile is not). This
-module wires JAX's persistent compilation cache (``jax_compilation_cache_dir``)
-into the launcher's env plumbing so round N+1's first step loads round N's
-executables instead of recompiling:
+module puts an integrity sweep and an event around JAX's own persistent
+compilation cache so round N+1's first step loads round N's executables
+instead of recompiling. There is ONE rule for where the cache lives:
 
-- ``tpu-ft-launcher --compile-cache-dir DIR`` exports
-  :data:`CACHE_DIR_ENV` (and ``JAX_COMPILATION_CACHE_DIR`` for workers that
-  never import this package) to every worker, scoped under the run dir by
-  convention so one job's cache never collides with another's.
+- ``$JAX_COMPILATION_CACHE_DIR`` (:data:`CACHE_DIR_ENV`, the variable jax itself
+  reads when it is imported) names the directory. Where it is set — by the
+  machine, the user, or an entry program — launcher and workers keep their
+  cache there and nowhere else; where it is unset there is no persistent
+  cache. The library never makes up a path and never sets another directory.
+- ``tpu-ft-launcher --compile-cache-dir DIR`` exports the variable to its
+  workers only when the environment does not already carry it (an outside
+  setting wins, and is logged). The entry programs (``chip_smoke.py``,
+  ``bench.py``) fall back to :func:`checkout_cache_dir`, one fixed git-ignored
+  directory in the checkout: the path is part of the cache key's world, so a
+  directory that moves between runs never hits.
 - Workers apply it through :func:`apply_from_env` (called by
   ``inprocess/wrap.py`` at engine start and by
-  ``platform/device.py:apply_platform_env``), which records ONE
+  ``platform/device.py:apply_compile_cache_env``), which records ONE
   ``compile_cache`` event per process — outcome ``hit`` (valid entries were
   waiting), ``miss`` (cold cache), or ``miss_corrupt`` (damaged entries were
   purged) — feeding ``tpu_compile_cache_total{outcome}`` and the goodput
@@ -46,8 +53,11 @@ from tpu_resiliency.utils.logging import get_logger
 
 log = get_logger(__name__)
 
-#: exported by the launcher; consumed by :func:`apply_from_env` in workers
-CACHE_DIR_ENV = "TPU_RESILIENCY_COMPILE_CACHE_DIR"
+#: the one variable that places the cache: jax's own (read at ``import jax``)
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: directory name :func:`checkout_cache_dir` uses (listed in ``.gitignore``)
+CHECKOUT_CACHE_NAME = ".jax_cache"
 
 #: integrity manifest file kept inside the cache dir (never a cache entry:
 #: JAX entry files end in ``-cache``)
@@ -178,12 +188,24 @@ def outcome_of(stats: dict) -> str:
     return "hit" if stats.get("entries") else "miss"
 
 
-def enable(path: str) -> dict:
-    """Sweep ``path``, point JAX's persistent compilation cache at it, and
-    register an exit-time manifest refresh. Returns the sweep stats.
+def checkout_cache_dir(checkout: str) -> str:
+    """The one fixed cache directory of a checkout, for entry programs to export
+    as :data:`CACHE_DIR_ENV` when the environment does not set it. Never made
+    from ``tempfile``, a pid or the time: every run of the same checkout gets
+    the same path, so the second run hits what the first compiled."""
+    return os.path.join(os.path.abspath(checkout), CHECKOUT_CACHE_NAME)
 
-    Every failure mode degrades to a cold compile: an unusable directory or a
-    JAX without the cache config simply leaves caching off."""
+
+def enable(path: str) -> dict:
+    """Sweep ``path`` (the directory :data:`CACHE_DIR_ENV` names), lower jax's
+    caching threshold, and register an exit-time manifest refresh. Returns the
+    sweep stats.
+
+    jax took the directory from the environment when it was imported; it is
+    handed the same value again here only for a process that set the variable
+    after its ``import jax``. No other directory is ever set. Every failure
+    mode degrades to a cold compile: an unusable directory simply leaves
+    caching off."""
     try:
         os.makedirs(path, exist_ok=True)
     except OSError:
@@ -192,18 +214,14 @@ def enable(path: str) -> dict:
                 "enabled": False}
     stats = sweep(path)
     stats["enabled"] = True
-    try:
-        import jax
+    import jax
 
+    if jax.config.jax_compilation_cache_dir != path:
         jax.config.update("jax_compilation_cache_dir", path)
-        # Loopback/test programs compile in microseconds; without a zero
-        # threshold nothing under 1 s would ever be cached and every restart
-        # bench would read as a miss.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        log.warning("JAX persistent compilation cache unavailable", exc_info=True)
-        stats["enabled"] = False
-        return stats
+    # Loopback/test programs compile in microseconds; without a zero
+    # threshold nothing under 1 s would ever be cached and every restart
+    # bench would read as a miss.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     import atexit
 
     atexit.register(lambda: write_manifest(path))
@@ -211,9 +229,10 @@ def enable(path: str) -> dict:
 
 
 def apply_from_env(record: bool = True) -> Optional[dict]:
-    """Apply :data:`CACHE_DIR_ENV` once per process; None when unset or when
-    already applied. On first application records the ``compile_cache``
-    event (hit / miss / miss_corrupt + entry count and bytes)."""
+    """Sweep and announce the directory :data:`CACHE_DIR_ENV` names, once per
+    process; None when unset or when already applied. On first application
+    records the ``compile_cache`` event (hit / miss / miss_corrupt + entry
+    count and bytes)."""
     global _applied
     path = os.environ.get(CACHE_DIR_ENV, "")
     if not path or _applied is not None:

@@ -11,8 +11,8 @@ shape this module:
 - **Peer death is fatal by default.** The XLA distributed client LOG(FATAL)s the
   *surviving* process the moment the coordination service reports any peer dead
   ("Terminating process because the JAX distributed service detected fatal
-  errors"). A resilient job must opt in to ``jax_enable_recoverability`` (jax >=
-  0.7) at initialize time — after the fault it is too late.
+  errors"). A resilient job must opt in to ``jax_enable_recoverability`` at
+  initialize time — after the fault it is too late.
 - **Re-initialize requires dead backends.** ``jax.distributed.initialize`` refuses
   to run once the XLA backends are live, so the restart teardown must also clear
   them (dropping device buffers — the restart loop reloads state from local
@@ -56,15 +56,7 @@ def initialize(
     import jax
 
     if recoverable:
-        try:
-            jax.config.update("jax_enable_recoverability", True)
-        except Exception:
-            # Older jax: flag absent. The job still runs, but peer death will
-            # kill survivors — only the in-job (launcher) restart layer applies.
-            log.warning(
-                "jax_enable_recoverability unavailable: peer death will "
-                "terminate surviving processes (in-job restart still works)"
-            )
+        jax.config.update("jax_enable_recoverability", True)
     jax.distributed.initialize(
         coordinator_address,
         num_processes=num_processes,
@@ -81,14 +73,13 @@ def initialize(
 
 
 def clear_backends() -> None:
-    """Tear down live XLA backends (public API removed in jax 0.9)."""
-    import jax
+    """Tear down live XLA backends (jax 0.9 has no public API for it).
 
-    try:
-        jax.clear_backends()  # pre-0.9 public API
-        return
-    except AttributeError:
-        pass
+    Only the multi-process restart needs this (``initialize`` refuses while
+    backends are live); a one-process restart keeps its backend, see
+    :func:`shutdown_for_restart`. On one v5e chip the same process re-acquired
+    the chip 0.16 s after this call, once nothing referenced the old client's
+    arrays or executables (chip run, PR 21)."""
     import jax._src.xla_bridge as xb  # noqa: SLF001
 
     xb._clear_backends()  # noqa: SLF001

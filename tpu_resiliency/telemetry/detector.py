@@ -118,7 +118,9 @@ class Detector:
         job runs one JAX process per rank (``jax.process_count() == world_size``) —
         ``generate_report`` skips the store summary gather entirely: the store carries
         only the name-column agreement, and per-rank summaries travel as shards of a
-        mesh array reduced by ICI/DCN collectives (the north-star path)."""
+        mesh array reduced by ICI/DCN collectives (the north-star path). A world of
+        one rank has nobody to agree with and needs no store: one worker on one
+        chip scores through the same compiled mesh program."""
         if cls.initialized:
             raise ResiliencyError("Detector already initialized")
         cls.initialized = True
@@ -290,8 +292,11 @@ class Detector:
         array, so it needs one authoritative order. A CAS loop appends locally-new
         names (sorted) to a single store tuple; every rank then adopts the same
         list. Append-only ⇒ per-column carried state (EWMA / historical min) in the
-        MeshTelemetry stays valid across rounds and late joiners.
+        MeshTelemetry stays valid across rounds and late joiners. A one-rank world
+        is its own authority: its registry's first-use order is append-only too.
         """
+        if cls.world_size == 1:
+            return cls._registry.names()
         local = set(cls._rings)
         while True:
             cur = cls.store.try_get(cls.COLUMNS_KEY)
@@ -360,15 +365,15 @@ class Detector:
         local = cls.local_summary()
         if (
             cls._mesh_telemetry is not None
-            and cls.store is not None
-            and cls.world_size > 1
+            and (cls.store is not None or cls.world_size == 1)
             and jax.process_count() == cls.world_size
         ):
             report = cls._generate_mesh_report(local)
             if cls._mesh_telemetry is not None:
                 return report
             # Capacity fallback tripped mid-round: continue into the store path.
-        if cls.store is not None and cls.world_size > 1:
+        gathered = cls.store is not None and cls.world_size > 1
+        if gathered:
             round_idx = cls._generator.iteration
             ns = f"telemetry/round/{round_idx}"
             cls._registry.publish(cls.store, key=f"{ns}/names")
@@ -413,6 +418,7 @@ class Detector:
             jnp.asarray(medians), jnp.asarray(weights), jnp.asarray(counts), names,
             rank=cls.rank,
         )
+        report.source = "store" if gathered else "local"
         cls._reset_rings()
         if cls.gather_on_rank0 and cls.rank != 0:
             return None

@@ -21,10 +21,15 @@ device-exact durations.
   ``computeStats`` (``CuptiProfiler.cpp:44-74``); ``reset()`` clears.
 
 Program names are stable across recompiles: the fingerprint hash suffix is stripped
-(``jit_train_step(123...)`` → ``jit_train_step``). On backends without a device plane
-(CPU), the capture falls back to the host trace's ``PjitFunction`` events —
-host-inclusive dispatch durations, clearly a different signal, but it keeps the whole
-pipeline exercisable in simulation.
+(``jit_train_step(123...)`` → ``jit_train_step``). On the CPU backend, which has no
+device plane, the capture reads the host trace's ``PjitFunction`` events —
+host-inclusive dispatch durations, clearly a different signal, kept so the CPU tests
+can exercise the whole pipeline. On a TPU backend a trace without a device plane is
+an error (:class:`NoDevicePlane`), never a reason to report host times, and
+``DeviceTimeProfiler.source`` tells a caller which of the two it got. Plane and line
+names are the profiler's own; on jax 0.9 / libtpu 0.0.34 a v5e trace has one
+``/device:TPU:0`` plane with ``XLA Modules`` and ``XLA Ops`` lines (opened by hand,
+chip run PR 21; ``tests/telemetry/data/v5e_window.xplane.pb`` is that trace).
 """
 
 from __future__ import annotations
@@ -39,13 +44,10 @@ from typing import Optional
 
 import numpy as np
 
-from tpu_resiliency.utils.logging import get_logger
-
-log = get_logger(__name__)
-
 _HASH_SUFFIX = re.compile(r"\(\d+\)$")
 _PJIT = re.compile(r"^PjitFunction\((.+)\)$")
 _OP_ID_SUFFIX = re.compile(r"\.\d+$")
+_HLO_INSTRUCTION = re.compile(r"^%([^\s=]+)\s*=")
 _JIT_COMPONENT = re.compile(r"^(jit|pjit)\(.*\)$")
 
 MAX_SAMPLES_PER_PROGRAM = 8192  # reference statsMaxLenPerKernel ring bound
@@ -69,6 +71,9 @@ def op_scope_key(name: str, stats: dict) -> Optional[str]:
     2. The ``hlo_op`` stat (or the event name), numeric instruction id
        stripped (``dot_general.2`` → ``dot_general``) — instruction ids are
        compile-order artifacts that would fragment signals across recompiles.
+       A v5e ``XLA Ops`` event carries neither stat and is named by its whole
+       HLO instruction (``%fused_median_weights.1 = (f32[...]) custom-call(...)``):
+       the key is the instruction's own name, ``fused_median_weights``.
     """
     if name.startswith("end: ") or "::" in name:
         return None
@@ -82,33 +87,63 @@ def op_scope_key(name: str, stats: dict) -> Optional[str]:
         if parts:
             return _OP_ID_SUFFIX.sub("", parts[0])
         return None
-    base = _OP_ID_SUFFIX.sub("", str(stats.get("hlo_op") or name))
+    base = str(stats.get("hlo_op") or name)
+    instruction = _HLO_INSTRUCTION.match(base)
+    if instruction:
+        base = instruction.group(1)
+    base = _OP_ID_SUFFIX.sub("", base)
     if not base or base.startswith("_"):
         return None
     return base
 
 
-def extract_program_times(profile_data) -> dict[str, list[float]]:
+class NoDevicePlane(RuntimeError):
+    """A trace that had to come from a device carries no device plane line."""
+
+
+def _device_lines(profile_data, line_name: str) -> list:
+    return [
+        line
+        for plane in profile_data.planes
+        if "/device:" in plane.name and "CUSTOM" not in plane.name
+        for line in plane.lines
+        if line.name == line_name
+    ]
+
+
+def _no_device_plane(line_name: str, profile_data) -> NoDevicePlane:
+    return NoDevicePlane(
+        f"no {line_name!r} line on a /device: plane; the trace has planes "
+        f"{[p.name for p in profile_data.planes]}"
+    )
+
+
+def trace_source(profile_data) -> str:
+    """``"device"`` when :func:`extract_program_times` reads true device times
+    from this trace, ``"host"`` when all it has are host dispatch times."""
+    return "device" if _device_lines(profile_data, "XLA Modules") else "host"
+
+
+def extract_program_times(
+    profile_data, require_device: bool = False
+) -> dict[str, list[float]]:
     """Per-program device durations (seconds) from one xplane ProfileData.
 
-    Primary source: device planes' ``XLA Modules`` line (true device time).
-    Fallback when no device plane exists (CPU simulation): the host plane's
-    ``PjitFunction`` events (host-inclusive dispatch time).
+    Source: device planes' ``XLA Modules`` line (true device time). Without one,
+    ``require_device`` (a TPU backend) raises :class:`NoDevicePlane`; otherwise
+    (the CPU tests) the host plane's ``PjitFunction`` events stand in
+    (host-inclusive dispatch time). :func:`trace_source` says which it was.
     """
     out: dict[str, list[float]] = {}
-    saw_device_plane = False
-    for plane in profile_data.planes:
-        if "/device:" not in plane.name or "CUSTOM" in plane.name:
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Modules":
-                continue
-            saw_device_plane = True
-            for ev in line.events:
-                name = normalize_program_name(ev.name)
-                out.setdefault(name, []).append(float(ev.duration_ns) * 1e-9)
-    if saw_device_plane:
+    lines = _device_lines(profile_data, "XLA Modules")
+    for line in lines:
+        for ev in line.events:
+            name = normalize_program_name(ev.name)
+            out.setdefault(name, []).append(float(ev.duration_ns) * 1e-9)
+    if lines:
         return out
+    if require_device:
+        raise _no_device_plane("XLA Modules", profile_data)
     for plane in profile_data.planes:
         if not plane.name.startswith("/host:"):
             continue
@@ -130,31 +165,30 @@ def _event_stats(ev) -> dict:
         return {}
 
 
-def extract_op_times(profile_data) -> dict[str, list[float]]:
+def extract_op_times(
+    profile_data, require_device: bool = False
+) -> dict[str, list[float]]:
     """Per-op/scope device durations (seconds) from one xplane ProfileData —
     one granularity below :func:`extract_program_times`, the closest XLA gets
     to CUPTI's per-kernel stream (kernels themselves are fused away).
 
-    Primary source: device planes' ``XLA Ops`` line (true device time, one
-    event per HLO op execution, ``tf_op`` scope attribution when XLA carries
-    it). Fallback when no device plane exists (CPU simulation): the PjRt CPU
-    client's per-op thread line (host-inclusive op durations — a different
-    clock, same pipeline mechanics)."""
+    Source: device planes' ``XLA Ops`` line (true device time, one event per
+    HLO op execution, ``tf_op`` scope attribution when XLA carries it).
+    Without one, ``require_device`` raises :class:`NoDevicePlane`; otherwise
+    (the CPU tests) the PjRt CPU client's per-op thread line stands in
+    (host-inclusive op durations — a different clock, same pipeline
+    mechanics)."""
     out: dict[str, list[float]] = {}
-    saw_device_ops = False
-    for plane in profile_data.planes:
-        if "/device:" not in plane.name or "CUSTOM" in plane.name:
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            saw_device_ops = True
-            for ev in line.events:
-                key = op_scope_key(ev.name, _event_stats(ev))
-                if key is not None:
-                    out.setdefault(key, []).append(float(ev.duration_ns) * 1e-9)
-    if saw_device_ops:
+    lines = _device_lines(profile_data, "XLA Ops")
+    for line in lines:
+        for ev in line.events:
+            key = op_scope_key(ev.name, _event_stats(ev))
+            if key is not None:
+                out.setdefault(key, []).append(float(ev.duration_ns) * 1e-9)
+    if lines:
         return out
+    if require_device:
+        raise _no_device_plane("XLA Ops", profile_data)
     for plane in profile_data.planes:
         for line in plane.lines:
             if "XLAPjRt" not in line.name:
@@ -167,7 +201,13 @@ def extract_op_times(profile_data) -> dict[str, list[float]]:
 
 
 class DeviceTimeProfiler:
-    """Windowed per-program device-time capture with the CUPTI manager contract."""
+    """Windowed per-program device-time capture with the CUPTI manager contract.
+
+    Strict: a window that cannot start, wrote no trace, cannot be parsed, or —
+    on a TPU backend — has no device plane RAISES. A caller that must not break
+    a step on a profiling fault (``integrations/straggler_callback.py``)
+    catches and counts; nothing here turns a fault into silence or into host
+    times under a device name."""
 
     def __init__(self, trace_root: Optional[str] = None, collect_ops: bool = False):
         self._root = trace_root
@@ -180,10 +220,17 @@ class DeviceTimeProfiler:
         self._op_samples: dict[str, deque] = {}
         self._op_fresh: dict[str, list[float]] = {}
         self.active = False
+        #: where the last parsed window's times came from (:func:`trace_source`):
+        #: ``"device"`` | ``"host"``; None before the first window
+        self.source: Optional[str] = None
+        #: windows parsed into the stats so far
+        self.windows = 0
 
     # -- capture window ------------------------------------------------------
 
     def start(self) -> None:
+        """Open a window. Raises when the process-global profiler is already
+        active (another window's leak, or user tracing)."""
         if self.active:
             return
         import jax
@@ -191,14 +238,10 @@ class DeviceTimeProfiler:
         self._window_dir = tempfile.mkdtemp(prefix="devprof_", dir=self._root)
         try:
             jax.profiler.start_trace(self._window_dir)
-        except Exception:
-            # The process-global profiler may already be active (another window's
-            # leak, or user tracing). Profiling is opportunistic observability —
-            # skip the window, never break the step.
-            log.warning("could not start a profiler window; skipping", exc_info=True)
+        except BaseException:
             shutil.rmtree(self._window_dir, ignore_errors=True)
             self._window_dir = None
-            return
+            raise
         self.active = True
 
     def stop(self) -> None:
@@ -210,13 +253,17 @@ class DeviceTimeProfiler:
 
         jax.profiler.stop_trace()
         self.active = False
+        require_device = jax.default_backend() == "tpu"
         try:
             files = glob.glob(
                 os.path.join(self._window_dir, "**", "*.xplane.pb"), recursive=True
             )
+            if not files:
+                raise RuntimeError("the profiler window wrote no xplane trace")
             for f in files:
                 data = ProfileData.from_file(f)
-                times = extract_program_times(data)
+                times = extract_program_times(data, require_device)
+                self.source = trace_source(data)
                 for name, secs in times.items():
                     ring = self._samples.setdefault(
                         name, deque(maxlen=MAX_SAMPLES_PER_PROGRAM)
@@ -224,14 +271,13 @@ class DeviceTimeProfiler:
                     ring.extend(secs)
                     self._fresh.setdefault(name, []).extend(secs)
                 if self.collect_ops:
-                    for name, secs in extract_op_times(data).items():
+                    for name, secs in extract_op_times(data, require_device).items():
                         ring = self._op_samples.setdefault(
                             name, deque(maxlen=MAX_SAMPLES_PER_PROGRAM)
                         )
                         ring.extend(secs)
                         self._op_fresh.setdefault(name, []).extend(secs)
-        except Exception:
-            log.exception("device profile parse failed; window dropped")
+            self.windows += 1
         finally:
             if self._window_dir:
                 shutil.rmtree(self._window_dir, ignore_errors=True)

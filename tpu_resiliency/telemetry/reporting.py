@@ -66,6 +66,11 @@ class Report:
     # per-rank per-section relative scores, [R, S], optional global view
     global_section_scores: Optional[np.ndarray] = None
     rank_to_host: Optional[dict[int, str]] = None
+    #: which path scored it: ``"mesh"`` (per-rank summaries as shards of a mesh
+    #: array, reduced by collectives — ``MeshTelemetry``), ``"store"`` (summaries
+    #: gathered through the coordination store) or ``"local"`` (one process, its
+    #: own summary). A job that asked for the mesh path can tell that it got it.
+    source: str = "local"
 
     def identify_stragglers(
         self,
@@ -184,8 +189,8 @@ class ReportGenerator:
     def score_summary(self, medians, weights, counts) -> scoring.TelemetryScores:
         """Score precomputed per-(rank, signal) ``medians``/``weights`` summaries
         (the store-aggregated multi-host path; window reduction already done).
-        One compiled program per shape (``score_summary_jit``) — eager dispatch
-        here cost ~350 ms/report over a remote-dispatch backend."""
+        One compiled program per shape (``score_summary_jit``), not dozens of
+        eager dispatches per report."""
         s = medians.shape[1]
         res = scoring.score_summary_jit(
             medians,

@@ -124,8 +124,8 @@ class MeshTelemetry:
         self.iteration = 0
 
         if use_pallas is None:
-            # The fused Pallas window reduction beats XLA's sort lowering 2x on
-            # TPU at the default window (device-true measurement, BASELINE.md);
+            # The fused Pallas window reduction beat XLA's sort lowering 2x on
+            # TPU at the default window (2026-07-31 capture, BASELINE.md);
             # other backends can't run the kernel, and the kernel tiles the
             # rank axis so incompatible per-shard rank counts fall back to the
             # shape-generic XLA path. Windows past the O(W²) crossover
@@ -373,4 +373,5 @@ class MeshTelemetry:
             ewma_scores={r: float(ewma[r]) for r in range(self.n_ranks)},
             global_section_scores=section[:, : len(names)],
             rank_to_host=self.rank_to_host,
+            source="mesh",
         )

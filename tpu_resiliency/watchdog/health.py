@@ -58,7 +58,12 @@ class CallbackHealthCheck(HealthCheck):
 class DeviceLivenessCheck(HealthCheck):
     """Tiny compiled add + block_until_ready under a timeout thread
     (the reference ``CudaHealthCheck`` double-sync analogue,
-    ``inprocess/health_check.py:70-110``)."""
+    ``inprocess/health_check.py:70-110``).
+
+    Runs in the worker, the one process that owns the chip (the in-process
+    restart health chain, or a poll from the train loop). A rank-monitor
+    process is pinned to the CPU (``monitor_server._monitor_main``): handed to
+    one, this check probes the host CPU and says nothing about the chip."""
 
     def __init__(self, timeout: float = 60.0):
         self.timeout = timeout
@@ -123,8 +128,10 @@ class TpuRuntimeCheck(HealthCheck):
     The analogue of the reference's NVML device/recovery-state poll
     (``shared_utils/health_check.py:148-303``) for a runtime with no out-of-process
     query API: the check must run in a process that owns the TPU (the worker — wire
-    it into the in-process restart health chain or poll it from the train loop; a
-    rank-monitor process cannot open a second client to the same chips).
+    it into the in-process restart health chain or poll it from the train loop). A
+    rank-monitor process cannot open a second client to the same chips and is
+    pinned to the CPU (``monitor_server._monitor_main``): there this check would
+    count CPU devices.
 
     Unhealthy when: the backend can no longer enumerate devices, the visible device
     count drops below ``expect_devices``, or any device's HBM usage exceeds

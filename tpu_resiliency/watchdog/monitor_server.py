@@ -587,6 +587,10 @@ class RankMonitorServer:
 
 
 def _monitor_main(cfg, socket_path, health_checks) -> None:
-    # A forked monitor must never touch the parent's TPU runtime.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # The chip belongs to the worker. Pinned unconditionally: the launcher's
+    # own $JAX_PLATFORMS (unset, or "tpu,cpu" on a TPU host) is the worker's,
+    # and a health check here that imported jax under it would ask for the
+    # worker's chip. The launcher never imports jax, so the variable is read
+    # fresh by any import in this process.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     RankMonitorServer(cfg, socket_path, health_checks).run()
