@@ -48,3 +48,34 @@ def coord_store(kv_server):
 def tmp_uds_path(tmp_path):
     # Keep UDS paths short (108-byte sun_path limit).
     return str(tmp_path / "s.sock")
+
+
+@pytest.fixture
+def profiler_window(tmp_path):
+    """``with profiler_window() as names: ...``: a CPU profiler window around the
+    block; afterwards ``names`` holds the host plane's event names in time order
+    (the ``tpures/`` annotations among them)."""
+    import contextlib
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    @contextlib.contextmanager
+    def window():
+        names: list[str] = []
+        trace_dir = str(tmp_path / "profiler_window")
+        jax.profiler.start_trace(trace_dir)
+        try:
+            yield names
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+        events = [
+            (ev.start_ns, ev.name)
+            for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+        ]
+        names.extend(name for _, name in sorted(events))
+
+    return window

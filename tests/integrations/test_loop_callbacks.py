@@ -93,6 +93,47 @@ def test_loop_exception_fires_hook_and_propagates():
     assert seen and "boom" in seen[0]
 
 
+def test_each_callbacks_hook_is_an_annotation_on_the_profilers_clock(profiler_window):
+    """``CallbackRunner.fire`` brackets every callback's hook in
+    ``tpures/loop/<hook>/<CallbackClass>``; the annotation of a callback that raises
+    is closed (the next one opens after it, not inside it) and the loop goes on."""
+
+    class Quiet(Callback):
+        pass
+
+    class Faulty(Callback):
+        def on_step_end(self, ctx):
+            raise RuntimeError("a callback's fault is logged, never fatal")
+
+    with profiler_window() as names:
+        ctx = run_training(lambda s, i: s + 1, 0, 2, callbacks=[Faulty(), Quiet()])
+    assert ctx.state == 2
+    ours = [n for n in names if n.startswith("tpures/loop/")]
+    per_step = [
+        "tpures/loop/on_step_start/Faulty", "tpures/loop/on_step_start/Quiet",
+        "tpures/loop/on_step_end/Faulty", "tpures/loop/on_step_end/Quiet",
+    ]
+    assert ours == (
+        ["tpures/loop/on_train_start/Faulty", "tpures/loop/on_train_start/Quiet"]
+        + per_step * 2
+        + ["tpures/loop/on_train_end/Faulty", "tpures/loop/on_train_end/Quiet"])
+
+
+def test_hooks_are_annotations_only_never_events():
+    """With no profiler window open, a step through ``run_training`` with a callback
+    attached records no event (an event per hook per step would flood an operator's
+    stream): tracing off costs a null context per hook and nothing else."""
+    from tpu_resiliency.utils import events
+
+    seen = []
+    events.add_sink(seen.append)
+    try:
+        run_training(lambda s, i: s + 1, 0, 3, callbacks=[Recorder()])
+    finally:
+        events.remove_sink(seen.append)
+    assert seen == []
+
+
 @pytest.fixture
 def monitor(tmp_path):
     sock = str(tmp_path / "m.sock")

@@ -136,3 +136,35 @@ def test_multirank_aggregation_via_store(kv_server):
     stragglers = report.identify_stragglers()
     assert {s.rank for s in stragglers.by_perf} == {1}
     assert report.perf_scores[1] == pytest.approx(0.25, abs=0.01)
+
+
+@pytest.mark.parametrize("mesh_path", [False, True])
+def test_a_report_round_names_its_parts_on_the_profilers_clock(mesh_path, profiler_window):
+    """Only a step that really generates a report writes ``tpures/telemetry/report``,
+    with the round's parts nested in it in order: rings to medians, the puts and
+    the scorer's dispatch, and on the mesh path the reads back (the store path's
+    scorer materializes inside ``score``)."""
+    device_telemetry = None
+    if mesh_path:
+        import jax
+        from jax.sharding import Mesh
+
+        from tpu_resiliency.telemetry.sharded import MeshTelemetry
+
+        device_telemetry = MeshTelemetry(
+            Mesh(np.array(jax.devices()[:1]), ("ranks",)), "ranks", n_ranks=1,
+            signal_names=tuple(f"c{i}" for i in range(4)))
+    Detector.initialize(report_time_interval=1e9, device_telemetry=device_telemetry)
+    with Detector.detection_section("step", profile_device=False):
+        pass
+    assert Detector.generate_report() is not None  # every program compiled before the window
+    with profiler_window() as names:
+        with Detector.detection_section("step", profile_device=False):
+            pass
+        assert Detector.generate_report_if_interval_elapsed() is None  # no report, no annotation
+        report = Detector.generate_report()
+    assert report.source == ("mesh" if mesh_path else "local")
+    ours = [n for n in names if n.startswith("tpures/")]
+    assert ours == ["tpures/telemetry/report", "tpures/telemetry/report/summary",
+                    "tpures/telemetry/report/score"] + (
+                        ["tpures/telemetry/report/materialize"] if mesh_path else [])

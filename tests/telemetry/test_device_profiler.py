@@ -4,6 +4,7 @@ integration that turns program times into scored ``prog/...`` signals."""
 
 import dataclasses
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -12,11 +13,17 @@ import pytest
 
 from tpu_resiliency.telemetry.detector import Detector
 from tpu_resiliency.telemetry.device_profiler import (
+    PHASES,
     DeviceTimeProfiler,
     NoDevicePlane,
+    device_ops,
     extract_op_times,
     extract_program_times,
+    hlo_instructions,
+    instruction_name,
     normalize_program_name,
+    phase_of,
+    step_phase_times,
     trace_source,
 )
 
@@ -24,6 +31,10 @@ from tpu_resiliency.telemetry.device_profiler import (
 #: five ``_push_impl`` executions and one ``_score_reset_impl`` with the Pallas
 #: median kernel inside — the plane and line names the extraction depends on
 V5E_TRACE = os.path.join(os.path.dirname(__file__), "data", "v5e_window.xplane.pb")
+#: a window recorded on a v5e chip by PR 25 (``benchmark/tools/record_step_trace.py``):
+#: five executions of a ``value_and_grad`` + AdamW step jitted as ``train_step`` under
+#: the product's loop, the straggler callback reporting on every step
+V5E_STEP_TRACE = os.path.join(os.path.dirname(__file__), "data", "v5e_step.xplane.pb")
 
 
 # --- xplane extraction on a stub object graph (device-plane case) -------------
@@ -307,3 +318,126 @@ def test_op_capture_window_end_to_end(tmp_path):
         Detector.shutdown()
     prof.reset()
     assert prof.get_op_stats() == {}
+
+
+# --- the HLO a trace embeds: op_name, phase, scope keys --------------------------
+
+@pytest.mark.parametrize("op_name, phase", [
+    ("jit(train_step)/jvp()/while/body/closed_call/mul", "fwd"),
+    ("jit(train_step)/jvp()/while/body/closed_call/jit(silu)/mul", "fwd"),
+    ("jit(train_step)/jvp()/dot_general", "fwd"),
+    ("jit(train_step)/transpose(jvp())/while/body/dynamic_update_slice", "bwd"),
+    ("jit(train_step)/transpose(jvp())/while/body/closed_call/add_any", "bwd"),
+    # a forward recomputed under jax.checkpoint runs in, and counts as, the backward
+    ("jit(train_step)/transpose(jvp())/checkpoint/rematted_computation/tanh", "bwd"),
+    ("jit(train_step)/div", "opt"),
+    ("jit(train_step)/sqrt", "opt"),
+    ("params['layers']['wq']", "opt"),  # a copy of an argument carries its name
+    ("", "opt"),  # the compiler's own instructions carry none
+])
+def test_phase_is_read_from_the_names_autodiff_writes(op_name, phase):
+    assert phase_of(op_name) == phase and phase in PHASES
+
+
+def test_recorded_trace_joins_each_op_to_its_op_name():
+    """The trace file embeds every program's ``Hlo Proto``: ``%add.1`` of the
+    telemetry push is ``jit(_push_impl)/add``, with no stat on the event saying so."""
+    from jax.profiler import ProfileData
+
+    with open(V5E_TRACE, "rb") as f:
+        hlo = hlo_instructions(f.read())
+    assert {len(instructions) for instructions in hlo.values()} == {3, 10}
+    ops = list(device_ops(ProfileData.from_file(V5E_TRACE), hlo))
+    assert len(ops) == 10 and all(op.instruction is not None for op in ops)
+    joined = {instruction_name(op.event.name): op.instruction for op in ops}
+    assert joined["add.1"].op_name == "jit(_push_impl)/add"
+    assert joined["add.1"].opcode == "add" and not joined["add.1"].is_container
+    assert joined["fused_median_weights.1"].op_name.endswith(
+        "jit(fused_median_weights)/pallas_call")
+    pushes = [op for op in ops if "_push_impl" in op.program]
+    assert [op.execution for op in pushes] == [0, 1, 2, 3, 4]
+    assert all(0 < op.event.duration_ns * 1e-9 <= op.execution_s for op in pushes)
+    assert hlo_instructions(b"") == {}  # a trace that embeds no HLO joins nothing
+    with open(V5E_TRACE, "rb") as f:  # a program already read is not read again
+        assert hlo_instructions(f.read(), known=hlo) == {}
+
+
+def test_every_window_counts_its_own_cost(tmp_path):
+    """``stop()`` records one ``profiler_window`` event a window; its three parts
+    are the window's host time (what ``start()`` and ``stop()`` took together)."""
+    from tpu_resiliency.utils import events
+
+    seen = []
+    events.add_sink(seen.append)
+    prof = DeviceTimeProfiler(trace_root=str(tmp_path))
+    try:
+        for _ in range(2):
+            t0 = time.perf_counter()
+            prof.start()
+            t1 = time.perf_counter()
+            jax.block_until_ready(jnp.ones((8,)) + 1)
+            t2 = time.perf_counter()
+            prof.stop()
+            host_s = (t1 - t0) + (time.perf_counter() - t2)
+    finally:
+        events.remove_sink(seen.append)
+    windows = [e.payload for e in seen if e.kind == "profiler_window"]
+    assert len(windows) == 2 and [e.source for e in seen] == ["telemetry"] * 2
+    last = windows[-1]
+    assert set(last) >= {"start_s", "stop_s", "parse_s", "trace_bytes", "profile_source"}
+    assert min(last["start_s"], last["stop_s"], last["parse_s"]) > 0
+    parts = last["start_s"] + last["stop_s"] + last["parse_s"]
+    assert parts <= host_s and parts == pytest.approx(host_s, rel=0.05, abs=2e-3)
+    assert last["trace_bytes"] > 0 and last["profile_source"] == "host"
+
+
+@pytest.fixture(scope="module")
+def step_trace():
+    from jax.profiler import ProfileData
+
+    with open(V5E_STEP_TRACE, "rb") as f:
+        hlo = hlo_instructions(f.read())
+    return ProfileData.from_file(V5E_STEP_TRACE), hlo
+
+
+def test_every_op_of_the_recorded_step_gets_its_op_name(step_trace):
+    data, hlo = step_trace
+    ops = [op for op in device_ops(data, hlo) if "train_step" in op.program]
+    assert len({op.execution for op in ops}) == 5
+    assert all(op.instruction is not None for op in ops)
+    work = [op for op in ops if not op.instruction.is_container]
+    assert len(work) < len(ops)  # the scanned layers are ``while`` containers
+    # what the compiler made itself (copies, broadcasts of zeros) has no name: a
+    # tenth of the step's time here, and by the rule ``opt``
+    named = [op for op in work if op.instruction.op_name]
+    seconds = lambda ops: sum(op.event.duration_ns for op in ops)  # noqa: E731
+    assert seconds(named) > 0.85 * seconds(work)
+    assert all(op.instruction.op_name.startswith("jit(train_step)/") for op in named
+               if "(" in op.instruction.op_name)
+    assert {op.instruction.phase for op in work} == set(PHASES)
+
+
+def test_the_recorded_step_splits_into_three_phases_that_sum_to_it(step_trace):
+    rows = step_phase_times(*step_trace)
+    assert len(rows) == 5
+    for row in rows:
+        assert min(row[p] for p in PHASES) > 0
+        assert sum(row[p] for p in PHASES) == pytest.approx(row["module"], rel=0.02)
+        assert row["bwd"] > row["fwd"]  # two matmuls back for each one forward
+        assert 0 < row["mixed"] < row["module"] and row["unnamed"] < row["opt"]
+    assert step_phase_times(step_trace[0], {}) == []  # no HLO, no split
+    assert step_phase_times(*step_trace, program="no_such_program") == []
+
+
+def test_op_times_key_by_scope_where_the_hlo_is_given(step_trace):
+    data, hlo = step_trace
+    bare = extract_op_times(data, require_device=True)
+    assert "fusion" in bare and "while" in bare  # what a v5e's events say of themselves
+    scoped = extract_op_times(data, require_device=True, hlo=hlo)
+    assert not any(k.startswith(("fusion", "while")) for k in scoped), sorted(scoped)
+    assert {"jvp()/while/body/closed_call", "transpose(jvp())/while/body/closed_call",
+            "jvp()", "transpose(jvp())"} <= set(scoped)
+    # containers are left out, so no op's time is counted twice
+    total = sum(sum(v) for v in scoped.values())
+    programs = sum(sum(v) for v in extract_program_times(data, require_device=True).values())
+    assert total <= programs

@@ -90,16 +90,51 @@ def test_ensure_trace_id_mints_once_and_exports():
     assert tracing.ensure_trace_id() == tid  # idempotent
 
 
-def test_traced_decorator(tmp_path):
+def test_annotate_is_a_null_context_until_jax_is_loaded(monkeypatch):
+    """``annotate`` never imports JAX: without it in ``sys.modules`` (the launcher,
+    the agents, the rank monitor) it is a null context; with it, the profiler's own
+    ``TraceAnnotation``."""
+    import contextlib
+
+    import jax
+
+    assert isinstance(tracing.annotate("tpures/x"), jax.profiler.TraceAnnotation)
+    monkeypatch.delitem(sys.modules, "jax")
+    null = tracing.annotate("tpures/x")
+    assert isinstance(null, contextlib.nullcontext)
+    with null:
+        pass
+    assert "jax" not in sys.modules
+
+
+def test_a_span_leaves_a_process_off_jax():
+    child = (
+        "import sys\n"
+        "from tpu_resiliency.utils.tracing import annotate, span\n"
+        "with span('launcher', 'launcher.round'):\n"
+        "    with annotate('tpures/inside'):\n"
+        "        pass\n"
+        "sys.exit(1 if any(m == 'jax' or m.startswith('jax.') for m in sys.modules) else 0)\n"
+    )
+    r = subprocess.run([sys.executable, "-c", child], capture_output=True, text=True,
+                       timeout=60)
+    assert r.returncode == 0, r.stderr
+
+
+def test_spans_and_annotations_are_on_the_profilers_host_plane(tmp_path, profiler_window):
     path = _sink(tmp_path)
-
-    @tracing.traced("a", "work")
-    def f(x):
-        return x + 1
-
-    assert f(1) == 2
-    kinds = [r["kind"] for r in events.read_events(path)]
-    assert kinds == ["span_begin", "span_end"]
+    with profiler_window() as names:
+        with tracing.span("ckpt", "ckpt.save.enqueue"):
+            with tracing.annotate("tpures/inside"):
+                pass
+        with pytest.raises(ValueError):
+            with tracing.span("a", "boom"):
+                raise ValueError("nope")
+    ours = [n for n in names if n.startswith(tracing.ANNOTATION_PREFIX)]
+    assert ours == ["tpures/ckpt.save.enqueue", "tpures/inside", "tpures/boom"]
+    # the event stream is what it was: the pair per span, nothing per annotation
+    assert [r["kind"] for r in events.read_events(path)] == [
+        "span_begin", "span_end", "span_begin", "span_end"]
 
 
 def test_child_env_carries_trace_and_active_span():

@@ -18,6 +18,7 @@ import dataclasses
 from typing import Any, Callable, Iterable, Optional
 
 from tpu_resiliency.utils.logging import get_logger
+from tpu_resiliency.utils.tracing import annotate
 
 log = get_logger(__name__)
 
@@ -61,7 +62,10 @@ class LoopContext:
 
 class CallbackRunner:
     """Dispatches a hook across callbacks; a callback failure is logged, never
-    fatal to training (reference callbacks guard the same way)."""
+    fatal to training (reference callbacks guard the same way). Each callback's
+    hook is the annotation ``tpures/loop/<hook>/<CallbackClass>`` in an open
+    profiler window (no event record: an event per hook per step would flood
+    the stream)."""
 
     def __init__(self, callbacks: Iterable[Callback]):
         self.callbacks = list(callbacks)
@@ -69,7 +73,8 @@ class CallbackRunner:
     def fire(self, hook: str, ctx: LoopContext, *args) -> None:
         for cb in self.callbacks:
             try:
-                getattr(cb, hook)(ctx, *args)
+                with annotate(f"tpures/loop/{hook}/{type(cb).__name__}"):
+                    getattr(cb, hook)(ctx, *args)
             except StopTraining:
                 ctx.should_stop = True
             except Exception:

@@ -49,10 +49,13 @@ class StragglerDetectionCallback(Callback):
         reference ``profiling_interval``). Tracing is not free — use O(100).
 
         ``profile_ops``: with ``profile_programs_every``, additionally feed
-        per-op/scope device times from the same windows as ``op/...`` signals
-        (``jax.named_scope`` paths when XLA carries them) — one granularity
-        below programs, the closest XLA analogue of the reference's per-kernel
-        CUPTI stream. Parse cost only; no extra tracing overhead. With
+        per-op/scope device times from the same windows as ``op/...`` signals,
+        keyed by the scope of each instruction's ``op_name`` in the HLO the
+        trace embeds (``jvp()/while/body``, a ``jax.named_scope`` path where
+        the model has one) — one granularity below programs, the closest XLA
+        analogue of the reference's per-kernel CUPTI stream. Parse cost only
+        (``parse_s`` of the ``profiler_window`` event); no extra tracing
+        overhead. With
         ``use_device_mesh`` the op signals count against
         ``mesh_signal_capacity`` like every other column — size it for
         sec/ + dev/ + prog/ + one op/<scope> per named scope, or the first

@@ -34,6 +34,7 @@ from tpu_resiliency.telemetry.name_registry import NameRegistry
 from tpu_resiliency.telemetry.reporting import Report, ReportGenerator
 from tpu_resiliency.telemetry.ring_buffer import RingView, SignalRings
 from tpu_resiliency.utils.logging import get_logger
+from tpu_resiliency.utils.tracing import annotate
 
 log = get_logger(__name__)
 
@@ -359,10 +360,18 @@ class Detector:
         """
         if not cls.initialized:
             raise ResiliencyError("Detector.initialize() must be called first")
+        # the round and its parts (summary, score, materialize: the last two from
+        # telemetry/sharded.py on the mesh path) on the profiler's clock
+        with annotate("tpures/telemetry/report"):
+            return cls._generate_report()
+
+    @classmethod
+    def _generate_report(cls) -> Optional[Report]:
         import jax
         import jax.numpy as jnp
 
-        local = cls.local_summary()
+        with annotate("tpures/telemetry/report/summary"):
+            local = cls.local_summary()
         if (
             cls._mesh_telemetry is not None
             and (cls.store is not None or cls.world_size == 1)
@@ -414,10 +423,11 @@ class Detector:
                 weights[r, j] = st["total"]
                 counts[r, j] = st["count"]
 
-        report = cls._generator.generate_summary_report(
-            jnp.asarray(medians), jnp.asarray(weights), jnp.asarray(counts), names,
-            rank=cls.rank,
-        )
+        with annotate("tpures/telemetry/report/score"):
+            report = cls._generator.generate_summary_report(
+                jnp.asarray(medians), jnp.asarray(weights), jnp.asarray(counts), names,
+                rank=cls.rank,
+            )
         report.source = "store" if gathered else "local"
         cls._reset_rings()
         if cls.gather_on_rank0 and cls.rank != 0:

@@ -30,21 +30,35 @@ an error (:class:`NoDevicePlane`), never a reason to report host times, and
 names are the profiler's own; on jax 0.9 / libtpu 0.0.34 a v5e trace has one
 ``/device:TPU:0`` plane with ``XLA Modules`` and ``XLA Ops`` lines (opened by hand,
 chip run PR 21; ``tests/telemetry/data/v5e_window.xplane.pb`` is that trace).
+
+**One granularity below programs.** An ``XLA Ops`` event of such a trace is named
+by its bare instruction text and has three stats (``device_offset_ps``,
+``device_duration_ps``, ``Time Scale Multiplier``): no ``tf_op``, no ``hlo_op``.
+The same file holds, on its ``/host:metadata`` plane, every traced program's
+``Hlo Proto`` with each instruction's ``op_name`` as JAX wrote it
+(``jit(train_step)/transpose(jvp())/while/body/mul``). :func:`hlo_instructions`
+reads those (a short wire-format reader, no dependency beyond this file),
+:func:`device_ops` joins every op event to its instruction by name, and
+:func:`phase_of` reads forward / backward / optimizer from the names autodiff
+itself writes — the compiled program is never touched.
 """
 
 from __future__ import annotations
 
+import bisect
+import dataclasses
 import glob
 import os
 import re
 import shutil
 import tempfile
+import time
 from collections import deque
-from typing import Optional
+from typing import Iterator, NamedTuple, Optional
 
 import numpy as np
 
-_HASH_SUFFIX = re.compile(r"\(\d+\)$")
+_HASH_SUFFIX = re.compile(r"\((\d+)\)$")
 _PJIT = re.compile(r"^PjitFunction\((.+)\)$")
 _OP_ID_SUFFIX = re.compile(r"\.\d+$")
 _HLO_INSTRUCTION = re.compile(r"^%([^\s=]+)\s*=")
@@ -73,7 +87,9 @@ def op_scope_key(name: str, stats: dict) -> Optional[str]:
        compile-order artifacts that would fragment signals across recompiles.
        A v5e ``XLA Ops`` event carries neither stat and is named by its whole
        HLO instruction (``%fused_median_weights.1 = (f32[...]) custom-call(...)``):
-       the key is the instruction's own name, ``fused_median_weights``.
+       the key is the instruction's own name, ``fused_median_weights``, unless
+       :func:`extract_op_times` was given the trace's HLO and hands the
+       instruction's ``op_name`` in as ``tf_op``.
     """
     if name.startswith("end: ") or "::" in name:
         return None
@@ -101,11 +117,15 @@ class NoDevicePlane(RuntimeError):
     """A trace that had to come from a device carries no device plane line."""
 
 
+def _device_planes(profile_data) -> list:
+    return [p for p in profile_data.planes
+            if "/device:" in p.name and "CUSTOM" not in p.name]
+
+
 def _device_lines(profile_data, line_name: str) -> list:
     return [
         line
-        for plane in profile_data.planes
-        if "/device:" in plane.name and "CUSTOM" not in plane.name
+        for plane in _device_planes(profile_data)
         for line in plane.lines
         if line.name == line_name
     ]
@@ -165,38 +185,296 @@ def _event_stats(ev) -> dict:
         return {}
 
 
+# --- the HLO the trace embeds: instruction name -> op_name -----------------------
+
+#: HLO opcodes whose event only contains others (its time is its body's)
+CONTAINER_OPCODES = frozenset({"while", "conditional", "call"})
+#: instructions that compute nothing: they say nothing of their fusion's phase
+_NO_WORK_OPCODES = frozenset(
+    {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"})
+PHASES = ("fwd", "bwd", "opt")
+
+
+def phase_of(op_name: str) -> str:
+    """Forward, backward or optimizer, from the names JAX's autodiff writes into
+    every instruction's ``op_name``: ``transpose(`` marks the transposed (backward)
+    computation, a recomputed forward under ``checkpoint`` included; else ``jvp(``
+    the linearized forward; everything else (AdamW, loss scaling, what autodiff
+    never saw, instructions the compiler added without a name) is ``opt``."""
+    if "transpose(" in op_name:
+        return "bwd"
+    if "jvp(" in op_name:
+        return "fwd"
+    return "opt"
+
+
+@dataclasses.dataclass(frozen=True)
+class HloInstruction:
+    op_name: str  # metadata.op_name, "" where the compiler wrote none
+    opcode: str
+    #: the phases of the named, working instructions of a fusion's fused
+    #: computation; more than one marks a fusion that no single phase owns
+    fused_phases: frozenset = frozenset()
+
+    @property
+    def is_container(self) -> bool:
+        return self.opcode in CONTAINER_OPCODES
+
+    @property
+    def phase(self) -> str:
+        """:func:`phase_of` its own ``op_name``; a fusion the compiler left
+        unnamed takes the phase of its fused computation where that is one."""
+        if not self.op_name and len(self.fused_phases) == 1:
+            return next(iter(self.fused_phases))
+        return phase_of(self.op_name)
+
+
+def _varint(buf, pos: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[pos]
+        pos += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, pos
+        shift += 7
+
+
+def _fields(buf) -> Iterator[tuple[int, int, object]]:
+    """(field number, wire type, value) for each field of one protobuf message:
+    an int for varints, a memoryview for length-delimited and fixed-width ones."""
+    buf = memoryview(buf)
+    pos, end = 0, len(buf)
+    while pos < end:
+        key, pos = _varint(buf, pos)
+        number, wire = key >> 3, key & 7
+        if wire == 0:
+            value, pos = _varint(buf, pos)
+        elif wire == 2:
+            size, pos = _varint(buf, pos)
+            value, pos = buf[pos:pos + size], pos + size
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+            value, pos = buf[pos:pos + size], pos + size
+        else:
+            raise ValueError(f"wire type {wire} at byte {pos}: not a protobuf message")
+        yield number, wire, value
+
+
+def _first(buf, number: int, default=None):
+    return next((v for n, _, v in _fields(buf) if n == number), default)
+
+
+def _text(value) -> str:
+    return bytes(value).decode("utf-8", "replace") if value is not None else ""
+
+
+def _ints(buf, number: int) -> list[int]:
+    """A repeated int64 field, packed or not."""
+    out = []
+    for n, wire, value in _fields(buf):
+        if n != number:
+            continue
+        if wire == 0:
+            out.append(value)
+        else:
+            pos = 0
+            while pos < len(value):
+                item, pos = _varint(value, pos)
+                out.append(item)
+    return out
+
+
+def _module_instructions(hlo_proto) -> dict[str, HloInstruction]:
+    """HloProto.hlo_module(1).computations(3).instructions(2): name(1), opcode(2),
+    metadata(7).op_name(2), called_computation_ids(38); a computation's id is 5.
+    Instruction names are unique in a module."""
+    module = _first(hlo_proto, 1)
+    if module is None:
+        return {}
+    raw: dict[int, list[tuple[str, str, str, list[int]]]] = {}
+    for number, _, computation in _fields(module):
+        if number != 3:
+            continue
+        rows = []
+        for n, _, instruction in _fields(computation):
+            if n == 2:
+                metadata = _first(instruction, 7)
+                rows.append((
+                    _text(_first(instruction, 1)), _text(_first(instruction, 2)),
+                    _text(_first(metadata, 2)) if metadata is not None else "",
+                    _ints(instruction, 38),
+                ))
+        raw[_first(computation, 5, 0)] = rows
+
+    def fused_phases(computation_id: int) -> frozenset:
+        found = set()
+        for _, opcode, op_name, called in raw.get(computation_id, ()):
+            if op_name and opcode not in _NO_WORK_OPCODES:
+                found.add(phase_of(op_name))
+            for c in called:  # a fused reduce's own computation
+                found |= fused_phases(c)
+        return frozenset(found)
+
+    return {
+        name: HloInstruction(
+            op_name, opcode,
+            fused_phases(called[0]) if opcode == "fusion" and called else frozenset())
+        for rows in raw.values() for name, opcode, op_name, called in rows
+    }
+
+
+def hlo_instructions(trace: bytes, known=()) -> dict[int, dict[str, HloInstruction]]:
+    """{program id: {instruction name: :class:`HloInstruction`}} from the
+    ``Hlo Proto`` stats of an ``.xplane.pb`` file's ``/host:metadata`` plane. The
+    program id is the number in an ``XLA Modules`` event's name
+    (``jit_train_step(<id>)``) and names one compiled program for good, so a
+    caller that reads window after window passes the ids it has as ``known`` and
+    gets only the others. Empty where the trace embeds no HLO.
+
+    Wire format read: XSpace.planes(1); XPlane.name(2), event_metadata(4) and
+    stat_metadata(5) maps (key 1, value 2); XEventMetadata.id(1), stats(5);
+    XStat.metadata_id(1), bytes_value(6); XStatMetadata.name(2)."""
+    out: dict[int, dict[str, HloInstruction]] = {}
+    for number, _, plane in _fields(trace):
+        if number != 1 or _text(_first(plane, 2)) != "/host:metadata":
+            continue
+        hlo_stat_ids = {
+            _first(entry, 1) for n, _, entry in _fields(plane)
+            if n == 5 and _text(_first(_first(entry, 2, b""), 2)) == "Hlo Proto"
+        }
+        for n, _, entry in _fields(plane):
+            if n != 4:
+                continue
+            metadata = _first(entry, 2, b"")
+            program_id = _first(metadata, 1, 0)
+            if program_id in known:
+                continue
+            for m, _, stat in _fields(metadata):
+                if m == 5 and _first(stat, 1) in hlo_stat_ids:
+                    proto = _first(stat, 6)
+                    if proto is not None:
+                        out[program_id] = _module_instructions(proto)
+    return out
+
+
+def instruction_name(event_name: str) -> str:
+    """``%fusion.12 = f32[...] fusion(...)`` -> ``fusion.12`` (an ``XLA Ops`` event
+    is named by its whole instruction); any other name is returned as it is."""
+    m = _HLO_INSTRUCTION.match(event_name)
+    return m.group(1) if m else event_name
+
+
+class DeviceOp(NamedTuple):
+    """One ``XLA Ops`` event joined to its program's execution and its instruction."""
+
+    plane: int  # index among the device planes
+    program: str  # the execution's name as the trace has it, ``jit_f(<id>)``
+    execution: int  # index of the execution among the plane's ``XLA Modules`` events
+    execution_s: float  # the execution's own device seconds
+    event: object
+    instruction: Optional[HloInstruction]  # None where the trace holds no HLO for it
+
+
+def device_ops(profile_data, hlo: dict[int, dict[str, HloInstruction]]) -> Iterator[DeviceOp]:
+    """Every ``XLA Ops`` event of the device planes joined to its program and its
+    HLO instruction. An op belongs to the ``XLA Modules`` execution that holds its
+    midpoint (one program runs at a time on a core); one outside every execution
+    is not yielded."""
+    for plane_index, plane in enumerate(_device_planes(profile_data)):
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Modules" not in lines or "XLA Ops" not in lines:
+            continue
+        executions = sorted(
+            (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+            for ev in lines["XLA Modules"].events)
+        starts = [e[0] for e in executions]
+        ids = [_HASH_SUFFIX.search(e[2]) for e in executions]
+        instructions = [hlo.get(int(m.group(1)), {}) if m else {} for m in ids]
+        for ev in lines["XLA Ops"].events:
+            middle = ev.start_ns + ev.duration_ns / 2
+            k = bisect.bisect_right(starts, middle) - 1
+            if k < 0 or middle > executions[k][1]:
+                continue
+            start, end, program = executions[k]
+            yield DeviceOp(plane_index, program, k, (end - start) * 1e-9, ev,
+                           instructions[k].get(instruction_name(ev.name)))
+
+
+def step_phase_times(profile_data, hlo, program: str = "train_step") -> list[dict]:
+    """The device time of each execution of the step's program (the ``XLA Modules``
+    events whose name holds ``program``), split by :func:`phase_of` of each op's own
+    ``op_name``: one ``{"fwd", "bwd", "opt", "mixed", "unnamed", "module"}`` of
+    seconds per execution, in time order. Containers are skipped (their body's ops
+    are events of their own). ``mixed`` is the time of fusions whose fused
+    computation holds named work of more than one phase and ``unnamed`` that of ops
+    the compiler made without an ``op_name`` (both are also inside the three
+    phases: :attr:`HloInstruction.phase`); ``module`` is the execution's own
+    duration.
+    Empty where the trace has no such program or embeds no HLO for it."""
+    rows: dict[tuple[int, int], dict] = {}
+    for op in device_ops(profile_data, hlo):
+        instruction = op.instruction
+        if program not in op.program or instruction is None or instruction.is_container:
+            continue
+        row = rows.setdefault((op.plane, op.execution), dict.fromkeys(
+            (*PHASES, "mixed", "unnamed"), 0.0) | {"module": op.execution_s})
+        seconds = op.event.duration_ns * 1e-9
+        row[instruction.phase] += seconds
+        if not instruction.op_name:
+            row["unnamed"] += seconds
+        if len(instruction.fused_phases) > 1:
+            row["mixed"] += seconds
+    return [row for _, row in sorted(rows.items())]
+
+
 def extract_op_times(
-    profile_data, require_device: bool = False
+    profile_data, require_device: bool = False,
+    hlo: Optional[dict[int, dict[str, HloInstruction]]] = None,
 ) -> dict[str, list[float]]:
     """Per-op/scope device durations (seconds) from one xplane ProfileData —
     one granularity below :func:`extract_program_times`, the closest XLA gets
     to CUPTI's per-kernel stream (kernels themselves are fused away).
 
     Source: device planes' ``XLA Ops`` line (true device time, one event per
-    HLO op execution, ``tf_op`` scope attribution when XLA carries it).
-    Without one, ``require_device`` raises :class:`NoDevicePlane`; otherwise
-    (the CPU tests) the PjRt CPU client's per-op thread line stands in
+    HLO op execution). With ``hlo`` (:func:`hlo_instructions` of the same trace
+    file) an event that carries no ``tf_op`` of its own, as a v5e's carry none,
+    is keyed by the scope of its instruction's ``op_name``, and containers are
+    left out; without it such an event keys by its instruction's bare name.
+    Without a device line, ``require_device`` raises :class:`NoDevicePlane`;
+    otherwise (the CPU tests) the PjRt CPU client's per-op thread line stands in
     (host-inclusive op durations — a different clock, same pipeline
     mechanics)."""
     out: dict[str, list[float]] = {}
+
+    def add(ev, stats: dict) -> None:
+        key = op_scope_key(ev.name, stats)
+        if key is not None:
+            out.setdefault(key, []).append(float(ev.duration_ns) * 1e-9)
+
     lines = _device_lines(profile_data, "XLA Ops")
+    if lines and hlo:
+        for op in device_ops(profile_data, hlo):
+            stats, instruction = _event_stats(op.event), op.instruction
+            if instruction is not None:
+                if instruction.is_container:
+                    continue
+                if instruction.op_name:
+                    stats.setdefault("tf_op", instruction.op_name)
+            add(op.event, stats)
+        return out
     for line in lines:
         for ev in line.events:
-            key = op_scope_key(ev.name, _event_stats(ev))
-            if key is not None:
-                out.setdefault(key, []).append(float(ev.duration_ns) * 1e-9)
+            add(ev, _event_stats(ev))
     if lines:
         return out
     if require_device:
         raise _no_device_plane("XLA Ops", profile_data)
     for plane in profile_data.planes:
         for line in plane.lines:
-            if "XLAPjRt" not in line.name:
-                continue
-            for ev in line.events:
-                key = op_scope_key(ev.name, _event_stats(ev))
-                if key is not None:
-                    out.setdefault(key, []).append(float(ev.duration_ns) * 1e-9)
+            if "XLAPjRt" in line.name:
+                for ev in line.events:
+                    add(ev, _event_stats(ev))
     return out
 
 
@@ -207,7 +485,14 @@ class DeviceTimeProfiler:
     on a TPU backend — has no device plane RAISES. A caller that must not break
     a step on a profiling fault (``integrations/straggler_callback.py``)
     catches and counts; nothing here turns a fault into silence or into host
-    times under a device name."""
+    times under a device name.
+
+    Every window counts its own cost on the host: ``stop()`` records one
+    ``profiler_window`` event with the seconds of its three parts (``start_s``
+    opening the profiler, ``stop_s`` closing it and writing the trace, ``parse_s``
+    reading it back and folding it in), the trace's ``trace_bytes`` and its
+    ``profile_source`` (:attr:`source`; the event's own ``source`` is the
+    envelope's, ``"telemetry"``)."""
 
     def __init__(self, trace_root: Optional[str] = None, collect_ops: bool = False):
         self._root = trace_root
@@ -219,12 +504,14 @@ class DeviceTimeProfiler:
         self.collect_ops = collect_ops
         self._op_samples: dict[str, deque] = {}
         self._op_fresh: dict[str, list[float]] = {}
+        self._hlo: dict[int, dict[str, HloInstruction]] = {}  # of every program seen
         self.active = False
         #: where the last parsed window's times came from (:func:`trace_source`):
         #: ``"device"`` | ``"host"``; None before the first window
         self.source: Optional[str] = None
         #: windows parsed into the stats so far
         self.windows = 0
+        self._start_s = 0.0
 
     # -- capture window ------------------------------------------------------
 
@@ -235,6 +522,7 @@ class DeviceTimeProfiler:
             return
         import jax
 
+        t0 = time.perf_counter()
         self._window_dir = tempfile.mkdtemp(prefix="devprof_", dir=self._root)
         try:
             jax.profiler.start_trace(self._window_dir)
@@ -243,6 +531,7 @@ class DeviceTimeProfiler:
             self._window_dir = None
             raise
         self.active = True
+        self._start_s = time.perf_counter() - t0
 
     def stop(self) -> None:
         """End the window and fold its per-program samples into the stats."""
@@ -251,9 +540,14 @@ class DeviceTimeProfiler:
         import jax
         from jax.profiler import ProfileData
 
+        from tpu_resiliency.utils.events import record
+
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        t_stopped = time.perf_counter()
         self.active = False
         require_device = jax.default_backend() == "tpu"
+        trace_bytes = 0
         try:
             files = glob.glob(
                 os.path.join(self._window_dir, "**", "*.xplane.pb"), recursive=True
@@ -261,7 +555,10 @@ class DeviceTimeProfiler:
             if not files:
                 raise RuntimeError("the profiler window wrote no xplane trace")
             for f in files:
-                data = ProfileData.from_file(f)
+                with open(f, "rb") as fh:
+                    blob = fh.read()
+                trace_bytes += len(blob)
+                data = ProfileData.from_serialized_xspace(blob)
                 times = extract_program_times(data, require_device)
                 self.source = trace_source(data)
                 for name, secs in times.items():
@@ -271,7 +568,9 @@ class DeviceTimeProfiler:
                     ring.extend(secs)
                     self._fresh.setdefault(name, []).extend(secs)
                 if self.collect_ops:
-                    for name, secs in extract_op_times(data, require_device).items():
+                    self._hlo.update(hlo_instructions(blob, known=self._hlo))
+                    ops = extract_op_times(data, require_device, self._hlo)
+                    for name, secs in ops.items():
                         ring = self._op_samples.setdefault(
                             name, deque(maxlen=MAX_SAMPLES_PER_PROGRAM)
                         )
@@ -282,6 +581,11 @@ class DeviceTimeProfiler:
             if self._window_dir:
                 shutil.rmtree(self._window_dir, ignore_errors=True)
                 self._window_dir = None
+            record(
+                "telemetry", "profiler_window", start_s=self._start_s,
+                stop_s=t_stopped - t0, parse_s=time.perf_counter() - t_stopped,
+                trace_bytes=trace_bytes, profile_source=self.source,
+            )
 
     def __enter__(self):
         self.start()

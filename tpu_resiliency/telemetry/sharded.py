@@ -41,6 +41,7 @@ import numpy as np
 
 from tpu_resiliency.telemetry import scoring
 from tpu_resiliency.telemetry.reporting import Report
+from tpu_resiliency.utils.tracing import annotate
 
 DEFAULT_WINDOW = 32
 
@@ -342,8 +343,10 @@ class MeshTelemetry:
         passes the globally-agreed column list, which can be shorter than this
         object's column capacity — the tail columns carry counts=0 and score 1.0).
         """
-        scores = self.score_local_summary(medians, weights, counts)
-        return self.materialize(scores, rank=rank, signal_names=signal_names)
+        with annotate("tpures/telemetry/report/score"):
+            scores = self.score_local_summary(medians, weights, counts)
+        with annotate("tpures/telemetry/report/materialize"):
+            return self.materialize(scores, rank=rank, signal_names=signal_names)
 
     def materialize(
         self, scores: scoring.TelemetryScores, *, rank: int = 0,

@@ -27,15 +27,24 @@ Usage::
 Spans are observability, not control flow: every operation here is best-effort
 and an exception inside the wrapped block still emits a ``span_end`` with
 ``ok=False`` and the error before re-raising.
+
+**On the profiler's clock.** :func:`annotate` is the one primitive that puts a
+host interval into an open ``jax.profiler`` window, beside the device's ops:
+every :func:`span` is also the annotation ``tpures/<name>``, and per-step sites
+(the loop's hooks, the telemetry report) use :func:`annotate` alone, without an
+event record. With no window open an annotation costs a fraction of a
+microsecond; this module never imports JAX (the launcher, the agents and the
+rank monitor import it and must stay off the chip).
 """
 
 from __future__ import annotations
 
 import os
 import secrets
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Optional
 
 from tpu_resiliency.utils import events
@@ -45,7 +54,21 @@ from tpu_resiliency.utils.events import record
 TRACE_ID_ENV = events.TRACE_ID_ENV
 PARENT_SPAN_ENV = events.PARENT_SPAN_ENV
 
+#: prefix of every annotation this package writes into a profiler window
+ANNOTATION_PREFIX = "tpures/"
+
 _tls = threading.local()
+
+
+def annotate(name: str):
+    """Context manager: the host interval ``name`` on the profiler's clock
+    (``jax.profiler.TraceAnnotation``), recorded only while a profiler window
+    is open. A null context in a process that has not imported JAX."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None too while jax is mid-import
+    if profiler is None:
+        return nullcontext()
+    return profiler.TraceAnnotation(name)
 
 
 def _stack() -> list:
@@ -116,7 +139,8 @@ def span(source: str, name: str, **payload: Any):
     event is recorded, so both span events (and every ``record()`` inside the
     block) carry it as their envelope ``span_id``; the parent linkage travels in
     the begin event's ``parent_id`` payload. Yields the span id (useful for
-    handing to threads or asserting pairing in tests).
+    handing to threads or asserting pairing in tests). The span is also the
+    annotation ``tpures/<name>`` in any open profiler window (:func:`annotate`).
     """
     sid = secrets.token_hex(8)
     parent = current_span_id()
@@ -126,7 +150,8 @@ def span(source: str, name: str, **payload: Any):
     record(source, "span_begin", span=name, parent_id=parent, **payload)
     failure: Optional[str] = None
     try:
-        yield sid
+        with annotate(ANNOTATION_PREFIX + name):
+            yield sid
     except BaseException as e:
         failure = repr(e)
         raise
@@ -148,21 +173,3 @@ def span(source: str, name: str, **payload: Any):
                     stack.remove(sid)
                 except ValueError:
                     pass
-
-
-def traced(source: str, name: Optional[str] = None):
-    """Decorator form of :func:`span` (``@prof``'s causal sibling: same timing
-    payload, but begin/end pairing and parent linkage instead of one record)."""
-
-    def deco(fn):
-        label = name or getattr(fn, "__name__", "call")
-
-        def wrapped(*args, **kwargs):
-            with span(source, label):
-                return fn(*args, **kwargs)
-
-        wrapped.__name__ = getattr(fn, "__name__", label)
-        wrapped.__wrapped__ = fn
-        return wrapped
-
-    return deco
