@@ -79,3 +79,34 @@ def profiler_window(tmp_path):
         names.extend(name for _, name in sorted(events))
 
     return window
+
+
+@pytest.fixture
+def held_stop_trace(monkeypatch):
+    """``hold = held_stop_trace(until)``: every ``jax.profiler.stop_trace`` first waits
+    for ``until()`` (a ``threading.Event``'s ``wait``, a ``time.sleep``), so a profiler
+    window's close is in flight for as long as the test wants; ``hold.calls`` has
+    ``"start_trace"`` / ``"stop_trace"`` in the order they went through."""
+    import types
+
+    import jax
+
+    real_stop, real_start = jax.profiler.stop_trace, jax.profiler.start_trace
+
+    def arm(until):
+        hold = types.SimpleNamespace(calls=[])
+
+        def stop_trace():
+            until()
+            real_stop()
+            hold.calls.append("stop_trace")
+
+        def start_trace(*args, **kwargs):
+            hold.calls.append("start_trace")
+            return real_start(*args, **kwargs)
+
+        monkeypatch.setattr(jax.profiler, "stop_trace", stop_trace)
+        monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+        return hold
+
+    return arm

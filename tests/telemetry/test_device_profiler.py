@@ -441,3 +441,192 @@ def test_op_times_key_by_scope_where_the_hlo_is_given(step_trace):
     total = sum(sum(v) for v in scoped.values())
     programs = sum(sum(v) for v in extract_program_times(data, require_device=True).values())
     assert total <= programs
+
+
+# --- the deferred close: stop_async / wait, and what a window asks for -----------
+
+def _window_events(seen):
+    return [e.payload for e in seen if e.kind == "profiler_window"]
+
+
+@pytest.fixture
+def gated_stop_trace(held_stop_trace):
+    """``gate, hold = gated_stop_trace()``: ``jax.profiler.stop_trace`` waits for
+    ``gate.set()`` (at most 5 s) before it closes the session."""
+    import threading
+
+    def arm():
+        gate = threading.Event()
+        return gate, held_stop_trace(lambda: gate.wait(5.0))
+
+    return arm
+
+
+def test_stop_async_returns_at_once_and_the_samples_follow(tmp_path, gated_stop_trace):
+    import threading
+
+    gate, hold = gated_stop_trace()
+    prof = DeviceTimeProfiler(trace_root=str(tmp_path))
+    work = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((64, 64))
+    work(x)
+    prof.start()
+    jax.block_until_ready(work(x))
+    prof.stop_async()  # returns while stop_trace is still held at the gate
+    assert prof.closing and not prof.active
+    assert prof.drain() == {} and prof.windows == 0
+    closers = [t for t in threading.enumerate() if t.name == "devprof-close"]
+    assert len(closers) == 1 and closers[0].daemon  # cannot hold a dying process
+    prof.stop_async()  # no window open: nothing to request
+    gate.set()
+    prof.wait()
+    assert not prof.closing and prof.windows == 1 and prof.source == "host"
+    assert prof.drain()
+    assert list(tmp_path.iterdir()) == []
+    prof.wait()  # nothing in flight, nothing to raise
+    assert closers[0].is_alive()  # one closer for all of a profiler's deferred closes
+    prof.stop()  # ... until the profiler is stopped for good
+    assert not closers[0].is_alive()
+
+
+@pytest.mark.parametrize("close", ["stop", "with"])
+def test_stop_and_with_are_still_synchronous(tmp_path, gated_stop_trace, close):
+    """``stop()`` is "request the close, then wait": when it returns the session is
+    closed, the samples are in, and another profiler can open a window at once."""
+    import threading
+
+    gate, hold = gated_stop_trace()
+    threading.Timer(0.05, gate.set).start()
+    prof = DeviceTimeProfiler(trace_root=str(tmp_path))
+    work = jax.jit(lambda x: jnp.tanh(x * 2.0).sum())
+    x = jnp.ones((32,))
+    work(x)
+    if close == "with":
+        with prof:
+            jax.block_until_ready(work(x))
+    else:
+        prof.start()
+        jax.block_until_ready(work(x))
+        prof.stop()
+    assert hold.calls == ["start_trace", "stop_trace"]
+    assert not prof.closing and not prof.active and prof.windows == 1
+    assert prof.drain()
+    with DeviceTimeProfiler(trace_root=str(tmp_path)):
+        pass
+
+
+def test_start_waits_for_a_close_in_flight_and_wait_s_says_so(tmp_path, gated_stop_trace):
+    """One process holds one profiler session: the next window opens after the last
+    one's close, late and never skipped, and its event counts the wait."""
+    import threading
+
+    from tpu_resiliency.utils import events
+
+    gate, hold = gated_stop_trace()
+    seen = []
+    events.add_sink(seen.append)
+    prof = DeviceTimeProfiler(trace_root=str(tmp_path))
+    try:
+        prof.start()
+        jax.block_until_ready(jnp.ones((8,)) + 1)
+        prof.stop_async()
+        # open the gate only once start() is waiting for the closer, and 0.05 s
+        # after that: the wait is then at least those 0.05 s
+        waiting, real_wait = threading.Event(), prof._idle.wait
+
+        def wait(*args):
+            waiting.set()
+            return real_wait(*args)
+
+        prof._idle.wait = wait
+
+        def release():
+            assert waiting.wait(5.0)
+            time.sleep(0.05)
+            gate.set()
+
+        threading.Thread(target=release, daemon=True).start()
+        prof.start()
+        jax.block_until_ready(jnp.ones((8,)) + 1)
+        prof.stop()
+    finally:
+        events.remove_sink(seen.append)
+    assert hold.calls == ["start_trace", "stop_trace", "start_trace", "stop_trace"]
+    first, second = _window_events(seen)
+    assert set(second) >= {"start_s", "wait_s", "stop_s", "parse_s", "trace_bytes",
+                           "profile_source"}
+    assert first["wait_s"] < 0.05 <= second["wait_s"]
+    assert first["stop_s"] >= 0.05  # the held stop_trace is the close's, not the caller's
+    assert prof.windows == 2
+
+
+def test_a_deferred_closes_fault_is_raised_by_wait_once(tmp_path, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prof = DeviceTimeProfiler(trace_root=str(tmp_path))
+    prof.start()
+    jax.block_until_ready(jnp.ones((8,)) + 1)
+    prof.stop_async()
+    with pytest.raises(NoDevicePlane):
+        prof.wait()  # a CPU trace under a backend that calls itself a TPU
+    prof.wait()
+    assert prof.windows == 0 and list(tmp_path.iterdir()) == []
+    monkeypatch.undo()
+    with prof:  # and the session was closed: the next window opens
+        pass
+    assert prof.windows == 1 and prof._closer is None
+
+
+@pytest.mark.parametrize("backend, collect_ops, want", [
+    ("cpu", False, None),
+    ("cpu", True, None),
+    ("tpu", False, (0, 0, False)),
+    ("tpu", True, (0, 0, True)),
+])
+def test_a_window_asks_for_what_its_backend_and_collect_ops_read(
+        tmp_path, monkeypatch, backend, collect_ops, want):
+    """On a TPU only the device planes (and the ``Hlo Proto`` for ``collect_ops``);
+    anywhere else the profiler's defaults, whose Python tracer the fallback reads."""
+    captured = []
+    real_start = jax.profiler.start_trace
+
+    def start_trace(log_dir, **kwargs):
+        captured.append(kwargs.get("profiler_options"))
+        return real_start(log_dir, **kwargs)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    prof = DeviceTimeProfiler(trace_root=str(tmp_path), collect_ops=collect_ops)
+    prof.start()
+    jax.block_until_ready(jax.jit(lambda x: x + 1)(jnp.ones((8,))))
+    try:
+        prof.stop()
+    except NoDevicePlane:
+        assert backend == "tpu"  # the CPU's trace has none
+    (options,) = captured
+    if want is None:
+        assert options is None and prof.drain()  # the fallback found its events
+    else:
+        assert (options.host_tracer_level, options.python_tracer_level,
+                options.enable_hlo_proto) == want
+
+
+@pytest.mark.parametrize("closed_after_ms, want", [
+    (None, {"jit__push_impl": 5, "jit__score_reset_impl": 1}),  # no request time: all
+    (60.0, {"jit__push_impl": 5, "jit__score_reset_impl": 1}),  # all ended before it
+    (50.0, {"jit__push_impl": 5}),  # 0.7 ms into the 1.05 ms scorer: not a sample
+    (48.2, {"jit__push_impl": 3}),  # between the third push and the fourth
+    (10.0, {}),  # nothing had run yet
+])
+def test_only_executions_that_ended_before_the_close_are_samples(closed_after_ms, want):
+    """The recorded v5e window (five pushes from 47.89 ms, the scorer from 49.28 to
+    50.34 ms after the session's start) read as if its close had been requested at
+    another time: what had not ended by then is left out, whole or cut."""
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(V5E_TRACE)
+    start_ns = 1790439250044175170  # the trace's own ``profile_start_time``
+    closed_at_ns = None if closed_after_ms is None else start_ns + int(closed_after_ms * 1e6)
+    times = extract_program_times(pd, require_device=True, closed_at_ns=closed_at_ns)
+    assert {k: len(v) for k, v in times.items()} == want
+    if "jit__score_reset_impl" in want:
+        assert times["jit__score_reset_impl"] == [pytest.approx(1.054546e-3)]

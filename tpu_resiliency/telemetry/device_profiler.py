@@ -13,7 +13,11 @@ device-exact durations.
 :class:`DeviceTimeProfiler` preserves the reference contract:
 
 - ``start()`` / ``stop()`` bracket a capture window (run a window every Nth report
-  interval, like CUPTI's ``profiling_interval`` — tracing is not free);
+  interval, like CUPTI's ``profiling_interval`` — tracing is not free: on a v5e
+  opening one takes 0.04 s and closing it 0.3 s). A loop that must not wait for
+  the close calls ``stop_async()`` instead: the profiler's closer thread stops
+  the session and parses the trace beside the next steps, and the next
+  ``start()`` waits for it only if it is still at work;
 - ``drain()`` yields the new per-program duration samples since the last drain
   (feed them to ``Detector.record_program_samples`` so programs join the scored
   telemetry matrix as ``prog/...`` signals);
@@ -49,14 +53,20 @@ import bisect
 import dataclasses
 import glob
 import os
+import queue
 import re
 import shutil
 import tempfile
+import threading
 import time
 from collections import deque
 from typing import Iterator, NamedTuple, Optional
 
 import numpy as np
+
+from tpu_resiliency.utils.logging import get_logger
+
+log = get_logger(__name__)
 
 _HASH_SUFFIX = re.compile(r"\((\d+)\)$")
 _PJIT = re.compile(r"^PjitFunction\((.+)\)$")
@@ -144,8 +154,18 @@ def trace_source(profile_data) -> str:
     return "device" if _device_lines(profile_data, "XLA Modules") else "host"
 
 
+def _session_start_ns(profile_data) -> Optional[int]:
+    """When the session began, on the host's wall clock (the ``Task Environment``
+    plane's ``profile_start_time``); event times count from it. None where the
+    trace does not say."""
+    for plane in profile_data.planes:
+        if plane.name == "Task Environment":
+            return dict(plane.stats).get("profile_start_time")
+    return None
+
+
 def extract_program_times(
-    profile_data, require_device: bool = False
+    profile_data, require_device: bool = False, closed_at_ns: Optional[int] = None
 ) -> dict[str, list[float]]:
     """Per-program device durations (seconds) from one xplane ProfileData.
 
@@ -153,11 +173,27 @@ def extract_program_times(
     ``require_device`` (a TPU backend) raises :class:`NoDevicePlane`; otherwise
     (the CPU tests) the host plane's ``PjitFunction`` events stand in
     (host-inclusive dispatch time). :func:`trace_source` says which it was.
-    """
+
+    ``closed_at_ns`` is the wall-clock time (``time.time_ns()``) at which the
+    session's close was requested: only executions that had ended by then are
+    samples. A v5e goes on tracing for 24-35 ms after the request and records an
+    execution it never saw the end of as an ordinary event, cut short where the
+    trace ends (51 ms of a 101 ms program; chip run, PR 26), with nothing on the
+    event to tell it by; an execution of a later step that ended in those
+    milliseconds is left out with it. The trace's clock and the host's agree to
+    0.1 ms there (a step's execution ends 1.1-2.9 ms before the request that
+    follows its loss's read-back)."""
     out: dict[str, list[float]] = {}
     lines = _device_lines(profile_data, "XLA Modules")
+    cutoff = None
+    if closed_at_ns is not None and lines:
+        session_start = _session_start_ns(profile_data)
+        if session_start is not None:
+            cutoff = closed_at_ns - session_start
     for line in lines:
         for ev in line.events:
+            if cutoff is not None and ev.start_ns + ev.duration_ns > cutoff:
+                continue
             name = normalize_program_name(ev.name)
             out.setdefault(name, []).append(float(ev.duration_ns) * 1e-9)
     if lines:
@@ -478,6 +514,23 @@ def extract_op_times(
     return out
 
 
+def _window_options(collect_ops: bool):
+    """What a window asks the profiler to collect: on a TPU backend only what this
+    module reads, the device planes (no host tracer, no Python tracer) and, for
+    ``collect_ops``, the ``Hlo Proto`` its join needs. ``None``, the profiler's own
+    defaults, anywhere else: the CPU fallback of :func:`extract_program_times`
+    reads the Python tracer's ``PjitFunction`` events."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 0
+    options.python_tracer_level = 0
+    options.enable_hlo_proto = collect_ops
+    return options
+
+
 class DeviceTimeProfiler:
     """Windowed per-program device-time capture with the CUPTI manager contract.
 
@@ -487,70 +540,164 @@ class DeviceTimeProfiler:
     catches and counts; nothing here turns a fault into silence or into host
     times under a device name.
 
-    Every window counts its own cost on the host: ``stop()`` records one
-    ``profiler_window`` event with the seconds of its three parts (``start_s``
-    opening the profiler, ``stop_s`` closing it and writing the trace, ``parse_s``
-    reading it back and folding it in), the trace's ``trace_bytes`` and its
-    ``profile_source`` (:attr:`source`; the event's own ``source`` is the
-    envelope's, ``"telemetry"``)."""
+    **Closing a window is the expensive part** (stop the session, collect, write,
+    read back, parse: 0.3 s on a v5e where opening takes 0.04 s). ``stop()`` does it
+    on the caller's thread, so ``with prof:`` and every caller of ``stop()`` see a
+    closed window and its faults as before. ``stop_async()`` hands it to the
+    profiler's closer thread and returns: the caller's next steps run while the
+    closer works, ``drain()`` yields the window's samples once it is done, and
+    ``wait()`` raises what the close raised. One process holds one profiler
+    session, so ``start()`` waits for a close still in flight; a window is late
+    then, never skipped. The closer is one daemon thread for all of a profiler's
+    deferred closes, started by the first and retired by ``stop()``, not one a
+    window: libtpu's collect takes a second longer on every thread's first call
+    (chip run, PR 26: 1.3 s a window from a new thread each, 0.3 s from one kept).
+
+    **What a window's samples are**: the executions that ended between
+    ``start()`` and the request to close it, by whichever of ``stop()`` and
+    ``stop_async()``. The session itself lives on until the close is done, beside
+    the caller's next steps after ``stop_async()``; what it records of those is
+    not this window's (:func:`extract_program_times`, ``closed_at_ns``): neither
+    an execution cut off where the trace ends nor a later one that ended before
+    that. On a v5e the windows the loop closed this way held their own step's
+    executions and nothing else (chip run, PR 26).
+
+    Every window counts its own cost on the host in one ``profiler_window`` event,
+    recorded when its close is done: ``start_s`` opening the profiler, ``wait_s``
+    the seconds ``start()`` first waited for the previous window's close,
+    ``stop_s`` closing the session and writing the trace, ``parse_s`` reading it
+    back and folding it in, the trace's ``trace_bytes`` and its ``profile_source``
+    (:attr:`source`; the event's own ``source`` is the envelope's,
+    ``"telemetry"``). ``start_s + wait_s`` is what the caller of ``start()`` paid;
+    after ``stop_async()`` the rest ran beside the caller."""
 
     def __init__(self, trace_root: Optional[str] = None, collect_ops: bool = False):
         self._root = trace_root
         self._window_dir: Optional[str] = None
+        #: guards what the closer thread writes and the caller reads: the four
+        #: sample stores, :attr:`source`, :attr:`windows`
+        self._lock = threading.Lock()
         self._samples: dict[str, deque] = {}
         self._fresh: dict[str, list[float]] = {}
         #: opt-in per-op/scope granularity (extract_op_times) alongside the
-        #: per-program default — parse cost only, no extra tracing overhead.
+        #: per-program default: parse cost, and on a TPU the ``Hlo Proto`` of
+        #: every traced program in the trace.
         self.collect_ops = collect_ops
         self._op_samples: dict[str, deque] = {}
         self._op_fresh: dict[str, list[float]] = {}
         self._hlo: dict[int, dict[str, HloInstruction]] = {}  # of every program seen
+        #: a window is open (``start()`` to the request to close it)
         self.active = False
         #: where the last parsed window's times came from (:func:`trace_source`):
         #: ``"device"`` | ``"host"``; None before the first window
         self.source: Optional[str] = None
         #: windows parsed into the stats so far
         self.windows = 0
-        self._start_s = 0.0
+        self._start_s = self._wait_s = 0.0
+        self._closer: Optional[threading.Thread] = None
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()  # the closer's inbox
+        self._idle = threading.Event()  # no deferred close is in flight
+        self._idle.set()
+        self._close_error: Optional[BaseException] = None
 
     # -- capture window ------------------------------------------------------
 
     def start(self) -> None:
-        """Open a window. Raises when the process-global profiler is already
-        active (another window's leak, or user tracing)."""
+        """Open a window, after the previous one's close where that is still in
+        flight. Raises when the process-global profiler is already active
+        (another window's leak, or user tracing)."""
         if self.active:
             return
         import jax
 
         t0 = time.perf_counter()
+        self._idle.wait()  # the close's fault, if any, stays for wait()
+        t_free = time.perf_counter()
         self._window_dir = tempfile.mkdtemp(prefix="devprof_", dir=self._root)
         try:
-            jax.profiler.start_trace(self._window_dir)
+            jax.profiler.start_trace(
+                self._window_dir, profiler_options=_window_options(self.collect_ops))
         except BaseException:
             shutil.rmtree(self._window_dir, ignore_errors=True)
             self._window_dir = None
             raise
         self.active = True
-        self._start_s = time.perf_counter() - t0
+        self._wait_s, self._start_s = t_free - t0, time.perf_counter() - t_free
 
-    def stop(self) -> None:
-        """End the window and fold its per-program samples into the stats."""
+    def _take_window(self) -> tuple[str, float, float]:
+        """The open window, handed to whoever closes it."""
+        self.active = False
+        window, self._window_dir = self._window_dir, None
+        return window, self._start_s, self._wait_s
+
+    def stop_async(self) -> None:
+        """Hand the open window to the closer thread and return: it stops the
+        session and folds the window's samples into the stats. ``drain()`` finds
+        them when it is done; ``wait()`` raises what it raised."""
         if not self.active:
             return
+        if self._closer is None:
+            self._closer = threading.Thread(
+                target=self._closer_loop, name="devprof-close",
+                daemon=True)  # never holds a dying process
+            self._closer.start()
+        self._idle.clear()
+        self._jobs.put(self._take_window())
+
+    def _closer_loop(self) -> None:
+        while (window := self._jobs.get()) is not None:
+            try:
+                self._close(*window)
+            except BaseException as e:
+                if self._close_error is not None:
+                    log.warning("a profiler window's uncollected fault is replaced: "
+                                f"{self._close_error!r}")
+                self._close_error = e
+            finally:
+                self._idle.set()
+
+    @property
+    def closing(self) -> bool:
+        """A deferred close is still in flight."""
+        return not self._idle.is_set()
+
+    def wait(self) -> None:
+        """Wait for a deferred close in flight; raise, once, what the last one
+        raised."""
+        self._idle.wait()
+        error, self._close_error = self._close_error, None
+        if error is not None:
+            raise error
+
+    def stop(self) -> None:
+        """End the window and fold its per-program samples into the stats, on
+        the caller's thread; a deferred close in flight is waited for first.
+        Afterwards no session is open and no closer thread is left."""
+        self._idle.wait()
+        if self._closer is not None:
+            self._jobs.put(None)
+            self._closer.join()
+            self._closer = None
+        if self.active:
+            self._close(*self._take_window())
+        self.wait()
+
+    def _close(self, window_dir: str, start_s: float, wait_s: float) -> None:
+        """Close one window: stop the session, read the trace back, fold it in."""
         import jax
         from jax.profiler import ProfileData
 
         from tpu_resiliency.utils.events import record
 
-        t0 = time.perf_counter()
-        jax.profiler.stop_trace()
-        t_stopped = time.perf_counter()
-        self.active = False
-        require_device = jax.default_backend() == "tpu"
+        t0 = t_stopped = time.perf_counter()
+        closed_at_ns = time.time_ns()
         trace_bytes = 0
         try:
+            jax.profiler.stop_trace()
+            t_stopped = time.perf_counter()
+            require_device = jax.default_backend() == "tpu"
             files = glob.glob(
-                os.path.join(self._window_dir, "**", "*.xplane.pb"), recursive=True
+                os.path.join(window_dir, "**", "*.xplane.pb"), recursive=True
             )
             if not files:
                 raise RuntimeError("the profiler window wrote no xplane trace")
@@ -559,33 +706,31 @@ class DeviceTimeProfiler:
                     blob = fh.read()
                 trace_bytes += len(blob)
                 data = ProfileData.from_serialized_xspace(blob)
-                times = extract_program_times(data, require_device)
-                self.source = trace_source(data)
-                for name, secs in times.items():
-                    ring = self._samples.setdefault(
-                        name, deque(maxlen=MAX_SAMPLES_PER_PROGRAM)
-                    )
-                    ring.extend(secs)
-                    self._fresh.setdefault(name, []).extend(secs)
+                times = extract_program_times(data, require_device, closed_at_ns)
+                ops = {}
                 if self.collect_ops:
                     self._hlo.update(hlo_instructions(blob, known=self._hlo))
                     ops = extract_op_times(data, require_device, self._hlo)
-                    for name, secs in ops.items():
-                        ring = self._op_samples.setdefault(
-                            name, deque(maxlen=MAX_SAMPLES_PER_PROGRAM)
-                        )
-                        ring.extend(secs)
-                        self._op_fresh.setdefault(name, []).extend(secs)
-            self.windows += 1
+                with self._lock:
+                    self.source = trace_source(data)
+                    self._fold(times, self._samples, self._fresh)
+                    self._fold(ops, self._op_samples, self._op_fresh)
+            with self._lock:
+                self.windows += 1
         finally:
-            if self._window_dir:
-                shutil.rmtree(self._window_dir, ignore_errors=True)
-                self._window_dir = None
+            shutil.rmtree(window_dir, ignore_errors=True)
             record(
-                "telemetry", "profiler_window", start_s=self._start_s,
+                "telemetry", "profiler_window", start_s=start_s, wait_s=wait_s,
                 stop_s=t_stopped - t0, parse_s=time.perf_counter() - t_stopped,
                 trace_bytes=trace_bytes, profile_source=self.source,
             )
+
+    @staticmethod
+    def _fold(times: dict[str, list[float]], rings: dict[str, deque],
+              fresh: dict[str, list[float]]) -> None:
+        for name, secs in times.items():
+            rings.setdefault(name, deque(maxlen=MAX_SAMPLES_PER_PROGRAM)).extend(secs)
+            fresh.setdefault(name, []).extend(secs)
 
     def __enter__(self):
         self.start()
@@ -597,24 +742,25 @@ class DeviceTimeProfiler:
     # -- consumption ---------------------------------------------------------
 
     def drain(self) -> dict[str, list[float]]:
-        """New samples since the last drain (seconds per execution)."""
-        fresh, self._fresh = self._fresh, {}
+        """New samples since the last drain (seconds per execution): those of
+        every window whose close is done."""
+        with self._lock:
+            fresh, self._fresh = self._fresh, {}
         return fresh
 
     def drain_ops(self) -> dict[str, list[float]]:
         """New per-op/scope samples since the last drain (collect_ops only);
         feed to ``Detector.record_op_samples``."""
-        fresh, self._op_fresh = self._op_fresh, {}
+        with self._lock:
+            fresh, self._op_fresh = self._op_fresh, {}
         return fresh
 
-    @staticmethod
-    def _stats_over(samples: dict[str, deque]) -> dict[str, dict[str, float]]:
-        out = {}
-        for name, ring in samples.items():
-            if not ring:
-                continue
-            arr = np.asarray(ring, dtype=np.float64)
-            out[name] = {
+    def _stats_over(self, samples: dict[str, deque]) -> dict[str, dict[str, float]]:
+        with self._lock:
+            arrays = {name: np.asarray(ring, dtype=np.float64)
+                      for name, ring in samples.items() if ring}
+        return {
+            name: {
                 "min": float(arr.min()),
                 "max": float(arr.max()),
                 "med": float(np.median(arr)),
@@ -622,7 +768,8 @@ class DeviceTimeProfiler:
                 "std": float(arr.std()),
                 "count": int(arr.size),
             }
-        return out
+            for name, arr in arrays.items()
+        }
 
     def get_stats(self) -> dict[str, dict[str, float]]:
         """Per-program stats over retained samples (reference ``computeStats``)."""
@@ -633,7 +780,8 @@ class DeviceTimeProfiler:
         return self._stats_over(self._op_samples)
 
     def reset(self) -> None:
-        self._samples.clear()
-        self._fresh.clear()
-        self._op_samples.clear()
-        self._op_fresh.clear()
+        with self._lock:
+            self._samples.clear()
+            self._fresh.clear()
+            self._op_samples.clear()
+            self._op_fresh.clear()
