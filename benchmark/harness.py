@@ -2,8 +2,9 @@
 from a configuration file, the window, the comparison with the reference, and the one
 result line.
 
-Nothing here names a cell, a configuration, a traffic mix, a job kind or a per-layer
-metric: those are files found by the names in ``BENCHMARK.json`` (see README.md).
+Nothing here names a cell, a configuration, a model family, a traffic mix, a job kind
+or a per-layer metric: those are files found by the names in ``BENCHMARK.json`` and in
+the files it names (see README.md).
 JAX is imported only inside functions, after :func:`Run.take_devices` has set the
 compile cache's directory.
 """
@@ -13,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import math
 import os
 import shutil
 import statistics
@@ -46,11 +48,31 @@ def load_by_path(kind: str, name: str):
     it is loaded by path, not imported by name)."""
     path = os.path.join(HERE, kind, f"{name}.py")
     if not os.path.exists(path):
-        raise NoResult(f"{kind[:-1]} {name!r} has no file {os.path.relpath(path, ROOT)}")
+        raise NoResult(f"{name!r} has no file {os.path.relpath(path, ROOT)}")
     spec = importlib.util.spec_from_file_location(f"benchmark_{kind}_{abs(hash(name))}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+#: what a family file has to expose (README.md, "A model family")
+FAMILY_CONTRACT = ("REFERENCE", "TINY", "program_config", "init_params", "make_train_step",
+                   "param_specs", "train_flops_per_token")
+
+
+def load_family(config: dict):
+    """``benchmark/families/<family>.py`` of a configuration: all the benchmark knows of
+    its architecture."""
+    family = load_by_path("families", config["family"])
+    missing = [name for name in FAMILY_CONTRACT if not hasattr(family, name)]
+    if missing:
+        raise NoResult(f"family {config['family']!r} lacks {missing} ({family.__file__})")
+    return family
+
+
+def load_reference(config: dict):
+    """The family's plain reference, ``benchmark/reference/<name>.py``."""
+    return load_by_path("reference", load_family(config).REFERENCE)
 
 
 @dataclasses.dataclass
@@ -123,6 +145,7 @@ class Run:
         self.excluded_s = 0.0  # the reference's time before the window: not set-up
         self.attempted = 0
         self.problems: list[str] = []  # one line per thing that broke a guarantee
+        self.compared: dict[str, dict] = {}  # number compared -> its gap and its limit
         self.notes: dict = {}  # facts the job and the layer readers share
         self.reference: dict | None = None
         self.program: dict = {}  # what the program's first steps gave
@@ -189,7 +212,8 @@ class Run:
     def open_window(self) -> None:
         self.t_open = time.time()
         self.compiles_at_open = self.compiles.snapshot()
-        self.say("window_open", setup_s=self.setup_s, excluded_reference_s=self.excluded_s)
+        self.say("window_open", setup_s=self.setup_s, excluded_reference_s=self.excluded_s,
+                 compiles_in_setup=self.compiles_at_open)
 
     def close_window(self) -> None:
         if self.t_close is not None:
@@ -292,6 +316,7 @@ class Run:
             device["busy_s"] = self.trace_result.busy_s
             device["window_s"] = self.trace_result.window_s
             out["breakdown"] = self.trace_result.breakdown()
+        out["compared"] = self.compared  # last: what a record of a run that failed keeps
         return out
 
     def cleanup(self) -> None:
@@ -311,40 +336,20 @@ class Session:
     def __init__(self, run: Run):
         import jax
 
-        from tpu_resiliency.models import moe, transformer as tfm
         from tpu_resiliency.parallel import mesh as pmesh
 
         self.run = run
         c = run.cell.config
         self.batch, self.seq = c["batch"]
-        common = dict(
-            vocab_size=c["vocab_size"], d_model=c["hidden_size"],
-            n_layers=c["num_hidden_layers"], n_heads=c["num_attention_heads"],
-            n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-            max_seq_len=self.seq, rope_theta=float(c["rope_theta"]),
-        )
-        if c["family"] == "moe":
-            self.family = moe
-            self.cfg = moe.MoEConfig(
-                **common, n_experts=c["num_local_experts"], top_k=c["num_experts_per_tok"],
-                capacity_factor=c["assumed"]["capacity_factor"],
-                router_aux_weight=c["router_aux_loss_coef"],
-            )
-            specs = pmesh.moe_param_specs
-        elif c["family"] == "dense":
-            self.family = tfm
-            self.cfg = tfm.TransformerConfig(**common)
-            specs = pmesh.param_specs
-        else:
-            raise NoResult(f"configuration family {c['family']!r} is not known")
-        if self.cfg.head_dim != c["head_dim"]:
-            raise NoResult("head_dim of the configuration is not hidden_size / heads")
+        self.family = load_family(c)
+        self.cfg = self.family.program_config(c, self.seq)
         self.train_step, self.init_opt = self.family.make_train_step(self.cfg)
         self.mesh = None
         axes = {k: int(v) for k, v in c.get("mesh", {}).items()}
         if axes:
             self.mesh = pmesh.build_mesh(devices=run.devices, **axes)
-            self.param_shardings = pmesh.tree_shardings(self.mesh, specs(self.cfg))
+            self.param_shardings = pmesh.tree_shardings(
+                self.mesh, self.family.param_specs(self.cfg))
             from jax.sharding import NamedSharding
 
             self.batch_sharding = NamedSharding(self.mesh, pmesh.batch_spec())
@@ -392,10 +397,12 @@ class Session:
         return self._jax.device_put(host, self.batch_sharding)
 
     def host_tokens(self, i: int):
+        """Ids below the configuration file's ``vocab_size``: of a sliced vocabulary,
+        the slice."""
         import numpy as np
 
         return np.random.default_rng([self.run.seed, i]).integers(
-            0, self.cfg.vocab_size, (self.batch, self.seq)).astype(np.int32)
+            0, self.run.cell.config["vocab_size"], (self.batch, self.seq)).astype(np.int32)
 
     def state_bytes(self, state) -> int:
         return sum(x.size * x.dtype.itemsize for x in self._jax.tree.leaves(state))
@@ -552,6 +559,8 @@ def compare_with_reference(run: Run, program: dict, reference: dict, limits: dic
     for row in rows:
         row["ok"] = bool(row["gap"] <= row["limit"])
         run.say("compare", **row)
+        run.compared[row["number"]] = {
+            "gap": row["gap"] if math.isfinite(row["gap"]) else None, "limit": row["limit"]}
         run.attempted += 1
         if not row["ok"]:
             run.problem(f"{row['number']}: gap {row['gap']:.6g} over its limit {row['limit']}")

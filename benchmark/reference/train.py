@@ -1,10 +1,13 @@
 """The reference's own training steps: what ``correct`` holds the program to.
 
-Follows the program's first steps on the same seeded weights and the same batches:
-float32 everywhere, ``highest`` matmul precision, AdamW written out (learning rate
-3e-4, betas 0.9/0.999, eps 1e-8, decoupled weight decay 0.01: the configurations'
-``assumed`` optimizer). Returns, per step, the loss; after the first step the norm of
-every gradient leaf; after the last the norm of every parameter leaf's change.
+Follows the program's first steps on the same seeded weights and the same batches, with
+the weights and the loss of the reference module the configuration's family names
+(``harness.load_reference``: ``init_params(seed, config)`` and ``loss(params, tokens,
+config, precision)``): float32 everywhere, ``highest`` matmul precision, AdamW written
+out (learning rate 3e-4, betas 0.9/0.999, eps 1e-8, decoupled weight decay 0.01: the
+configurations' ``assumed`` optimizer). Returns, per step, the loss; after the first
+step the norm of every gradient leaf; after the last the norm of every parameter leaf's
+change.
 
 It runs before the program's state exists. Only the parameters and one gradient live
 on the device; AdamW's two moments stay on the host between steps and cross leaf by
@@ -19,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import model
+from .. import harness
 
 LR, B1, B2, EPS, WEIGHT_DECAY = 3e-4, 0.9, 0.999, 1e-8, 0.01
 
@@ -48,6 +51,7 @@ def leaf_norms(tree) -> dict[str, float]:
 def follow(seed: int, cfg: dict, batches, precision: str = "f32", params=None) -> dict:
     """Train ``len(batches)`` steps from the seeded weights. ``batches`` are int32
     arrays [B, T]. ``precision`` other than ``"f32"`` is the control's."""
+    model = harness.load_reference(cfg)
     grad = jax.jit(jax.value_and_grad(
         lambda p, t: model.loss(p, t, cfg, precision)))
     if params is None:

@@ -5,8 +5,8 @@ line: a number from a CPU run is not a measurement.
     JAX_PLATFORMS=cpu python benchmark/rehearse.py --workload mistral7b_steady [--seconds 3] [--trace 1]
 
 The cell, its traffic file, its job kind and its readers are the real ones; only the
-configuration's widths and batch are replaced by ``rehearsal/tiny.json``. A four-chip
-cell runs on four virtual CPU devices.
+configuration's widths and batch are replaced by its family's ``TINY`` preset
+(``families/<family>.py``). A four-chip cell runs on four virtual CPU devices.
 """
 
 import time
@@ -29,8 +29,7 @@ def rehearse(workload: str, seed: int, seconds: float, trace: bool, manifest=Non
     from benchmark.run import measure
 
     cell = harness.load_cell(workload, manifest)
-    tiny = harness.read_json(harness.HERE, "rehearsal", "tiny.json")[cell.config["family"]]
-    cell.config = {**cell.config, **tiny}
+    cell.config = {**cell.config, **harness.load_family(cell.config).TINY}
     run = harness.Run(cell, seed, seconds, trace, T_PROCESS, rehearsal=True)
     try:
         run.take_devices()

@@ -71,6 +71,10 @@ def main() -> int:
     finally:
         run.cleanup()
     print(json.dumps(result), flush=True)
+    for number, row in run.compared.items():  # the last lines of standard error
+        print(f"compared {number}: gap {row['gap']} limit {row['limit']}", file=sys.stderr)
+    for problem in run.problems:
+        print(f"problem: {problem}", file=sys.stderr)
     return 0
 
 

@@ -1,5 +1,5 @@
 """``correct`` can come out false. Two tests at a size a test run holds (the tiny
-widths of ``rehearsal/tiny.json``, on the CPU), run by hand:
+widths of the family's ``TINY`` preset, on the CPU), run by hand:
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 
@@ -28,8 +28,7 @@ SEEDS = (11, 2147483659, 4000000007)
 
 def tiny_run(seed):
     cell = harness.load_cell("mistral7b_steady")
-    cell.config = {**cell.config,
-                   **harness.read_json(harness.HERE, "rehearsal", "tiny.json")["dense"]}
+    cell.config = {**cell.config, **harness.load_family(cell.config).TINY}
     return harness.Run(cell, seed, 1.0, False, 0.0, rehearsal=True)
 
 
@@ -61,14 +60,16 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(monkeypatch):
 
     def broken_build(self):
         state = real_build(self)
-        loss_of = jax.jit(lambda p, t: self.family.loss_fn(p, t, self.cfg))
-        self.step = lambda params, opt_state, tokens: (params, opt_state, loss_of(params, tokens))
+        sound = jax.jit(self.train_step)  # no donation: the state handed in survives
+        self.step = lambda params, opt_state, tokens: (
+            params, opt_state, sound(params, opt_state, tokens)[2])
         return state
 
     monkeypatch.setattr(harness.Session, "build_state", broken_build)
     run, metrics = rehearse.rehearse("mistral7b_steady", SEEDS[1], 1.0, False)
     result = run.result(metrics)
     assert result["correct"] is False and result["failed"] >= 1
+    assert any(row["gap"] > row["limit"] for row in result["compared"].values())
     assert any("change_norms" in p or "grad_norms" in p for p in run.problems), run.problems
 
 
@@ -78,3 +79,5 @@ def test_the_unbroken_harness_is_correct():
     assert result["correct"] is True, run.problems
     assert set(result["metrics"]) == {"tokens_per_s", "step_ms_p95", "setup_s"}
     assert all(v["value"] > 0 for v in result["metrics"].values())
+    assert list(result)[-1] == "compared" and len(result["compared"]) == 5
+    assert all(row["gap"] <= row["limit"] for row in result["compared"].values())
