@@ -1,0 +1,249 @@
+"""Pattern-of-layers model (models/pattern.py): the program against the benchmark's
+plain reference on loss and every gradient leaf; each attention kind against plain
+masked attention; the experts' shares against the uncut layer; routing that drops
+nothing; derived parameter specs on a mesh; the state through the local checkpoint."""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding
+
+from tpu_resiliency.models import pattern
+from tpu_resiliency.parallel import mesh as pmesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+SEQ = 37  # longer than the window (8), a multiple of neither block (8, 16)
+
+
+@pytest.fixture(scope="module")
+def tiny_file():
+    """The laguna configuration at its family's tiny widths, as a configuration dict,
+    with the family and the reference that go with it."""
+    from benchmark import harness
+
+    config = harness.read_json(harness.HERE, "configs", "laguna-xs2-l5-ep8.json")
+    family = harness.load_family(config)
+    config = {**config, **family.TINY}
+    return config, family, harness.load_reference(config)
+
+
+@pytest.fixture(scope="module")
+def exact(tiny_file):
+    """Program (float32 activations) and reference on the same seeded weights: value
+    and gradient of the loss on one batch."""
+    config, family, reference = tiny_file
+    cfg = dataclasses.replace(family.program_config(config, SEQ), dtype=jnp.float32)
+    tokens = jnp.asarray(np.random.default_rng(5).integers(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        params = pattern.init_params(jax.random.PRNGKey(5), cfg)
+        ref_params = reference.init_params(5, config)
+        got = jax.jit(jax.value_and_grad(lambda p: pattern.loss_fn(p, tokens, cfg)))(params)
+        want = jax.jit(jax.value_and_grad(
+            lambda p: reference.loss(p, tokens, config, "f32")))(ref_params)
+    return params, ref_params, got, want
+
+
+def leaf_paths() -> list[str]:
+    leaves = jax.tree_util.tree_flatten_with_path(
+        pattern.describe_params(pattern.PatternConfig.tiny()),
+        is_leaf=lambda x: isinstance(x, pattern.Leaf))[0]
+    return [jax.tree_util.keystr(path) for path, _ in leaves]
+
+
+def test_seeded_weights_and_loss_equal_the_reference(exact):
+    params, ref_params, (loss, _), (ref_loss, _) = exact
+    assert jax.tree.structure(params) == jax.tree.structure(ref_params)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(ref_params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert abs(float(loss) - float(ref_loss)) < 1e-5
+
+
+@pytest.mark.parametrize("path", leaf_paths())
+def test_gradient_leaf_equals_the_reference(exact, path):
+    _, _, (_, grads), (_, ref_grads) = exact
+    got = {jax.tree_util.keystr(p): g for p, g in jax.tree_util.tree_flatten_with_path(grads)[0]}
+    want = {jax.tree_util.keystr(p): g
+            for p, g in jax.tree_util.tree_flatten_with_path(ref_grads)[0]}
+    scale = float(jnp.max(jnp.abs(want[path])))
+    assert scale > 0
+    np.testing.assert_allclose(np.asarray(got[path]), np.asarray(want[path]),
+                               rtol=0, atol=2e-5 * max(scale, 1e-2))
+
+
+def plain_attention(q, k, v, window=None):
+    """Masked softmax attention with the whole T x T array."""
+    b, t, h, dh = q.shape
+    k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(dh)
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    allowed = j <= i if window is None else (j <= i) & (j > i - window)
+    probs = jax.nn.softmax(jnp.where(allowed, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, t, h * dh)
+
+
+@pytest.mark.parametrize("kind,seq", [
+    ("sliding", 37), ("sliding", 8), ("sliding", 5), ("full", 37), ("full", 16), ("full", 7)])
+def test_attention_kind_equals_plain_masked_attention(kind, seq):
+    """Value and gradient, at lengths over, at and under a block."""
+    keys = jax.random.split(jax.random.PRNGKey(seq), 3)
+    q = jax.random.normal(keys[0], (2, seq, 6, 16))
+    k, v = (jax.random.normal(key, (2, seq, 2, 16)) for key in keys[1:])
+    if kind == "sliding":
+        blocked = lambda q, k, v: pattern.sliding_attention(q, k, v, 8)  # noqa: E731
+        plain = lambda q, k, v: plain_attention(q, k, v, 8)  # noqa: E731
+    else:
+        blocked = lambda q, k, v: pattern.full_attention(q, k, v, 16)  # noqa: E731
+        plain = plain_attention
+    weight = jax.random.normal(jax.random.PRNGKey(9), (2, seq, 6 * 16))
+    with jax.default_matmul_precision("highest"):
+        got, got_grad = jax.value_and_grad(
+            lambda *a: jnp.sum(blocked(*a) * weight), argnums=(0, 1, 2))(q, k, v)
+        want, want_grad = jax.value_and_grad(
+            lambda *a: jnp.sum(plain(*a) * weight), argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(np.asarray(blocked(q, k, v)), np.asarray(plain(q, k, v)),
+                                   atol=2e-5)
+    assert abs(float(got) - float(want)) < 1e-3
+    for a, b in zip(got_grad, want_grad):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def sparse_layer(cfg, seed=0):
+    """One sparse layer's weights over ALL experts (float32), and normed tokens."""
+    whole = dataclasses.replace(cfg, experts_held=(0, cfg.n_experts))
+    lp = jax.tree.map(lambda w: w[0], pattern.init_params(
+        jax.random.PRNGKey(seed), whole)["mlp"][pattern.SPARSE])
+    y = jax.random.normal(jax.random.PRNGKey(seed + 1), (64, cfg.d_model))
+    return lp, y
+
+
+def share_of(lp: dict, first: int, count: int) -> dict:
+    return {k: (w[first:first + count] if k.startswith("we_") else w) for k, w in lp.items()}
+
+
+@pytest.mark.parametrize("shares", [1, 2, 4, 16])
+def test_shares_add_up_to_the_uncut_layer(tiny_file, shares):
+    """The routed parts that all chips of a deployment compute (16 experts over
+    ``shares`` chips), plus the shared expert once, equal the uncut reference layer."""
+    config, _, reference = tiny_file
+    cfg = pattern.PatternConfig.tiny(dtype=jnp.float32)
+    lp, y = sparse_layer(cfg)
+    held = cfg.n_experts // shares
+    with jax.default_matmul_precision("highest"):
+        total = pattern._swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        for s in range(shares):
+            part = dataclasses.replace(cfg, experts_held=(s * held, held))
+            routed, counts = pattern.routed_experts(part, y, share_of(lp, s * held, held))
+            total = total + routed
+            assert int(counts["dropped"]) == 0
+        uncut = {**config, "num_experts": cfg.n_experts,
+                 "deployment": {"num_experts": cfg.n_experts, "experts_held": [0, cfg.n_experts]}}
+        want = reference.sparse_mlp(y, lp, uncut, "f32")
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("held", [(0, 4), (4, 4), (12, 4), (0, 16)])
+def test_no_pair_is_dropped_under_a_biased_router(held):
+    """A router biased so that one held expert takes every token's first choice (half
+    of all tokens and more): every pair that lands here is computed, the counts say
+    so, and the result equals the dense weighted sum over the held experts."""
+    cfg = pattern.PatternConfig.tiny(dtype=jnp.float32, experts_held=held)
+    lp, y = sparse_layer(cfg, seed=3)
+    y = jnp.abs(y)  # so that a positive router column wins for every token
+    hot = held[0] + 1
+    lp["w_router"] = lp["w_router"].at[:, hot].set(1.0)
+    part = share_of(lp, *held)
+    with jax.default_matmul_precision("highest"):
+        routed, counts = pattern.routed_experts(cfg, y, part)
+        weights, experts = pattern.route(cfg, y, lp["w_router"])
+        want = jnp.zeros_like(y)
+        for e in range(held[1]):
+            gate = jnp.sum(jnp.where(experts == held[0] + e, weights, 0.0), -1, keepdims=True)
+            want = want + gate * pattern._swiglu(
+                y, part["we_gate"][e], part["we_up"][e], part["we_down"][e])
+    n = y.shape[0]
+    landed = int(jnp.sum((experts >= held[0]) & (experts < held[0] + held[1])))
+    assert int(counts["max_load"]) == n  # the hot expert got every token
+    assert int(counts["pairs_held"]) == landed and int(counts["dropped"]) == 0
+    assert float(counts["mean_load"]) == pytest.approx(landed / held[1])
+    np.testing.assert_allclose(np.asarray(routed), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("axes", [{"dp": 4, "ep": 2}, {"dp": 2, "ep": 2, "tp": 2}])
+def test_derived_specs_on_a_mesh_give_the_one_chip_loss(axes):
+    # float32 activations: in bf16 another reduction order flips near-tied router choices
+    cfg = pattern.PatternConfig.tiny(dtype=jnp.float32)
+    params = pattern.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
+    loss = jax.jit(lambda p, t: pattern.loss_fn(p, t, cfg))
+    want = float(loss(params, tokens))
+    mesh = pmesh.build_mesh(devices=jax.devices()[:8], **axes)
+    specs = pmesh.pattern_param_specs(cfg)
+    assert specs["mlp"]["sparse"]["we_gate"] == jax.sharding.PartitionSpec(
+        None, "ep", None, "tp")
+    assert specs["mlp"]["sparse"]["w_router"] == jax.sharding.PartitionSpec(None, None, None)
+    sharded = jax.device_put(params, pmesh.tree_shardings(mesh, specs))
+    assert len(sharded["mlp"]["sparse"]["we_up"].sharding.device_set) == 8
+    with mesh:
+        got = float(loss(sharded, jax.device_put(
+            tokens, NamedSharding(mesh, pmesh.batch_spec()))))
+    assert abs(got - want) < 1e-4
+
+
+def test_forward_counts_and_causality():
+    cfg = pattern.PatternConfig.tiny()
+    params = pattern.init_params(jax.random.PRNGKey(0), cfg)
+    t1 = jax.random.randint(jax.random.PRNGKey(2), (1, SEQ), 0, cfg.vocab_size)
+    t2 = t1.at[0, 30].set((t1[0, 30] + 1) % cfg.vocab_size)
+    forward = jax.jit(lambda p, t: pattern.forward(p, t, cfg))
+    l1, counts = forward(params, t1)
+    l2, _ = forward(params, t2)
+    assert l1.shape == (1, SEQ, cfg.vocab_size) and l1.dtype == jnp.float32
+    assert counts["pairs_held"].shape == (cfg.count(pattern.SPARSE),)
+    assert int(counts["dropped"].sum()) == 0
+    assert int(counts["pairs_held"].max()) <= SEQ * cfg.top_k
+    np.testing.assert_allclose(np.asarray(l1[0, :30]), np.asarray(l2[0, :30]), atol=2e-2)
+    assert not np.allclose(np.asarray(l1[0, 30:]), np.asarray(l2[0, 30:]), atol=1e-3)
+
+
+def test_description_rejects_what_cannot_be_stacked():
+    with pytest.raises(ValueError, match="head count"):
+        pattern.PatternConfig.tiny(layers=(
+            pattern.Layer("full", 6, "dense"), pattern.Layer("full", 4, "sparse")))
+    with pytest.raises(ValueError, match="experts_held"):
+        pattern.PatternConfig.tiny(experts_held=(14, 4))
+
+
+def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path):
+    """Per-kind stacks and the held experts' leaves through
+    ``checkpoint/local_manager.py``: the restored state gives the next loss exactly."""
+    from tpu_resiliency.checkpoint import LocalCheckpointManager, PyTreeStateDict
+
+    cfg = pattern.PatternConfig.tiny()
+    train_step, init_opt = pattern.make_train_step(cfg)
+    step = jax.jit(train_step)
+    params = pattern.init_params(jax.random.PRNGKey(7), cfg)
+    opt_state = init_opt(params)
+    batch = lambda i: jnp.asarray(  # noqa: E731
+        np.random.default_rng([7, i]).integers(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+    for i in range(2):
+        params, opt_state, _ = step(params, opt_state, batch(i))
+    mgr = LocalCheckpointManager(str(tmp_path / "ckpt"), rank=0)
+    mgr.save(2, PyTreeStateDict({"params": params, "opt": opt_state}), is_async=False)
+    _, _, want = step(params, opt_state, batch(2))
+    assert mgr.find_latest() == 2
+    tree, _ = mgr.load_tree(2)
+    assert jax.tree.structure(tree["params"]) == jax.tree.structure(params)
+    assert len(jax.tree.leaves(tree["params"])) == 27
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves({"params": params, "opt": opt_state})):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _, _, got = step(tree["params"], tree["opt"], batch(2))
+    assert float(got) == float(want)
+    mgr.close()

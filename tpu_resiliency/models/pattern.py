@@ -1,0 +1,508 @@
+"""Third model family: a decoder described as a *pattern of layers*.
+
+Where ``transformer.py`` and ``moe.py`` scan one homogeneous stacked layer, this
+model is a list of layers, each an attention kind (``full`` or ``sliding``, with its
+own head count and rotary table) and an MLP kind (``dense`` SwiGLU, or ``sparse``:
+a float32 router over all experts of the deployment, the top-k routed experts that
+this chip holds, and one shared expert). Parameters are stacked per kind; the layers
+run in the order the description gives (a Python loop: the kinds differ in shape,
+so there is no single body to scan).
+
+Built TPU-first, static shapes throughout:
+
+- **Attention never holds a T x T array.** Sliding layers compute the band: query
+  blocks of one window against their own and the previous key block. Full layers go
+  by query blocks against the causal prefix of the keys. Both loop over the KV heads
+  with the block's scores recomputed in the backward pass.
+- **Routing drops nothing.** Every (token, choice) pair whose expert this chip holds
+  is computed: the pairs are sorted by expert and the three SwiGLU products run as
+  grouped products over the ragged groups (``jax.lax.ragged_dot``). Pairs for
+  experts held elsewhere contribute nothing here, and nothing stands in for the
+  chips that hold them or for the exchange with them.
+- **The description says how each leaf may be sharded** (:func:`describe_params`:
+  logical axis names per dimension), so ``parallel/mesh.py`` derives the
+  ``PartitionSpec`` tree and holds no key name of this model.
+
+It reuses the dense model's ``rms_norm``, ``apply_rope``, ``token_nll`` and
+``make_train_step_from_loss``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from tpu_resiliency.models import transformer as tfm
+
+FULL, SLIDING = "full", "sliding"
+DENSE, SPARSE = "dense", "sparse"
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's frequency blend (Peng et al., arXiv:2309.00071) as the published
+    configurations state it."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    theta: float = 10000.0
+    #: share of each head's dimensions that rotate (the first ones); the rest pass
+    rotary_fraction: float = 1.0
+    yarn: Optional[Yarn] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Layer:
+    attn: str  # FULL | SLIDING
+    n_heads: int
+    mlp: str  # DENSE | SPARSE
+
+
+@dataclasses.dataclass(frozen=True)
+class PatternConfig:
+    vocab_size: int
+    d_model: int
+    head_dim: int
+    n_kv_heads: int
+    layers: tuple[Layer, ...]
+    d_ff: int  # the dense MLP's width
+    d_expert: int  # a routed expert's width
+    d_shared: int  # the shared expert's width
+    n_experts: int  # the router's outputs: every expert of the deployment
+    top_k: int
+    #: (first, count): the contiguous range of the ``n_experts`` whose weights are here
+    experts_held: tuple[int, int]
+    routed_scale: float = 1.0
+    window: int = 512
+    rope_full: Rope = Rope()
+    rope_sliding: Rope = Rope()
+    norm_eps: float = 1e-6
+    #: query rows a full layer scores at a time (against all the keys before them)
+    attn_block: int = 1024
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        first, count = self.experts_held
+        if not (0 <= first and count > 0 and first + count <= self.n_experts):
+            raise ValueError(f"experts_held {self.experts_held} is not a range of "
+                             f"the {self.n_experts} experts")
+        for kind in (FULL, SLIDING):
+            heads = {l.n_heads for l in self.layers if l.attn == kind}
+            if len(heads) > 1:
+                raise ValueError(f"{kind} layers differ in head count {sorted(heads)}: "
+                                 "their weights cannot be stacked")
+        for l in self.layers:
+            if l.attn not in (FULL, SLIDING) or l.mlp not in (DENSE, SPARSE):
+                raise ValueError(f"unknown layer kind in {l}")
+            if l.n_heads % self.n_kv_heads:
+                raise ValueError(f"{l.n_heads} heads do not group over {self.n_kv_heads}")
+
+    def rope(self, kind: str) -> Rope:
+        return self.rope_full if kind == FULL else self.rope_sliding
+
+    def heads(self, kind: str) -> int:
+        return next(l.n_heads for l in self.layers if l.attn == kind)
+
+    def count(self, kind: str) -> int:
+        """Layers whose attention or MLP is of ``kind``."""
+        return sum(1 for l in self.layers if kind in (l.attn, l.mlp))
+
+    @staticmethod
+    def tiny(**kw) -> "PatternConfig":
+        base = dict(
+            vocab_size=256, d_model=64, head_dim=16, n_kv_heads=2,
+            layers=(Layer(FULL, 6, DENSE), Layer(SLIDING, 8, SPARSE), Layer(FULL, 6, SPARSE)),
+            d_ff=128, d_expert=32, d_shared=32, n_experts=16, top_k=4,
+            experts_held=(0, 4), routed_scale=2.5, window=8, attn_block=16,
+            rope_full=Rope(500000.0, 0.5, Yarn(64.0, 4096, 64.0, 1.0, 1.4158883083359672)),
+        )
+        base.update(kw)
+        return PatternConfig(**base)
+
+
+# ---------------------------------------------------------------------------------
+# the parameters, described
+# ---------------------------------------------------------------------------------
+
+class Leaf(NamedTuple):
+    """One parameter leaf: its shape, the logical name of each dimension (what
+    ``parallel/mesh.py`` maps to mesh axes; ``None`` is never sharded) and the
+    fan-in its normal initialisation is scaled by (``None``: a norm, at one)."""
+
+    shape: tuple[int, ...]
+    axes: tuple[Optional[str], ...]
+    fan_in: Optional[int]
+
+
+def describe_params(cfg: PatternConfig) -> dict:
+    """The parameter tree as :class:`Leaf` descriptions. Layer weights are stacked on a
+    leading axis per kind: ``attn/<full|sliding>`` and ``mlp/<dense|sparse>``, in the
+    order the layers of that kind appear."""
+    d, dh, hkv = cfg.d_model, cfg.head_dim, cfg.n_kv_heads
+    tree: dict = {
+        "embed": Leaf((cfg.vocab_size, d), ("vocab", None), d),
+        "final_norm": Leaf((d,), (None,), None),
+        "lm_head": Leaf((d, cfg.vocab_size), (None, "vocab"), d),
+        "attn": {}, "mlp": {},
+    }
+    for kind in (FULL, SLIDING):
+        n = cfg.count(kind)
+        if not n:
+            continue
+        h = cfg.heads(kind)
+        tree["attn"][kind] = {
+            "attn_norm": Leaf((n, d), (None, None), None),
+            "wq": Leaf((n, d, h * dh), (None, None, "heads"), d),
+            "wk": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
+            "wv": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
+            "wg": Leaf((n, d, h), (None, None, "heads"), d),
+            "wo": Leaf((n, h * dh, d), (None, "heads", None), h * dh),
+        }
+
+    def swiglu(prefix: str, lead: tuple, lead_axes: tuple, f: int) -> dict:
+        return {
+            f"{prefix}_gate": Leaf((*lead, d, f), (*lead_axes, None, "ff"), d),
+            f"{prefix}_up": Leaf((*lead, d, f), (*lead_axes, None, "ff"), d),
+            f"{prefix}_down": Leaf((*lead, f, d), (*lead_axes, "ff", None), f),
+        }
+
+    n = cfg.count(DENSE)
+    if n:
+        tree["mlp"][DENSE] = {"mlp_norm": Leaf((n, d), (None, None), None),
+                              **swiglu("w", (n,), (None,), cfg.d_ff)}
+    n = cfg.count(SPARSE)
+    if n:
+        held = cfg.experts_held[1]
+        tree["mlp"][SPARSE] = {
+            "mlp_norm": Leaf((n, d), (None, None), None),
+            "w_router": Leaf((n, d, cfg.n_experts), (None, None, None), d),
+            **swiglu("we", (n, held), (None, "experts"), cfg.d_expert),
+            **swiglu("ws", (n,), (None,), cfg.d_shared),
+        }
+    return tree
+
+
+def _is_leaf(x) -> bool:
+    return isinstance(x, Leaf)
+
+
+def init_params(rng: jax.Array, cfg: PatternConfig) -> dict:
+    """Seeded weights: normal / sqrt(fan_in), norms at one. One key a leaf, split from
+    ``rng`` in the order the tree flattens (sorted keys), so that anything that knows
+    the description makes the same weights."""
+    leaves, treedef = jax.tree.flatten(describe_params(cfg), is_leaf=_is_leaf)
+    keys = jax.random.split(rng, len(leaves))
+    return jax.tree.unflatten(treedef, [
+        jnp.ones(leaf.shape, jnp.float32) if leaf.fan_in is None
+        else jax.random.normal(key, leaf.shape, jnp.float32) / np.sqrt(leaf.fan_in)
+        for key, leaf in zip(keys, leaves)])
+
+
+# ---------------------------------------------------------------------------------
+# rotary tables
+# ---------------------------------------------------------------------------------
+
+def rope_tables(rope: Rope, head_dim: int, seq_len: int):
+    """cos, sin ``[T, rot/2]`` over the ``rot = head_dim * rotary_fraction`` rotating
+    dimensions. With YaRN the frequencies blend the interpolated and the original
+    ones over a ramp between the dimensions that turn ``beta_fast`` and ``beta_slow``
+    times in the original context, and cos and sin carry the attention factor."""
+    rot = int(head_dim * rope.rotary_fraction)
+    exponent = np.arange(0, rot, 2, dtype=np.float64) / rot
+    inv_freq = 1.0 / (rope.theta ** exponent)
+    scale = 1.0
+    if rope.yarn is not None:
+        y = rope.yarn
+
+        def turns_to_dim(turns: float) -> float:
+            return rot * math.log(y.original_max_position / (turns * 2 * math.pi)) / (
+                2 * math.log(rope.theta))
+
+        low = max(math.floor(turns_to_dim(y.beta_fast)), 0)
+        high = min(math.ceil(turns_to_dim(y.beta_slow)), rot - 1)
+        ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        inv_freq = inv_freq / y.factor * ramp + inv_freq * (1.0 - ramp)
+        scale = y.attention_factor
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * jnp.asarray(
+        inv_freq, jnp.float32)[None, :]
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotary positions on the first ``2 * cos.shape[-1]`` dimensions of each head."""
+    rot = 2 * cos.shape[-1]
+    if rot == x.shape[-1]:
+        return tfm.apply_rope(x, cos, sin)
+    return jnp.concatenate([tfm.apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
+
+
+# ---------------------------------------------------------------------------------
+# attention: a band, or query blocks over the causal prefix
+# ---------------------------------------------------------------------------------
+
+def _attend(q, k, v, mask):
+    """Softmax attention of one KV head's query group. q ``[..., G, Q, dh]``, k / v
+    ``[..., K, dh]``, mask broadcastable to ``[..., Q, K]`` -> ``[..., G, Q, dh]``.
+
+    The softmax is written out so that neither row reduction is broadcast back over
+    the keys inside one fusion: the row maximum (a constant of the softmax, so no
+    gradient flows through it) crosses an optimization barrier, and the row sum
+    divides the product with ``v``, not the scores. Left to ``jax.nn.softmax`` the
+    TPU compiler turns reduce-and-broadcast into a ``reduce-window`` as wide as the
+    key axis, which at 8,192 keys cost 2.4 s a step (chip run, PR 28)."""
+    scores = jnp.einsum("...gqd,...kd->...gqk", q, k,
+                        preferred_element_type=jnp.float32) / np.sqrt(q.shape[-1])
+    scores = jnp.where(mask[..., None, :, :], scores, -1e30)
+    top = jax.lax.optimization_barrier(
+        jax.lax.stop_gradient(jnp.max(scores, axis=-1, keepdims=True)))
+    weights = jnp.exp(scores - top)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    out = jnp.einsum("...gqk,...kd->...gqd", weights.astype(q.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return (out / total).astype(q.dtype)
+
+
+def _over_kv_heads(q, k, v, mask):
+    """:func:`_attend` for each KV head in turn (leading axis), its scores recomputed
+    in the backward pass: one head's block of scores is all that is ever alive."""
+    body = jax.checkpoint(lambda qkv: _attend(*qkv, mask))
+    return jax.lax.map(body, (q, k, v))
+
+
+def _pad_rows(x, axis: int, multiple: int):
+    pad = -x.shape[axis] % multiple
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
+def _heads_first(q, k, v):
+    """q ``[B, T, H, dh]``, k / v ``[B, T, Hkv, dh]`` -> q ``[Hkv, B, G, T, dh]``,
+    k / v ``[Hkv, B, T, dh]``."""
+    b, t, h, dh = q.shape
+    hkv = k.shape[2]
+    q = q.reshape(b, t, hkv, h // hkv, dh).transpose(2, 0, 3, 1, 4)
+    return q, k.transpose(2, 0, 1, 3), v.transpose(2, 0, 1, 3)
+
+
+def _heads_last(out, t: int):
+    """``[Hkv, B, G, T', dh]`` -> ``[B, t, H * dh]`` (``T' >= t``: padding dropped)."""
+    hkv, b, g, _, dh = out.shape
+    return out[:, :, :, :t].transpose(1, 3, 0, 2, 4).reshape(b, t, hkv * g * dh)
+
+
+def sliding_attention(q, k, v, window: int):
+    """Causal attention over the last ``window`` keys (``i - window < j <= i``), as a
+    band: the sequence is cut into blocks of ``window`` rows and each query block is
+    scored against its own key block and the one before it."""
+    b, t, _, dh = q.shape
+    q, k, v = (_pad_rows(x, 1, window) for x in (q, k, v))
+    q, k, v = _heads_first(q, k, v)
+    hkv, _, g, tp, _ = q.shape
+    nb = tp // window
+    q = q.reshape(hkv, b, g, nb, window, dh).transpose(0, 1, 3, 2, 4, 5)  # [Hkv,B,nb,G,W,dh]
+
+    def with_previous(x):  # [Hkv, B, T', dh] -> [Hkv, B, nb, 2W, dh]
+        x = x.reshape(hkv, b, nb, window, dh)
+        before = jnp.pad(x, ((0, 0), (0, 0), (1, 0), (0, 0), (0, 0)))[:, :, :-1]
+        return jnp.concatenate([before, x], axis=3)
+
+    qpos = jnp.arange(nb)[:, None, None] * window + jnp.arange(window)[None, :, None]
+    kpos = (jnp.arange(nb)[:, None, None] - 1) * window + jnp.arange(2 * window)[None, None, :]
+    mask = (kpos <= qpos) & (kpos > qpos - window) & (kpos >= 0)  # [nb, W, 2W]
+    out = _over_kv_heads(q, with_previous(k), with_previous(v), mask)  # [Hkv,B,nb,G,W,dh]
+    out = out.transpose(0, 1, 3, 2, 4, 5).reshape(hkv, b, g, tp, dh)
+    return _heads_last(out, t)
+
+
+def full_attention(q, k, v, block: int):
+    """Causal attention by query blocks of ``block`` rows, each against the keys up
+    to its own last row: the rows of one block see all their keys at once, so no
+    running softmax is carried, and the keys after a block are never scored."""
+    t = q.shape[1]
+    block = min(block, t)
+    q, k, v = (_pad_rows(x, 1, block) for x in (q, k, v))
+    q, k, v = _heads_first(q, k, v)
+    outs = []
+    for start in range(0, q.shape[3], block):
+        end = start + block
+        mask = jnp.arange(end)[None, :] <= jnp.arange(start, end)[:, None]
+        outs.append(_over_kv_heads(q[:, :, :, start:end], k[:, :, :end], v[:, :, :end], mask))
+    return _heads_last(jnp.concatenate(outs, axis=3), t)
+
+
+def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos, sin):
+    """Pre-norm grouped-query attention of one kind with an output gate (one sigmoid
+    a head, from the normed input) and the residual."""
+    with jax.named_scope(f"attn/{kind}"):
+        b, t, _ = x.shape
+        h, hkv, dh = cfg.heads(kind), cfg.n_kv_heads, cfg.head_dim
+        y = tfm.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = (y @ lp["wq"].astype(y.dtype)).reshape(b, t, h, dh)
+        k = (y @ lp["wk"].astype(y.dtype)).reshape(b, t, hkv, dh)
+        v = (y @ lp["wv"].astype(y.dtype)).reshape(b, t, hkv, dh)
+        gate = jax.nn.sigmoid(y @ lp["wg"].astype(y.dtype))  # [B, T, H]
+        q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
+        with jax.named_scope("core"):
+            if kind == SLIDING:
+                attn = sliding_attention(q, k, v, cfg.window)
+            else:
+                attn = full_attention(q, k, v, cfg.attn_block)
+        attn = (attn.reshape(b, t, h, dh) * gate[..., None]).reshape(b, t, h * dh)
+        return x + attn @ lp["wo"].astype(attn.dtype)
+
+
+# ---------------------------------------------------------------------------------
+# the MLPs
+# ---------------------------------------------------------------------------------
+
+def _swiglu(y, w_gate, w_up, w_down):
+    gate = jax.nn.silu(y @ w_gate.astype(y.dtype))
+    return (gate * (y @ w_up.astype(y.dtype))) @ w_down.astype(y.dtype)
+
+
+@jax.custom_vjp
+def _take_rows(x, index, inverse):
+    """``x[index]`` for a permutation ``index`` of the rows with inverse ``inverse``;
+    the backward pass is the inverse gather, where autodiff would scatter."""
+    return x[index]
+
+
+_take_rows.defvjp(lambda x, index, inverse: (x[index], (index, inverse)),
+                  lambda res, g: (g[res[1]], None, None))
+
+
+def route(cfg: PatternConfig, y, w_router):
+    """Float32 routing of tokens ``y [N, D]`` over all ``n_experts``: sigmoid scores,
+    the ``top_k`` largest, their weights normalised to one and scaled. Returns
+    (weights ``[N, K]`` float32, experts ``[N, K]`` int32)."""
+    logits = jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    weights, experts = jax.lax.top_k(jax.nn.sigmoid(logits), cfg.top_k)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * cfg.routed_scale
+    return weights, experts
+
+
+def routed_experts(cfg: PatternConfig, y, lp: dict):
+    """What the experts held here add for tokens ``y [N, D]``, and the routing counts.
+
+    All ``N * top_k`` (token, choice) pairs are sorted by the local index of their
+    expert, pairs for experts held elsewhere last; ``group_sizes`` counts the pairs
+    of each held expert, so the grouped products compute every pair that landed here
+    and no other row. The shapes are static (``N * top_k`` rows: room for every
+    pair), whatever the router does."""
+    n, d = y.shape
+    k = cfg.top_k
+    first, held = cfg.experts_held
+    with jax.named_scope("moe/route"):
+        weights, experts = route(cfg, y, lp["w_router"])
+    with jax.named_scope("moe/dispatch"):
+        local = experts - first
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held).reshape(n * k)
+        order = jnp.argsort(key)  # stable: the pairs of one expert stay in token order
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=order.dtype), unique_indices=True)
+        sorted_key = key[order]
+        ends = jnp.searchsorted(sorted_key, jnp.arange(held + 1), side="left")
+        group_sizes = jnp.diff(ends).astype(jnp.int32)  # [held]
+        valid = (sorted_key < held)[:, None]
+        pairs = jnp.broadcast_to(y[:, None, :], (n, k, d)).reshape(n * k, d)
+        rows = jnp.where(valid, _take_rows(pairs, order, inverse), 0)
+    with jax.named_scope("moe/experts"):
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
+        gate = jax.nn.silu(dot(rows, lp["we_gate"].astype(y.dtype)))
+        out = dot(gate * dot(rows, lp["we_up"].astype(y.dtype)), lp["we_down"].astype(y.dtype))
+        # rows past the last group are whatever the grouped product left there
+        out = jnp.where(valid, out, 0)
+    with jax.named_scope("moe/combine"):
+        out = _take_rows(out, inverse, order).reshape(n, k, d)
+        routed = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1).astype(y.dtype)
+    landed = jnp.sum(here)
+    counts = {
+        "pairs_held": landed,
+        "max_load": jnp.max(group_sizes),
+        "mean_load": landed / held,
+        "dropped": landed - jnp.sum(group_sizes),
+    }
+    return routed, counts
+
+
+def _mlp_block(cfg: PatternConfig, kind: str, x, lp: dict):
+    """Pre-norm MLP with the residual; a sparse one also returns its routing counts."""
+    y = tfm.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    if kind == DENSE:
+        with jax.named_scope("mlp/dense"):
+            return x + _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+    b, t, d = y.shape
+    routed, counts = routed_experts(cfg, y.reshape(b * t, d), lp)
+    with jax.named_scope("moe/shared"):
+        shared = _swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+    return x + routed.reshape(b, t, d) + shared, counts
+
+
+# ---------------------------------------------------------------------------------
+# the model
+# ---------------------------------------------------------------------------------
+
+def forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
+    """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32, routing counts: a dict of
+    ``[sparse layers]`` arrays, see :func:`routed_experts`)."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    t = tokens.shape[1]
+    tables = {kind: rope_tables(cfg.rope(kind), cfg.head_dim, t)
+              for kind in (FULL, SLIDING) if cfg.count(kind)}
+
+    def layer(x, attn_lp, mlp_lp, spec: Layer):
+        x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
+        return _mlp_block(cfg, spec.mlp, x, mlp_lp)
+
+    seen = dict.fromkeys((FULL, SLIDING, DENSE, SPARSE), 0)
+    counts = []
+    for spec in cfg.layers:
+        attn_lp = jax.tree.map(lambda w: w[seen[spec.attn]], params["attn"][spec.attn])
+        mlp_lp = jax.tree.map(lambda w: w[seen[spec.mlp]], params["mlp"][spec.mlp])
+        seen[spec.attn] += 1
+        seen[spec.mlp] += 1
+        # each layer's forward is recomputed in the backward pass: only x is kept
+        x, layer_counts = jax.checkpoint(functools.partial(layer, spec=spec))(
+            x, attn_lp, mlp_lp)
+        if layer_counts is not None:
+            counts.append(layer_counts)
+    x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *counts) if counts else {}
+    return logits, stacked
+
+
+def loss_and_counts(params: dict, tokens: jax.Array, cfg: PatternConfig):
+    """Next-token cross-entropy over tokens ``[B, T]`` (the last position's logits
+    dropped, as in the dense model) and the routing counts of each sparse layer."""
+    logits, counts = forward(params, tokens, cfg)
+    return tfm.token_nll(logits[:, :-1], tokens[:, 1:]).mean(), counts
+
+
+def loss_fn(params: dict, tokens: jax.Array, cfg: PatternConfig) -> jax.Array:
+    return loss_and_counts(params, tokens, cfg)[0]
+
+
+def make_train_step(cfg: PatternConfig, optimizer=None):
+    """(train_step, init_opt_state) — jit-ready, the dense model's contract."""
+    return tfm.make_train_step_from_loss(
+        lambda params, tokens: loss_fn(params, tokens, cfg), optimizer)

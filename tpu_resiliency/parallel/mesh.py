@@ -132,6 +132,27 @@ def moe_param_specs(cfg) -> dict:
     return specs
 
 
+#: the mesh axis a parameter dimension of each logical name shards over
+#: (``models/pattern.py:describe_params`` names the dimensions)
+LOGICAL_AXES = {"vocab": TP, "heads": TP, "ff": TP, "experts": EP}
+
+
+def pattern_param_specs(cfg) -> dict:
+    """PartitionSpecs for the pattern-of-layers parameter pytree (see
+    models/pattern.py), derived from the model's own description: every leaf names its
+    dimensions, and this function holds no key of the tree. Vocabulary, heads and
+    MLP widths shard over ``tp`` (column- then row-parallel, as :func:`param_specs`),
+    the held experts' ``[E]`` axis over ``ep``; norms and the router replicate."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_resiliency.models import pattern
+
+    return jax.tree.map(
+        lambda leaf: P(*(LOGICAL_AXES.get(name) for name in leaf.axes)),
+        pattern.describe_params(cfg), is_leaf=lambda x: isinstance(x, pattern.Leaf))
+
+
 def pipeline_layer_specs(layer_specs: dict) -> dict:
     """Prepend ``pp`` to the leading stacked-``[L]`` dim of every per-layer spec, so
     each pipeline stage holds only its own layers."""
