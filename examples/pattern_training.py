@@ -9,13 +9,17 @@ nothing here). Every ``--routing-every`` steps the script runs the model's forwa
 more on the step's batch and emits its routing counts as a ``moe_routing`` event: for
 each sparse layer the (token, choice) pairs that landed on held experts, the largest
 and the mean load of a held expert, and the pairs dropped (always 0: the grouped
-products have room for every pair).
+products have room for every pair). Once, before the first step, it emits an
+``attention_path`` event: for each kind of attention layer, whether its products run
+as the blocked kernels of ``ops/attention.py`` (on a TPU, at shapes that tile) or as
+the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block.
 
 Run (CPU simulation)::
 
     python examples/pattern_training.py --cpu --steps 20
 
-Prints one ``ROUTING step=<n> ...`` line per event and ``DONE loss=<x>`` on success.
+Prints ``ATTENTION {...}``, one ``ROUTING step=<n> ...`` line per routing event and
+``DONE loss=<x>`` on success.
 """
 
 from __future__ import annotations
@@ -57,6 +61,9 @@ def main() -> None:
     counts_of = jax.jit(lambda p, t: pattern.loss_and_counts(p, t, cfg)[1])
     params = pattern.init_params(jax.random.PRNGKey(0), cfg)
     losses = []
+    paths = pattern.attention_paths(cfg, args.batch[1])
+    events.record("model", "attention_path", seq=args.batch[1], **paths)
+    print(f"ATTENTION {paths}", flush=True)
 
     def tokens(i: int):
         return jnp.asarray(np.random.default_rng([0, i]).integers(

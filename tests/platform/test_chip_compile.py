@@ -179,3 +179,36 @@ def test_flagship_train_step_compiles_for_the_dp_tp_mesh(topo):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES  # per chip
     assert "all-reduce" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["full", "sliding"])
+def test_attention_kernels_compile_for_v5e_under_the_core_scope(one_chip, as_on_a_tpu, kind):
+    """One attention sublayer of the laguna configuration at its own widths (8,192
+    tokens, heads of 128, 48 or 64 query heads over 8 KV heads), differentiated through
+    the layer's ``jax.checkpoint``: Mosaic takes the four kernels (forward, recomputed
+    forward, dQ, dK/dV), and in the compiled program each is a custom call whose
+    ``op_name`` the benchmark's ``attn.roofline`` reader finds under
+    ``attn/<kind>/core``."""
+    import re
+
+    from benchmark import harness
+    from tpu_resiliency.models import pattern
+
+    config = harness.read_json(harness.HERE, "configs", "laguna-xs2-l5-ep8.json")
+    seq = config["batch"][1]
+    cfg = harness.load_family(config).program_config(config, seq)
+    assert pattern.attention_paths(cfg, seq)[kind]["path"] == "kernel"
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    lp = jax.tree.map(lambda w: sds(w.shape[1:], w.dtype, one_chip), params["attn"][kind])
+    tables = pattern.rope_tables(cfg.rope(kind), cfg.head_dim, seq)
+
+    def loss(x, lp):
+        layer = jax.checkpoint(lambda x, lp: pattern._attn_block(cfg, kind, x, lp, *tables))
+        return jnp.sum(layer(x, lp).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        sds((1, seq, cfg.d_model), cfg.dtype, one_chip), lp).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in kernels]
+    mark = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
+    assert len(names) == 4 and all(mark.search(name) for name in names), names

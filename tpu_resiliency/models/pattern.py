@@ -10,10 +10,15 @@ so there is no single body to scan).
 
 Built TPU-first, static shapes throughout:
 
-- **Attention never holds a T x T array.** Sliding layers compute the band: query
-  blocks of one window against their own and the previous key block. Full layers go
-  by query blocks against the causal prefix of the keys. Both loop over the KV heads
-  with the block's scores recomputed in the backward pass.
+- **Attention never holds a T x T array.** On a TPU, at heads of whole lane groups
+  and sequences of whole tiles, both kinds run as the blocked kernels of
+  ``ops/attention.py``: a tile of scores lives in VMEM, the key tiles a query tile
+  cannot see are skipped (:func:`attention_paths` says which path a shape takes).
+  Off the TPU, or at shapes that do not tile, the blocks below are plain
+  ``jax.numpy``: sliding layers compute the band (query blocks of one window against
+  their own and the previous key block), full layers go by query blocks against the
+  causal prefix of the keys, and both loop over the KV heads with the block's scores
+  recomputed in the backward pass.
 - **Routing drops nothing.** Every (token, choice) pair whose expert this chip holds
   is computed: the pairs are sorted by expert and the three SwiGLU products run as
   grouped products over the ragged groups (``jax.lax.ragged_dot``). Pairs for
@@ -39,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_resiliency.models import transformer as tfm
+from tpu_resiliency.ops import attention
 
 FULL, SLIDING = "full", "sliding"
 DENSE, SPARSE = "dense", "sparse"
@@ -90,7 +96,8 @@ class PatternConfig:
     rope_full: Rope = Rope()
     rope_sliding: Rope = Rope()
     norm_eps: float = 1e-6
-    #: query rows a full layer scores at a time (against all the keys before them)
+    #: query rows a full layer scores at a time (against all the keys before them) on
+    #: the ``jax.numpy`` path; the kernel path has its own tiles and does not read it
     attn_block: int = 1024
     dtype: Any = jnp.bfloat16
 
@@ -251,7 +258,8 @@ def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------------
-# attention: a band, or query blocks over the causal prefix
+# attention: the blocked kernels where they apply; else a band, or query blocks over
+# the causal prefix, in plain jax.numpy (everything down to full_attention)
 # ---------------------------------------------------------------------------------
 
 def _attend(q, k, v, mask):
@@ -334,7 +342,9 @@ def sliding_attention(q, k, v, window: int):
 def full_attention(q, k, v, block: int):
     """Causal attention by query blocks of ``block`` rows, each against the keys up
     to its own last row: the rows of one block see all their keys at once, so no
-    running softmax is carried, and the keys after a block are never scored."""
+    running softmax is carried, and the keys after a block are never scored. (The
+    path taken off the TPU or at shapes that do not tile; the kernel path carries a
+    running softmax over key tiles.)"""
     t = q.shape[1]
     block = min(block, t)
     q, k, v = (_pad_rows(x, 1, block) for x in (q, k, v))
@@ -345,6 +355,29 @@ def full_attention(q, k, v, block: int):
         mask = jnp.arange(end)[None, :] <= jnp.arange(start, end)[:, None]
         outs.append(_over_kv_heads(q[:, :, :, start:end], k[:, :, :end], v[:, :, :end], mask))
     return _heads_last(jnp.concatenate(outs, axis=3), t)
+
+
+def _window(cfg: PatternConfig, kind: str) -> Optional[int]:
+    return cfg.window if kind == SLIDING else None
+
+
+def attention_paths(cfg: PatternConfig, seq: int) -> dict:
+    """Which path the attention products of each kind of layer take at sequences of
+    ``seq``, from what the code can see (the backend, the head size, whether the
+    sequence is whole tiles): ``{kind: {"path": "kernel", "tile": rows}}`` for the
+    blocked kernels of ``ops/attention.py``, ``{"path": "blocks", "block": rows}`` for
+    the ``jax.numpy`` blocks."""
+    paths = {}
+    for kind in (FULL, SLIDING):
+        if not cfg.count(kind):
+            continue
+        window = _window(cfg, kind)
+        if jax.default_backend() == "tpu" and attention.applies(seq, cfg.head_dim, window):
+            paths[kind] = {"path": "kernel", "tile": attention.tile_of(seq, window)}
+        else:
+            paths[kind] = {"path": "blocks",
+                           "block": cfg.window if kind == SLIDING else min(cfg.attn_block, seq)}
+    return paths
 
 
 def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos, sin):
@@ -360,7 +393,9 @@ def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos, sin):
         gate = jax.nn.sigmoid(y @ lp["wg"].astype(y.dtype))  # [B, T, H]
         q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
         with jax.named_scope("core"):
-            if kind == SLIDING:
+            if attention_paths(cfg, t)[kind]["path"] == "kernel":
+                attn = attention.blocked_attention(q, k, v, window=_window(cfg, kind))
+            elif kind == SLIDING:
                 attn = sliding_attention(q, k, v, cfg.window)
             else:
                 attn = full_attention(q, k, v, cfg.attn_block)
