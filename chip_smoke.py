@@ -10,7 +10,7 @@ calls, and checks what comes out by the repo's own means. One chip, no arguments
 - phase ``telemetry``: ``MeshTelemetry`` at 4096 ranks x 64 signals x 32 window on
   a one-device mesh — pushes from inside a jitted, donated step, one scoring
   round with the Pallas kernel, medians against numpy, straggler F1 against
-  ``bench.make_telemetry``'s truth, program times from the profiler's device plane;
+  ``make_telemetry``'s truth, program times from the profiler's device plane;
 - phase ``train``: ``tpu-ft-launcher`` -> this file as the worker ->
   ``integrations.run_training`` + FT / straggler / hierarchical-checkpoint
   callbacks over a ``LocalCheckpointManager``, at the full width and depth of the
@@ -70,7 +70,7 @@ DEADLINE_S = 1100
 PHASE_TIMEOUT_S = {"telemetry": 300, "train": 600, "inprocess": 300, "multichip": 900}
 
 #: what a run is sized to. ``full`` is the north-star telemetry configuration
-#: (BASELINE.json / bench.py) and the flagship model of scripts/bench_model.py;
+#: (BASELINE.json) and the 160M-parameter model of ``_model_config``;
 #: ``tiny`` keeps every control-flow step and shrinks only the arrays.
 SIZES = {
     "full": dict(ranks=4096, signals=64, window=32, batch=8, seq=1024),
@@ -183,8 +183,8 @@ def model_config(tiny: bool):
 
     if tiny:
         return tfm.TransformerConfig.tiny()
-    # The one model the repo has a chip record for (scripts/bench_model.py):
-    # 160M parameters, 1.9 GB of f32 params + AdamW moments.
+    # 160M parameters, 1.9 GB of f32 params + AdamW moments: the model this
+    # check has run with on the chip since PR 21 (CHANGES.md).
     return tfm.TransformerConfig(
         vocab_size=32000, d_model=1024, n_layers=8, n_heads=16, n_kv_heads=8,
         d_ff=2816, max_seq_len=1024,
@@ -196,6 +196,31 @@ def make_tokens(cfg, sz: dict, seed: int):
 
     rng = np.random.default_rng(seed)
     return rng.integers(0, cfg.vocab_size, (sz["batch"], sz["seq"])).astype(np.int32)
+
+
+def make_telemetry(seed: int, ranks: int, signals: int, window: int):
+    """Timing windows ``[ranks, signals, window]`` with 5% noise in which 5% of the
+    ranks, drawn from the seed, run 1.6x slow; the counts, and the truth mask."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    base = rng.uniform(0.8, 1.2, size=(1, signals, 1)).astype(np.float32)
+    data = base * (1.0 + 0.05 * rng.standard_normal((ranks, signals, window)).astype(np.float32))
+    slow_ranks = rng.choice(ranks, size=int(ranks * 0.05), replace=False)
+    data[slow_ranks] *= 1.6
+    counts = np.full((ranks, signals), window, dtype=np.int32)
+    truth = np.zeros(ranks, dtype=bool)
+    truth[slow_ranks] = True
+    return data, counts, truth
+
+
+def f1_score(pred_mask, truth) -> float:
+    tp = int((pred_mask & truth).sum())
+    fp = int((pred_mask & ~truth).sum())
+    fn = int((~pred_mask & truth).sum())
+    prec = tp / max(tp + fp, 1)
+    rec = tp / max(tp + fn, 1)
+    return 2 * prec * rec / max(prec + rec, 1e-9)
 
 
 def tree_nbytes(tree) -> int:
@@ -239,7 +264,6 @@ def phase_telemetry(args) -> dict:
     import numpy as np
     from jax.sharding import Mesh
 
-    import bench
     from tpu_resiliency.telemetry.device_profiler import DeviceTimeProfiler
     from tpu_resiliency.telemetry.sharded import MeshTelemetry
 
@@ -248,8 +272,7 @@ def phase_telemetry(args) -> dict:
     on_tpu = device["platform"] == "tpu"
     sz = SIZES["tiny" if args.tiny else "full"]
     r, s, w = sz["ranks"], sz["signals"], sz["window"]
-    bench.R, bench.S, bench.W = r, s, w
-    data, counts, truth = bench.make_telemetry(args.seed)
+    data, counts, truth = make_telemetry(args.seed, r, s, w)
 
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("rank",))
     # On a TPU the kernel must be what auto-selection picks; anywhere else
@@ -307,7 +330,7 @@ def phase_telemetry(args) -> dict:
     reference = np.median(data, axis=-1).astype(np.float32)
     check(np.array_equal(medians, reference), "kernel medians are not bit-equal to numpy's")
     mask = np.asarray(scores.straggler)
-    f1 = bench.f1(mask, truth)
+    f1 = f1_score(mask, truth)
     check(f1 >= 0.99, f"straggler F1 {f1:.4f} < 0.99")
     check(bool(np.isfinite(np.asarray(scores.perf)).all()), "non-finite perf scores")
 
@@ -539,7 +562,6 @@ def phase_multichip_worker(args) -> dict:
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
 
-    import bench
     from tpu_resiliency.checkpoint.local_manager import LocalCheckpointManager
     from tpu_resiliency.checkpoint.state_dict import PyTreeStateDict
     from tpu_resiliency.launcher.errors import record
@@ -652,8 +674,7 @@ def phase_multichip_worker(args) -> dict:
 
         # -- telemetry: four-way sharded scorer against the unsharded one ---------
         r, s, w = sz["ranks"], sz["signals"], sz["window"]
-        bench.R, bench.S, bench.W = r, s, w
-        data, counts, truth = bench.make_telemetry(args.seed)
+        data, counts, truth = make_telemetry(args.seed, r, s, w)
         rows = np.ascontiguousarray(np.transpose(data, (2, 0, 1)))
         names = tuple(f"sig{j}" for j in range(s))
         use_pallas = None if on_tpu else True
@@ -686,7 +707,7 @@ def phase_multichip_worker(args) -> dict:
         check(score_diff <= 1e-6, f"sharded scores differ from unsharded by {score_diff:.3g}")
         same_set = bool(np.array_equal(np.asarray(sc4.straggler), np.asarray(sc1.straggler)))
         check(same_set, "sharded and unsharded scorers flag different ranks")
-        f1 = bench.f1(np.asarray(sc4.straggler), truth)
+        f1 = f1_score(np.asarray(sc4.straggler), truth)
         check(f1 >= 0.99, f"straggler F1 {f1:.4f} < 0.99")
 
         result = {

@@ -1547,12 +1547,10 @@ def _autoscale_campaign(seed: int, workdir: str, controlled: bool,
     the deadline). Returns ``(records, decision_schedule, disk_schedule)``.
 
     ``repriced`` selects which reshard price the controlled arm's cost model
-    reads from its (synthetic) bench artifact — both prices via the SAME
-    ``CostModel.from_bench`` path production uses. ``True`` gives it the
-    ``phases`` decomposition (plan + fetch = the real per-rank stall once
-    serve/fetch/assembly overlap); ``False`` strips the ``phases`` block so
-    ``from_bench`` falls back to the serial-era ``ranged_s`` top line, which
-    also charges the local assembly that now hides under the fetch. The
+    is built with. ``True`` gives it ``reshard_s=0.04`` (plan + fetch: the
+    per-rank stall once serve, fetch and assembly overlap); ``False`` gives
+    it ``0.12``, the serial-era wall time, which also charges the local
+    assembly that now hides under the fetch. The
     inflated price keeps shrink's predicted gain under the hysteresis bar at
     the ripe preemption, so the old-priced arm declines the resize and pays
     the death it could have dodged — identical fault script, identical
@@ -1617,29 +1615,16 @@ def _autoscale_campaign(seed: int, workdir: str, controlled: bool,
             request_restart_fn=swap_restart,
             cooldown=0.0,
         )
-        # Price the model the way production does — ``from_bench`` over a
-        # bench artifact. Both arms share ranged_s (the serial-era top line
-        # = the sim's actual reshard stall); only the repriced arm's doc
-        # carries the phase decomposition, whose plan+fetch sum is what the
-        # overlapped hot path really stalls a rank for.
-        bench_dir = os.path.join(workdir, f"bench_{arm}")
-        os.makedirs(bench_dir, exist_ok=True)
-        bench_doc = {"ranged_s": _AutoscaleSim.RESHARD_S}
-        if repriced:
-            bench_doc["phases"] = {"plan_s": 0.002, "fetch_s": 0.038}
-        with open(os.path.join(bench_dir, "BENCH_reshard.json"), "w") as f:
-            json.dump(bench_doc, f)
-        cost_model = CostModel.from_bench(
-            bench_dir,
+        # Both arms share the sim's physics; only the reshard price differs:
+        # plan + fetch for the repriced arm, the serial-era wall time (= the
+        # sim's actual reshard stall) for the other.
+        cost_model = CostModel(
             horizon_s=4.0,
             warm_restart_s=_AutoscaleSim.WARM_RESTART_S,
             cold_restart_s=_AutoscaleSim.COLD_RESTART_S,
+            reshard_s=0.04 if repriced else _AutoscaleSim.RESHARD_S,
             ckpt_s=0.02,
             preempt_block_s=_AutoscaleSim.PREEMPT_BLOCK_S,
-        )
-        assert abs(cost_model.reshard_s - (0.04 if repriced else 0.12)) < 1e-9, (
-            f"from_bench priced reshard_s={cost_model.reshard_s} "
-            f"(repriced={repriced})"
         )
         ctl = AutoscaleController(
             mode="act",

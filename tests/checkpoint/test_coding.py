@@ -519,8 +519,7 @@ class TestDeltaErasureE2E:
                   if e.kind == "ckpt_parity"}
         small, big = min(parity), max(parity)
         # One dirty chunk of an 8-chunk container: the frame round's coded
-        # payload collapses to ~prefix+trailer+1 chunk (the ≥20× win at 5%
-        # dirty on a wide container is BENCH_replication's gate).
+        # payload collapses to ~prefix+trailer+1 chunk.
         assert small * 4 < big  # frame rounds vs keyframe rounds
         for e in parity.values():
             k = e.payload["k"]

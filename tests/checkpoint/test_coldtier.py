@@ -126,6 +126,32 @@ class TestSpill:
         spilled = [e for e in sink if e.kind == "coldtier_spilled"]
         assert len(spilled) == 1 and spilled[0].payload["iteration"] == 3
 
+    def test_every_keyframe_of_every_rank_spills_undegraded(self, tmp_path, sink):
+        """World x rounds: two ranks' three asynchronous saves each put six
+        artifacts in the object store, none degraded, every iteration covered
+        by both ranks."""
+        world, rounds = 2, 3
+
+        def rank_saves(rank):
+            cold = _cold(tmp_path, rank=rank)
+            # keep=rounds: retention of one would prune a container the
+            # low-priority spiller has not read yet, which it skips by design.
+            mgr = LocalCheckpointManager(
+                str(tmp_path / "work"), rank=rank, cold=cold, keep=rounds
+            )
+            for it in range(1, rounds + 1):
+                mgr.save(it, PyTreeStateDict(_tree(rank)), is_async=True)
+                mgr.maybe_finalize(blocking=True)
+            assert cold.flush(timeout=30.0)
+            mgr.close()
+
+        run_ranks(world, rank_saves)
+        spilled = [e.payload for e in sink if e.kind == "coldtier_spilled"]
+        assert len(spilled) == world * rounds
+        assert not [e for e in sink if e.kind == "coldtier_degraded"]
+        assert all(p["bytes"] > 0 for p in spilled)
+        assert _cold(tmp_path).coverage() == {it: {0, 1} for it in range(1, rounds + 1)}
+
     def test_non_keyframe_spills_are_skipped(self, tmp_path):
         cold = _cold(tmp_path)
         assert cold.spill(5, 0, "unused", keyframe=False) is False

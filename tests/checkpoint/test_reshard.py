@@ -434,6 +434,13 @@ class TestReshardE2E:
             locals3[rank] = tensors
         got, _ = _reassemble_global(tgt3, locals3, 0)
         assert np.array_equal(got, GLOBAL)
+        # The wire's count: every byte of the state moved once, and the peer
+        # path moved less than the one source shard (a quarter of the state)
+        # that a full-mirror retrieve of rank 2's copy would have moved.
+        fetched = [e.payload for e in sink if e.kind == "reshard_fetch"]
+        assert sum(f["bytes"] for f in fetched) == GLOBAL.nbytes
+        peer_bytes = sum(f["bytes"] for f in fetched if f["via"] == "peer")
+        assert 0 < peer_bytes < GLOBAL.nbytes // 4
 
         # -- grow 3 → 4 (rank 3 returns with a wiped disk; newest iteration
         # is the shrunken world's save, so the resume is a true grow)

@@ -118,52 +118,54 @@ class TestCostModel:
         m.note_outcome(ACTION_SWAP, predicted=1.0, realized=-100.0)
         assert m.corrections[ACTION_SWAP] >= 0.25  # clamped, never zero/negative
 
-    def test_from_bench_reads_repo_artifacts(self, tmp_path):
+    def test_reshard_price_moves_the_shrink_delta_one_for_one(self):
+        """The shrink delta charges ``reshard_s`` once: a model priced with
+        plan + fetch (0.04) instead of the serial wall time (0.25) raises the
+        delta by exactly the difference."""
+        v = view()
+        priced_old = CostModel(reshard_s=0.25).estimate(ACTION_SHRINK, v)
+        priced_new = CostModel(reshard_s=0.04).estimate(ACTION_SHRINK, v)
+        assert priced_new > priced_old
+        assert priced_new - priced_old == pytest.approx(0.25 - 0.04)
+
+    def test_there_is_no_from_bench(self):
+        """The constants come from the constructor alone: nothing on
+        ``CostModel`` reads a record from a directory."""
+        assert not hasattr(CostModel, "from_bench")
+        priors = {"horizon_s": 60.0, "warm_restart_s": 0.06, "cold_restart_s": 0.75,
+                  "reshard_s": 0.15, "ckpt_s": 0.10}
+        constants = CostModel().constants()
+        assert {k: constants[k] for k in priors} == priors
+
+    def test_the_agent_prices_one_way_wherever_it_is_started(
+        self, tmp_path, monkeypatch, coord_store
+    ):
+        """A launcher started in a directory that holds ``BENCH_restart.json``
+        and ``BENCH_reshard.json`` builds its controller on ``CostModel()``'s
+        constants: the working directory is no input."""
+        from tpu_resiliency.launcher.agent import AgentConfig, ElasticAgent
+        from tpu_resiliency.watchdog.config import FaultToleranceConfig
+
         with open(tmp_path / "BENCH_restart.json", "w") as f:
             json.dump({
                 "in_job": {"respawn_ms": 500.0, "detect_ms": 100.0},
                 "in_job_warm_spares": {"respawn_ms": 30.0, "detect_ms": 10.0},
             }, f)
         with open(tmp_path / "BENCH_reshard.json", "w") as f:
-            json.dump({"ranged_s": 0.25}, f)
-        m = CostModel.from_bench(str(tmp_path))
-        assert m.cold_restart_s == pytest.approx(0.6)
-        assert m.warm_restart_s == pytest.approx(0.04)
-        assert m.reshard_s == pytest.approx(0.25)
-        # Missing artifacts: defaults survive.
-        d = CostModel.from_bench(str(tmp_path / "nope"))
-        assert d.cold_restart_s == CostModel().cold_restart_s
-
-    def test_from_bench_prefers_phase_decomposition(self, tmp_path):
-        """A refreshed bench file with a ``phases`` block reprices the shrink
-        delta: plan+fetch beats the top-line ranged_s (which still charges
-        the local assembly that now hides under the overlapped fetch)."""
-        with open(tmp_path / "BENCH_reshard.json", "w") as f:
-            json.dump({"ranged_s": 0.25}, f)
-        old = CostModel.from_bench(str(tmp_path))
-        assert old.reshard_s == pytest.approx(0.25)
-        v = view()
-        priced_old = old.estimate(ACTION_SHRINK, v)
-        # Refresh the artifact with the phase decomposition.
-        with open(tmp_path / "BENCH_reshard.json", "w") as f:
-            json.dump(
-                {"ranged_s": 0.25,
-                 "phases": {"plan_s": 0.01, "fetch_s": 0.03}}, f
-            )
-        new = CostModel.from_bench(str(tmp_path))
-        assert new.reshard_s == pytest.approx(0.04)
-        priced_new = new.estimate(ACTION_SHRINK, v)
-        # The repriced model strictly raises the shrink delta.
-        assert priced_new > priced_old
-        assert priced_new - priced_old == pytest.approx(0.25 - 0.04)
-        # A malformed phases block degrades to the top-line number.
-        with open(tmp_path / "BENCH_reshard.json", "w") as f:
-            json.dump(
-                {"ranged_s": 0.25, "phases": {"plan_s": "x"}}, f
-            )
-        assert CostModel.from_bench(
-            str(tmp_path)
-        ).reshard_s == pytest.approx(0.25)
+            json.dump({"ranged_s": 0.25,
+                       "phases": {"plan_s": 0.01, "fetch_s": 0.03}}, f)
+        monkeypatch.chdir(tmp_path)
+        agent = ElasticAgent(
+            AgentConfig(argv=["true"], autoscale="advise",
+                        run_dir=str(tmp_path / "run")),
+            FaultToleranceConfig(), coord_store,
+        )
+        agent._start_autoscale()
+        try:
+            assert agent.autoscale.model.constants() == CostModel().constants()
+        finally:
+            agent.autoscale.stop()
+            agent.rdzv.stop_keepalive()
 
 
 # -- deciding ----------------------------------------------------------------

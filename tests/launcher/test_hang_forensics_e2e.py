@@ -81,6 +81,11 @@ WORKER = textwrap.dedent(
             # The victim: opens its section, then wedges. The monitor must
             # detect, capture stacks, and run the kill ladder.
             client.start_section("step")
+            # A starved thread that gets the GIL between two chunks keeps it
+            # for one switch interval before the wedged main thread may force
+            # it off again: 5 ms by default, which on a loaded machine can pass
+            # before the dumper is even scheduled. Widen the gap, not the grace.
+            sys.setswitchinterval(0.25)
             inj.inject_fault(getattr(inj.Fault, fault_name), duration=90.0)
             time.sleep(90)
             sys.exit(0)
@@ -145,7 +150,10 @@ def _launch(tmp_path, fault_name):
          "--ft-param-rank_heartbeat_timeout", "2.0",
          "--ft-param-workload_check_interval", "0.25",
          "--ft-param-rank_section_timeouts", "{step: 4.0}",
-         "--ft-param-stack_dump_grace", "6.0",
+         # Chunks of 3 s put the victim's gaps 3 s apart, and one gap serves
+         # one starved thread (a beat, or one of the two dumpers): three gaps,
+         # and not a multiple of the chunk, so none races the kill ladder.
+         "--ft-param-stack_dump_grace", "10.0",
          "--events-file", str(events_file), "--run-dir", str(run_dir),
          "--incidents-dir", str(incidents),
          str(script), str(stop), fault_name],

@@ -1,6 +1,6 @@
 """Warm-spare promotion: parked pre-imported interpreters serve restart rounds
-without paying interpreter+import startup (the BENCH_restart respawn tax the
-reference's cold ``start_processes`` path pays on every round)."""
+without paying interpreter+import startup (the respawn tax the reference's
+cold ``start_processes`` path pays on every round)."""
 
 import json
 import os

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-R, S = 4096, 64  # the north-star telemetry width (BASELINE.json, bench.py)
+R, S = 4096, 64  # the north-star telemetry width (BASELINE.json)
 HBM_BYTES = 16e9  # one v5e chip
 
 
@@ -75,7 +75,7 @@ def test_median_kernel_compiles_for_v5e(one_chip, mode, window):
 
 
 def test_score_program_compiles_for_v5e(one_chip):
-    """bench.py's whole scoring round: the kernel plus the cross-rank scoring math."""
+    """The whole scoring round: the kernel plus the cross-rank scoring math."""
     from tpu_resiliency.ops.scoring_pallas import fused_median_weights
     from tpu_resiliency.telemetry import scoring
 

@@ -145,6 +145,23 @@ def test_store_stats_aggregates_shards(client, clique):
     assert all(s["backend"] == "epoll" for s in doc["shards"])
 
 
+def test_two_shards_share_a_1024_rank_keyspace():
+    """The per-shard balance, as an exact count: one key a rank for 1,024 ranks
+    over a two-shard clique lands on both shards, the busier holding under
+    three quarters (1/2 is perfect, 1.0 one loop serving it all)."""
+    two = LocalClique(2)
+    c = ShardedKVClient(two.endpoints, timeout=30.0)
+    try:
+        for rank in range(1024):
+            c.set(f"storm/{rank}", rank)
+        keys = [s["keys"] for s in c.store_stats()["shards"]]
+        assert len(keys) == 2 and sum(keys) == 1024 and min(keys) > 0
+        assert max(keys) / sum(keys) < 0.75
+    finally:
+        c.close()
+        two.close()
+
+
 def test_clique_store_view_and_factory(clique, monkeypatch):
     cs = CliqueStore(clique.endpoints, prefix="ns/")
     try:

@@ -1,5 +1,5 @@
-"""Critical-path analyzer: span collection, milestone decomposition (the
-arithmetic bench_restart.py publishes), the dominant chain, self-time, and
+"""Critical-path analyzer: span collection, milestone decomposition,
+the dominant chain, self-time, and
 the tpu-critpath CLI with highlighted trace export."""
 
 import json

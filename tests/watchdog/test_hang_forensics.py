@@ -203,6 +203,36 @@ def test_capture_stacks_sees_other_threads():
         t.join(5.0)
 
 
+def test_capture_stacks_reads_no_file(monkeypatch):
+    """A capture under a GIL-holding hang has one chunk gap to finish in, and
+    every system call hands the GIL back to the holder for its next whole
+    chunk: with a cold ``linecache`` the capture still names file, line and
+    function of every frame, opens nothing, and leaves the cache cold; with
+    the file cached it adds the source text."""
+    import builtins
+    import linecache
+    import tokenize
+
+    def refuse(*a, **kw):
+        raise AssertionError(f"capture_stacks opened a file: {a}")
+
+    here = __file__
+    linecache.clearcache()
+    with monkeypatch.context() as m:
+        m.setattr(builtins, "open", refuse)
+        m.setattr(tokenize, "open", refuse)
+        m.setattr(linecache, "updatecache", refuse)
+        cold = stackdump.capture_stacks()
+    mine = [f for f in cold[0]["frames"] if "test_capture_stacks_reads_no_file" in f]
+    assert mine and mine[0].startswith(f"{here}:") and " | " not in mine[0]
+    assert here not in linecache.cache
+
+    linecache.getlines(here)  # what rendering any earlier traceback does
+    warm = stackdump.capture_stacks()
+    mine = [f for f in warm[0]["frames"] if "test_capture_stacks_reads_no_file" in f]
+    assert mine[0].endswith("| warm = stackdump.capture_stacks()")
+
+
 def test_dump_stacks_records_event_and_counts(sink_events, tmp_path):
     from tpu_resiliency.utils import flight_recorder
 

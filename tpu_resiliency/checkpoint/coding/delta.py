@@ -2,8 +2,8 @@
 
 Steady-state training mutates a small fraction of the state between
 checkpoint intervals (optimizer moments and touched parameters), yet the
-mirror strategy re-ships every byte every round — BENCH_ckpt_save.json shows
-the 1 GB save bandwidth-bound on exactly that. The ``TPURES03`` chunk
+mirror strategy re-ships every byte every round, which makes a large save
+bandwidth-bound. The ``TPURES03`` chunk
 manifest (``checkpoint/format.py``) makes consecutive saves diffable for
 free: the per-chunk CRCs both saves already compute ARE the diff input.
 

@@ -6,12 +6,11 @@ to: hollow metadata (small pickle) followed by each leaf's raw bytes, streamed
 sequentially — large contiguous writes are how you saturate local NVMe, and the hollow /
 payload split means the metadata can be read without touching the payload.
 
-**Measured justification for single-stream (the reference fans out per-bucket
-writers, ``filesystem_async.py:232-334,558``):** on this class of host storage,
-writing a 1 GiB tree (fsync'd, warm, alternating runs —
-``scripts/bench_ckpt_io.py``) measured single-stream at 0.30 GB/s median vs 0.16
-GB/s for a 4-way thread fan-out: concurrent streams halve throughput by
-interleaving what would be contiguous writes. Writes here are also already
+**Why single-stream (the reference fans out per-bucket writers,
+``filesystem_async.py:232-334,558``):** on one local device concurrent streams
+interleave what would be contiguous writes, and a sandbox reading of 2026-07
+had a 4-way thread fan-out at half the single stream's rate (no chip host has
+been asked). Writes here are also already
 asynchronous to the train loop (``async_core``), so writer parallelism buys no
 step-time; it would only shorten the background window.
 
@@ -19,10 +18,10 @@ The capability exists anyway, behind the ``$TPU_RESILIENCY_CKPT_STRIPES``
 storage-class knob (``stripes=`` on :func:`write_payload`/:func:`write_blob`):
 N threads pwrite byte-balanced contiguous leaf groups at their final offsets in
 the SAME container, so the striped file is byte-identical to the sequential one
-and the read path never changes. Measured on this host (0.5 GiB, 64 leaves,
-``scripts/bench_ckpt_io.py``): single-stream 0.59 GB/s vs 4-way striped 0.61
-GB/s — a wash here, hence default 1; on striped NVMe arrays or parallel
-filesystems re-run the script and set the env for the measured winner.
+and the read path never changes. On one local device striping was a wash in
+the one sandbox reading taken, hence default 1; striped NVMe arrays and parallel
+filesystems are what the knob is for (ROADMAP Queue 3 item 7 queues its
+removal).
 
 Atomicity follows the reference's ``.dirty``-then-rename protocol
 (``checkpointing/local/ckpt_managers/local_manager.py:110-131``): write to
@@ -61,7 +60,7 @@ from a seed exactly like network fault plans.
 **Chunk manifest (format v3, ``TPURES03``).** v2's unit of verification is the
 *leaf* — fine for whole-container reads, hostile to ranged ones: serving a
 4 KB reshard range out of a 256 MB leaf forced a CRC pass over the entire
-container (BENCH_reshard.json's 0.42 speedup was exactly that stall). v3
+container, which made a ranged resume slower than fetching the whole mirror. v3
 additionally records a **per-chunk CRC manifest** in the trailer: every leaf's
 payload is cut into fixed-size, leaf-aligned chunks (``chunk_size`` rides in
 the trailer; chunks never span leaves, the last chunk of a leaf is short) and
@@ -175,8 +174,8 @@ _VERIFIABLE_TAGS = (_ALGO_TAG,)
 #: Storage-class knob for writer parallelism (reference analogue: per-bucket
 #: writer fan-out, ``filesystem_async.py:232-334``). Default 1: on this class of
 #: host storage one stream saturates the device and a fan-out HALVES throughput
-#: (measured, see module docstring). Set >1 only after ``scripts/bench_ckpt_io.py``
-#: shows a win on the target storage (striped NVMe arrays, parallel filesystems).
+#: (see module docstring). Set >1 only where a measurement on the target
+#: storage shows a win (striped NVMe arrays, parallel filesystems).
 STRIPES_ENV = "TPU_RESILIENCY_CKPT_STRIPES"
 
 

@@ -1335,8 +1335,8 @@ class LocalCheckpointManager:
         loads here in O(trailer) — two small reads — and every byte the
         reshard path later serves or slices is verified CHUNK-GRANULAR on
         first touch (``_read_ranges``), so serving a 4 KB range never pays a
-        whole-container CRC scan (the serve-side stall BENCH_reshard.json
-        measured). Pre-chunk containers (``TPURES02``/v1/foreign algo) keep
+        whole-container CRC scan (the serve-side stall of format v2).
+        Pre-chunk containers (``TPURES02``/v1/foreign algo) keep
         the one-time full streaming pass. A corrupt container is quarantined
         and surfaces as CheckpointError either way."""
         path = self._path(CkptID(iteration, owner, self.session))

@@ -28,7 +28,9 @@ costs one timed-out GET and a ``status: unreachable`` row (+
 ``fleet_job_unreachable`` event); it never degrades a fleet endpoint and
 never blocks the other jobs' scrapes (parallel fan-out — the wall clock of a
 scrape is the slowest single job, not the sum, which is what keeps scrape
-cost sub-linear in job count; ``scripts/bench_fleet.py`` gates it).
+cost sub-linear in job count;
+``tests/fleet/test_aggregator.py::test_dead_job_is_unreachable_never_fatal``
+holds the containment).
 """
 
 from __future__ import annotations

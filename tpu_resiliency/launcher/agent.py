@@ -69,8 +69,8 @@ class AgentConfig:
     store_host: str = "127.0.0.1"
     store_port: int = 0
     #: parked pre-imported interpreters kept warm per node: restart rounds
-    #: promote one instead of paying the measured multi-second spawn+import
-    #: serialization (BENCH_restart.json decomposition). 0 disables.
+    #: promote one instead of paying process spawn, interpreter start and
+    #: imports, which serialize across concurrent spawns. 0 disables.
     warm_spares: int = 0
     warm_spare_preload: str = "jax"
     #: park phase for spares: "imports" (preloads only), "runtime" (the
@@ -292,7 +292,7 @@ class ElasticAgent:
         watchtower = self.watchtower
         self.autoscale = AutoscaleController(
             mode=self.cfg.autoscale,
-            cost_model=CostModel.from_bench(os.getcwd()),
+            cost_model=CostModel(),
             remediation=engine,
             spare_capacity_fn=self._spare_capacity,
             active_alerts_fn=(
@@ -741,8 +741,8 @@ class ElasticAgent:
         )
         watcher = None
         try:
-            # The spawn segment is the restart-latency hot path (BENCH_restart
-            # decomposition) — give it its own slice in the trace.
+            # The spawn segment is the restart-latency hot path
+            # (``tools/critpath``) — give it its own slice in the trace.
             with span(
                 "launcher", "worker.spawn",
                 round=outcome.round, nproc=cfg.nproc_per_node,

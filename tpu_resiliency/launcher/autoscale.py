@@ -38,10 +38,10 @@ action                when it wins
 
 using an **explicit, testable cost model**: :meth:`CostModel.estimate` turns
 one candidate action into a predicted goodput delta in seconds over a fixed
-horizon, from constants seeded by the measured benchmarks
-(``BENCH_restart.json`` / ``BENCH_reshard.json`` — :meth:`CostModel.
-from_bench`) and refined online from realized outcomes
-(:meth:`CostModel.note_outcome`, a bounded per-action EWMA correction).
+horizon, from constants that are the constructor's defaults wherever the
+launcher is started (their origin is in :class:`CostModel`'s docstring) and
+refined online from realized outcomes (:meth:`CostModel.note_outcome`, a
+bounded per-action EWMA correction).
 
 Audit is the contract. Every decision is an ``autoscale_decision`` event
 (action, victims, mode, actuation outcome, ``predicted_delta_s``, reason) →
@@ -68,7 +68,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
 import threading
 import time
 from typing import Any, Callable, Optional
@@ -166,14 +165,17 @@ class Decision:
 class CostModel:
     """Predicted goodput delta, in seconds over ``horizon_s``, per action.
 
-    The constants are the measured world: ``warm_restart_s`` and
-    ``cold_restart_s`` from ``BENCH_restart.json`` (warm-spare vs cold
-    respawn chains), ``reshard_s`` from ``BENCH_reshard.json`` (the ranged
-    resharded-resume wall time), ``ckpt_s`` the proactive save's
-    caller-visible stall. ``estimate`` is pure over a
-    :class:`ControllerView`; :meth:`note_outcome` folds realized outcomes
-    into a bounded per-action EWMA correction factor so a systematically
-    optimistic forecast self-deflates instead of repeating its mistake.
+    The restart constants (``warm_restart_s``, ``cold_restart_s``,
+    ``reshard_s``, ``ckpt_s``) are one loopback CPU sandbox's readings of
+    2026-07, kept as priors: a warm-spare and a cold respawn chain, a ranged
+    resharded resume, a proactive save's caller-visible stall. They are the
+    same wherever the launcher is started; a process on a TPU host needs
+    seconds, not milliseconds, to reach the chip (``PERF.md`` section 5).
+    ``estimate`` is pure over a :class:`ControllerView`;
+    :meth:`note_outcome` refines them from the job's own restarts: it folds
+    realized outcomes into a bounded per-action EWMA correction factor so a
+    systematically optimistic forecast self-deflates instead of repeating
+    its mistake.
     """
 
     def __init__(
@@ -215,57 +217,6 @@ class CostModel:
         self.corrections: dict[str, float] = {}
         #: per-action (n, sum_predicted, sum_realized) — forecast accuracy
         self.outcomes: dict[str, list[float]] = {}
-
-    @classmethod
-    def from_bench(cls, bench_dir: str, **overrides) -> "CostModel":
-        """Seed the constants from the repo's measured benchmarks when the
-        artifacts exist; silently keep the defaults where they don't (a fresh
-        checkout prices conservatively instead of crashing)."""
-        kw: dict[str, float] = {}
-        try:
-            with open(os.path.join(bench_dir, "BENCH_restart.json")) as f:
-                b = json.load(f)
-            warm = b.get("in_job_warm_spares") or {}
-            cold = b.get("in_job") or {}
-            w = sum(
-                warm.get(k, 0.0) or 0.0
-                for k in ("detect_ms", "teardown_ms", "rendezvous_ms",
-                          "respawn_ms")
-            ) / 1e3
-            c = sum(
-                cold.get(k, 0.0) or 0.0
-                for k in ("detect_ms", "teardown_ms", "rendezvous_ms",
-                          "respawn_ms")
-            ) / 1e3
-            if w > 0:
-                kw["warm_restart_s"] = w
-            if c > 0:
-                kw["cold_restart_s"] = c
-        except (OSError, ValueError):
-            pass
-        try:
-            with open(os.path.join(bench_dir, "BENCH_reshard.json")) as f:
-                r = json.load(f)
-            # Prefer the phase decomposition (PR 13): plan + fetch is the
-            # true per-rank resize stall once serve/fetch/assembly overlap —
-            # the top-line ranged_s also charges the local assembly that now
-            # hides under the fetch, so pricing from it overstates elasticity
-            # cost and the controller under-chooses shrink/expand.
-            phases = r.get("phases") or {}
-            plan_s = phases.get("plan_s")
-            fetch_s = phases.get("fetch_s")
-            if (
-                isinstance(plan_s, (int, float))
-                and isinstance(fetch_s, (int, float))
-                and plan_s >= 0 and fetch_s > 0
-            ):
-                kw["reshard_s"] = float(plan_s) + float(fetch_s)
-            elif isinstance(r.get("ranged_s"), (int, float)) and r["ranged_s"] > 0:
-                kw["reshard_s"] = float(r["ranged_s"])
-        except (OSError, ValueError):
-            pass
-        kw.update(overrides)
-        return cls(**kw)
 
     # -- the estimates ------------------------------------------------------
 

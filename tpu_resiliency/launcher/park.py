@@ -1,8 +1,9 @@
 """Warm spare workers: pre-paid interpreter+import cost for restart rounds.
 
-``BENCH_restart.json`` decomposes the in-job respawn tax: of ~4-6 s, nearly all
-is process spawn + interpreter startup, with a measured multi-second
-bare-interpreter floor that *serializes* across concurrent spawns. The
+Nearly all of an in-job respawn is process spawn, interpreter startup and
+imports, a floor that *serializes* across concurrent spawns
+(``tools/critpath`` splits a restart into its segments; on a TPU host a fresh
+process then needs 10-17 s more to reach the chip, ``PERF.md`` section 5). The
 reference pays the same tax on every restart round (its ``start_processes``
 spawn path, ``_torch_elastic_compat/multiprocessing/api.py``) — this module
 removes it:

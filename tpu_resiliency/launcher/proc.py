@@ -114,8 +114,8 @@ class WorkerGroup:
         self.per_rank_env = None
         #: set by a per-worker reaper thread the instant ANY worker exits, so
         #: the supervise loop wakes immediately instead of discovering the exit
-        #: at its next poll tick — this takes the detection segment of
-        #: BENCH_restart's respawn decomposition from O(monitor_interval) to ~ms.
+        #: at its next poll tick — this takes the detect segment of
+        #: ``tools/critpath.restart_decomposition`` off the monitor interval.
         self._change = threading.Event()
 
     def start(self, round_no: int, first_global_rank: int, world_size: int) -> None:

@@ -825,7 +825,7 @@ class RestartWatcher:
         even a bounded one: stop() runs in the round-teardown path of every
         restart, and the thread is parked in a multi-second store wait — a
         100 ms join timeout here was a flat 100 ms tax on EVERY respawn
-        (visible as the rendezvous segment of BENCH_restart's decomposition).
+        (the rendezvous segment of ``tools/critpath.restart_decomposition``).
         A wake racing the flag is harmless: wake_fn only sets an Event whose
         consumer re-reads store state for truth."""
         self._stop.set()

@@ -25,8 +25,8 @@ again). It is therefore the **default window reduction on TPU** for the mesh sco
 path (``MeshTelemetry(use_pallas=None)`` auto-selects by backend and shape via
 :func:`pallas_supported`); non-TPU backends use the XLA lowering. Rank-counting is
 O(W²), so auto-selection caps it at a window crossover and switches to the O(32·W)
-radix-select kernel beyond it (``auto_mode``); ``scripts/bench_pallas_sweep.py``
-measures all three variants.
+radix-select kernel beyond it (``auto_mode``); ``BASELINE.md`` keeps the one sweep
+of the three variants on a chip.
 """
 
 from __future__ import annotations
@@ -84,8 +84,8 @@ def _write_median_and_weight(data, counts, valid, rank, med_ref, weight_ref):
 def _median_weights_pairwise_kernel(data_ref, counts_ref, med_ref, weight_ref):
     """All-pairs variant: one [RT, S, W, W] comparison block instead of W
     sequential VPU passes — more VMEM (quadratic temporaries, so it runs at a
-    smaller rank tile) but no serial loop. Which formulation wins is measured, not
-    assumed: bench.py times both as separate variants on the real chip."""
+    smaller rank tile) but no serial loop. Which formulation wins is a question for
+    a chip, not an assumption: ``BASELINE.md`` has the one sweep that asked it."""
     data = data_ref[:]  # [RT, S, W] f32
     counts = counts_ref[:]  # [RT, S] i32
     rt, s, w = data.shape
@@ -169,9 +169,8 @@ def _median_weights_radix_kernel(data_ref, counts_ref, med_ref, weight_ref):
 #: one v5e chip on 2026-07-31 (W∈{32..256} × R∈{256..4096}, an older jax and
 #: compiler; its record is gone): the loop kernel beat both the XLA sort and
 #: the radix kernel at every tested R for W≤128 and lost at W=256. Not measured
-#: on today's installation — ROADMAP queues that. Operators re-derive it per
-#: device via ``scripts/bench_pallas_sweep.py`` →
-#: ``$TPU_RESILIENCY_PALLAS_MAX_WINDOW``.
+#: on today's installation — ROADMAP queues that (one probe on the chip).
+#: ``$TPU_RESILIENCY_PALLAS_MAX_WINDOW`` overrides it.
 DEFAULT_MAX_WINDOW = 128
 MAX_WINDOW_ENV = "TPU_RESILIENCY_PALLAS_MAX_WINDOW"
 

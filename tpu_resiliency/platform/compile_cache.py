@@ -14,8 +14,8 @@ instead of recompiling. There is ONE rule for where the cache lives:
   cache. The library never makes up a path and never sets another directory.
 - ``tpu-ft-launcher --compile-cache-dir DIR`` exports the variable to its
   workers only when the environment does not already carry it (an outside
-  setting wins, and is logged). The entry programs (``chip_smoke.py``,
-  ``bench.py``) fall back to :func:`checkout_cache_dir`, one fixed git-ignored
+  setting wins, and is logged). The entry program ``chip_smoke.py``
+  falls back to :func:`checkout_cache_dir`, one fixed git-ignored
   directory in the checkout: the path is part of the cache key's world, so a
   directory that moves between runs never hits.
 - Workers apply it through :func:`apply_from_env` (called by

@@ -5,9 +5,8 @@ event loop — by design (no locks, parked continuations instead of blocked
 threads), and measured flat in *connection* count, but its op throughput is
 one core's dict-op rate. At 4096 ranks every subsystem's traffic (rendezvous
 CAS, barrier storms, heartbeat touches, metrics pushes, reshard
-holder-gathers) funnels through that one loop and queue wait dominates —
-``BENCH_store_baseline.json``'s 37 µs → 3.3 ms p50 curve from 1 → 64 clients
-is that funnel.
+holder-gathers) funnels through that one loop and queue wait dominates: a
+client's latency grows with the number of clients queued on the loop.
 
 This module scales the plane *horizontally* without touching the wire
 protocol or the server: a **clique** of ordinary ``KVServer`` processes plus

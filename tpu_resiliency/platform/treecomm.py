@@ -3,10 +3,9 @@
 The flat collectives the store grew up with put O(N) work on ONE event loop:
 a full-world barrier is N arrivals serialized through one selector thread and
 N release frames sent from it; a flat ``all_gather`` adds N ``prefix_get``
-responses each carrying the whole world's values. ``BENCH_store_baseline.json``
-records the resulting curve — p50 37 µs at 1 client, 3.3 ms at 64 — and every
-subsystem since PR 4 (reshard holder-gather, metrics push, barrier census,
-fleet leases) stacked onto it.
+responses each carrying the whole world's values. Latency on that loop grows
+with the clients queued on it, and every subsystem since PR 4 (reshard
+holder-gather, metrics push, barrier census, fleet leases) stacked onto it.
 
 This module restructures the two collective shapes through a ``fanout``-ary
 tree over the *group index space* (0..world-1, parent of ``i`` is
@@ -150,9 +149,8 @@ class TreeComm:
         #: so each wait_changed parks from where the previous round left off
         #: instead of re-reading history.
         self._seen: dict[str, int] = {}
-        #: client-side op counter — the measured half of the hop accounting
-        #: (``scripts/bench_store.py`` records it next to the analytic
-        #: :func:`tree_hops` / :func:`flat_hops` figures).
+        #: client-side op counter — the counted half of the hop accounting,
+        #: beside the analytic :func:`tree_hops` / :func:`flat_hops` figures.
         self.ops = 0
 
     # -- key-wait plumbing --------------------------------------------------

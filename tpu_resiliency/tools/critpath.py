@@ -1,10 +1,9 @@
 """Span critical-path analysis over the structured event stream.
 
-Every BENCH script so far re-implemented phase decomposition by hand over the
-events JSONL (``bench_restart.py`` walked ``failure_detected`` /
-``restart_requested`` / ``rendezvous_round`` timestamps itself;
-``bench_reshard.py`` had its own stopwatch). This module is the ONE code path
-both the benchmarks and the operator tooling use: it builds the span DAG of a
+Phase decomposition over the events JSONL (the ``failure_detected`` /
+``restart_requested`` / ``rendezvous_round`` timestamps of a restart, the
+spans of a resharded resume) has ONE code path, this module, which the tests
+and the operator tooling both use: it builds the span DAG of a
 restart / save / reshard episode from the events JSONL (parenting already
 env-propagated by ``utils/tracing.py``), computes the **dominant chain** — the
 sequence of spans that actually gates the episode's wall clock — with
@@ -14,11 +13,11 @@ Chrome-trace export with the critical path highlighted
 
 Three layers of answer, cheapest first:
 
-- **milestone decomposition** (:func:`restart_decomposition`): the published
+- **milestone decomposition** (:func:`restart_decomposition`): the
   detect / teardown / rendezvous / promote / first-step-ready split, computed
-  from the same milestone events ``BENCH_restart.json`` is built from — the
-  benchmarks now *consume this function*, so the operator tool and the
-  committed numbers can never drift;
+  from the launcher's own milestone events
+  (``tests/launcher/test_restart_milestones.py`` drives it through one real
+  restart);
 - **dominant chain** (:func:`dominant_chain`): walk backward from the episode
   end, at each instant charging the wall clock to the most specific span
   covering it — the restart's critical path reads
@@ -154,8 +153,8 @@ def _first_ts(recs: list[dict], kind: str, after: float = float("-inf"),
 
 def find_restart_episodes(records: Iterable[dict]) -> list[dict]:
     """Every restart episode in the stream: fault evidence → training
-    resumed, decomposed at the launcher's own milestone events. The segment
-    arithmetic is the ONE definition ``bench_restart.py`` publishes."""
+    resumed, decomposed at the launcher's own milestone events. This segment
+    arithmetic is the one definition."""
     recs = [
         r for r in records
         if isinstance(r.get("ts"), (int, float)) and isinstance(r.get("kind"), str)
@@ -249,9 +248,9 @@ def restart_decomposition(
     resume_ts: Optional[float] = None,
 ) -> Optional[dict]:
     """The first restart episode's decomposition, with optional external
-    anchors: a benchmark that knows the exact fault/resume instants (worker
-    stamp files, on the same wall clock as the stream) passes them so the
-    published numbers and the pure-events view share one arithmetic."""
+    anchors: a caller that knows the exact fault/resume instants (worker
+    stamp files, on the same wall clock as the stream) passes them so its
+    numbers and the pure-events view share one arithmetic."""
     recs = [
         r for r in records
         if isinstance(r.get("ts"), (int, float)) and isinstance(r.get("kind"), str)
@@ -268,8 +267,7 @@ def restart_decomposition(
 
 def reshard_decomposition(records: Iterable[dict]) -> dict:
     """Phase split of a resharded resume from its own spans/events: plan
-    build, ranged peer fetch (wall + bytes), local slice bytes — the
-    decomposition ``bench_reshard.py`` publishes."""
+    build, ranged peer fetch (wall + bytes), local slice bytes."""
     recs = [r for r in records if isinstance(r, dict)]
     spans = collect_spans(recs)
     plan_s = sum(s.t1 - s.t0 for s in spans if s.name == "reshard.plan")

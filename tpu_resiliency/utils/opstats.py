@@ -167,9 +167,8 @@ class OpStats:
     #: so the documents read naturally but carry ±SAMPLE granularity: a hot
     #: op's figures are statistically exact, an op called twice ever may
     #: show 0 or 16 (one sample's weight). That trade is deliberate — exact per-op accounting was
-    #: measured at 2-4 µs/op of py3.10 attribute traffic in situ, >5% of a
-    #: ~35 µs loopback op (scripts/bench_store.py's overhead leg is the
-    #: regression gate), and the rare-op forensics live elsewhere anyway
+    #: a few µs/op of attribute traffic in situ, a visible share of a
+    #: loopback op, and the rare-op forensics live elsewhere anyway
     #: (``barrier_census``, the exact live conn/park counts in the doc).
     SAMPLE = 16
 

@@ -27,16 +27,18 @@ capture — no in-process mechanism can observe that state while it lasts. The
 capture fires the moment the GIL frees (chunk boundaries of
 ``Fault.GIL_SLEEP``, or the end of the native call); hangs parked in
 GIL-releasing waits (collectives, ``block_until_ready``, socket reads, locks)
-capture immediately.
+capture immediately. For that moment to be enough the capture must not give
+the GIL back before it is done, so it reads no file: every system call
+releases the GIL, and a wedged holder retakes it for its next whole chunk.
 """
 
 from __future__ import annotations
 
+import linecache
 import os
 import signal
 import sys
 import threading
-import traceback
 from typing import Optional
 
 from tpu_resiliency.utils.events import record as record_event
@@ -55,12 +57,23 @@ MAX_THREADS = 64
 DUMP_SIGNAL = signal.SIGUSR1
 
 
+def _cached_source_line(filename: str, lineno: int) -> str:
+    """The source line if ``linecache`` already holds the file, else ``""``.
+    Never reads a file (see the module docstring's capture limits)."""
+    entry = linecache.cache.get(filename)
+    if entry is None or len(entry) == 1:  # absent, or a lazy loader not yet run
+        return ""
+    lines = entry[2]
+    return lines[lineno - 1].strip() if 1 <= lineno <= len(lines) else ""
+
+
 def capture_stacks(max_frames: int = MAX_FRAMES_PER_THREAD) -> list[dict]:
     """Every thread's Python stack as JSON-serializable dicts.
 
     Each entry: ``{"name", "ident", "daemon", "main", "frames": [
     "file:line in func | source"]}`` — outermost frame first, truncated to the
-    *deepest* ``max_frames`` (the leaf is where the thread is stuck).
+    *deepest* ``max_frames`` (the leaf is where the thread is stuck). The
+    ``| source`` part is there only for files ``linecache`` already holds.
     """
     frames_by_id = sys._current_frames()
     threads = {t.ident: t for t in threading.enumerate()}
@@ -78,14 +91,16 @@ def capture_stacks(max_frames: int = MAX_FRAMES_PER_THREAD) -> list[dict]:
     out: list[dict] = []
     for ident, frame in ranked[:MAX_THREADS]:
         t = threads.get(ident)
-        stack = traceback.extract_stack(frame)
-        if len(stack) > max_frames:
-            stack = stack[-max_frames:]
-        rendered = [
-            f"{s.filename}:{s.lineno} in {s.name}"
-            + (f" | {s.line.strip()}" if s.line else "")
-            for s in stack
-        ]
+        rendered: list[str] = []
+        while frame is not None and len(rendered) < max_frames:  # leaf first
+            code, lineno = frame.f_code, frame.f_lineno
+            line = _cached_source_line(code.co_filename, lineno)
+            rendered.append(
+                f"{code.co_filename}:{lineno} in {code.co_name}"
+                + (f" | {line}" if line else "")
+            )
+            frame = frame.f_back
+        rendered.reverse()
         out.append(
             {
                 "name": t.name if t is not None else f"thread-{ident}",
