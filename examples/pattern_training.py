@@ -8,18 +8,21 @@ one chip's share of an expert-parallel split; pairs routed to experts held elsew
 nothing here). Every ``--routing-every`` steps the script runs the model's forward once
 more on the step's batch and emits its routing counts as a ``moe_routing`` event: for
 each sparse layer the (token, choice) pairs that landed on held experts, the largest
-and the mean load of a held expert, and the pairs dropped (always 0: the grouped
-products have room for every pair). Once, before the first step, it emits an
+and the mean load of a held expert, the pairs dropped (always 0) and the rows the
+dispatch carried (twice the even share of the experts held, or every pair in a step
+whose router sent more than that here). Once, before the first step, it emits an
 ``attention_path`` event: for each kind of attention layer, whether its products run
 as the blocked kernels of ``ops/attention.py`` (on a TPU, at shapes that tile) or as
-the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block.
+the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block;
+and a ``dispatch_path`` event: the rows the expert dispatch carries at this batch
+(``pattern.dispatch_rows``), ``bounded`` or ``full``.
 
 Run (CPU simulation)::
 
     python examples/pattern_training.py --cpu --steps 20
 
-Prints ``ATTENTION {...}``, one ``ROUTING step=<n> ...`` line per routing event and
-``DONE loss=<x>`` on success.
+Prints ``ATTENTION {...}``, ``DISPATCH {...}``, one ``ROUTING step=<n> ...`` line per
+routing event and ``DONE loss=<x>`` on success.
 """
 
 from __future__ import annotations
@@ -64,6 +67,10 @@ def main() -> None:
     paths = pattern.attention_paths(cfg, args.batch[1])
     events.record("model", "attention_path", seq=args.batch[1], **paths)
     print(f"ATTENTION {paths}", flush=True)
+    n_tokens = args.batch[0] * args.batch[1]
+    dispatch = pattern.dispatch_rows(cfg, n_tokens)
+    events.record("model", "dispatch_path", tokens=n_tokens, **dispatch)
+    print(f"DISPATCH {dispatch}", flush=True)
 
     def tokens(i: int):
         return jnp.asarray(np.random.default_rng([0, i]).integers(

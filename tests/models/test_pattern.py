@@ -176,6 +176,127 @@ def test_no_pair_is_dropped_under_a_biased_router(held):
     np.testing.assert_allclose(np.asarray(routed), np.asarray(want), atol=2e-5)
 
 
+def biased(cfg, seed, always=(), never=()):
+    """A sparse layer's share of the weights and positive tokens, the router biased so
+    that every token chooses the experts ``always`` and none chooses ``never``; and
+    the dense weighted sum over the held experts, which the routed part must equal."""
+    lp, y = sparse_layer(cfg, seed=seed)
+    y = jnp.abs(y)  # so that the sign of a router column decides for every token
+    for experts, value in ((always, 1.0), (never, -1.0)):
+        for e in experts:
+            lp["w_router"] = lp["w_router"].at[:, e].set(value)
+    first, held = cfg.experts_held
+    part = share_of(lp, first, held)
+    weights, experts = pattern.route(cfg, y, lp["w_router"])
+    want = jnp.zeros_like(y)
+    for e in range(held):
+        gate = jnp.sum(jnp.where(experts == first + e, weights, 0.0), -1, keepdims=True)
+        want = want + gate * pattern._swiglu(
+            y, part["we_gate"][e], part["we_up"][e], part["we_down"][e])
+    return part, y, want
+
+
+def primitives(jaxpr) -> set[str]:
+    """Names of the primitives of a jaxpr and of every jaxpr inside it."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= primitives(sub)
+    return names
+
+
+@pytest.fixture(scope="module")
+def both_widths():
+    """Value and gradients of one routed layer (64 tokens, 256 pairs, 4 of 16 experts
+    held: 128 rows carried) through the bounded dispatch and, with the static choice
+    overridden, through the full width alone."""
+    cfg = pattern.PatternConfig.tiny(dtype=jnp.float32)
+    lp, y = sparse_layer(cfg, seed=11)
+    part = share_of(lp, *cfg.experts_held)
+    cot = jax.random.normal(jax.random.PRNGKey(12), y.shape)
+
+    def value_and_grads():
+        def f(y, part):
+            routed, counts = jax.checkpoint(
+                lambda y, part: pattern.routed_experts(cfg, y, part))(y, part)
+            return jnp.sum(routed * cot), (routed, counts)
+        with jax.default_matmul_precision("highest"):
+            (_, (routed, counts)), (dy, dpart) = jax.value_and_grad(
+                f, argnums=(0, 1), has_aux=True)(y, part)
+        return {"output": routed, "y": dy, **dpart}, counts
+
+    bounded, counts = value_and_grads()
+    assert int(counts["rows_carried"]) == 128 >= int(counts["pairs_held"])
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pattern, "dispatch_rows", lambda cfg, n: {
+            "path": "full", "rows": n * cfg.top_k, "pairs": n * cfg.top_k})
+        full, counts = value_and_grads()
+    assert int(counts["rows_carried"]) == 256
+    return bounded, full
+
+
+@pytest.mark.parametrize("what", ["output", "y", "w_router", "we_gate", "we_up", "we_down"])
+def test_bounded_dispatch_equals_the_full_width(both_widths, what):
+    """The output, and the gradient to the tokens, the router and each expert leaf."""
+    bounded, full = both_widths
+    scale = float(jnp.max(jnp.abs(full[what])))
+    assert scale > 0
+    np.testing.assert_allclose(np.asarray(bounded[what]), np.asarray(full[what]),
+                               rtol=0, atol=2e-5 * max(scale, 1e-2))
+
+
+@pytest.mark.parametrize("held,always,never,carried", [
+    # three held experts take every token: 192 pairs over the 128 carried -> full width
+    ((0, 4), (0, 1, 2), (), 256),
+    ((4, 4), (5, 6, 7), (), 256),
+    ((12, 4), (12, 14, 15), (), 256),
+    # two take every token and two none: exactly the 128 carried -> bounded
+    ((0, 4), (1, 2), (0, 3), 128),
+    ((12, 4), (12, 15), (13, 14), 128),
+    # one takes every token, the others what falls to them -> bounded
+    ((4, 4), (5,), (), 128),
+])
+def test_a_router_over_its_bound_takes_the_full_width_and_drops_nothing(
+        held, always, never, carried):
+    cfg = pattern.PatternConfig.tiny(dtype=jnp.float32, experts_held=held)
+    with jax.default_matmul_precision("highest"):
+        part, y, want = biased(cfg, 3, always, never)
+        routed, counts = jax.jit(lambda y, part: pattern.routed_experts(cfg, y, part))(y, part)
+    n = y.shape[0]
+    assert int(counts["rows_carried"]) == carried
+    assert int(counts["dropped"]) == 0
+    if never:
+        assert int(counts["pairs_held"]) == len(always) * n == carried
+    else:
+        assert (int(counts["pairs_held"]) > 128) == (carried == 256)
+    assert int(counts["max_load"]) == n
+    np.testing.assert_allclose(np.asarray(routed), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("held,tokens,path,rows", [
+    ((0, 4), 64, "bounded", 128),     # twice the share of 64: 128 of 256
+    ((0, 1), 64, "bounded", 128),     # twice the share is 32: rounded up to the row tile
+    ((0, 4), 1000, "bounded", 2048),  # 2,000 rounded up
+    ((0, 4), 32, "full", 128),        # the row tile is all 128 pairs
+    ((0, 8), 64, "full", 256),        # twice a half share
+    ((0, 16), 64, "full", 256),       # every expert held
+])
+def test_dispatch_rows_is_what_the_layer_carries_and_full_width_has_no_cond(
+        held, tokens, path, rows):
+    cfg = pattern.PatternConfig.tiny(dtype=jnp.float32, experts_held=held)
+    assert pattern.dispatch_rows(cfg, tokens) == {
+        "path": path, "rows": rows, "pairs": tokens * cfg.top_k}
+    lp, _ = sparse_layer(cfg)
+    part = share_of(lp, *held)
+    y = jax.random.normal(jax.random.PRNGKey(2), (tokens, cfg.d_model))
+    layer = lambda y, part: pattern.routed_experts(cfg, y, part)  # noqa: E731
+    assert ("cond" in primitives(jax.make_jaxpr(layer)(y, part).jaxpr)) == (path == "bounded")
+    counts = jax.jit(layer)(y, part)[1]
+    assert int(counts["pairs_held"]) <= rows  # this router stays under twice its share
+    assert int(counts["rows_carried"]) == rows and int(counts["dropped"]) == 0
+
+
 @pytest.mark.parametrize("axes", [{"dp": 4, "ep": 2}, {"dp": 2, "ep": 2, "tp": 2}])
 def test_derived_specs_on_a_mesh_give_the_one_chip_loss(axes):
     # float32 activations: in bf16 another reduction order flips near-tied router choices

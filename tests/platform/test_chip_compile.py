@@ -212,3 +212,35 @@ def test_attention_kernels_compile_for_v5e_under_the_core_scope(one_chip, as_on_
     names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in kernels]
     mark = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
     assert len(names) == 4 and all(mark.search(name) for name in names), names
+
+
+def test_routed_layer_compiles_for_v5e_with_both_widths_under_one_conditional(one_chip):
+    """One sparse layer's routed part at the laguna configuration's own widths (8,192
+    tokens, 8 choices of 256 experts, 32 held), differentiated through the layer's
+    ``jax.checkpoint``: the bounded dispatch (16,384 rows) and the full width (65,536)
+    are the two branches of conditionals, the grouped products exist at both widths,
+    and what the backward pass keeps alive fits beside the cell's 8.3 GB of state."""
+    import re
+
+    from benchmark import harness
+    from tpu_resiliency.models import pattern
+
+    config = harness.read_json(harness.HERE, "configs", "laguna-xs2-l5-ep8.json")
+    tokens = config["batch"][0] * config["batch"][1]
+    cfg = harness.load_family(config).program_config(config, config["batch"][1])
+    assert pattern.dispatch_rows(cfg, tokens) == {
+        "path": "bounded", "rows": 16384, "pairs": 65536}
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    lp = jax.tree.map(lambda w: sds(w.shape[1:], w.dtype, one_chip), params["mlp"]["sparse"])
+
+    def loss(y, lp):
+        layer = jax.checkpoint(lambda y, lp: pattern.routed_experts(cfg, y, lp)[0])
+        return jnp.sum(layer(y, lp).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        sds((tokens, cfg.d_model), cfg.dtype, one_chip), lp).compile()
+    text = compiled.as_text()
+    assert " conditional(" in text
+    grouped = set(re.findall(r"ragged-dot[\w.\-]* = bf16\[(\d+),", text))
+    assert {"16384", "65536"} <= grouped, grouped
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9  # 1.3e9 (compile, PR 31)

@@ -23,7 +23,9 @@ Built TPU-first, static shapes throughout:
   is computed: the pairs are sorted by expert and the three SwiGLU products run as
   grouped products over the ragged groups (``jax.lax.ragged_dot``). Pairs for
   experts held elsewhere contribute nothing here, and nothing stands in for the
-  chips that hold them or for the exchange with them.
+  chips that hold them or for the exchange with them. The rows moved are the head
+  of the sorted order, twice the even-routing share of the experts held; a step
+  whose router sends more here takes the full width (:func:`dispatch_rows`).
 - **The description says how each leaf may be sharded** (:func:`describe_params`:
   logical axis names per dimension), so ``parallel/mesh.py`` derives the
   ``PartitionSpec`` tree and holds no key name of this model.
@@ -434,14 +436,90 @@ def route(cfg: PatternConfig, y, w_router):
     return weights, experts
 
 
+def _rows_at(rows, place):
+    """``rows [C, D]`` at ``place [N, K]``; a place of ``C`` (past the end) reads zeros."""
+    return rows.at[place].get(mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _carry(y, token, place):
+    """``y[token]``: for each of the ``C`` pairs carried, its token's row of ``y [N, D]``.
+    ``place [N, K]`` is where each of a token's pairs went among the ``C`` (``C``: not
+    carried); with it the backward pass is a gather too: each token sums its own
+    pairs' cotangents, in float32, rounded once."""
+    return y[token]
+
+
+_carry.defvjp(
+    lambda y, token, place: (y[token], place),
+    lambda place, g: (
+        jnp.sum(_rows_at(g, place).astype(jnp.float32), axis=1).astype(g.dtype), None, None))
+
+
+@jax.custom_vjp
+def _bring_back(out, weights, pair, place):
+    """``[N, D]``: each token's weighted sum over its pairs of the rows of ``out [C, D]``,
+    in float32, rounded once. ``weights [N, K]`` float32; ``pair [C]`` is which of the
+    ``N * K`` pairs each row is, ``place [N, K]`` its inverse (``C``: not carried). The
+    backward pass gathers ``C`` rows of the cotangent by token."""
+    total = jnp.sum(_rows_at(out, place).astype(jnp.float32) * weights[..., None], axis=1)
+    return total.astype(out.dtype)
+
+
+def _bring_back_bwd(res, g):
+    out, weights, pair, place = res
+    rows = g[pair // weights.shape[1]].astype(jnp.float32)
+    d_out = (rows * weights.reshape(-1)[pair][:, None]).astype(out.dtype)
+    d_weight = jnp.sum(rows * out.astype(jnp.float32), axis=-1)  # [C]: one a pair carried
+    return d_out, _rows_at(d_weight[:, None], place)[..., 0], None, None
+
+
+_bring_back.defvjp(lambda *args: (_bring_back(*args), args), _bring_back_bwd)
+
+
+#: the rows the dispatch carries are whole tiles of this many (the MXU's rows)
+ROW_TILE = 128
+
+
+def dispatch_rows(cfg: PatternConfig, n_tokens: int) -> dict:
+    """How many (token, choice) pairs the expert dispatch of a sparse layer carries for
+    ``n_tokens`` tokens, from the configuration and the shapes alone: ``{"path":
+    "bounded", "rows": C, "pairs": n_tokens * top_k}`` where twice the even-routing
+    share of the experts held, rounded up to :data:`ROW_TILE`, is under all the pairs
+    (a step whose router sends more than ``C`` pairs here carries all of them, see
+    :func:`routed_experts`), else ``{"path": "full", "rows": pairs, "pairs": pairs}``."""
+    pairs = n_tokens * cfg.top_k
+    tiles = -(-2 * pairs * cfg.experts_held[1] // (cfg.n_experts * ROW_TILE))
+    if tiles * ROW_TILE < pairs:
+        return {"path": "bounded", "rows": tiles * ROW_TILE, "pairs": pairs}
+    return {"path": "full", "rows": pairs, "pairs": pairs}
+
+
+def _expert_rows(rows, valid, group_sizes, lp: dict):
+    """The held experts' SwiGLU of ``rows``, sorted by expert into groups of
+    ``group_sizes``, with the weights ``lp`` in the rows' dtype; rows that are not
+    ``valid`` give zero."""
+    with jax.named_scope("moe/experts"):
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
+        gate = jax.nn.silu(dot(rows, lp["we_gate"]))
+        out = dot(gate * dot(rows, lp["we_up"]), lp["we_down"])
+        # rows past the last group are whatever the grouped product left there
+        return jnp.where(valid, out, 0)
+
+
 def routed_experts(cfg: PatternConfig, y, lp: dict):
     """What the experts held here add for tokens ``y [N, D]``, and the routing counts.
 
     All ``N * top_k`` (token, choice) pairs are sorted by the local index of their
     expert, pairs for experts held elsewhere last; ``group_sizes`` counts the pairs
     of each held expert, so the grouped products compute every pair that landed here
-    and no other row. The shapes are static (``N * top_k`` rows: room for every
-    pair), whatever the router does."""
+    and no other row. The shapes are static and nothing is dropped: the rows gathered,
+    multiplied and added back are the first ``C`` of the sorted order, room for twice
+    the even-routing share of the experts held (:func:`dispatch_rows`); a step whose
+    router sends more than ``C`` pairs here takes the full width, ``N * top_k`` rows,
+    under the same ``jax.lax.cond``. Where ``C`` would not be under the full width
+    (every expert held; tiny shapes) there is only the full width and no ``cond``.
+    ``rows_carried`` among the counts says which it was."""
     n, d = y.shape
     k = cfg.top_k
     first, held = cfg.experts_held
@@ -457,24 +535,50 @@ def routed_experts(cfg: PatternConfig, y, lp: dict):
         sorted_key = key[order]
         ends = jnp.searchsorted(sorted_key, jnp.arange(held + 1), side="left")
         group_sizes = jnp.diff(ends).astype(jnp.int32)  # [held]
-        valid = (sorted_key < held)[:, None]
-        pairs = jnp.broadcast_to(y[:, None, :], (n, k, d)).reshape(n * k, d)
-        rows = jnp.where(valid, _take_rows(pairs, order, inverse), 0)
-    with jax.named_scope("moe/experts"):
-        dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
-        gate = jax.nn.silu(dot(rows, lp["we_gate"].astype(y.dtype)))
-        out = dot(gate * dot(rows, lp["we_up"].astype(y.dtype)), lp["we_down"].astype(y.dtype))
-        # rows past the last group are whatever the grouped product left there
-        out = jnp.where(valid, out, 0)
-    with jax.named_scope("moe/combine"):
-        out = _take_rows(out, inverse, order).reshape(n, k, d)
-        routed = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1).astype(y.dtype)
     landed = jnp.sum(here)
+    bound = dispatch_rows(cfg, n)["rows"]
+    with jax.named_scope("moe/experts"):
+        experts_lp = {name: lp[name].astype(y.dtype) for name in ("we_gate", "we_up", "we_down")}
+
+    def full(y, weights, order, inverse, sorted_key, group_sizes, experts_lp):
+        with jax.named_scope("moe/dispatch"):
+            valid = (sorted_key < held)[:, None]
+            pairs = jnp.broadcast_to(y[:, None, :], (n, k, d)).reshape(n * k, d)
+            rows = jnp.where(valid, _take_rows(pairs, order, inverse), 0)
+        out = _expert_rows(rows, valid, group_sizes, experts_lp)
+        with jax.named_scope("moe/combine"):
+            out = _take_rows(out, inverse, order).reshape(n, k, d)
+            return jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1).astype(y.dtype)
+
+    def bounded(y, weights, order, inverse, sorted_key, group_sizes, experts_lp):
+        with jax.named_scope("moe/dispatch"):
+            pair = order[:bound]
+            place = jnp.minimum(inverse, bound).reshape(n, k)
+            valid = (sorted_key[:bound] < held)[:, None]
+            rows = jnp.where(valid, _carry(y, pair // k, place), 0)
+        out = _expert_rows(rows, valid, group_sizes, experts_lp)
+        with jax.named_scope("moe/combine"):
+            return _bring_back(out, weights, pair, place)
+
+    operands = (y, weights, order, inverse, sorted_key, group_sizes)
+    if bound < n * k:
+        fits = landed <= bound
+        # Each branch recomputes itself in the backward pass, so what crosses the ``cond``
+        # is its operands and not the union of both branches' residuals. The weights are
+        # fenced from it: left free, the compiler moves their gradients' widening and
+        # padding to the stack of all layers into both branches (17 ms a step at
+        # 8,192 tokens, chip run) where the sum over the layers reads each once.
+        routed = jax.lax.cond(fits, jax.checkpoint(bounded), jax.checkpoint(full),
+                              *operands, jax.lax.optimization_barrier(experts_lp))
+        carried = jnp.where(fits, bound, n * k)
+    else:
+        routed, carried = full(*operands, experts_lp), jnp.asarray(n * k)
     counts = {
         "pairs_held": landed,
         "max_load": jnp.max(group_sizes),
         "mean_load": landed / held,
         "dropped": landed - jnp.sum(group_sizes),
+        "rows_carried": carried,
     }
     return routed, counts
 
