@@ -1,7 +1,11 @@
 """Train the pattern-of-layers model (``models/pattern.py``) with the toolkit attached,
 and report what its routing does.
 
-The model mixes full and sliding-window attention layers of different head counts, a
+``--description mixed`` (the default) mixes full and sliding-window attention layers of
+different head counts with an output gate; ``--description latent`` has latent attention
+in every layer (keys and values decompressed from one normed latent, a rotary key part
+shared by all heads, scores wider than values), a router that chooses by score + a
+selection bias and weighs by the score, and two shared experts' width. Either has a
 leading dense MLP and sparse layers whose router scores every expert of a deployment
 while this process holds a contiguous range of them (``--experts-held FIRST COUNT``:
 one chip's share of an expert-parallel split; pairs routed to experts held elsewhere add
@@ -10,10 +14,12 @@ more on the step's batch and emits its routing counts as a ``moe_routing`` event
 each sparse layer the (token, choice) pairs that landed on held experts, the largest
 and the mean load of a held expert, the pairs dropped (always 0) and the rows the
 dispatch carried (twice the even share of the experts held, or every pair in a step
-whose router sent more than that here). Once, before the first step, it emits an
+whose router sent more than that here), and under a selection bias ``chosen_by_bias``,
+the pairs (of all of them) whose expert the scores alone would not have chosen. Once, before the first step, it emits an
 ``attention_path`` event: for each kind of attention layer, whether its products run
 as the blocked kernels of ``ops/attention.py`` (on a TPU, at shapes that tile) or as
-the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block;
+the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block,
+and for the latent kind its ``score_width`` and ``value_width``;
 and a ``dispatch_path`` event: the rows the expert dispatch carries at this batch
 (``pattern.dispatch_rows``), ``bounded`` or ``full``.
 
@@ -41,6 +47,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cpu", action="store_true",
                     help="simulate on the CPU (without it $JAX_PLATFORMS / JAX decide)")
+    ap.add_argument("--description", choices=("mixed", "latent"), default="mixed",
+                    help="which pattern of layers to train (see the module docstring)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, nargs=2, default=(2, 64), metavar=("B", "T"))
     ap.add_argument("--experts-held", type=int, nargs=2, default=(0, 4),
@@ -58,7 +66,8 @@ def main() -> None:
     from tpu_resiliency.models import pattern
     from tpu_resiliency.utils import events
 
-    cfg = pattern.PatternConfig.tiny(experts_held=tuple(args.experts_held))
+    preset = {"mixed": pattern.PatternConfig.tiny, "latent": pattern.PatternConfig.tiny_latent}
+    cfg = preset[args.description](experts_held=tuple(args.experts_held))
     train_step, init_opt = pattern.make_train_step(cfg)
     step = jax.jit(train_step, donate_argnums=(0, 1))
     counts_of = jax.jit(lambda p, t: pattern.loss_and_counts(p, t, cfg)[1])

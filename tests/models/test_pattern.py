@@ -1,10 +1,16 @@
-"""Pattern-of-layers model (models/pattern.py): the program against the benchmark's
-plain reference on loss and every gradient leaf; each attention kind against plain
-masked attention; the experts' shares against the uncut layer; routing that drops
-nothing; derived parameter specs on a mesh; the state through the local checkpoint."""
+"""Pattern-of-layers model (models/pattern.py), in both descriptions the benchmark
+runs (window and full grouped-query layers with a gate; latent layers under a router
+with a selection bias): the program against the benchmark's plain references on loss and
+every gradient leaf; each attention kind against plain masked attention, the latent
+layer against attention written the long way; the experts' shares against the uncut
+layer; a bias that changes the choice and never a weight; routing that drops nothing;
+derived parameter specs on a mesh; the state through the local checkpoint; the scopes
+the benchmark's readers look for in the lowered step."""
 
 import dataclasses
+import functools
 import os
+import re
 import sys
 
 import jax
@@ -23,23 +29,34 @@ if ROOT not in sys.path:
 SEQ = 37  # longer than the window (8), a multiple of neither block (8, 16)
 
 
-@pytest.fixture(scope="module")
-def tiny_file():
-    """The laguna configuration at its family's tiny widths, as a configuration dict,
-    with the family and the reference that go with it."""
+#: the two descriptions: the configuration file whose family and reference go with
+#: each, and the model's own tiny preset of it
+DESCRIPTIONS = {"mixed": ("laguna-xs2-l5-ep8", pattern.PatternConfig.tiny),
+                "latent": ("kimi-vl-a3b-l6-ep8", pattern.PatternConfig.tiny_latent)}
+
+
+@functools.cache
+def tiny_file_of(description: str):
+    """A configuration at its family's tiny widths, as a configuration dict, with the
+    family and the reference that go with it."""
     from benchmark import harness
 
-    config = harness.read_json(harness.HERE, "configs", "laguna-xs2-l5-ep8.json")
+    config = harness.read_json(harness.HERE, "configs", f"{DESCRIPTIONS[description][0]}.json")
     family = harness.load_family(config)
     config = {**config, **family.TINY}
     return config, family, harness.load_reference(config)
 
 
 @pytest.fixture(scope="module")
-def exact(tiny_file):
+def tiny_file():
+    return tiny_file_of("mixed")
+
+
+@functools.cache
+def exact_of(description: str):
     """Program (float32 activations) and reference on the same seeded weights: value
     and gradient of the loss on one batch."""
-    config, family, reference = tiny_file
+    config, family, reference = tiny_file_of(description)
     cfg = dataclasses.replace(family.program_config(config, SEQ), dtype=jnp.float32)
     tokens = jnp.asarray(np.random.default_rng(5).integers(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -51,28 +68,42 @@ def exact(tiny_file):
     return params, ref_params, got, want
 
 
-def leaf_paths() -> list[str]:
-    leaves = jax.tree_util.tree_flatten_with_path(
-        pattern.describe_params(pattern.PatternConfig.tiny()),
-        is_leaf=lambda x: isinstance(x, pattern.Leaf))[0]
-    return [jax.tree_util.keystr(path) for path, _ in leaves]
+def leaf_paths() -> list[tuple[str, str]]:
+    """(description, leaf path) of every parameter leaf of both tiny presets."""
+    out = []
+    for description, (_, preset) in DESCRIPTIONS.items():
+        leaves = jax.tree_util.tree_flatten_with_path(
+            pattern.describe_params(preset()), is_leaf=lambda x: isinstance(x, pattern.Leaf))[0]
+        out += [(description, jax.tree_util.keystr(path)) for path, _ in leaves]
+    return out
 
 
-def test_seeded_weights_and_loss_equal_the_reference(exact):
-    params, ref_params, (loss, _), (ref_loss, _) = exact
+@pytest.mark.parametrize("description", list(DESCRIPTIONS))
+def test_seeded_weights_and_loss_equal_the_reference(description):
+    """The weights are bit-equal (one PRNG key a leaf in flatten order on both sides);
+    the float32 losses differ by summation order alone: 1e-5 of a loss near 6."""
+    params, ref_params, (loss, _), (ref_loss, _) = exact_of(description)
     assert jax.tree.structure(params) == jax.tree.structure(ref_params)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(ref_params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert abs(float(loss) - float(ref_loss)) < 1e-5
 
 
-@pytest.mark.parametrize("path", leaf_paths())
-def test_gradient_leaf_equals_the_reference(exact, path):
-    _, _, (_, grads), (_, ref_grads) = exact
+@pytest.mark.parametrize("description,path", leaf_paths())
+def test_gradient_leaf_equals_the_reference(description, path):
+    """Every element within 2e-5 of the leaf's largest (float32 under ``highest`` on both
+    sides, other summation orders: the gaps read 2e-7 to 1.2e-6 of it). The selection
+    bias enters a top-k and nothing else: the loss sends it nothing, and its gradient is
+    the balancing rule's +-1 an expert, the same signs on both sides."""
+    _, _, (_, grads), (_, ref_grads) = exact_of(description)
     got = {jax.tree_util.keystr(p): g for p, g in jax.tree_util.tree_flatten_with_path(grads)[0]}
     want = {jax.tree_util.keystr(p): g
             for p, g in jax.tree_util.tree_flatten_with_path(ref_grads)[0]}
     scale = float(jnp.max(jnp.abs(want[path])))
+    if path.endswith("['b_router']"):
+        assert set(np.unique(np.asarray(want[path]))) == {-1.0, 1.0}
+        np.testing.assert_array_equal(np.asarray(got[path]), np.asarray(want[path]))
+        return
     assert scale > 0
     np.testing.assert_allclose(np.asarray(got[path]), np.asarray(want[path]),
                                rtol=0, atol=2e-5 * max(scale, 1e-2))
@@ -115,6 +146,64 @@ def test_attention_kind_equals_plain_masked_attention(kind, seq):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
 
+def latent_layer(cfg, seed=0, seq=SEQ):
+    """One latent layer's weights (float32), its input and its rotary table."""
+    lp = jax.tree.map(lambda w: w[0], pattern.init_params(
+        jax.random.PRNGKey(seed), cfg)["attn"][pattern.LATENT])
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, seq, cfg.d_model))
+    return lp, x, pattern.rope_tables(cfg.rope_latent, cfg.latent.d_rope, seq)
+
+
+def latent_the_long_way(cfg, x, lp):
+    """Latent attention with nothing shared and nothing blocked: every head gets keys of
+    the whole score width (its own non-rotary part beside a copy of the rotary key), the
+    rotation is written out per pair of dimensions, and the softmax is over the whole
+    masked T x T array."""
+    b, t, d = x.shape
+    h, la = cfg.heads(pattern.LATENT), cfg.latent
+    norm = lambda z, w, eps: z / jnp.sqrt(jnp.mean(z * z, -1, keepdims=True) + eps) * w  # noqa: E731
+    y = norm(x, lp["attn_norm"], cfg.norm_eps)
+    q = (y @ lp["wq"]).reshape(b, t, h, la.d_score)
+    down = y @ lp["wkv_a"]
+    up = (norm(down[..., :la.kv_rank], lp["kv_norm"], la.norm_eps) @ lp["wkv_b"]).reshape(
+        b, t, h, la.d_nope + la.d_value)
+
+    def turn(z):  # [B, T, n, d_rope]: dimension i with dimension i + d_rope / 2
+        half = la.d_rope // 2
+        angle = jnp.arange(t)[:, None] / cfg.rope_latent.theta ** (jnp.arange(half) / half)[None]
+        cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
+        return jnp.concatenate([z[..., :half] * cos - z[..., half:] * sin,
+                                z[..., half:] * cos + z[..., :half] * sin], -1)
+
+    k_rope = jnp.repeat(turn(down[..., la.kv_rank:][:, :, None]), h, axis=2)
+    q = jnp.concatenate([q[..., :la.d_nope], turn(q[..., la.d_nope:])], -1)
+    k = jnp.concatenate([up[..., :la.d_nope], k_rope], -1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(la.d_score)
+    scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), up[..., la.d_nope:])
+    return x + out.reshape(b, t, h * la.d_value) @ lp["wo"]
+
+
+@pytest.mark.parametrize("seq", [37, 16, 7])
+def test_latent_layer_equals_attention_written_the_long_way(seq):
+    """Value and the gradient to the input and to each of the six leaves, at lengths
+    over, at and under a query block; float32 under ``highest``: 5e-5 absolute."""
+    cfg = pattern.PatternConfig.tiny_latent(dtype=jnp.float32)
+    lp, x, tables = latent_layer(cfg, seq=seq)
+    weight = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    with jax.default_matmul_precision("highest"):
+        got, got_grad = jax.value_and_grad(lambda x, lp: jnp.sum(
+            pattern._latent_block(cfg, x, lp, *tables) * weight), argnums=(0, 1))(x, lp)
+        want, want_grad = jax.value_and_grad(lambda x, lp: jnp.sum(
+            latent_the_long_way(cfg, x, lp) * weight), argnums=(0, 1))(x, lp)
+        np.testing.assert_allclose(np.asarray(pattern._latent_block(cfg, x, lp, *tables)),
+                                   np.asarray(latent_the_long_way(cfg, x, lp)), atol=2e-5)
+    assert abs(float(got) - float(want)) < 1e-3
+    assert set(got_grad[1]) == {"attn_norm", "wq", "wkv_a", "kv_norm", "wkv_b", "wo"}
+    for a, b in zip(jax.tree.leaves(got_grad), jax.tree.leaves(want_grad)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
 def sparse_layer(cfg, seed=0):
     """One sparse layer's weights over ALL experts (float32), and normed tokens."""
     whole = dataclasses.replace(cfg, experts_held=(0, cfg.n_experts))
@@ -140,13 +229,151 @@ def test_shares_add_up_to_the_uncut_layer(tiny_file, shares):
         total = pattern._swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
         for s in range(shares):
             part = dataclasses.replace(cfg, experts_held=(s * held, held))
-            routed, counts = pattern.routed_experts(part, y, share_of(lp, s * held, held))
+            routed, counts, _ = pattern.routed_experts(part, y, share_of(lp, s * held, held))
             total = total + routed
             assert int(counts["dropped"]) == 0
         uncut = {**config, "num_experts": cfg.n_experts,
                  "deployment": {"num_experts": cfg.n_experts, "experts_held": [0, cfg.n_experts]}}
         want = reference.sparse_mlp(y, lp, uncut, "f32")
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("experts,shares", [(64, 8), (16, 4), (16, 1)])
+def test_shares_of_a_latent_layer_add_up_to_the_uncut_reference_layer(experts, shares):
+    """A whole layer of the second description: attention and the shared experts, which
+    every chip computes alike, counted once, plus the routed parts of all the shares
+    (64 experts over eight chips, as the configuration states its deployment), equal the
+    reference's layer with every expert held."""
+    config, _, reference = tiny_file_of("latent")
+    cfg = pattern.PatternConfig.tiny_latent(dtype=jnp.float32, n_experts=experts,
+                                            experts_held=(0, experts // shares))
+    whole = dataclasses.replace(cfg, experts_held=(0, experts))
+    params = pattern.init_params(jax.random.PRNGKey(4), whole)
+    attn_lp = jax.tree.map(lambda w: w[1], params["attn"][pattern.LATENT])
+    lp = jax.tree.map(lambda w: w[0], params["mlp"][pattern.SPARSE])
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, cfg.d_model))
+    tables = pattern.rope_tables(cfg.rope_latent, cfg.latent.d_rope, SEQ)
+    held = experts // shares
+    uncut = {**config, "n_routed_experts": experts,
+             "deployment": {"n_routed_experts": experts, "experts_held": [0, experts]}}
+    with jax.default_matmul_precision("highest"):
+        x1 = pattern._latent_block(cfg, x, attn_lp, *tables)
+        y = pattern.tfm.rms_norm(x1, lp["mlp_norm"], cfg.norm_eps)
+        total = x1 + pattern._swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        for s in range(shares):
+            part = dataclasses.replace(cfg, experts_held=(s * held, held))
+            routed, counts, _ = pattern.routed_experts(
+                part, y.reshape(-1, cfg.d_model), share_of(lp, s * held, held))
+            total = total + routed.reshape(x.shape)
+            assert int(counts["dropped"]) == 0
+        x1_ref = x + reference.attention(x, attn_lp, uncut, "f32")
+        want = x1_ref + reference.sparse_mlp(
+            reference.rms_norm(x1_ref, lp["mlp_norm"], cfg.norm_eps), lp, uncut, "f32")[0]
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=5e-5)
+
+
+def scores_of(y, w_router):
+    return jax.nn.sigmoid(jnp.matmul(y, w_router, precision="highest"))
+
+
+@pytest.mark.parametrize("std", [0.05, 0.1, 0.5])
+def test_a_bias_changes_the_choice_and_never_a_weight(std):
+    """The experts are the top-k of score + bias; the weights are the chosen experts'
+    scores, normalised and scaled, with no trace of the bias; ``chosen_by_bias`` counts
+    the pairs the scores alone would not have chosen."""
+    cfg = pattern.PatternConfig.tiny_latent(dtype=jnp.float32)
+    lp, y = sparse_layer(cfg, seed=6)
+    bias = std * jax.random.normal(jax.random.PRNGKey(7), (cfg.n_experts,))
+    weights, experts, by_bias, _ = pattern.route(  # the leaf is in units of 1 / gain
+        cfg, y, lp["w_router"], bias / cfg.route_bias_gain)
+    _, plain, none, _ = pattern.route(cfg, y, lp["w_router"])
+    scores = np.asarray(scores_of(y, lp["w_router"]))
+    want = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :cfg.top_k]
+    assert none is None
+    np.testing.assert_array_equal(np.sort(np.asarray(experts), -1), np.sort(want, -1))
+    chosen = np.take_along_axis(scores, np.asarray(experts), -1)
+    np.testing.assert_allclose(np.asarray(weights), chosen / chosen.sum(-1, keepdims=True)
+                               * cfg.routed_scale, rtol=1e-6)
+    differ = sum(len(set(a) - set(b)) for a, b in zip(np.asarray(experts), np.asarray(plain)))
+    assert differ > 0 and int(by_bias) == differ
+
+
+@pytest.mark.parametrize("std", [0.0, 0.1, 0.5])
+def test_the_balancing_rule_is_a_term_of_no_value_whose_gradient_is_each_experts_sign(std):
+    """``balance`` is zero whatever the bias; its gradient by the bias is +1 for every
+    expert that more than the even share of the ``N x top_k`` choices fell on and -1 for
+    every other, so a step of any optimizer that follows the sign lowers the crowded
+    experts' bias and raises the rest: the loss-free rule."""
+    cfg = pattern.PatternConfig.tiny_latent(dtype=jnp.float32)
+    lp, y = sparse_layer(cfg, seed=6)
+    bias = std / cfg.route_bias_gain * jax.random.normal(jax.random.PRNGKey(7), (cfg.n_experts,))
+    term = lambda b: pattern.route(cfg, y, lp["w_router"], b)[3]  # noqa: E731
+    value, grad = jax.value_and_grad(term)(bias)
+    experts = np.asarray(pattern.route(cfg, y, lp["w_router"], bias)[1])
+    load = np.bincount(experts.ravel(), minlength=cfg.n_experts)
+    assert float(value) == 0.0 and load.sum() == y.shape[0] * cfg.top_k
+    np.testing.assert_array_equal(
+        np.asarray(grad), np.where(load > load.sum() / cfg.n_experts, 1.0, -1.0))
+    assert pattern.route(cfg, y, lp["w_router"])[3] is None
+
+
+@pytest.mark.parametrize("gain", [1.0, 100.0])
+def test_adamw_applies_the_balancing_rule_and_the_load_evens_out(gain):
+    """Trained on one batch with the router's weights held (only the bias moves, by
+    ``lr x gain`` a step), the largest load of an expert falls towards the even share."""
+    import optax
+
+    cfg = pattern.PatternConfig.tiny_latent(dtype=jnp.float32, route_bias_gain=gain)
+    lp, y = sparse_layer(cfg, seed=6)
+    even = y.shape[0] * cfg.top_k / cfg.n_experts
+    load = lambda b: np.bincount(np.asarray(  # noqa: E731
+        pattern.route(cfg, y, lp["w_router"], b)[1]).ravel(), minlength=cfg.n_experts)
+    bias = lp["b_router"]
+    optimizer = optax.adamw(0.01 / gain, weight_decay=0.01)
+    state = optimizer.init(bias)
+    first = load(bias).max()
+    for _ in range(60):
+        grad = jax.grad(lambda b: pattern.route(cfg, y, lp["w_router"], b)[3])(bias)
+        updates, state = optimizer.update(grad, state, bias)
+        bias = optax.apply_updates(bias, updates)
+    assert first > 2 * even and load(bias).max() < 1.3 * even, (first, load(bias).max(), even)
+
+
+def test_a_zero_bias_is_the_plain_router():
+    cfg = pattern.PatternConfig.tiny_latent(dtype=jnp.float32)
+    lp, y = sparse_layer(cfg, seed=6)
+    weights, experts, by_bias, _ = pattern.route(cfg, y, lp["w_router"], jnp.zeros(cfg.n_experts))
+    plain_weights, plain_experts, _, _ = pattern.route(cfg, y, lp["w_router"])
+    np.testing.assert_array_equal(np.asarray(experts), np.asarray(plain_experts))
+    np.testing.assert_array_equal(np.asarray(weights), np.asarray(plain_weights))
+    assert int(by_bias) == 0
+    part = share_of({**lp, "b_router": jnp.zeros(cfg.n_experts)}, *cfg.experts_held)
+    without = {k: w for k, w in part.items() if k != "b_router"}
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_array_equal(np.asarray(pattern.routed_experts(cfg, y, part)[0]),
+                                      np.asarray(pattern.routed_experts(cfg, y, without)[0]))
+
+
+@pytest.mark.parametrize("held", [(0, 4), (4, 4), (12, 4)])
+def test_no_pair_is_dropped_under_a_bias_that_sends_everything_here(held):
+    """A bias of +10 on the four experts held (top-k is four): all ``N x top_k`` pairs
+    land here, over any bound, so the layer takes the full width; nothing is dropped, and
+    the result is the dense weighted sum with weights that are the scores' own."""
+    cfg = pattern.PatternConfig.tiny_latent(dtype=jnp.float32, experts_held=held)
+    lp, y = sparse_layer(cfg, seed=8)
+    lp["b_router"] = jnp.zeros(cfg.n_experts).at[held[0]:held[0] + held[1]].set(10.0)
+    part = share_of(lp, *held)
+    n = y.shape[0]
+    with jax.default_matmul_precision("highest"):
+        routed, counts, _ = jax.jit(lambda y, part: pattern.routed_experts(cfg, y, part))(y, part)
+        scores = scores_of(y, lp["w_router"])[:, held[0]:held[0] + held[1]]
+        gates = scores / jnp.sum(scores, -1, keepdims=True) * cfg.routed_scale
+        want = sum(gates[:, e:e + 1] * pattern._swiglu(
+            y, part["we_gate"][e], part["we_up"][e], part["we_down"][e]) for e in range(held[1]))
+    assert int(counts["pairs_held"]) == int(counts["rows_carried"]) == n * cfg.top_k
+    assert int(counts["dropped"]) == 0 and int(counts["max_load"]) == n
+    assert pattern.dispatch_rows(cfg, n)["rows"] < n * cfg.top_k  # the bound was passed
+    np.testing.assert_allclose(np.asarray(routed), np.asarray(want), atol=2e-5)
 
 
 @pytest.mark.parametrize("held", [(0, 4), (4, 4), (12, 4), (0, 16)])
@@ -161,8 +388,8 @@ def test_no_pair_is_dropped_under_a_biased_router(held):
     lp["w_router"] = lp["w_router"].at[:, hot].set(1.0)
     part = share_of(lp, *held)
     with jax.default_matmul_precision("highest"):
-        routed, counts = pattern.routed_experts(cfg, y, part)
-        weights, experts = pattern.route(cfg, y, lp["w_router"])
+        routed, counts, _ = pattern.routed_experts(cfg, y, part)
+        weights, experts, _, _ = pattern.route(cfg, y, lp["w_router"])
         want = jnp.zeros_like(y)
         for e in range(held[1]):
             gate = jnp.sum(jnp.where(experts == held[0] + e, weights, 0.0), -1, keepdims=True)
@@ -187,7 +414,7 @@ def biased(cfg, seed, always=(), never=()):
             lp["w_router"] = lp["w_router"].at[:, e].set(value)
     first, held = cfg.experts_held
     part = share_of(lp, first, held)
-    weights, experts = pattern.route(cfg, y, lp["w_router"])
+    weights, experts, _, _ = pattern.route(cfg, y, lp["w_router"])
     want = jnp.zeros_like(y)
     for e in range(held):
         gate = jnp.sum(jnp.where(experts == first + e, weights, 0.0), -1, keepdims=True)
@@ -218,7 +445,7 @@ def both_widths():
 
     def value_and_grads():
         def f(y, part):
-            routed, counts = jax.checkpoint(
+            routed, counts, _ = jax.checkpoint(
                 lambda y, part: pattern.routed_experts(cfg, y, part))(y, part)
             return jnp.sum(routed * cot), (routed, counts)
         with jax.default_matmul_precision("highest"):
@@ -262,7 +489,7 @@ def test_a_router_over_its_bound_takes_the_full_width_and_drops_nothing(
     cfg = pattern.PatternConfig.tiny(dtype=jnp.float32, experts_held=held)
     with jax.default_matmul_precision("highest"):
         part, y, want = biased(cfg, 3, always, never)
-        routed, counts = jax.jit(lambda y, part: pattern.routed_experts(cfg, y, part))(y, part)
+        routed, counts, _ = jax.jit(lambda y, part: pattern.routed_experts(cfg, y, part))(y, part)
     n = y.shape[0]
     assert int(counts["rows_carried"]) == carried
     assert int(counts["dropped"]) == 0
@@ -297,10 +524,11 @@ def test_dispatch_rows_is_what_the_layer_carries_and_full_width_has_no_cond(
     assert int(counts["rows_carried"]) == rows and int(counts["dropped"]) == 0
 
 
+@pytest.mark.parametrize("description", list(DESCRIPTIONS))
 @pytest.mark.parametrize("axes", [{"dp": 4, "ep": 2}, {"dp": 2, "ep": 2, "tp": 2}])
-def test_derived_specs_on_a_mesh_give_the_one_chip_loss(axes):
+def test_derived_specs_on_a_mesh_give_the_one_chip_loss(axes, description):
     # float32 activations: in bf16 another reduction order flips near-tied router choices
-    cfg = pattern.PatternConfig.tiny(dtype=jnp.float32)
+    cfg = DESCRIPTIONS[description][1](dtype=jnp.float32)
     params = pattern.init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
     loss = jax.jit(lambda p, t: pattern.loss_fn(p, t, cfg))
@@ -310,6 +538,13 @@ def test_derived_specs_on_a_mesh_give_the_one_chip_loss(axes):
     assert specs["mlp"]["sparse"]["we_gate"] == jax.sharding.PartitionSpec(
         None, "ep", None, "tp")
     assert specs["mlp"]["sparse"]["w_router"] == jax.sharding.PartitionSpec(None, None, None)
+    if description == "latent":  # what all heads share replicates; a head's columns go together
+        latent = specs["attn"]["latent"]
+        assert latent["wkv_a"] == jax.sharding.PartitionSpec(None, None, None)
+        assert latent["kv_norm"] == jax.sharding.PartitionSpec(None, None)
+        assert latent["wq"] == latent["wkv_b"] == jax.sharding.PartitionSpec(None, None, "tp")
+        assert latent["wo"] == jax.sharding.PartitionSpec(None, "tp", None)
+        assert specs["mlp"]["sparse"]["b_router"] == jax.sharding.PartitionSpec(None, None)
     sharded = jax.device_put(params, pmesh.tree_shardings(mesh, specs))
     assert len(sharded["mlp"]["sparse"]["we_up"].sharding.device_set) == 8
     with mesh:
@@ -342,12 +577,13 @@ def test_description_rejects_what_cannot_be_stacked():
         pattern.PatternConfig.tiny(experts_held=(14, 4))
 
 
-def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path):
+@pytest.mark.parametrize("description,leaves", [("mixed", 27), ("latent", 22)])
+def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path, description, leaves):
     """Per-kind stacks and the held experts' leaves through
     ``checkpoint/local_manager.py``: the restored state gives the next loss exactly."""
     from tpu_resiliency.checkpoint import LocalCheckpointManager, PyTreeStateDict
 
-    cfg = pattern.PatternConfig.tiny()
+    cfg = DESCRIPTIONS[description][1]()
     train_step, init_opt = pattern.make_train_step(cfg)
     step = jax.jit(train_step)
     params = pattern.init_params(jax.random.PRNGKey(7), cfg)
@@ -362,9 +598,35 @@ def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path):
     assert mgr.find_latest() == 2
     tree, _ = mgr.load_tree(2)
     assert jax.tree.structure(tree["params"]) == jax.tree.structure(params)
-    assert len(jax.tree.leaves(tree["params"])) == 27
+    assert len(jax.tree.leaves(tree["params"])) == leaves
     for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves({"params": params, "opt": opt_state})):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     _, _, got = step(tree["params"], tree["opt"], batch(2))
     assert float(got) == float(want)
     mgr.close()
+
+
+def test_the_lowered_step_carries_the_scopes_the_readers_look_for():
+    """The second description's train step, lowered (nothing compiles): its ops' names
+    hold ``attn/full``, ``attn/full/core`` and ``moe/*`` as the accepted readers'
+    patterns want them, and ``attn/full/latent`` as the new reader's does; what is under
+    the new scope is under ``attn`` too and never under ``core``."""
+    from benchmark import harness
+
+    scopes = harness.load_by_path("layer_metrics", "scope_times").SCOPES
+    latent = harness.load_by_path("layer_metrics", "attn.latent_ms").SCOPE
+    cfg = pattern.PatternConfig.tiny_latent()
+    train_step, init_opt = pattern.make_train_step(cfg)
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    text = jax.jit(train_step).trace(
+        params, jax.eval_shape(init_opt, params), jax.ShapeDtypeStruct((2, SEQ), jnp.int32)
+    ).lower().as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    under = {key: {n for n in names if mark.search(n)} for key, mark in scopes.items()}
+    under["latent"] = {n for n in names if latent.search(n)}
+    assert all(under.values()), {k: len(v) for k, v in under.items()}
+    assert under["latent"] <= under["attn"] and not under["latent"] & under["attn_core"]
+    assert under["attn_core"] <= under["attn"] and under["moe_experts"] <= under["moe"]
+    for phase in ("jvp(", "transpose("):  # the first forward and the backward alike
+        assert any(phase in n for n in under["latent"]), phase
+    assert any(n.endswith("dot_general") for n in under["latent"])

@@ -35,20 +35,21 @@ F32_GAP = 2e-6
 def plain_attention(q, k, v, window=None):
     """Masked softmax attention with the whole T x T array (as
     ``tests/models/test_pattern.py:plain_attention``; the test directories are no
-    packages, so it cannot be imported from there)."""
+    packages, so it cannot be imported from there). The values' width is their own."""
     b, t, h, dh = q.shape
     k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(dh)
     i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
     allowed = j <= i if window is None else (j <= i) & (j > i - window)
     probs = jax.nn.softmax(jnp.where(allowed, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, t, h * dh)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, t, h * v.shape[-1])
 
 
-def inputs(seq: int, groups: int):
+def inputs(seq: int, groups: int, score_width: int = DH):
     keys = jax.random.split(jax.random.PRNGKey(seq + groups), 4)
-    q = jax.random.normal(keys[0], (1, seq, HKV * groups, DH))
-    k, v = (jax.random.normal(key, (1, seq, HKV, DH)) for key in keys[1:3])
+    q = jax.random.normal(keys[0], (1, seq, HKV * groups, score_width))
+    k = jax.random.normal(keys[1], (1, seq, HKV, score_width))
+    v = jax.random.normal(keys[2], (1, seq, HKV, DH))
     weight = jax.random.normal(keys[3], (1, seq, HKV * groups * DH))
     return q, k, v, weight
 
@@ -88,6 +89,34 @@ def test_kernels_equal_plain_masked_attention(window, seq, groups, dtype):
     assert all(g < limit for g, limit in zip(gaps, limits)), gaps
 
 
+# latent attention's widths (a score 192 wide over values of 128), a score narrower than
+# the values, and one of whole lane groups; a group of one, as latent attention has it, and
+# of two. The kernels take one width, so these shapes go by ``pattern.full_attention``
+UNEQUAL = [(width, seq, groups, block) for width in (192, 64, 256) for seq in (256, 512)
+           for groups in (1, 2) for block in (128,)] + [(192, 512, 1, 512)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("score_width,seq,groups,block", UNEQUAL)
+def test_blocks_at_a_score_width_that_differs_from_the_value_width(
+        score_width, seq, groups, block, dtype):
+    """Forward, dQ, dK and dV of the ``jax.numpy`` blocks against plain masked attention,
+    whose scores are scaled by the score width; the cotangents of q and k come back at
+    that width. The limits are the kernels' (the same rounding: bf16 operands, float32
+    scores and sums)."""
+    q, k, v, weight = inputs(seq, groups, score_width)
+    with jax.default_matmul_precision("highest"):
+        want = value_and_grads(plain_attention, q, k, v, weight)
+        got = value_and_grads(lambda *a: pattern.full_attention(*a, block),
+                              *(x.astype(dtype) for x in (q, k, v)), weight)
+    assert got[0].shape == (1, seq, HKV * groups * DH)
+    for a, b in zip(got[1:], (q, k, v)):
+        assert a.shape == b.shape and a.dtype == dtype
+    limits = (F32_GAP,) * 4 if dtype == jnp.float32 else (BF16_VALUE_GAP,) + (BF16_GRAD_GAP,) * 3
+    gaps = [gap(a, b) for a, b in zip(got, want)]
+    assert all(g < limit for g, limit in zip(gaps, limits)), gaps
+
+
 def test_shapes_that_do_not_tile_are_refused():
     q, k, v, _ = inputs(256, 1)
     assert not attention.applies(200, DH, None) and not attention.applies(256, 64, None)
@@ -108,6 +137,36 @@ def laguna_config(seq: int):
 
     config = harness.read_json(harness.HERE, "configs", "laguna-xs2-l5-ep8.json")
     return harness.load_family(config).program_config(config, seq)
+
+
+def kimi_config(seq: int):
+    from benchmark import harness
+
+    config = harness.read_json(harness.HERE, "configs", "kimi-vl-a3b-l6-ep8.json")
+    return harness.load_family(config).program_config(config, seq)
+
+
+def test_attention_paths_of_the_latent_kind_off_the_tpu_are_the_blocks():
+    assert pattern.attention_paths(pattern.PatternConfig.tiny_latent(), 40) == {
+        "latent": {"path": "blocks", "block": 16, "score_width": 24, "value_width": 16}}
+    assert pattern.attention_paths(kimi_config(8192), 8192) == {
+        "latent": {"path": "blocks", "block": 1024, "score_width": 192, "value_width": 128}}
+
+
+EQUAL_WIDTHS = pattern.Latent(kv_rank=32, d_nope=64, d_rope=64, d_value=128)
+
+
+def test_attention_paths_of_the_latent_kind_on_a_tpu_follow_the_shapes(as_on_a_tpu):
+    """The kernels take one width for queries, keys and values: a score of 192 over
+    values of 128 goes by the blocks on a TPU too, a latent kind of equal widths by the
+    kernels."""
+    cfg = kimi_config(8192)
+    assert pattern.attention_paths(cfg, 8192) == {"latent": {
+        "path": "blocks", "block": 1024, "score_width": 192, "value_width": 128}}
+    equal = pattern.PatternConfig.tiny_latent(head_dim=128, latent=EQUAL_WIDTHS)
+    assert pattern.attention_paths(equal, 512) == {"latent": {
+        "path": "kernel", "tile": attention.FULL_TILE, "score_width": 128, "value_width": 128}}
+    assert pattern.attention_paths(equal, 512 + 128)["latent"]["path"] == "blocks"
 
 
 def test_attention_paths_off_the_tpu_are_the_blocks():
@@ -163,3 +222,38 @@ def test_every_kernel_of_a_layer_carries_the_core_scope(kind, as_on_a_tpu):
         "blocked_attention_fwd", "blocked_attention_fwd"], kernels
     assert all(mark.search(name) and f"attn/{kind}" in name for name in kernels), kernels
     assert sum("rematted_computation" in name for name in kernels) == 1
+
+
+def test_every_kernel_of_a_latent_layer_carries_the_core_scope_and_none_the_latent_one(
+        as_on_a_tpu):
+    """As above for latent attention of equal widths (the shapes the kernels take): the
+    four kernels are under ``attn/full/core``, and the projections' products under
+    ``attn/full/latent``, where the new reader looks."""
+    from benchmark import harness
+
+    core = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
+    latent = harness.load_by_path("layer_metrics", "attn.latent_ms").SCOPE
+    cfg = pattern.PatternConfig.tiny_latent(head_dim=128, latent=EQUAL_WIDTHS)
+    seq = 256
+    assert pattern.attention_paths(cfg, seq)["latent"]["path"] == "kernel"
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    lp = jax.tree.map(lambda w: jax.ShapeDtypeStruct(w.shape[1:], w.dtype), params["attn"]["latent"])
+    x = jax.ShapeDtypeStruct((1, seq, cfg.d_model), cfg.dtype)
+    tables = pattern.rope_tables(cfg.rope_latent, cfg.latent.d_rope, seq)
+
+    def loss(x, lp):
+        layer = jax.checkpoint(lambda x, lp: pattern._latent_block(cfg, x, lp, *tables))
+        return jnp.sum(layer(x, lp).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(x, lp).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    kernels = [names[ref] for ref in re.findall(
+        r"stablehlo\.custom_call @tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
+    assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
+        "blocked_attention_dkv", "blocked_attention_dq",
+        "blocked_attention_fwd", "blocked_attention_fwd"], kernels
+    assert all(core.search(name) and not latent.search(name) for name in kernels), kernels
+    products = [n for n in names.values() if latent.search(n) and n.endswith("dot_general")]
+    for pass_ in ("jvp(attn/full)", "rematted_computation/attn/full", "transpose("):
+        assert any(pass_ in n for n in products), (pass_, products)

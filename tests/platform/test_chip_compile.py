@@ -244,3 +244,65 @@ def test_routed_layer_compiles_for_v5e_with_both_widths_under_one_conditional(on
     grouped = set(re.findall(r"ragged-dot[\w.\-]* = bf16\[(\d+),", text))
     assert {"16384", "65536"} <= grouped, grouped
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9  # 1.3e9 (compile, PR 31)
+
+
+def kimi_config():
+    from benchmark import harness
+
+    config = harness.read_json(harness.HERE, "configs", "kimi-vl-a3b-l6-ep8.json")
+    return config, harness.load_family(config).program_config(config, config["batch"][1])
+
+
+def test_latent_attention_compiles_for_v5e_on_the_blocks_with_its_scopes(one_chip, as_on_a_tpu):
+    """One latent attention sublayer at the kimi configuration's own widths (8,192
+    tokens, 16 heads, scores 192 wide over values of 128, a latent of 512),
+    differentiated through the layer's ``jax.checkpoint``: the kernels take one width, so
+    the products go by the ``jax.numpy`` blocks, no custom call, their ops under
+    ``attn/full/core`` where ``attn.roofline`` looks, and ops remain under
+    ``attn/full/latent`` for ``attn.latent_ms``."""
+    import re
+
+    from benchmark import harness
+    from tpu_resiliency.models import pattern
+
+    config, cfg = kimi_config()
+    seq = config["batch"][1]
+    assert pattern.attention_paths(cfg, seq) == {"latent": {
+        "path": "blocks", "block": 1024, "score_width": 192, "value_width": 128}}
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    lp = jax.tree.map(lambda w: sds(w.shape[1:], w.dtype, one_chip), params["attn"]["latent"])
+    tables = pattern.rope_tables(cfg.rope_latent, cfg.latent.d_rope, seq)
+
+    def loss(x, lp):
+        layer = jax.checkpoint(lambda x, lp: pattern._latent_block(cfg, x, lp, *tables))
+        return jnp.sum(layer(x, lp).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        sds((1, seq, cfg.d_model), cfg.dtype, one_chip), lp).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    core = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
+    latent = harness.load_by_path("layer_metrics", "attn.latent_ms").SCOPE
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert sum(1 for name in names if core.search(name)) >= 8
+    assert sum(1 for name in names if latent.search(name)) >= 8
+
+
+def test_kimi_train_step_fits_one_chip(one_chip, as_on_a_tpu):
+    """The whole donating step of ``kimi-vl-a3b-l6-ep8`` at 1 x 8192, attention on the blocks:
+    668,890,432 parameters, 8.03e9 B of f32 weights and AdamW moments, and what the step
+    needs beside them inside one v5e's 15.75 GiB."""
+    from tpu_resiliency.models import pattern
+
+    config, cfg = kimi_config()
+    train_step, init_opt = pattern.make_train_step(cfg)
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    opt = jax.eval_shape(init_opt, params)
+    assert sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params)) == 668_890_432
+    on_chip = lambda tree: placed(tree, jax.tree.map(lambda _: one_chip, tree))  # noqa: E731
+    compiled = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt), sds(tuple(config["batch"]), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 8.0e9 < mem.argument_size_in_bytes < 8.1e9
+    needed = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+              + mem.generated_code_size_in_bytes)
+    assert needed < 15.75 * 2 ** 30, needed  # 11.64e9 (compile, PR 32)

@@ -1,24 +1,30 @@
 """Third model family: a decoder described as a *pattern of layers*.
 
 Where ``transformer.py`` and ``moe.py`` scan one homogeneous stacked layer, this
-model is a list of layers, each an attention kind (``full`` or ``sliding``, with its
-own head count and rotary table) and an MLP kind (``dense`` SwiGLU, or ``sparse``:
-a float32 router over all experts of the deployment, the top-k routed experts that
-this chip holds, and one shared expert). Parameters are stacked per kind; the layers
-run in the order the description gives (a Python loop: the kinds differ in shape,
-so there is no single body to scan).
+model is a list of layers, each an attention kind and an MLP kind. Attention:
+``full`` or ``sliding`` (grouped-query, with an output gate, each with its own head
+count and rotary table), or ``latent`` (:class:`Latent`: keys and values of every head
+decompressed from one normed low-rank latent, one rotary key part shared by all heads,
+a score width that differs from the value width, no gate). MLP: ``dense`` SwiGLU, or
+``sparse``: a float32 router over all experts of the deployment (chosen by score, or
+by score plus a selection bias that never enters a weight and that the loss-free
+balancing rule moves), the top-k routed experts that this chip holds, and one shared
+SwiGLU (several shared experts are one SwiGLU of their summed width). Parameters are
+stacked per kind; the layers run in the order the description gives (a Python loop:
+the kinds differ in shape, so there is no single body to scan).
 
 Built TPU-first, static shapes throughout:
 
 - **Attention never holds a T x T array.** On a TPU, at heads of whole lane groups
-  and sequences of whole tiles, both kinds run as the blocked kernels of
-  ``ops/attention.py``: a tile of scores lives in VMEM, the key tiles a query tile
-  cannot see are skipped (:func:`attention_paths` says which path a shape takes).
-  Off the TPU, or at shapes that do not tile, the blocks below are plain
+  and sequences of whole tiles, a kind whose queries, keys and values have one width
+  runs as the blocked kernels of ``ops/attention.py``: a tile of scores lives in VMEM,
+  the key tiles a query tile cannot see are skipped (:func:`attention_paths` says which
+  path a shape takes). Off the TPU, at shapes that do not tile, or where the score
+  width differs from the value width (latent attention), the blocks below are plain
   ``jax.numpy``: sliding layers compute the band (query blocks of one window against
-  their own and the previous key block), full layers go by query blocks against the
-  causal prefix of the keys, and both loop over the KV heads with the block's scores
-  recomputed in the backward pass.
+  their own and the previous key block), full and latent layers go by query blocks
+  against the causal prefix of the keys, and all loop over the KV heads with the
+  block's scores recomputed in the backward pass.
 - **Routing drops nothing.** Every (token, choice) pair whose expert this chip holds
   is computed: the pairs are sorted by expert and the three SwiGLU products run as
   grouped products over the ragged groups (``jax.lax.ragged_dot``). Pairs for
@@ -48,7 +54,8 @@ import numpy as np
 from tpu_resiliency.models import transformer as tfm
 from tpu_resiliency.ops import attention
 
-FULL, SLIDING = "full", "sliding"
+FULL, SLIDING, LATENT = "full", "sliding", "latent"
+ATTENTION_KINDS = (FULL, SLIDING, LATENT)
 DENSE, SPARSE = "dense", "sparse"
 
 
@@ -73,8 +80,28 @@ class Rope:
 
 
 @dataclasses.dataclass(frozen=True)
+class Latent:
+    """Latent attention (DeepSeek-V2, arXiv:2405.04434, without the query's own low
+    rank): a token's keys and values are ``kv_rank`` numbers, normed, from which every
+    head's ``d_nope`` key dimensions and ``d_value`` values are decompressed; ``d_rope``
+    more key dimensions carry the rotary positions and are one vector for all heads.
+    A head's score is ``d_nope + d_rope`` wide."""
+
+    kv_rank: int
+    d_nope: int
+    d_rope: int
+    d_value: int
+    #: the epsilon of the norm on the latent (the layers' own is ``norm_eps``)
+    norm_eps: float = 1e-6
+
+    @property
+    def d_score(self) -> int:
+        return self.d_nope + self.d_rope
+
+
+@dataclasses.dataclass(frozen=True)
 class Layer:
-    attn: str  # FULL | SLIDING
+    attn: str  # FULL | SLIDING | LATENT
     n_heads: int
     mlp: str  # DENSE | SPARSE
 
@@ -97,6 +124,16 @@ class PatternConfig:
     window: int = 512
     rope_full: Rope = Rope()
     rope_sliding: Rope = Rope()
+    #: the widths of the ``latent`` layers, and the rotary table of their ``d_rope`` part
+    latent: Optional[Latent] = None
+    rope_latent: Rope = Rope()
+    #: the router chooses by score + a selection bias (seeded at this standard deviation)
+    #: and weighs by the score alone; ``None``: no bias, it chooses by score
+    route_bias_std: Optional[float] = None
+    #: what one unit of the leaf ``b_router`` adds to a score in the choice. The balancing
+    #: rule reaches the leaf as a gradient of +-1 (:func:`route`), so an optimizer of
+    #: step ``lr`` moves the bias by ``lr * route_bias_gain`` a step
+    route_bias_gain: float = 1.0
     norm_eps: float = 1e-6
     #: query rows a full layer scores at a time (against all the keys before them) on
     #: the ``jax.numpy`` path; the kernel path has its own tiles and does not read it
@@ -108,19 +145,26 @@ class PatternConfig:
         if not (0 <= first and count > 0 and first + count <= self.n_experts):
             raise ValueError(f"experts_held {self.experts_held} is not a range of "
                              f"the {self.n_experts} experts")
-        for kind in (FULL, SLIDING):
+        for kind in ATTENTION_KINDS:
             heads = {l.n_heads for l in self.layers if l.attn == kind}
             if len(heads) > 1:
                 raise ValueError(f"{kind} layers differ in head count {sorted(heads)}: "
                                  "their weights cannot be stacked")
         for l in self.layers:
-            if l.attn not in (FULL, SLIDING) or l.mlp not in (DENSE, SPARSE):
+            if l.attn not in ATTENTION_KINDS or l.mlp not in (DENSE, SPARSE):
                 raise ValueError(f"unknown layer kind in {l}")
-            if l.n_heads % self.n_kv_heads:
+            if l.attn == LATENT:  # every head has keys of its own
+                if self.latent is None:
+                    raise ValueError("latent layers need the widths of `latent`")
+            elif l.n_heads % self.n_kv_heads:
                 raise ValueError(f"{l.n_heads} heads do not group over {self.n_kv_heads}")
 
     def rope(self, kind: str) -> Rope:
-        return self.rope_full if kind == FULL else self.rope_sliding
+        return {FULL: self.rope_full, SLIDING: self.rope_sliding, LATENT: self.rope_latent}[kind]
+
+    def rotary_width(self, kind: str) -> int:
+        """The dimensions of a head that :meth:`rope`'s table of ``kind`` is made for."""
+        return self.latent.d_rope if kind == LATENT else self.head_dim
 
     def heads(self, kind: str) -> int:
         return next(l.n_heads for l in self.layers if l.attn == kind)
@@ -141,6 +185,21 @@ class PatternConfig:
         base.update(kw)
         return PatternConfig(**base)
 
+    @staticmethod
+    def tiny_latent(**kw) -> "PatternConfig":
+        """Latent attention throughout, a router with a selection bias, two shared
+        experts' width: the second description the tests and the example train."""
+        base = dict(
+            vocab_size=256, d_model=64, head_dim=16, n_kv_heads=4,
+            layers=(Layer(LATENT, 4, DENSE), Layer(LATENT, 4, SPARSE), Layer(LATENT, 4, SPARSE)),
+            latent=Latent(kv_rank=32, d_nope=16, d_rope=8, d_value=16),
+            rope_latent=Rope(800000.0), route_bias_std=0.1, route_bias_gain=0.001 / 3e-4,
+            d_ff=128, d_expert=32, d_shared=64, n_experts=16, top_k=4,
+            experts_held=(0, 4), routed_scale=2.446, attn_block=16, norm_eps=1e-5,
+        )
+        base.update(kw)
+        return PatternConfig(**base)
+
 
 # ---------------------------------------------------------------------------------
 # the parameters, described
@@ -148,18 +207,24 @@ class PatternConfig:
 
 class Leaf(NamedTuple):
     """One parameter leaf: its shape, the logical name of each dimension (what
-    ``parallel/mesh.py`` maps to mesh axes; ``None`` is never sharded) and the
-    fan-in its normal initialisation is scaled by (``None``: a norm, at one)."""
+    ``parallel/mesh.py`` maps to mesh axes; ``None`` is never sharded) and how it is
+    seeded: normal / sqrt(``fan_in``), or normal x ``std`` where that is given, or at
+    one (a norm: neither)."""
 
     shape: tuple[int, ...]
     axes: tuple[Optional[str], ...]
     fan_in: Optional[int]
+    std: Optional[float] = None
 
 
 def describe_params(cfg: PatternConfig) -> dict:
     """The parameter tree as :class:`Leaf` descriptions. Layer weights are stacked on a
     leading axis per kind: ``attn/<full|sliding>`` and ``mlp/<dense|sparse>``, in the
-    order the layers of that kind appear."""
+    order the layers of that kind appear. A latent layer's down-projection ``wkv_a`` and
+    its latent norm serve all heads and are never sharded; ``wq`` and the up-projection
+    ``wkv_b`` have a head's columns together. ``b_router`` is there only where the
+    router chooses by a bias, seeded so that the bias it stands for (``route_bias_gain``
+    times it) has ``route_bias_std``."""
     d, dh, hkv = cfg.d_model, cfg.head_dim, cfg.n_kv_heads
     tree: dict = {
         "embed": Leaf((cfg.vocab_size, d), ("vocab", None), d),
@@ -179,6 +244,18 @@ def describe_params(cfg: PatternConfig) -> dict:
             "wv": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
             "wg": Leaf((n, d, h), (None, None, "heads"), d),
             "wo": Leaf((n, h * dh, d), (None, "heads", None), h * dh),
+        }
+    n = cfg.count(LATENT)
+    if n:
+        h, la = cfg.heads(LATENT), cfg.latent
+        tree["attn"][LATENT] = {
+            "attn_norm": Leaf((n, d), (None, None), None),
+            "wq": Leaf((n, d, h * la.d_score), (None, None, "heads"), d),
+            "wkv_a": Leaf((n, d, la.kv_rank + la.d_rope), (None, None, None), d),
+            "kv_norm": Leaf((n, la.kv_rank), (None, None), None),
+            "wkv_b": Leaf((n, la.kv_rank, h * (la.d_nope + la.d_value)),
+                          (None, None, "heads"), la.kv_rank),
+            "wo": Leaf((n, h * la.d_value, d), (None, "heads", None), h * la.d_value),
         }
 
     def swiglu(prefix: str, lead: tuple, lead_axes: tuple, f: int) -> dict:
@@ -201,6 +278,10 @@ def describe_params(cfg: PatternConfig) -> dict:
             **swiglu("we", (n, held), (None, "experts"), cfg.d_expert),
             **swiglu("ws", (n,), (None,), cfg.d_shared),
         }
+        if cfg.route_bias_std is not None:
+            tree["mlp"][SPARSE]["b_router"] = Leaf(
+                (n, cfg.n_experts), (None, None), None,
+                std=cfg.route_bias_std / cfg.route_bias_gain)
     return tree
 
 
@@ -209,15 +290,20 @@ def _is_leaf(x) -> bool:
 
 
 def init_params(rng: jax.Array, cfg: PatternConfig) -> dict:
-    """Seeded weights: normal / sqrt(fan_in), norms at one. One key a leaf, split from
-    ``rng`` in the order the tree flattens (sorted keys), so that anything that knows
-    the description makes the same weights."""
+    """Seeded weights: normal / sqrt(fan_in) or normal x std, norms at one
+    (:class:`Leaf`). One key a leaf, split from ``rng`` in the order the tree flattens
+    (sorted keys), so that anything that knows the description makes the same weights."""
     leaves, treedef = jax.tree.flatten(describe_params(cfg), is_leaf=_is_leaf)
     keys = jax.random.split(rng, len(leaves))
-    return jax.tree.unflatten(treedef, [
-        jnp.ones(leaf.shape, jnp.float32) if leaf.fan_in is None
-        else jax.random.normal(key, leaf.shape, jnp.float32) / np.sqrt(leaf.fan_in)
-        for key, leaf in zip(keys, leaves)])
+
+    def seeded(key, leaf: Leaf):
+        if leaf.std is not None:
+            return jax.random.normal(key, leaf.shape, jnp.float32) * leaf.std
+        if leaf.fan_in is None:
+            return jnp.ones(leaf.shape, jnp.float32)
+        return jax.random.normal(key, leaf.shape, jnp.float32) / np.sqrt(leaf.fan_in)
+
+    return jax.tree.unflatten(treedef, [seeded(key, leaf) for key, leaf in zip(keys, leaves)])
 
 
 # ---------------------------------------------------------------------------------
@@ -365,21 +451,39 @@ def _window(cfg: PatternConfig, kind: str) -> Optional[int]:
 
 def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     """Which path the attention products of each kind of layer take at sequences of
-    ``seq``, from what the code can see (the backend, the head size, whether the
-    sequence is whole tiles): ``{kind: {"path": "kernel", "tile": rows}}`` for the
-    blocked kernels of ``ops/attention.py``, ``{"path": "blocks", "block": rows}`` for
-    the ``jax.numpy`` blocks."""
+    ``seq``, from what the code can see (the backend, the widths, whether the sequence
+    is whole tiles): ``{kind: {"path": "kernel", "tile": rows}}`` for the blocked
+    kernels of ``ops/attention.py``, ``{"path": "blocks", "block": rows}`` for the
+    ``jax.numpy`` blocks. The kernels take one width for queries, keys and values, so a
+    latent kind whose score width differs from its value width takes the blocks, and
+    says both widths."""
     paths = {}
-    for kind in (FULL, SLIDING):
+    for kind in ATTENTION_KINDS:
         if not cfg.count(kind):
             continue
         window = _window(cfg, kind)
-        if jax.default_backend() == "tpu" and attention.applies(seq, cfg.head_dim, window):
+        score, value = ((cfg.latent.d_score, cfg.latent.d_value) if kind == LATENT
+                        else (cfg.head_dim, cfg.head_dim))
+        if (jax.default_backend() == "tpu" and score == value
+                and attention.applies(seq, score, window)):
             paths[kind] = {"path": "kernel", "tile": attention.tile_of(seq, window)}
         else:
             paths[kind] = {"path": "blocks",
                            "block": cfg.window if kind == SLIDING else min(cfg.attn_block, seq)}
+        if kind == LATENT:
+            paths[kind].update(score_width=score, value_width=value)
     return paths
+
+
+def _products(cfg: PatternConfig, kind: str, q, k, v):
+    """Causal softmax attention of one layer by the path :func:`attention_paths` names:
+    q ``[B, T, H, dk]``, k ``[B, T, Hkv, dk]``, v ``[B, T, Hkv, dv]`` -> ``[B, T, H * dv]``."""
+    with jax.named_scope("core"):
+        if attention_paths(cfg, q.shape[1])[kind]["path"] == "kernel":
+            return attention.blocked_attention(q, k, v, window=_window(cfg, kind))
+        if kind == SLIDING:
+            return sliding_attention(q, k, v, cfg.window)
+        return full_attention(q, k, v, cfg.attn_block)
 
 
 def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos, sin):
@@ -394,14 +498,41 @@ def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos, sin):
         v = (y @ lp["wv"].astype(y.dtype)).reshape(b, t, hkv, dh)
         gate = jax.nn.sigmoid(y @ lp["wg"].astype(y.dtype))  # [B, T, H]
         q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
-        with jax.named_scope("core"):
-            if attention_paths(cfg, t)[kind]["path"] == "kernel":
-                attn = attention.blocked_attention(q, k, v, window=_window(cfg, kind))
-            elif kind == SLIDING:
-                attn = sliding_attention(q, k, v, cfg.window)
-            else:
-                attn = full_attention(q, k, v, cfg.attn_block)
+        attn = _products(cfg, kind, q, k, v)
         attn = (attn.reshape(b, t, h, dh) * gate[..., None]).reshape(b, t, h * dh)
+        return x + attn @ lp["wo"].astype(attn.dtype)
+
+
+def _latent_block(cfg: PatternConfig, x, lp: dict, cos, sin):
+    """Pre-norm latent attention and the residual: no gate, no bias. Every head's keys
+    are ``[k_nope | k_rope]`` with the one rotary ``k_rope`` of the token, its queries
+    ``[q_nope | q_rope]``, so a score is ``(q_nope . k_nope + q_rope . k_rope) /
+    sqrt(d_nope + d_rope)``; keys and values are decompressed for the products (the
+    training form: nothing is absorbed into ``wq`` or ``wo``).
+
+    The scope is ``attn/full``: it names the mask, which is what the benchmark's readers
+    of ``attn/<mask>`` and ``attn/<mask>/core`` know; the projections, the latent norm
+    and the rotary of both parts are under ``attn/full/latent``."""
+    with jax.named_scope("attn/full"):
+        b, t, _ = x.shape
+        h, la = cfg.heads(LATENT), cfg.latent
+        y = tfm.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        with jax.named_scope("latent"):
+            q = (y @ lp["wq"].astype(y.dtype)).reshape(b, t, h, la.d_score)
+            # [B, T, kv_rank + d_rope], left in float32 until it is normed and rotated:
+            # every head's keys and values come from these few numbers, and a rounding
+            # here is repeated in all of them (576 of a token's 5,000 activations)
+            down = jnp.matmul(y, lp["wkv_a"].astype(y.dtype), preferred_element_type=jnp.float32)
+            c = tfm.rms_norm(down[..., :la.kv_rank], lp["kv_norm"], la.norm_eps).astype(y.dtype)
+            up = (c @ lp["wkv_b"].astype(c.dtype)).reshape(b, t, h, la.d_nope + la.d_value)
+            k_rope = _rotate(down[..., la.kv_rank:].reshape(b, t, 1, la.d_rope), cos, sin)
+            q = jnp.concatenate(
+                [q[..., :la.d_nope], _rotate(q[..., la.d_nope:], cos, sin)], axis=-1)
+            k = jnp.concatenate(
+                [up[..., :la.d_nope],
+                 jnp.broadcast_to(k_rope.astype(y.dtype), (b, t, h, la.d_rope))], axis=-1)
+            v = up[..., la.d_nope:]
+        attn = _products(cfg, LATENT, q, k, v)
         return x + attn @ lp["wo"].astype(attn.dtype)
 
 
@@ -425,15 +556,36 @@ _take_rows.defvjp(lambda x, index, inverse: (x[index], (index, inverse)),
                   lambda res, g: (g[res[1]], None, None))
 
 
-def route(cfg: PatternConfig, y, w_router):
+def route(cfg: PatternConfig, y, w_router, bias=None):
     """Float32 routing of tokens ``y [N, D]`` over all ``n_experts``: sigmoid scores,
-    the ``top_k`` largest, their weights normalised to one and scaled. Returns
-    (weights ``[N, K]`` float32, experts ``[N, K]`` int32)."""
+    the ``top_k`` largest, their weights normalised to one and scaled. With a ``bias
+    [E]`` (Wang et al., arXiv:2408.15664, as DeepSeek-V3's ``noaux_tc`` with one group)
+    the experts are the ``top_k`` largest of score + ``route_bias_gain`` x bias and the
+    weights are their scores: the bias enters the choice and nothing else, so the loss
+    sends it no gradient. What moves it is that paper's balancing rule, down where an
+    expert got more than the even share of this batch's pairs and up where it got less:
+    ``balance`` is a term that is always zero and whose gradient by the bias is that
+    sign, +-1 an expert, so the optimizer that takes the loss's gradient applies the
+    rule. Returns (weights ``[N, K]`` float32, experts ``[N, K]`` int32, and with a bias
+    the count of chosen pairs that the scores alone would not have chosen and
+    ``balance``, else ``None`` twice)."""
     logits = jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    weights, experts = jax.lax.top_k(jax.nn.sigmoid(logits), cfg.top_k)
+    scores = jax.nn.sigmoid(logits)
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, cfg.top_k)
+        by_bias = balance = None
+    else:
+        bias = bias.astype(jnp.float32)
+        _, experts = jax.lax.top_k(scores + cfg.route_bias_gain * bias, cfg.top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        least = jax.lax.top_k(scores, cfg.top_k)[0][:, -1:]  # the unbiased choice's last
+        by_bias = jnp.sum(weights < least)
+        load = jnp.sum(experts[..., None] == jnp.arange(cfg.n_experts), axis=(0, 1))
+        over = jnp.where(load * cfg.n_experts > experts.size, 1.0, -1.0)
+        balance = jnp.sum((bias - jax.lax.stop_gradient(bias)) * over)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * cfg.routed_scale
-    return weights, experts
+    return weights, experts, by_bias, balance
 
 
 def _rows_at(rows, place):
@@ -519,12 +671,15 @@ def routed_experts(cfg: PatternConfig, y, lp: dict):
     router sends more than ``C`` pairs here takes the full width, ``N * top_k`` rows,
     under the same ``jax.lax.cond``. Where ``C`` would not be under the full width
     (every expert held; tiny shapes) there is only the full width and no ``cond``.
-    ``rows_carried`` among the counts says which it was."""
+    ``rows_carried`` among the counts says which it was; under a selection bias
+    ``chosen_by_bias`` counts the pairs (of all ``N * top_k``) whose expert the scores
+    alone would not have chosen."""
     n, d = y.shape
     k = cfg.top_k
     first, held = cfg.experts_held
     with jax.named_scope("moe/route"):
-        weights, experts = route(cfg, y, lp["w_router"])
+        weights, experts, by_bias, balance = route(
+            cfg, y, lp["w_router"], lp.get("b_router"))
     with jax.named_scope("moe/dispatch"):
         local = experts - first
         here = (local >= 0) & (local < held)
@@ -580,61 +735,77 @@ def routed_experts(cfg: PatternConfig, y, lp: dict):
         "dropped": landed - jnp.sum(group_sizes),
         "rows_carried": carried,
     }
-    return routed, counts
+    if by_bias is not None:
+        counts["chosen_by_bias"] = by_bias
+    return routed, counts, balance
 
 
 def _mlp_block(cfg: PatternConfig, kind: str, x, lp: dict):
-    """Pre-norm MLP with the residual; a sparse one also returns its routing counts."""
+    """Pre-norm MLP with the residual; a sparse one also returns its routing counts and,
+    under a selection bias, :func:`route`'s ``balance``."""
     y = tfm.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if kind == DENSE:
         with jax.named_scope("mlp/dense"):
-            return x + _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+            return x + _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None, None
     b, t, d = y.shape
-    routed, counts = routed_experts(cfg, y.reshape(b * t, d), lp)
+    routed, counts, balance = routed_experts(cfg, y.reshape(b * t, d), lp)
     with jax.named_scope("moe/shared"):
         shared = _swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-    return x + routed.reshape(b, t, d) + shared, counts
+    return x + routed.reshape(b, t, d) + shared, counts, balance
 
 
 # ---------------------------------------------------------------------------------
 # the model
 # ---------------------------------------------------------------------------------
 
-def forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
-    """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32, routing counts: a dict of
-    ``[sparse layers]`` arrays, see :func:`routed_experts`)."""
+def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
+    """:func:`forward`, and the sum of the sparse layers' ``balance`` (:func:`route`)."""
     x = params["embed"].astype(cfg.dtype)[tokens]
     t = tokens.shape[1]
-    tables = {kind: rope_tables(cfg.rope(kind), cfg.head_dim, t)
-              for kind in (FULL, SLIDING) if cfg.count(kind)}
+    tables = {kind: rope_tables(cfg.rope(kind), cfg.rotary_width(kind), t)
+              for kind in ATTENTION_KINDS if cfg.count(kind)}
 
     def layer(x, attn_lp, mlp_lp, spec: Layer):
-        x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
+        if spec.attn == LATENT:
+            x = _latent_block(cfg, x, attn_lp, *tables[LATENT])
+        else:
+            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
         return _mlp_block(cfg, spec.mlp, x, mlp_lp)
 
-    seen = dict.fromkeys((FULL, SLIDING, DENSE, SPARSE), 0)
-    counts = []
+    seen = dict.fromkeys((*ATTENTION_KINDS, DENSE, SPARSE), 0)
+    counts, balance = [], None
     for spec in cfg.layers:
         attn_lp = jax.tree.map(lambda w: w[seen[spec.attn]], params["attn"][spec.attn])
         mlp_lp = jax.tree.map(lambda w: w[seen[spec.mlp]], params["mlp"][spec.mlp])
         seen[spec.attn] += 1
         seen[spec.mlp] += 1
         # each layer's forward is recomputed in the backward pass: only x is kept
-        x, layer_counts = jax.checkpoint(functools.partial(layer, spec=spec))(
+        x, layer_counts, layer_balance = jax.checkpoint(functools.partial(layer, spec=spec))(
             x, attn_lp, mlp_lp)
         if layer_counts is not None:
             counts.append(layer_counts)
+        if layer_balance is not None:
+            balance = layer_balance if balance is None else balance + layer_balance
     x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *counts) if counts else {}
-    return logits, stacked
+    return logits, stacked, balance
+
+
+def forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
+    """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32, routing counts: a dict of
+    ``[sparse layers]`` arrays, see :func:`routed_experts`)."""
+    return _forward(params, tokens, cfg)[:2]
 
 
 def loss_and_counts(params: dict, tokens: jax.Array, cfg: PatternConfig):
     """Next-token cross-entropy over tokens ``[B, T]`` (the last position's logits
-    dropped, as in the dense model) and the routing counts of each sparse layer."""
-    logits, counts = forward(params, tokens, cfg)
-    return tfm.token_nll(logits[:, :-1], tokens[:, 1:]).mean(), counts
+    dropped, as in the dense model) and the routing counts of each sparse layer. Under a
+    selection bias the loss carries the layers' ``balance``: nothing in value, the
+    balancing rule in the gradient (:func:`route`)."""
+    logits, counts, balance = _forward(params, tokens, cfg)
+    loss = tfm.token_nll(logits[:, :-1], tokens[:, 1:]).mean()
+    return (loss if balance is None else loss + balance), counts
 
 
 def loss_fn(params: dict, tokens: jax.Array, cfg: PatternConfig) -> jax.Array:
