@@ -21,14 +21,17 @@ as the blocked kernels of ``ops/attention.py`` (on a TPU, at shapes that tile) o
 the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block,
 and for the latent kind its ``score_width`` and ``value_width``;
 and a ``dispatch_path`` event: the rows the expert dispatch carries at this batch
-(``pattern.dispatch_rows``), ``bounded`` or ``full``.
+(``pattern.dispatch_rows``), ``bounded`` or ``full``; and a ``kept_residuals`` event:
+the named values each layer keeps for its backward pass at this batch on this device's
+memory, and their bytes (``pattern.kept_residuals``; ``names`` empty: every layer
+recomputes its whole forward).
 
 Run (CPU simulation)::
 
     python examples/pattern_training.py --cpu --steps 20
 
-Prints ``ATTENTION {...}``, ``DISPATCH {...}``, one ``ROUTING step=<n> ...`` line per
-routing event and ``DONE loss=<x>`` on success.
+Prints ``ATTENTION {...}``, ``DISPATCH {...}``, ``KEPT {...}``, one ``ROUTING step=<n>
+...`` line per routing event and ``DONE loss=<x>`` on success.
 """
 
 from __future__ import annotations
@@ -80,6 +83,10 @@ def main() -> None:
     dispatch = pattern.dispatch_rows(cfg, n_tokens)
     events.record("model", "dispatch_path", tokens=n_tokens, **dispatch)
     print(f"DISPATCH {dispatch}", flush=True)
+    memory = pattern.device_memory_bytes()
+    kept = pattern.kept_residuals(cfg, n_tokens, memory)
+    events.record("model", "kept_residuals", tokens=n_tokens, memory_bytes=memory, **kept)
+    print(f"KEPT {kept}", flush=True)
 
     def tokens(i: int):
         return jnp.asarray(np.random.default_rng([0, i]).integers(

@@ -4,8 +4,9 @@ with a selection bias): the program against the benchmark's plain references on 
 every gradient leaf; each attention kind against plain masked attention, the latent
 layer against attention written the long way; the experts' shares against the uncut
 layer; a bias that changes the choice and never a weight; routing that drops nothing;
-derived parameter specs on a mesh; the state through the local checkpoint; the scopes
-the benchmark's readers look for in the lowered step."""
+what the layers keep for the backward pass against keeping nothing, and the list by
+tokens and memory; derived parameter specs on a mesh; the state through the local
+checkpoint; the scopes the benchmark's readers look for in the lowered step."""
 
 import dataclasses
 import functools
@@ -423,14 +424,17 @@ def biased(cfg, seed, always=(), never=()):
     return part, y, want
 
 
+def equations(jaxpr):
+    """Every equation of a jaxpr and of every jaxpr inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub)
+
+
 def primitives(jaxpr) -> set[str]:
     """Names of the primitives of a jaxpr and of every jaxpr inside it."""
-    names = set()
-    for eqn in jaxpr.eqns:
-        names.add(eqn.primitive.name)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            names |= primitives(sub)
-    return names
+    return {eqn.primitive.name for eqn in equations(jaxpr)}
 
 
 @pytest.fixture(scope="module")
@@ -522,6 +526,125 @@ def test_dispatch_rows_is_what_the_layer_carries_and_full_width_has_no_cond(
     counts = jax.jit(layer)(y, part)[1]
     assert int(counts["pairs_held"]) <= rows  # this router stays under twice its share
     assert int(counts["rows_carried"]) == rows and int(counts["dropped"]) == 0
+
+
+@functools.cache
+def kept_and_not(description: str):
+    """Loss, routing counts and gradient of one batch (float32 activations) with the
+    list ``kept_residuals`` gives where no memory limit is stated, and with nothing kept
+    (a device too small for any group): ``{kept: (value, gradient, matrix products in
+    the differentiated program)}``."""
+    cfg = DESCRIPTIONS[description][1](dtype=jnp.float32)
+    params = pattern.init_params(jax.random.PRNGKey(3), cfg)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+    out = {}
+    for kept, memory in ((True, None), (False, 0)):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(pattern, "device_memory_bytes", lambda: memory)
+            assert bool(pattern.kept_residuals(cfg, tokens.size, memory)["names"]) == kept
+            grad = jax.value_and_grad(
+                lambda p: pattern.loss_and_counts(p, tokens, cfg), has_aux=True)
+            products = sum(eqn.primitive.name == "dot_general"
+                           for eqn in equations(jax.make_jaxpr(grad)(params).jaxpr))
+            out[kept] = (*jax.jit(grad)(params), products)
+    return out
+
+
+@pytest.mark.parametrize("description,path", leaf_paths())
+def test_gradient_leaf_with_the_kept_list_equals_nothing_kept(description, path):
+    both = kept_and_not(description)
+    got, want = (dict(jax.tree_util.tree_flatten_with_path(both[kept][1])[0])
+                 for kept in (True, False))
+    key = next(k for k in want if jax.tree_util.keystr(k) == path)
+    assert float(jnp.linalg.norm(want[key])) > 0
+    np.testing.assert_allclose(np.asarray(got[key]), np.asarray(want[key]), rtol=1e-6, atol=1e-9)
+
+
+@pytest.mark.parametrize("description", list(DESCRIPTIONS))
+def test_the_kept_list_gives_the_same_loss_and_counts_with_fewer_products(description):
+    both = kept_and_not(description)
+    (kept, _, kept_dots), (bare, _, bare_dots) = both[True], both[False]
+    assert float(kept[0]) == float(bare[0])
+    for name, want in bare[1].items():
+        np.testing.assert_array_equal(np.asarray(kept[1][name]), np.asarray(want), err_msg=name)
+    # kept: the forward products of q, k, v, the gate, the output matrix, the router and
+    # the gate and up of every SwiGLU outside the dispatch are not made a second time
+    assert kept_dots < bare_dots, (kept_dots, bare_dots)
+
+
+@pytest.mark.parametrize("description", list(DESCRIPTIONS))
+def test_the_forward_names_what_the_groups_list(description):
+    cfg = DESCRIPTIONS[description][1]()
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    jaxpr = jax.make_jaxpr(lambda p, t: pattern.loss_fn(p, t, cfg))(
+        params, jax.ShapeDtypeStruct((2, SEQ), jnp.int32)).jaxpr
+    named = {eqn.params["name"] for eqn in equations(jaxpr) if eqn.primitive.name == "name"}
+    assert named == set(ALL_NAMES) - {"attn_lse"}  # the blocks make no log-sum-exp
+
+
+def cell_config(description: str, seq: int) -> pattern.PatternConfig:
+    """The program's configuration of a benchmark cell, at its published widths."""
+    from benchmark import harness
+
+    config = harness.read_json(harness.HERE, "configs", f"{DESCRIPTIONS[description][0]}.json")
+    return harness.load_family(config).program_config(config, seq)
+
+
+ALL_NAMES = [name for names in pattern.KEPT_GROUPS.values() for name in names]
+V5E_BYTES = 16.9e9  # one v5e's ``bytes_limit``
+
+
+@pytest.mark.parametrize("description,tokens,memory,groups,kept_bytes", [
+    # the two cells: every group, 1.93e9 and 2.05e9 B beside 12.6e9 and 12.8e9 of step
+    ("mixed", 8192, V5E_BYTES, 6, 1_926_234_112),
+    ("latent", 8192, V5E_BYTES, 6, 2_052_849_664),
+    ("mixed", 8192, None, 6, 1_926_234_112),  # no limit stated (the CPU): as the chip
+    # twice the tokens on the same chip: q, k, v and the SwiGLUs' products no longer fit
+    # (laguna compiles to 15.08e9 B so, and to 16.89e9 of the chip's 16.91e9 with all kept)
+    ("mixed", 16384, V5E_BYTES, 3, 1_637_875_712),
+    ("latent", 16384, V5E_BYTES, 3, 834_142_208),
+    # four times: the step's own state and logits are over the chip before anything is kept
+    ("mixed", 32768, V5E_BYTES, 0, 0),
+    ("latent", 32768, V5E_BYTES, 0, 0),
+    ("mixed", 32768, 32e9, 6, 4 * 1_926_234_112),  # and all of it on a chip twice the size
+    # a device that holds the step and the routing group's 38 MB, and one that holds neither
+    ("mixed", 8192, 12.64e9, 1, 37_748_736),
+    ("mixed", 8192, 12.0e9, 0, 0),
+    ("latent", 8192, 1e9, 0, 0),
+])
+def test_kept_residuals_by_tokens_and_memory(description, tokens, memory, groups, kept_bytes):
+    cfg = cell_config(description, tokens)
+    kept = pattern.kept_residuals(cfg, tokens, memory)
+    taken = list(pattern.KEPT_GROUPS.items())[:groups]
+    assert kept["names"] == [name for _, names in taken for name in names]
+    assert list(kept["per_layer"]) == [group for group, _ in taken]
+    assert all(len(layers) == len(cfg.layers) for layers in kept["per_layer"].values())
+    assert kept["bytes"] == sum(map(sum, kept["per_layer"].values())) == kept_bytes
+    if memory is not None and groups:
+        assert kept["step_bytes"] + kept["bytes"] <= memory
+    # what the step holds anyway: at least its weights, moments and gradients
+    n_params = sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(
+        pattern.describe_params(cfg), is_leaf=pattern._is_leaf))
+    assert kept["step_bytes"] > 16 * n_params
+
+
+def test_kept_residuals_of_the_laguna_cell_layer_by_layer():
+    """The bytes of each group in each layer of ``laguna-xs2-l5-ep8`` at 8,192 tokens:
+    a full layer (48 heads) keeps 102 MB of output and log-sum-exp and 134 MB of q, k
+    and v, a sliding one (64 heads) 136 and 168; a sparse layer 9 MB of routing and 17 MB
+    of the shared expert's products, the dense layer 268 MB of its own."""
+    kept = pattern.kept_residuals(cell_config("mixed", 8192), 8192, V5E_BYTES)
+    assert kept["names"] == ALL_NAMES
+    assert kept["per_layer"] == {
+        "routing": [0] + [8192 * 4 * (256 + 4 * 8)] * 4,
+        "stream": [8192 * 2048 * 2] * 5,
+        "attention": [8192 * 48 * (128 * 2 + 4)] + [8192 * 64 * (128 * 2 + 4)] * 3
+                     + [8192 * 48 * (128 * 2 + 4)],
+        "qkv": [8192 * 2 * 128 * (48 + 16)] + [8192 * 2 * 128 * (64 + 16)] * 3
+               + [8192 * 2 * 128 * (48 + 16)],
+        "shared": [0] + [2 * 8192 * 512 * 2] * 4,
+        "dense": [2 * 8192 * 8192 * 2] + [0] * 4,
+    }
 
 
 @pytest.mark.parametrize("description", list(DESCRIPTIONS))

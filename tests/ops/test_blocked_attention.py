@@ -191,12 +191,17 @@ def test_attention_paths_on_a_tpu_follow_the_shapes(as_on_a_tpu):
     assert pattern.attention_paths(cfg, 512)["sliding"] == {"path": "kernel", "tile": 512}
 
 
+@pytest.mark.parametrize("kept,forwards", [
+    ((), 2),  # nothing kept: the layer's ``jax.checkpoint`` runs the forward kernel again
+    ((attention.OUT_NAME,), 2),  # the output alone: again, for the log-sum-exp
+    ((attention.OUT_NAME, attention.LSE_NAME), 1),
+], ids=["nothing", "out", "out+lse"])
 @pytest.mark.parametrize("kind", ["full", "sliding"])
-def test_every_kernel_of_a_layer_carries_the_core_scope(kind, as_on_a_tpu):
+def test_every_kernel_of_a_layer_carries_the_core_scope(kind, kept, forwards, as_on_a_tpu):
     """Lowered for the TPU (nothing compiles or runs): the forward, the forward that the
-    layer's ``jax.checkpoint`` recomputes and the two backward kernels are custom calls
-    whose ``op_name`` the benchmark's ``attn.roofline`` reader puts under
-    ``attn/<kind>/core``."""
+    layer's ``jax.checkpoint`` recomputes unless its policy keeps both residuals the
+    kernel names, and the two backward kernels are custom calls whose ``op_name`` the
+    benchmark's ``attn.roofline`` reader puts under ``attn/<kind>/core``."""
     from benchmark import harness
 
     mark = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
@@ -209,7 +214,8 @@ def test_every_kernel_of_a_layer_carries_the_core_scope(kind, as_on_a_tpu):
     tables = pattern.rope_tables(cfg.rope(kind), cfg.head_dim, seq)
 
     def loss(x, lp):
-        layer = jax.checkpoint(lambda x, lp: pattern._attn_block(cfg, kind, x, lp, *tables))
+        layer = jax.checkpoint(lambda x, lp: pattern._attn_block(cfg, kind, x, lp, *tables),
+                               policy=jax.checkpoint_policies.save_only_these_names(*kept))
         return jnp.sum(layer(x, lp).astype(jnp.float32) ** 2)
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(x, lp).lower(
@@ -219,9 +225,9 @@ def test_every_kernel_of_a_layer_carries_the_core_scope(kind, as_on_a_tpu):
         r"stablehlo\.custom_call @tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
     assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
         "blocked_attention_dkv", "blocked_attention_dq",
-        "blocked_attention_fwd", "blocked_attention_fwd"], kernels
+        *["blocked_attention_fwd"] * forwards], kernels
     assert all(mark.search(name) and f"attn/{kind}" in name for name in kernels), kernels
-    assert sum("rematted_computation" in name for name in kernels) == 1
+    assert sum("rematted_computation" in name for name in kernels) == forwards - 1
 
 
 def test_every_kernel_of_a_latent_layer_carries_the_core_scope_and_none_the_latent_one(

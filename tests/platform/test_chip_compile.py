@@ -185,8 +185,8 @@ def test_flagship_train_step_compiles_for_the_dp_tp_mesh(topo):
 def test_attention_kernels_compile_for_v5e_under_the_core_scope(one_chip, as_on_a_tpu, kind):
     """One attention sublayer of the laguna configuration at its own widths (8,192
     tokens, heads of 128, 48 or 64 query heads over 8 KV heads), differentiated through
-    the layer's ``jax.checkpoint``: Mosaic takes the four kernels (forward, recomputed
-    forward, dQ, dK/dV), and in the compiled program each is a custom call whose
+    a ``jax.checkpoint`` that keeps nothing: Mosaic takes the four kernels (forward,
+    recomputed forward, dQ, dK/dV), and in the compiled program each is a custom call whose
     ``op_name`` the benchmark's ``attn.roofline`` reader finds under
     ``attn/<kind>/core``."""
     import re
@@ -246,10 +246,11 @@ def test_routed_layer_compiles_for_v5e_with_both_widths_under_one_conditional(on
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9  # 1.3e9 (compile, PR 31)
 
 
-def kimi_config():
+def cell_config(name: str):
+    """(the benchmark's configuration file, the program's configuration at its batch)."""
     from benchmark import harness
 
-    config = harness.read_json(harness.HERE, "configs", "kimi-vl-a3b-l6-ep8.json")
+    config = harness.read_json(harness.HERE, "configs", f"{name}.json")
     return config, harness.load_family(config).program_config(config, config["batch"][1])
 
 
@@ -265,7 +266,7 @@ def test_latent_attention_compiles_for_v5e_on_the_blocks_with_its_scopes(one_chi
     from benchmark import harness
     from tpu_resiliency.models import pattern
 
-    config, cfg = kimi_config()
+    config, cfg = cell_config("kimi-vl-a3b-l6-ep8")
     seq = config["batch"][1]
     assert pattern.attention_paths(cfg, seq) == {"latent": {
         "path": "blocks", "block": 1024, "score_width": 192, "value_width": 128}}
@@ -287,22 +288,49 @@ def test_latent_attention_compiles_for_v5e_on_the_blocks_with_its_scopes(one_chi
     assert sum(1 for name in names if latent.search(name)) >= 8
 
 
-def test_kimi_train_step_fits_one_chip(one_chip, as_on_a_tpu):
-    """The whole donating step of ``kimi-vl-a3b-l6-ep8`` at 1 x 8192, attention on the blocks:
-    668,890,432 parameters, 8.03e9 B of f32 weights and AdamW moments, and what the step
-    needs beside them inside one v5e's 15.75 GiB."""
+def pattern_step(config, cfg, one_chip):
+    """The whole donating step of a ``models/pattern.py`` configuration at its cell's
+    batch, compiled for one described chip, every layer keeping what
+    ``pattern.kept_residuals`` gives where no memory limit is stated (here as on the
+    chip at these shapes: the tier-1 tests pin that): (compiled, parameters, bytes the
+    step needs)."""
     from tpu_resiliency.models import pattern
 
-    config, cfg = kimi_config()
     train_step, init_opt = pattern.make_train_step(cfg)
     params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
     opt = jax.eval_shape(init_opt, params)
-    assert sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params)) == 668_890_432
     on_chip = lambda tree: placed(tree, jax.tree.map(lambda _: one_chip, tree))  # noqa: E731
     compiled = jax.jit(train_step, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(opt), sds(tuple(config["batch"]), jnp.int32, one_chip)).compile()
     mem = compiled.memory_analysis()
-    assert 8.0e9 < mem.argument_size_in_bytes < 8.1e9
     needed = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
               + mem.generated_code_size_in_bytes)
-    assert needed < 15.75 * 2 ** 30, needed  # 11.64e9 (compile, PR 32)
+    return compiled, sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params)), needed
+
+
+def test_kimi_train_step_fits_one_chip(one_chip, as_on_a_tpu):
+    """The whole donating step of ``kimi-vl-a3b-l6-ep8`` at 1 x 8192, attention on the blocks:
+    668,890,432 parameters, 8.03e9 B of f32 weights and AdamW moments, and what the step
+    needs beside them inside one v5e's 15.75 GiB, with every group of residuals kept."""
+    compiled, n_params, needed = pattern_step(*cell_config("kimi-vl-a3b-l6-ep8"), one_chip)
+    assert n_params == 668_890_432
+    assert 8.0e9 < compiled.memory_analysis().argument_size_in_bytes < 8.1e9
+    # 14.94e9 (compile, PR 33; 15.06e9 with nothing kept, PR 32)
+    assert needed < 15.75 * 2 ** 30, needed
+
+
+def test_laguna_train_step_fits_one_chip_and_runs_each_forward_kernel_once(one_chip, as_on_a_tpu):
+    """The whole donating step of ``laguna-xs2-l5-ep8`` at 1 x 8192, attention on the
+    kernels: 691,623,936 parameters, 8.30e9 B of state, every group of residuals kept
+    inside one v5e's 15.75 GiB; and the forward attention kernel is called once a layer,
+    5 times and not 10: its output and its log-sum-exp are kept, so the backward pass
+    does not run it again."""
+    compiled, n_params, needed = pattern_step(*cell_config("laguna-xs2-l5-ep8"), one_chip)
+    assert n_params == 691_623_936
+    assert 8.2e9 < compiled.memory_analysis().argument_size_in_bytes < 8.4e9
+    assert needed < 15.75 * 2 ** 30, needed  # 13.64e9 (compile, PR 33; 12.10e9 with nothing kept)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = {name: sum(1 for line in calls if f"blocked_attention_{name}" in line)
+               for name in ("fwd", "dq", "dkv")}
+    assert kernels == {"fwd": 5, "dq": 5, "dkv": 5}, kernels

@@ -32,6 +32,12 @@ Built TPU-first, static shapes throughout:
   chips that hold them or for the exchange with them. The rows moved are the head
   of the sorted order, twice the even-routing share of the experts held; a step
   whose router sends more here takes the full width (:func:`dispatch_rows`).
+- **A layer keeps what its backward pass reads, as far as the device's memory goes.**
+  Each layer runs under a ``jax.checkpoint`` whose policy keeps a list of named values
+  beside the layer's input (the router's choices and the sort's indices, the stream
+  after attention, the attention output and its log-sum-exp, q, k and v, the gate and
+  up products of the SwiGLUs) and recomputes the rest; :func:`kept_residuals` chooses
+  the list from the configuration, the tokens of a step and the device's memory.
 - **The description says how each leaf may be sharded** (:func:`describe_params`:
   logical axis names per dimension), so ``parallel/mesh.py`` derives the
   ``PartitionSpec`` tree and holds no key name of this model.
@@ -50,6 +56,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from tpu_resiliency.models import transformer as tfm
 from tpu_resiliency.ops import attention
@@ -449,6 +456,13 @@ def _window(cfg: PatternConfig, kind: str) -> Optional[int]:
     return cfg.window if kind == SLIDING else None
 
 
+def _widths(cfg: PatternConfig, kind: str) -> tuple[int, int]:
+    """(score width, value width) of a head of ``kind``."""
+    if kind == LATENT:
+        return cfg.latent.d_score, cfg.latent.d_value
+    return cfg.head_dim, cfg.head_dim
+
+
 def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     """Which path the attention products of each kind of layer take at sequences of
     ``seq``, from what the code can see (the backend, the widths, whether the sequence
@@ -462,8 +476,7 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
         if not cfg.count(kind):
             continue
         window = _window(cfg, kind)
-        score, value = ((cfg.latent.d_score, cfg.latent.d_value) if kind == LATENT
-                        else (cfg.head_dim, cfg.head_dim))
+        score, value = _widths(cfg, kind)
         if (jax.default_backend() == "tpu" and score == value
                 and attention.applies(seq, score, window)):
             paths[kind] = {"path": "kernel", "tile": attention.tile_of(seq, window)}
@@ -477,13 +490,18 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
 
 def _products(cfg: PatternConfig, kind: str, q, k, v):
     """Causal softmax attention of one layer by the path :func:`attention_paths` names:
-    q ``[B, T, H, dk]``, k ``[B, T, Hkv, dk]``, v ``[B, T, Hkv, dv]`` -> ``[B, T, H * dv]``."""
+    q ``[B, T, H, dk]``, k ``[B, T, Hkv, dk]``, v ``[B, T, Hkv, dv]`` -> ``[B, T, H * dv]``.
+    The operands and the result carry the names of :data:`KEPT_GROUPS` (the kernel names
+    its own output and log-sum-exp where it makes them)."""
+    q, k, v = (checkpoint_name(x, name) for x, name in zip((q, k, v), KEPT_GROUPS["qkv"]))
     with jax.named_scope("core"):
         if attention_paths(cfg, q.shape[1])[kind]["path"] == "kernel":
             return attention.blocked_attention(q, k, v, window=_window(cfg, kind))
         if kind == SLIDING:
-            return sliding_attention(q, k, v, cfg.window)
-        return full_attention(q, k, v, cfg.attn_block)
+            out = sliding_attention(q, k, v, cfg.window)
+        else:
+            out = full_attention(q, k, v, cfg.attn_block)
+    return checkpoint_name(out, attention.OUT_NAME)
 
 
 def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos, sin):
@@ -540,9 +558,13 @@ def _latent_block(cfg: PatternConfig, x, lp: dict, cos, sin):
 # the MLPs
 # ---------------------------------------------------------------------------------
 
-def _swiglu(y, w_gate, w_up, w_down):
-    gate = jax.nn.silu(y @ w_gate.astype(y.dtype))
-    return (gate * (y @ w_up.astype(y.dtype))) @ w_down.astype(y.dtype)
+def _swiglu(y, w_gate, w_up, w_down, names: Optional[tuple[str, str]] = None):
+    """SwiGLU of ``y``; with ``names``, the gate product (before its activation) and
+    the up product carry them."""
+    gate, up = y @ w_gate.astype(y.dtype), y @ w_up.astype(y.dtype)
+    if names is not None:
+        gate, up = checkpoint_name(gate, names[0]), checkpoint_name(up, names[1])
+    return (jax.nn.silu(gate) * up) @ w_down.astype(y.dtype)
 
 
 @jax.custom_vjp
@@ -569,8 +591,9 @@ def route(cfg: PatternConfig, y, w_router, bias=None):
     rule. Returns (weights ``[N, K]`` float32, experts ``[N, K]`` int32, and with a bias
     the count of chosen pairs that the scores alone would not have chosen and
     ``balance``, else ``None`` twice)."""
-    logits = jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
+    logits = checkpoint_name(
+        jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST), "route_logits")
     scores = jax.nn.sigmoid(logits)
     if bias is None:
         weights, experts = jax.lax.top_k(scores, cfg.top_k)
@@ -585,7 +608,8 @@ def route(cfg: PatternConfig, y, w_router, bias=None):
         over = jnp.where(load * cfg.n_experts > experts.size, 1.0, -1.0)
         balance = jnp.sum((bias - jax.lax.stop_gradient(bias)) * over)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * cfg.routed_scale
-    return weights, experts, by_bias, balance
+    return (checkpoint_name(weights, "route_weights"), checkpoint_name(experts, "route_experts"),
+            by_bias, balance)
 
 
 def _rows_at(rows, place):
@@ -687,6 +711,8 @@ def routed_experts(cfg: PatternConfig, y, lp: dict):
         order = jnp.argsort(key)  # stable: the pairs of one expert stay in token order
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(n * k, dtype=order.dtype), unique_indices=True)
+        order = checkpoint_name(order, "dispatch_order")
+        inverse = checkpoint_name(inverse, "dispatch_inverse")
         sorted_key = key[order]
         ends = jnp.searchsorted(sorted_key, jnp.arange(held + 1), side="left")
         group_sizes = jnp.diff(ends).astype(jnp.int32)  # [held]
@@ -746,12 +772,95 @@ def _mlp_block(cfg: PatternConfig, kind: str, x, lp: dict):
     y = tfm.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if kind == DENSE:
         with jax.named_scope("mlp/dense"):
-            return x + _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"]), None, None
+            mlp = _swiglu(y, lp["w_gate"], lp["w_up"], lp["w_down"], KEPT_GROUPS["dense"])
+            return x + mlp, None, None
     b, t, d = y.shape
     routed, counts, balance = routed_experts(cfg, y.reshape(b * t, d), lp)
     with jax.named_scope("moe/shared"):
-        shared = _swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        shared = _swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"], KEPT_GROUPS["shared"])
     return x + routed.reshape(b, t, d) + shared, counts, balance
+
+
+# ---------------------------------------------------------------------------------
+# what a layer keeps for its backward pass
+# ---------------------------------------------------------------------------------
+
+#: The named values (``jax.ad_checkpoint.checkpoint_name``) a layer may keep beside its
+#: input, by group, in the order :func:`kept_residuals` takes them: most device time
+#: bought for a byte kept first.
+KEPT_GROUPS = {
+    # the float32 router product, the sort of all pairs and the scatter that inverts it
+    "routing": ("route_logits", "route_weights", "route_experts",
+                "dispatch_order", "dispatch_inverse"),
+    # x + attention @ wo: the output matrix's forward product
+    "stream": ("attn_stream",),
+    # the attention products' forward; without the log-sum-exp the kernel runs again
+    "attention": (attention.OUT_NAME, attention.LSE_NAME),
+    # q and k after the rotary, v: the projections and the rotary
+    "qkv": ("attn_q", "attn_k", "attn_v"),
+    # the gate and up products of the shared expert, then of the dense MLP
+    "shared": ("shared_gate", "shared_up"),
+    "dense": ("dense_gate", "dense_up"),
+}
+
+
+def device_memory_bytes() -> Optional[int]:
+    """The memory of the device this process computes on, or ``None`` where the backend
+    states no limit (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int) -> dict:
+    """Bytes of each group of :data:`KEPT_GROUPS` in one layer over ``n_tokens`` tokens."""
+    act = jnp.dtype(cfg.dtype).itemsize
+    score, value = _widths(cfg, spec.attn)
+    kv_heads = spec.n_heads if spec.attn == LATENT else cfg.n_kv_heads
+    # the kernels take one width of whole lane groups; only they make a log-sum-exp
+    lse = 4 * spec.n_heads if score == value and not score % attention.LANES else 0
+    sparse = spec.mlp == SPARSE
+    return {
+        "routing": n_tokens * 4 * (cfg.n_experts + 4 * cfg.top_k) if sparse else 0,
+        "stream": n_tokens * cfg.d_model * act,
+        "attention": n_tokens * (spec.n_heads * value * act + lse),
+        "qkv": n_tokens * act * (spec.n_heads * score + kv_heads * (score + value)),
+        "shared": 2 * n_tokens * cfg.d_shared * act if sparse else 0,
+        "dense": 0 if sparse else 2 * n_tokens * cfg.d_ff * act,
+    }
+
+
+def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int]) -> dict:
+    """What each layer keeps for its backward pass beside its input, at ``n_tokens``
+    tokens a step on a device of ``memory_bytes`` (``None``: no limit stated, everything
+    is kept), from the configuration and the shapes alone: ``{"names": the names the
+    layers' ``jax.checkpoint`` keeps, "bytes": what they hold over all layers,
+    "per_layer": {group: [bytes in each layer]}, "step_bytes": what the step holds
+    anyway}``.
+
+    The groups of :data:`KEPT_GROUPS` are taken in order while the bytes kept and
+    ``step_bytes`` stay inside the memory, and the first that does not fit ends the
+    list: a longer step or a smaller device gets the shorter list, down to none
+    (``names`` empty: every layer recomputes its whole forward). ``step_bytes`` is
+    16 B a parameter (float32 weights, two moments, gradients), every layer's input,
+    the float32 logits with their cotangent, and all the groups of the largest layer
+    once (the layer whose backward pass runs holds them, kept or recomputed)."""
+    per_layer = [_group_bytes(cfg, spec, n_tokens) for spec in cfg.layers]
+    n_params = sum(math.prod(leaf.shape) for leaf in
+                   jax.tree.leaves(describe_params(cfg), is_leaf=_is_leaf))
+    step_bytes = (16 * n_params
+                  + len(cfg.layers) * n_tokens * cfg.d_model * jnp.dtype(cfg.dtype).itemsize
+                  + 2 * n_tokens * cfg.vocab_size * 4
+                  + max(sum(groups.values()) for groups in per_layer))
+    kept = {"names": [], "bytes": 0, "per_layer": {}, "step_bytes": step_bytes}
+    for group, names in KEPT_GROUPS.items():
+        layers = [groups[group] for groups in per_layer]
+        if (memory_bytes is not None
+                and step_bytes + kept["bytes"] + sum(layers) > memory_bytes):
+            break
+        kept["names"] += names
+        kept["bytes"] += sum(layers)
+        kept["per_layer"][group] = layers
+    return kept
 
 
 # ---------------------------------------------------------------------------------
@@ -770,7 +879,12 @@ def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
             x = _latent_block(cfg, x, attn_lp, *tables[LATENT])
         else:
             x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
-        return _mlp_block(cfg, spec.mlp, x, mlp_lp)
+        return _mlp_block(cfg, spec.mlp, checkpoint_name(x, "attn_stream"), mlp_lp)
+
+    # each layer keeps its input and the values named here for its backward pass, and
+    # recomputes the rest of its forward there
+    kept = kept_residuals(cfg, tokens.size, device_memory_bytes())
+    policy = jax.checkpoint_policies.save_only_these_names(*kept["names"])
 
     seen = dict.fromkeys((*ATTENTION_KINDS, DENSE, SPARSE), 0)
     counts, balance = [], None
@@ -779,9 +893,8 @@ def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
         mlp_lp = jax.tree.map(lambda w: w[seen[spec.mlp]], params["mlp"][spec.mlp])
         seen[spec.attn] += 1
         seen[spec.mlp] += 1
-        # each layer's forward is recomputed in the backward pass: only x is kept
-        x, layer_counts, layer_balance = jax.checkpoint(functools.partial(layer, spec=spec))(
-            x, attn_lp, mlp_lp)
+        x, layer_counts, layer_balance = jax.checkpoint(
+            functools.partial(layer, spec=spec), policy=policy)(x, attn_lp, mlp_lp)
         if layer_counts is not None:
             counts.append(layer_counts)
         if layer_balance is not None:

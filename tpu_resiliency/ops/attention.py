@@ -37,6 +37,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -51,6 +52,11 @@ LANES = 128
 #: masked gives exp(0) there and is wiped by the first real maximum (exp(MASKED - m) = 0)
 MASKED = -0.7 * float(np.finfo(np.float32).max)
 VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+#: the names (``jax.ad_checkpoint.checkpoint_name``) of the two residuals the forward
+#: kernel makes, for a caller whose ``jax.checkpoint`` policy keeps them: without the
+#: log-sum-exp kept, the backward pass runs the whole forward kernel again for it
+OUT_NAME, LSE_NAME = "attn_out", "attn_lse"
 
 _NT = (((1,), (1,)), ((), ()))  # [m, d] x [n, d] -> [m, n]
 
@@ -342,6 +348,7 @@ def _attention(q, k, v, window):
 
 def _attention_fwd(q, k, v, window):
     out, lse = _forward(q, k, v, window)
+    out, lse = checkpoint_name(out, OUT_NAME), checkpoint_name(lse, LSE_NAME)
     return out, (q, k, v, out, lse)
 
 
