@@ -5,8 +5,12 @@ and report what its routing does.
 different head counts with an output gate; ``--description latent`` has latent attention
 in every layer (keys and values decompressed from one normed latent, a rotary key part
 shared by all heads, scores wider than values), a router that chooses by score + a
-selection bias and weighs by the score, and two shared experts' width. Either has a
-leading dense MLP and sparse layers whose router scores every expert of a deployment
+selection bias and weighs by the score, and two shared experts' width;
+``--description indexed`` has in every layer grouped-query attention over the keys a
+small indexer selects for each query (an exact top-k of its scores; the indexer learns
+from a loss of its own, ``index_kl``), a softmax router, no shared expert and no dense
+layer. The first two have a leading dense MLP; all have sparse layers whose router
+scores every expert of a deployment
 while this process holds a contiguous range of them (``--experts-held FIRST COUNT``:
 one chip's share of an expert-parallel split; pairs routed to experts held elsewhere add
 nothing here). Every ``--routing-every`` steps the script runs the model's forward once
@@ -15,11 +19,15 @@ each sparse layer the (token, choice) pairs that landed on held experts, the lar
 and the mean load of a held expert, the pairs dropped (always 0) and the rows the
 dispatch carried (twice the even share of the experts held, or every pair in a step
 whose router sent more than that here), and under a selection bias ``chosen_by_bias``,
-the pairs (of all of them) whose expert the scores alone would not have chosen. Once, before the first step, it emits an
+the pairs (of all of them) whose expert the scores alone would not have chosen; and for
+each indexed layer ``index_kl`` (its indexer's loss), ``keys_selected`` and
+``select_ties`` (queries whose last selected score equals the next). Once, before the
+first step, it emits an
 ``attention_path`` event: for each kind of attention layer, whether its products run
 as the blocked kernels of ``ops/attention.py`` (on a TPU, at shapes that tile) or as
 the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block,
-and for the latent kind its ``score_width`` and ``value_width``;
+for the latent kind its ``score_width`` and ``value_width``, for the indexed kind how many
+keys a query keeps;
 and a ``dispatch_path`` event: the rows the expert dispatch carries at this batch
 (``pattern.dispatch_rows``), ``bounded`` or ``full``; and a ``kept_residuals`` event:
 the named values each layer keeps for its backward pass at this batch on this device's
@@ -50,7 +58,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cpu", action="store_true",
                     help="simulate on the CPU (without it $JAX_PLATFORMS / JAX decide)")
-    ap.add_argument("--description", choices=("mixed", "latent"), default="mixed",
+    ap.add_argument("--description", choices=("mixed", "latent", "indexed"), default="mixed",
                     help="which pattern of layers to train (see the module docstring)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, nargs=2, default=(2, 64), metavar=("B", "T"))
@@ -69,7 +77,8 @@ def main() -> None:
     from tpu_resiliency.models import pattern
     from tpu_resiliency.utils import events
 
-    preset = {"mixed": pattern.PatternConfig.tiny, "latent": pattern.PatternConfig.tiny_latent}
+    preset = {"mixed": pattern.PatternConfig.tiny, "latent": pattern.PatternConfig.tiny_latent,
+              "indexed": pattern.PatternConfig.tiny_indexed}
     cfg = preset[args.description](experts_held=tuple(args.experts_held))
     train_step, init_opt = pattern.make_train_step(cfg)
     step = jax.jit(train_step, donate_argnums=(0, 1))
@@ -84,7 +93,7 @@ def main() -> None:
     events.record("model", "dispatch_path", tokens=n_tokens, **dispatch)
     print(f"DISPATCH {dispatch}", flush=True)
     memory = pattern.device_memory_bytes()
-    kept = pattern.kept_residuals(cfg, n_tokens, memory)
+    kept = pattern.kept_residuals(cfg, n_tokens, memory, args.batch[1])
     events.record("model", "kept_residuals", tokens=n_tokens, memory_bytes=memory, **kept)
     print(f"KEPT {kept}", flush=True)
 

@@ -1,9 +1,12 @@
-"""Pattern-of-layers model (models/pattern.py), in both descriptions the benchmark
+"""Pattern-of-layers model (models/pattern.py), in the three descriptions the benchmark
 runs (window and full grouped-query layers with a gate; latent layers under a router
-with a selection bias): the program against the benchmark's plain references on loss and
-every gradient leaf; each attention kind against plain masked attention, the latent
-layer against attention written the long way; the experts' shares against the uncut
-layer; a bias that changes the choice and never a weight; routing that drops nothing;
+with a selection bias; indexed layers, whose keys an indexer selects and whose indexer
+learns from a loss of its own, under a softmax router with no shared expert): the program
+against the benchmark's plain references on loss and every gradient leaf; each attention
+kind against plain masked attention, the latent layer against attention written the long
+way, the indexed layer against its reference's and its selection against a sort; the
+experts' shares against the uncut layer; a bias that changes the choice and never a
+weight; routing that drops nothing;
 what the layers keep for the backward pass against keeping nothing, and the list by
 tokens and memory; derived parameter specs on a mesh; the state through the local
 checkpoint; the scopes the benchmark's readers look for in the lowered step."""
@@ -30,10 +33,13 @@ if ROOT not in sys.path:
 SEQ = 37  # longer than the window (8), a multiple of neither block (8, 16)
 
 
-#: the two descriptions: the configuration file whose family and reference go with
+#: the three descriptions: the configuration file whose family and reference go with
 #: each, and the model's own tiny preset of it
 DESCRIPTIONS = {"mixed": ("laguna-xs2-l5-ep8", pattern.PatternConfig.tiny),
-                "latent": ("kimi-vl-a3b-l6-ep8", pattern.PatternConfig.tiny_latent)}
+                "latent": ("kimi-vl-a3b-l6-ep8", pattern.PatternConfig.tiny_latent),
+                "indexed": ("keye-vl2-30b-a3b-l6-ep8", pattern.PatternConfig.tiny_indexed)}
+#: the leaves of an indexed layer that only the indexer's own loss reaches
+INDEXER_LEAVES = ("wq_index", "wk_index", "ww_index", "k_index_norm")
 
 
 @functools.cache
@@ -64,8 +70,10 @@ def exact_of(description: str):
         params = pattern.init_params(jax.random.PRNGKey(5), cfg)
         ref_params = reference.init_params(5, config)
         got = jax.jit(jax.value_and_grad(lambda p: pattern.loss_fn(p, tokens, cfg)))(params)
+        # the reference on its own choices (family ``keye`` hands it the bfloat16
+        # program's): in float32 on both sides they are the same
         want = jax.jit(jax.value_and_grad(
-            lambda p: reference.loss(p, tokens, config, "f32")))(ref_params)
+            lambda p: reference.loss(p, tokens, {**config, "choices": None}, "f32")))(ref_params)
     return params, ref_params, got, want
 
 
@@ -203,6 +211,173 @@ def test_latent_layer_equals_attention_written_the_long_way(seq):
     assert set(got_grad[1]) == {"attn_norm", "wq", "wkv_a", "kv_norm", "wkv_b", "wo"}
     for a, b in zip(jax.tree.leaves(got_grad), jax.tree.leaves(want_grad)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def indexed_layer(cfg, seed=0, seq=SEQ):
+    """One indexed layer's weights (float32), its input and its two rotary tables."""
+    lp = jax.tree.map(lambda w: w[0], pattern.init_params(
+        jax.random.PRNGKey(seed), cfg)["attn"][pattern.INDEXED])
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, seq, cfg.d_model))
+    tables = (pattern.rope_tables(cfg.rope_indexed, cfg.head_dim, seq)
+              + pattern.rope_tables(cfg.rope_indexed, cfg.indexer.head_dim, seq))
+    return lp, x, tables
+
+
+@pytest.mark.parametrize("seq", [37, 16, 7])
+def test_indexed_layer_equals_the_references_layer(seq):
+    """The stream after attention and the indexer's own loss, and the gradient of each by
+    the input and by each of the eleven leaves, against the reference's layer (index
+    scores of every key, a sort, a mask, a softmax over the masked row) on seeded weights,
+    at lengths over, at and under a query block and over and under the 12 keys kept. The
+    indexer's four leaves get their gradient from its loss alone, and no other leaf does;
+    the selection passes nothing."""
+    config, _, reference = tiny_file_of("indexed")
+    cfg = pattern.PatternConfig.tiny_indexed(dtype=jnp.float32)
+    lp, x, tables = indexed_layer(cfg, seq=seq)
+    weight = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def program(x, lp):
+        out, counts, _ = pattern._indexed_block(cfg, x, lp, *tables)
+        return jnp.sum(out * weight), counts["index_kl"]
+
+    def plain(x, lp):
+        added, divergence = reference.attention(x, lp, config, "f32")[:2]
+        return jnp.sum((x + added) * weight), divergence
+
+    with jax.default_matmul_precision("highest"):
+        for term in (0, 1):
+            got, got_grad = jax.jit(jax.value_and_grad(
+                lambda x, lp: program(x, lp)[term], argnums=(0, 1)))(x, lp)  # noqa: B023
+            want, want_grad = jax.jit(jax.value_and_grad(
+                lambda x, lp: plain(x, lp)[term], argnums=(0, 1)))(x, lp)  # noqa: B023
+            assert abs(float(got) - float(want)) < (1e-3 if term == 0 else 1e-6)
+            for a, b in zip(jax.tree.leaves(got_grad), jax.tree.leaves(want_grad)):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+            reached = {name for name, g in got_grad[1].items() if float(jnp.max(jnp.abs(g))) > 0}
+            assert reached == (set(INDEXER_LEAVES) if term else set(lp) - set(INDEXER_LEAVES))
+            assert bool(jnp.any(got_grad[0] != 0)) == (term == 0)  # the indexer's input is detached
+
+
+def selection_by_sort(scores: np.ndarray, start: int, top_k: int) -> np.ndarray:
+    """Each row's ``top_k`` keys ``s <= t`` by (score descending, position ascending)."""
+    mask = np.zeros(scores.shape, bool)
+    for index in np.ndindex(scores.shape[:-1]):
+        t = start + index[-1]
+        row = scores[index][:t + 1]
+        order = np.lexsort((np.arange(t + 1), -row))
+        mask[index][order[:top_k]] = True
+    return mask
+
+
+@pytest.mark.parametrize("top_k", [5, 12, 37, 50])
+@pytest.mark.parametrize("ties", ["none", "many", "zeros"])
+def test_selection_equals_a_sorts_ties_included(top_k, ties):
+    """:func:`select_keys` against a sort of every row, at a ``top_k`` smaller than, equal
+    to and larger than the sequence of 37: continuous scores; scores of five values, so
+    that nearly every row's ``top_k``-th is tied with its neighbours; and scores that are
+    zero of either sign in two places of three (a ReLU's). Blocks of 16 rows against
+    their causal prefix, as the layer runs them, and the rows whose ``top_k``-th and next
+    scores are equal are counted as such."""
+    rng = np.random.default_rng(top_k)
+    scores = rng.normal(size=(2, 48, 48)).astype(np.float32)
+    if ties == "many":
+        scores = np.round(scores)
+    if ties == "zeros":
+        scores = np.where(rng.random(scores.shape) < 2 / 3,
+                          np.where(rng.random(scores.shape) < 0.5, -0.0, 0.0), scores).astype(np.float32)
+    for start in (0, 16, 32):
+        end = start + 16
+        block = scores[:, start:end, :end]
+        mask, tied = jax.jit(lambda a: pattern.select_keys(a, start, top_k))(block)  # noqa: B023
+        want = selection_by_sort(block, start, top_k)
+        np.testing.assert_array_equal(np.asarray(mask), want)
+        for index in np.ndindex(block.shape[:-1]):
+            t = start + index[-1]
+            row = np.sort(block[index][:t + 1])[::-1]
+            assert bool(tied[index]) == (t + 1 > top_k and row[top_k - 1] == row[top_k]), index
+    if ties == "many" and top_k < 37:
+        assert int(jnp.sum(tied)) > 0
+
+
+@pytest.mark.parametrize("description,terms", [("indexed", "lm"), ("indexed", "index")])
+def test_the_two_losses_meet_on_no_leaf(description, terms):
+    """The whole model's loss is the cross-entropy plus the mean of the layers'
+    ``index_kl``: with the indexer's loss taken out, the indexer's four leaves get a
+    gradient of exactly zero and every other leaf its whole gradient; the indexer's loss
+    alone reaches those four and nothing else."""
+    cfg = DESCRIPTIONS[description][1](dtype=jnp.float32)
+    params = pattern.init_params(jax.random.PRNGKey(2), cfg)
+    tokens = jnp.asarray(np.random.default_rng(2).integers(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+
+    def parts(p):
+        loss, counts = pattern.loss_and_counts(p, tokens, cfg)
+        own = jnp.mean(counts["index_kl"])
+        return {"lm": loss - own, "index": own, "both": loss}
+
+    grads = {name: jax.jit(jax.grad(lambda p: parts(p)[name]))(params)  # noqa: B023
+             for name in (terms, "both")}
+    flat = lambda tree: {jax.tree_util.keystr(path): g for path, g in  # noqa: E731
+                         jax.tree_util.tree_flatten_with_path(tree)[0]}
+    part, both = flat(grads[terms]), flat(grads["both"])
+    for path, g in part.items():
+        indexer = any(f"['{leaf}']" in path for leaf in INDEXER_LEAVES)
+        if indexer == (terms == "index"):
+            assert float(jnp.max(jnp.abs(g))) > 0, path
+            np.testing.assert_allclose(np.asarray(g), np.asarray(both[path]), rtol=1e-5, atol=1e-9)
+        else:
+            assert float(jnp.max(jnp.abs(g))) == 0.0, path
+
+
+@pytest.mark.parametrize("shares", [1, 2, 4, 8])
+def test_shares_of_an_indexed_layer_add_up_to_the_uncut_reference_layer(shares):
+    """A whole layer of the third description: attention under the indexer's selection,
+    which every chip computes alike, counted once, plus the routed parts of all the shares
+    (16 experts over 1, 2, 4 and 8 chips; no shared expert to count once), equal the
+    reference's layer with every expert held; the router is a softmax over all 16."""
+    config, _, reference = tiny_file_of("indexed")
+    experts = 16
+    cfg = pattern.PatternConfig.tiny_indexed(dtype=jnp.float32,
+                                             experts_held=(0, experts // shares))
+    whole = dataclasses.replace(cfg, experts_held=(0, experts))
+    params = pattern.init_params(jax.random.PRNGKey(4), whole)
+    lp = jax.tree.map(lambda w: w[0], params["mlp"][pattern.SPARSE])
+    assert not any(name.startswith("ws_") for name in lp)
+    attn_lp, x, tables = indexed_layer(whole, seed=4)
+    held = experts // shares
+    uncut = {**config, "num_experts": experts, "num_local_experts": experts,
+             "deployment": {"num_experts": experts, "experts_held": [0, experts]}}
+    with jax.default_matmul_precision("highest"):
+        x1, *_ = pattern._indexed_block(cfg, x, attn_lp, *tables)
+        y = pattern.tfm.rms_norm(x1, lp["mlp_norm"], cfg.norm_eps)
+        total = x1
+        for s in range(shares):
+            part = dataclasses.replace(cfg, experts_held=(s * held, held))
+            routed, counts, balance = pattern.routed_experts(
+                part, y.reshape(-1, cfg.d_model), share_of(lp, s * held, held))
+            total = total + routed.reshape(x.shape)
+            assert int(counts["dropped"]) == 0 and balance is None
+        x1_ref = x + reference.attention(x, attn_lp, uncut, "f32")[0]
+        want = x1_ref + reference.sparse_mlp(
+            reference.rms_norm(x1_ref, lp["mlp_norm"], cfg.norm_eps), lp, uncut, "f32")[0]
+        np.testing.assert_allclose(
+            np.asarray(pattern._mlp_block(whole, pattern.SPARSE, x1, lp)[0]), np.asarray(want),
+            atol=5e-5)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=5e-5)
+
+
+def test_softmax_routing_is_the_renormalised_top_of_a_softmax_over_all_experts():
+    cfg = pattern.PatternConfig.tiny_indexed(dtype=jnp.float32)
+    lp, y = sparse_layer(cfg, seed=6)
+    weights, experts, by_bias, balance = pattern.route(cfg, y, lp["w_router"])
+    probs = np.asarray(jax.nn.softmax(jnp.matmul(y, lp["w_router"], precision="highest"), -1))
+    want = np.argsort(-probs, axis=-1)[:, :cfg.top_k]
+    assert by_bias is None and balance is None
+    np.testing.assert_array_equal(np.asarray(experts), want)
+    chosen = np.take_along_axis(probs, want, -1)
+    np.testing.assert_allclose(np.asarray(weights), chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(probs.sum(-1), 1.0, rtol=1e-6)
+    sigmoid = pattern.route(dataclasses.replace(cfg, route_score=pattern.SIGMOID), y, lp["w_router"])
+    assert not np.allclose(np.asarray(sigmoid[0]), np.asarray(weights))
 
 
 def sparse_layer(cfg, seed=0):
@@ -579,7 +754,12 @@ def test_the_forward_names_what_the_groups_list(description):
     jaxpr = jax.make_jaxpr(lambda p, t: pattern.loss_fn(p, t, cfg))(
         params, jax.ShapeDtypeStruct((2, SEQ), jnp.int32)).jaxpr
     named = {eqn.params["name"] for eqn in equations(jaxpr) if eqn.primitive.name == "name"}
-    assert named == set(ALL_NAMES) - {"attn_lse"}  # the blocks make no log-sum-exp
+    listed = set(pattern.kept_residuals(cfg, 2 * SEQ, None, SEQ)["names"])
+    assert named == listed - {"attn_lse"}  # the blocks make no log-sum-exp
+    if description == "indexed":  # no shared expert, no dense layer
+        assert listed == set(ALL_NAMES) - {"shared_gate", "shared_up", "dense_gate", "dense_up"}
+    else:  # every group but the two that only an indexer makes
+        assert listed == set(ALL_NAMES) - {"select_mask", "index_q", "index_w", "index_k"}
 
 
 def cell_config(description: str, seq: int) -> pattern.PatternConfig:
@@ -598,6 +778,16 @@ V5E_BYTES = 16.9e9  # one v5e's ``bytes_limit``
     # the two cells: every group, 1.93e9 and 2.05e9 B beside 12.6e9 and 12.8e9 of step
     ("mixed", 8192, V5E_BYTES, 6, 1_926_234_112),
     ("latent", 8192, V5E_BYTES, 6, 2_052_849_664),
+    # the third: its six groups (no shared expert, no dense layer), 1.48e9 B beside 12.2e9:
+    # a layer keeps 38 MB of mask (three groups of 2,048 rows against 4,096, 6,144 and 8,192
+    # keys; the first 2,048 rows keep every key), 5 of routing, 34 of stream, 67 of output,
+    # 84 of q, k, v and 18 of the indexer's q, k and weights
+    ("indexed", 8192, V5E_BYTES, 6, 6 * (37_748_736 + 5_242_880 + 33_554_432 + 67_108_864
+                                         + 83_886_080 + 18_350_080)),
+    ("indexed", 8192, 12.5e9, 2, 6 * (37_748_736 + 5_242_880)),  # routing, then the selection
+    # one sequence of 16,384: four groups of 4,096 rows, all of which select
+    ("indexed", 16384, V5E_BYTES, 4, 6 * (10_485_760 + 4096 * (4096 + 8192 + 12288 + 16384)
+                                          + 67_108_864 + 134_217_728)),
     ("mixed", 8192, None, 6, 1_926_234_112),  # no limit stated (the CPU): as the chip
     # twice the tokens on the same chip: q, k, v and the SwiGLUs' products no longer fit
     # (laguna compiles to 15.08e9 B so, and to 16.89e9 of the chip's 16.91e9 with all kept)
@@ -615,7 +805,11 @@ V5E_BYTES = 16.9e9  # one v5e's ``bytes_limit``
 def test_kept_residuals_by_tokens_and_memory(description, tokens, memory, groups, kept_bytes):
     cfg = cell_config(description, tokens)
     kept = pattern.kept_residuals(cfg, tokens, memory)
-    taken = list(pattern.KEPT_GROUPS.items())[:groups]
+    # the groups that hold anything in this description, in the order of KEPT_GROUPS
+    holds = {"mixed": set(pattern.KEPT_GROUPS) - {"selection", "index"},
+             "latent": set(pattern.KEPT_GROUPS) - {"selection", "index"},
+             "indexed": set(pattern.KEPT_GROUPS) - {"shared", "dense"}}[description]
+    taken = [(g, names) for g, names in pattern.KEPT_GROUPS.items() if g in holds][:groups]
     assert kept["names"] == [name for _, names in taken for name in names]
     assert list(kept["per_layer"]) == [group for group, _ in taken]
     assert all(len(layers) == len(cfg.layers) for layers in kept["per_layer"].values())
@@ -634,7 +828,7 @@ def test_kept_residuals_of_the_laguna_cell_layer_by_layer():
     and v, a sliding one (64 heads) 136 and 168; a sparse layer 9 MB of routing and 17 MB
     of the shared expert's products, the dense layer 268 MB of its own."""
     kept = pattern.kept_residuals(cell_config("mixed", 8192), 8192, V5E_BYTES)
-    assert kept["names"] == ALL_NAMES
+    assert kept["names"] == [name for name in ALL_NAMES if "select" not in name and "index" not in name]
     assert kept["per_layer"] == {
         "routing": [0] + [8192 * 4 * (256 + 4 * 8)] * 4,
         "stream": [8192 * 2048 * 2] * 5,
@@ -668,6 +862,12 @@ def test_derived_specs_on_a_mesh_give_the_one_chip_loss(axes, description):
         assert latent["wq"] == latent["wkv_b"] == jax.sharding.PartitionSpec(None, None, "tp")
         assert latent["wo"] == jax.sharding.PartitionSpec(None, "tp", None)
         assert specs["mlp"]["sparse"]["b_router"] == jax.sharding.PartitionSpec(None, None)
+    if description == "indexed":  # the indexer and the head norms replicate, like the router
+        indexed = specs["attn"]["indexed"]
+        for name in (*INDEXER_LEAVES, "q_norm", "k_norm"):
+            assert all(axis is None for axis in indexed[name]), name
+        assert indexed["wq"] == indexed["wk"] == jax.sharding.PartitionSpec(None, None, "tp")
+        assert "ws_gate" not in specs["mlp"]["sparse"] and "dense" not in specs["mlp"]
     sharded = jax.device_put(params, pmesh.tree_shardings(mesh, specs))
     assert len(sharded["mlp"]["sparse"]["we_up"].sharding.device_set) == 8
     with mesh:
@@ -692,15 +892,68 @@ def test_forward_counts_and_causality():
     assert not np.allclose(np.asarray(l1[0, 30:]), np.asarray(l2[0, 30:]), atol=1e-3)
 
 
+def test_indexed_counts_are_one_value_a_layer_and_the_keys_selected_are_what_the_shapes_fix():
+    cfg = pattern.PatternConfig.tiny_indexed()
+    params = pattern.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (3, SEQ), 0, cfg.vocab_size)
+    loss, counts = jax.jit(lambda p, t: pattern.loss_and_counts(p, t, cfg))(params, tokens)
+    layers = cfg.count(pattern.INDEXED)
+    for name in ("index_kl", "keys_selected", "select_ties", "pairs_held", "dropped"):
+        assert counts[name].shape == (layers,), name
+    assert "chosen_by_bias" not in counts
+    want = 3 * sum(min(t + 1, cfg.indexer.top_k) for t in range(SEQ))
+    assert [int(n) for n in counts["keys_selected"]] == [want] * layers
+    assert bool(jnp.all(counts["index_kl"] > 0)) and bool(jnp.all(counts["select_ties"] >= 0))
+    nll = float(loss) - float(jnp.mean(counts["index_kl"]))
+    assert 5.0 < nll < 6.5  # near log(256): the indexer's loss is a term with a value
+
+
+@pytest.mark.parametrize("description", list(DESCRIPTIONS))
+def test_choices_are_what_the_forward_pass_chose(description):
+    """``pattern.choices`` says which keys each indexed layer read and which experts each
+    sparse layer took: a key of its result only where the pattern has such layers, as many
+    keys a query as the counts say, and in float32 the reference's own choices on the
+    same weights (which is what lets the reference be compared on the program's)."""
+    config, family, reference = tiny_file_of(description)
+    cfg = dataclasses.replace(family.program_config(config, SEQ), dtype=jnp.float32)
+    params = pattern.init_params(jax.random.PRNGKey(5), cfg)
+    tokens = jnp.asarray(np.random.default_rng(5).integers(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        chose = jax.jit(lambda p, t: pattern.choices(p, t, cfg))(params, tokens)
+        counts = jax.jit(lambda p, t: pattern.forward(p, t, cfg)[1])(params, tokens)
+    assert set(chose) == {"experts"} | ({"selected"} if cfg.count(pattern.INDEXED) else set())
+    experts = np.asarray(chose["experts"])
+    assert experts.shape == (cfg.count(pattern.SPARSE), 2, SEQ, cfg.top_k)
+    first, held = cfg.experts_held
+    np.testing.assert_array_equal(
+        ((experts >= first) & (experts < first + held)).sum(axis=(1, 2, 3)),
+        np.asarray(counts["pairs_held"]))
+    if description != "indexed":
+        return
+    selected = np.asarray(chose["selected"])
+    assert selected.shape == (cfg.count(pattern.INDEXED), 2, SEQ, SEQ) and selected.dtype == bool
+    assert not np.triu(selected, 1).any()  # no key after its query
+    np.testing.assert_array_equal(selected.sum(axis=(1, 2, 3)), np.asarray(counts["keys_selected"]))
+    with jax.default_matmul_precision("highest"):
+        own = jax.jit(lambda p, t: reference.forward(p, t, {**config, "choices": None}, "f32")[2])(
+            reference.init_params(5, config), tokens)
+    np.testing.assert_array_equal(selected, np.asarray(own["selected"]))
+    np.testing.assert_array_equal(np.sort(experts, -1), np.sort(np.asarray(own["experts"]), -1))
+
+
 def test_description_rejects_what_cannot_be_stacked():
     with pytest.raises(ValueError, match="head count"):
         pattern.PatternConfig.tiny(layers=(
             pattern.Layer("full", 6, "dense"), pattern.Layer("full", 4, "sparse")))
     with pytest.raises(ValueError, match="experts_held"):
         pattern.PatternConfig.tiny(experts_held=(14, 4))
+    with pytest.raises(ValueError, match="indexer"):
+        pattern.PatternConfig.tiny_indexed(indexer=None)
+    with pytest.raises(ValueError, match="router score"):
+        pattern.PatternConfig.tiny_indexed(route_score="tanh")
 
 
-@pytest.mark.parametrize("description,leaves", [("mixed", 27), ("latent", 22)])
+@pytest.mark.parametrize("description,leaves", [("mixed", 27), ("latent", 22), ("indexed", 19)])
 def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path, description, leaves):
     """Per-kind stacks and the held experts' leaves through
     ``checkpoint/local_manager.py``: the restored state gives the next loss exactly."""
@@ -729,27 +982,38 @@ def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path, desc
     mgr.close()
 
 
-def test_the_lowered_step_carries_the_scopes_the_readers_look_for():
-    """The second description's train step, lowered (nothing compiles): its ops' names
-    hold ``attn/full``, ``attn/full/core`` and ``moe/*`` as the accepted readers'
-    patterns want them, and ``attn/full/latent`` as the new reader's does; what is under
-    the new scope is under ``attn`` too and never under ``core``."""
+@pytest.mark.parametrize("description", ["latent", "indexed"])
+def test_the_lowered_step_carries_the_scopes_the_readers_look_for(description):
+    """The second and the third description's train step, lowered (nothing compiles): its
+    ops' names hold ``attn/full``, ``attn/full/core`` and ``moe/*`` as the accepted
+    readers' patterns want them, and ``attn/full/latent``, or ``attn/full/indexer`` and
+    ``attn/full/select``, as the new readers' do; what is under a new scope is under
+    ``attn`` too and never under ``core``."""
     from benchmark import harness
 
     scopes = harness.load_by_path("layer_metrics", "scope_times").SCOPES
-    latent = harness.load_by_path("layer_metrics", "attn.latent_ms").SCOPE
-    cfg = pattern.PatternConfig.tiny_latent()
+    if description == "latent":
+        own = {"latent": harness.load_by_path("layer_metrics", "attn.latent_ms").SCOPE}
+    else:
+        own = harness.load_by_path("layer_metrics", "attn.indexer_ms").SCOPES
+        assert set(own) == {"indexer", "select"}
+    cfg = DESCRIPTIONS[description][1]()
     train_step, init_opt = pattern.make_train_step(cfg)
     params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
     text = jax.jit(train_step).trace(
         params, jax.eval_shape(init_opt, params), jax.ShapeDtypeStruct((2, SEQ), jnp.int32)
     ).lower().as_text(debug_info=True)
     names = set(re.findall(r'loc\("([^"]*)"', text))
-    under = {key: {n for n in names if mark.search(n)} for key, mark in scopes.items()}
-    under["latent"] = {n for n in names if latent.search(n)}
+    under = {key: {n for n in names if mark.search(n)} for key, mark in {**scopes, **own}.items()}
+    if description == "indexed":  # no shared expert: the other moe scopes are all there
+        assert not any("moe/shared" in n for n in names)
     assert all(under.values()), {k: len(v) for k, v in under.items()}
-    assert under["latent"] <= under["attn"] and not under["latent"] & under["attn_core"]
+    for key in own:
+        assert under[key] <= under["attn"] and not under[key] & under["attn_core"], key
     assert under["attn_core"] <= under["attn"] and under["moe_experts"] <= under["moe"]
     for phase in ("jvp(", "transpose("):  # the first forward and the backward alike
-        assert any(phase in n for n in under["latent"]), phase
-    assert any(n.endswith("dot_general") for n in under["latent"])
+        assert any(phase in n for n in under[next(iter(own))]), phase
+    assert any(n.endswith("dot_general") for n in under[next(iter(own))])
+    if description == "indexed":  # the mask is kept: the selection runs in the first forward alone
+        assert all("jvp(" in n and "transpose(" not in n for n in under["select"])
+        assert not under["select"] & under["indexer"]

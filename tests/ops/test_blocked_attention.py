@@ -169,6 +169,23 @@ def test_attention_paths_of_the_latent_kind_on_a_tpu_follow_the_shapes(as_on_a_t
     assert pattern.attention_paths(equal, 512 + 128)["latent"]["path"] == "blocks"
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_attention_paths_of_the_indexed_kind_are_the_blocks_everywhere(monkeypatch, backend):
+    """The kernels compute their mask from positions and an indexed layer's is data: at
+    heads of 128 and 8,192 tokens, shapes the kernels tile, the kind still takes the
+    blocks, on a TPU too, and says how many keys a query keeps."""
+    from benchmark import harness
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    config = harness.read_json(harness.HERE, "configs", "keye-vl2-30b-a3b-l6-ep8.json")
+    cfg = harness.load_family(config).program_config(config, 8192)
+    assert attention.applies(8192, cfg.head_dim, None)
+    assert pattern.attention_paths(cfg, 8192) == {"indexed": {
+        "path": "blocks", "block": 512, "selected": 2048, "selection": "mask"}}
+    assert pattern.attention_paths(pattern.PatternConfig.tiny_indexed(), 8) == {"indexed": {
+        "path": "blocks", "block": 8, "selected": 8, "selection": "mask"}}
+
+
 def test_attention_paths_off_the_tpu_are_the_blocks():
     tiny = pattern.PatternConfig.tiny()
     assert pattern.attention_paths(tiny, 40) == {

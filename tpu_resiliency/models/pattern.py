@@ -3,13 +3,18 @@
 Where ``transformer.py`` and ``moe.py`` scan one homogeneous stacked layer, this
 model is a list of layers, each an attention kind and an MLP kind. Attention:
 ``full`` or ``sliding`` (grouped-query, with an output gate, each with its own head
-count and rotary table), or ``latent`` (:class:`Latent`: keys and values of every head
+count and rotary table), ``latent`` (:class:`Latent`: keys and values of every head
 decompressed from one normed low-rank latent, one rotary key part shared by all heads,
-a score width that differs from the value width, no gate). MLP: ``dense`` SwiGLU, or
-``sparse``: a float32 router over all experts of the deployment (chosen by score, or
-by score plus a selection bias that never enters a weight and that the loss-free
-balancing rule moves), the top-k routed experts that this chip holds, and one shared
-SwiGLU (several shared experts are one SwiGLU of their summed width). Parameters are
+a score width that differs from the value width, no gate), or ``indexed``
+(:class:`Indexer`: grouped-query heads with a norm on each head's q and k and no gate,
+over the keys that a second, small set of heads chooses for each query from the data:
+an exact top-k of their scores, the same set for all heads; the indexer is taught by a
+loss of its own). MLP: ``dense`` SwiGLU, or ``sparse``: a float32 router over all
+experts of the deployment (sigmoid or softmax scores; chosen by score, or by score plus
+a selection bias that never enters a weight and that the loss-free balancing rule
+moves), the top-k routed experts that this chip holds, and one shared SwiGLU where the
+description has one (several shared experts are one SwiGLU of their summed width).
+Parameters are
 stacked per kind; the layers run in the order the description gives (a Python loop:
 the kinds differ in shape, so there is no single body to scan).
 
@@ -22,9 +27,11 @@ Built TPU-first, static shapes throughout:
   path a shape takes). Off the TPU, at shapes that do not tile, or where the score
   width differs from the value width (latent attention), the blocks below are plain
   ``jax.numpy``: sliding layers compute the band (query blocks of one window against
-  their own and the previous key block), full and latent layers go by query blocks
-  against the causal prefix of the keys, and all loop over the KV heads with the
-  block's scores recomputed in the backward pass.
+  their own and the previous key block), full, latent and indexed layers go by query
+  blocks against the causal prefix of the keys, and all loop over the KV heads with the
+  block's scores recomputed in the backward pass. An indexed layer's key sets are a
+  mask that is an operand of its blocks (one block of index scores, one of the mask
+  and one of the heads' mean probabilities at a time).
 - **Routing drops nothing.** Every (token, choice) pair whose expert this chip holds
   is computed: the pairs are sorted by expert and the three SwiGLU products run as
   grouped products over the ragged groups (``jax.lax.ragged_dot``). Pairs for
@@ -34,9 +41,10 @@ Built TPU-first, static shapes throughout:
   whose router sends more here takes the full width (:func:`dispatch_rows`).
 - **A layer keeps what its backward pass reads, as far as the device's memory goes.**
   Each layer runs under a ``jax.checkpoint`` whose policy keeps a list of named values
-  beside the layer's input (the router's choices and the sort's indices, the stream
-  after attention, the attention output and its log-sum-exp, q, k and v, the gate and
-  up products of the SwiGLUs) and recomputes the rest; :func:`kept_residuals` chooses
+  beside the layer's input (the router's choices and the sort's indices, the keys an
+  indexer selected, the stream after attention, the attention output and its
+  log-sum-exp, q, k and v, the gate and up products of the SwiGLUs) and recomputes the
+  rest; :func:`kept_residuals` chooses
   the list from the configuration, the tokens of a step and the device's memory.
 - **The description says how each leaf may be sharded** (:func:`describe_params`:
   logical axis names per dimension), so ``parallel/mesh.py`` derives the
@@ -61,9 +69,10 @@ from jax.ad_checkpoint import checkpoint_name
 from tpu_resiliency.models import transformer as tfm
 from tpu_resiliency.ops import attention
 
-FULL, SLIDING, LATENT = "full", "sliding", "latent"
-ATTENTION_KINDS = (FULL, SLIDING, LATENT)
+FULL, SLIDING, LATENT, INDEXED = "full", "sliding", "latent", "indexed"
+ATTENTION_KINDS = (FULL, SLIDING, LATENT, INDEXED)
 DENSE, SPARSE = "dense", "sparse"
+SIGMOID, SOFTMAX = "sigmoid", "softmax"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,8 +116,25 @@ class Latent:
 
 
 @dataclasses.dataclass(frozen=True)
+class Indexer:
+    """Learned sparse attention (DeepSeek-V3.2's sparse attention in its sparse-training
+    stage): ``n_heads`` small query heads of ``head_dim``, one key of ``head_dim`` a token
+    for all of them and one weight a head score every earlier token for every query,
+    ``I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])``; the ``top_k`` keys of largest score
+    (ties to the earlier key; all of them while there are no more) are the only keys the
+    layer's attention heads see for that query. The selection passes no gradient: the
+    indexer learns from the KL divergence between the heads' mean probabilities over the
+    selected keys and the softmax of its own scores there, and reads the layer's normed
+    input detached, so the two losses meet on no leaf."""
+
+    n_heads: int
+    head_dim: int
+    top_k: int
+
+
+@dataclasses.dataclass(frozen=True)
 class Layer:
-    attn: str  # FULL | SLIDING | LATENT
+    attn: str  # FULL | SLIDING | LATENT | INDEXED
     n_heads: int
     mlp: str  # DENSE | SPARSE
 
@@ -122,7 +148,7 @@ class PatternConfig:
     layers: tuple[Layer, ...]
     d_ff: int  # the dense MLP's width
     d_expert: int  # a routed expert's width
-    d_shared: int  # the shared expert's width
+    d_shared: int  # the shared expert's width; 0: the sparse layers have none
     n_experts: int  # the router's outputs: every expert of the deployment
     top_k: int
     #: (first, count): the contiguous range of the ``n_experts`` whose weights are here
@@ -134,6 +160,12 @@ class PatternConfig:
     #: the widths of the ``latent`` layers, and the rotary table of their ``d_rope`` part
     latent: Optional[Latent] = None
     rope_latent: Rope = Rope()
+    #: the small heads of the ``indexed`` layers, and the rotary table of those layers
+    #: (made once for the attention heads' width and once for the indexer's)
+    indexer: Optional[Indexer] = None
+    rope_indexed: Rope = Rope()
+    #: the router's scores over all experts: SIGMOID (each expert's own) or SOFTMAX
+    route_score: str = SIGMOID
     #: the router chooses by score + a selection bias (seeded at this standard deviation)
     #: and weighs by the score alone; ``None``: no bias, it chooses by score
     route_bias_std: Optional[float] = None
@@ -142,8 +174,9 @@ class PatternConfig:
     #: step ``lr`` moves the bias by ``lr * route_bias_gain`` a step
     route_bias_gain: float = 1.0
     norm_eps: float = 1e-6
-    #: query rows a full layer scores at a time (against all the keys before them) on
-    #: the ``jax.numpy`` path; the kernel path has its own tiles and does not read it
+    #: query rows a full, latent or indexed layer scores at a time (against all the keys
+    #: before them) on the ``jax.numpy`` path; the kernel path has its own tiles and does
+    #: not read it
     attn_block: int = 1024
     dtype: Any = jnp.bfloat16
 
@@ -157,6 +190,8 @@ class PatternConfig:
             if len(heads) > 1:
                 raise ValueError(f"{kind} layers differ in head count {sorted(heads)}: "
                                  "their weights cannot be stacked")
+        if self.route_score not in (SIGMOID, SOFTMAX):
+            raise ValueError(f"unknown router score {self.route_score!r}")
         for l in self.layers:
             if l.attn not in ATTENTION_KINDS or l.mlp not in (DENSE, SPARSE):
                 raise ValueError(f"unknown layer kind in {l}")
@@ -165,9 +200,12 @@ class PatternConfig:
                     raise ValueError("latent layers need the widths of `latent`")
             elif l.n_heads % self.n_kv_heads:
                 raise ValueError(f"{l.n_heads} heads do not group over {self.n_kv_heads}")
+            if l.attn == INDEXED and self.indexer is None:
+                raise ValueError("indexed layers need the heads of `indexer`")
 
     def rope(self, kind: str) -> Rope:
-        return {FULL: self.rope_full, SLIDING: self.rope_sliding, LATENT: self.rope_latent}[kind]
+        return {FULL: self.rope_full, SLIDING: self.rope_sliding, LATENT: self.rope_latent,
+                INDEXED: self.rope_indexed}[kind]
 
     def rotary_width(self, kind: str) -> int:
         """The dimensions of a head that :meth:`rope`'s table of ``kind`` is made for."""
@@ -207,6 +245,21 @@ class PatternConfig:
         base.update(kw)
         return PatternConfig(**base)
 
+    @staticmethod
+    def tiny_indexed(**kw) -> "PatternConfig":
+        """Indexed attention throughout (8 heads over the 12 keys that 4 small heads
+        select), softmax routing, no shared expert, no dense layer: the third
+        description the tests train."""
+        base = dict(
+            vocab_size=256, d_model=64, head_dim=16, n_kv_heads=2,
+            layers=(Layer(INDEXED, 8, SPARSE),) * 3,
+            indexer=Indexer(n_heads=4, head_dim=8, top_k=12), rope_indexed=Rope(1e7),
+            route_score=SOFTMAX, d_ff=128, d_expert=32, d_shared=0, n_experts=16, top_k=4,
+            experts_held=(0, 4), attn_block=16,
+        )
+        base.update(kw)
+        return PatternConfig(**base)
+
 
 # ---------------------------------------------------------------------------------
 # the parameters, described
@@ -229,7 +282,10 @@ def describe_params(cfg: PatternConfig) -> dict:
     leading axis per kind: ``attn/<full|sliding>`` and ``mlp/<dense|sparse>``, in the
     order the layers of that kind appear. A latent layer's down-projection ``wkv_a`` and
     its latent norm serve all heads and are never sharded; ``wq`` and the up-projection
-    ``wkv_b`` have a head's columns together. ``b_router`` is there only where the
+    ``wkv_b`` have a head's columns together. An indexed layer's ``q_norm`` and ``k_norm``
+    are one weight vector for all heads; its indexer (``wq_index``, ``wk_index``,
+    ``ww_index``, ``k_index_norm``) is replicated like the router: every chip scores all
+    the keys of its own tokens. ``b_router`` is there only where the
     router chooses by a bias, seeded so that the bias it stands for (``route_bias_gain``
     times it) has ``route_bias_std``."""
     d, dh, hkv = cfg.d_model, cfg.head_dim, cfg.n_kv_heads
@@ -265,6 +321,23 @@ def describe_params(cfg: PatternConfig) -> dict:
             "wo": Leaf((n, h * la.d_value, d), (None, "heads", None), h * la.d_value),
         }
 
+    n = cfg.count(INDEXED)
+    if n:
+        h, ix = cfg.heads(INDEXED), cfg.indexer
+        tree["attn"][INDEXED] = {
+            "attn_norm": Leaf((n, d), (None, None), None),
+            "wq": Leaf((n, d, h * dh), (None, None, "heads"), d),
+            "wk": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
+            "wv": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
+            "q_norm": Leaf((n, dh), (None, None), None),
+            "k_norm": Leaf((n, dh), (None, None), None),
+            "wo": Leaf((n, h * dh, d), (None, "heads", None), h * dh),
+            "wq_index": Leaf((n, d, ix.n_heads * ix.head_dim), (None, None, None), d),
+            "wk_index": Leaf((n, d, ix.head_dim), (None, None, None), d),
+            "ww_index": Leaf((n, d, ix.n_heads), (None, None, None), d),
+            "k_index_norm": Leaf((n, ix.head_dim), (None, None), None),
+        }
+
     def swiglu(prefix: str, lead: tuple, lead_axes: tuple, f: int) -> dict:
         return {
             f"{prefix}_gate": Leaf((*lead, d, f), (*lead_axes, None, "ff"), d),
@@ -283,8 +356,9 @@ def describe_params(cfg: PatternConfig) -> dict:
             "mlp_norm": Leaf((n, d), (None, None), None),
             "w_router": Leaf((n, d, cfg.n_experts), (None, None, None), d),
             **swiglu("we", (n, held), (None, "experts"), cfg.d_expert),
-            **swiglu("ws", (n,), (None,), cfg.d_shared),
         }
+        if cfg.d_shared:
+            tree["mlp"][SPARSE].update(swiglu("ws", (n,), (None,), cfg.d_shared))
         if cfg.route_bias_std is not None:
             tree["mlp"][SPARSE]["b_router"] = Leaf(
                 (n, cfg.n_experts), (None, None), None,
@@ -379,11 +453,53 @@ def _attend(q, k, v, mask):
     return (out / total).astype(q.dtype)
 
 
+def _attend_summed(q, k, v, mask):
+    """:func:`_attend`, written the same way, and with it the group's summed probabilities
+    ``[..., Q, K]`` in float32, which pass no gradient (what an indexer is taught to
+    predict)."""
+    scores = jnp.einsum("...gqd,...kd->...gqk", q, k,
+                        preferred_element_type=jnp.float32) / np.sqrt(q.shape[-1])
+    scores = jnp.where(mask[..., None, :, :], scores, -1e30)
+    top = jax.lax.optimization_barrier(
+        jax.lax.stop_gradient(jnp.max(scores, axis=-1, keepdims=True)))
+    weights = jnp.exp(scores - top)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    out = jnp.einsum("...gqk,...kd->...gqd", weights.astype(q.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return (out / total).astype(q.dtype), jax.lax.stop_gradient(jnp.sum(weights / total, axis=-3))
+
+
 def _over_kv_heads(q, k, v, mask):
     """:func:`_attend` for each KV head in turn (leading axis), its scores recomputed
     in the backward pass: one head's block of scores is all that is ever alive."""
     body = jax.checkpoint(lambda qkv: _attend(*qkv, mask))
     return jax.lax.map(body, (q, k, v))
+
+
+def _over_heads_summed(q, k, v, mask):
+    """:func:`_attend_summed` for each query head in turn, its scores recomputed in the backward
+    pass, with the heads' probabilities summed on the way: q ``[Hkv, B, G, Q, dh]``, k / v
+    ``[Hkv, B, K, dh]``, mask ``[B, Q, K]`` -> (``[Hkv, B, G, Q, dh]``, ``[B, Q, K]``
+    float32). One head at a time, not one KV head's group: a head's ``[Q, K]`` float32
+    scores are 16e6 B at 512 rows and 8,192 keys, a group of eight's 134e6 B, and the
+    softmax and its backward pass go over them some twenty times. By groups the step of
+    the 8,192-token cell read 1,867 ms on a v5e, 1,538 of it these products; by heads 549
+    and 223 (chip runs, PR 35), as kimi's one-head blocks of 33e6 B had let expect: what
+    passes between the fusions stays on the chip at the smaller size."""
+    body = jax.checkpoint(lambda q1, k1, v1: _attend_summed(q1, k1, v1, mask))
+
+    def kv_head(total, qkv):
+        group, k1, v1 = qkv  # [B, G, Q, dh], [B, K, dh] twice
+
+        def one_head(total, q1):
+            out, probs = body(q1[:, None], k1, v1)
+            return total + probs, out[:, 0]
+
+        total, out = jax.lax.scan(one_head, total, group.swapaxes(0, 1))
+        return total, out.swapaxes(0, 1)
+
+    total, out = jax.lax.scan(kv_head, jnp.zeros(mask.shape, jnp.float32), (q, k, v))
+    return out, total
 
 
 def _pad_rows(x, axis: int, multiple: int):
@@ -452,6 +568,181 @@ def full_attention(q, k, v, block: int):
     return _heads_last(jnp.concatenate(outs, axis=3), t)
 
 
+def index_scores(q, w, k):
+    """An indexer's score of every key for every query: q ``[B, Q, J, di]`` (``J`` small
+    heads), w ``[B, Q, J]`` float32 (a head's weight for the query, scaled), k
+    ``[B, K, di]`` (one key a token for all heads) -> ``[B, Q, K]`` float32, ``sum_j
+    w[q, j] relu(q[q, j] . k[s])``: the products in the operands' type accumulated in
+    float32, the ReLU and the weighted sum in float32."""
+    dots = jnp.einsum("bqjd,bkd->bqjk", q, k, preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots) * w[..., None], axis=2)
+
+
+def _ordered(x):
+    """float32 -> uint32 whose unsigned order is the floats' own (-0.0 is 0.0)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x).astype(jnp.float32), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _bisect(bits: int, dtype, rows: tuple, holds):
+    """The largest ``bits``-bit number ``x`` (one for each of ``rows``) for which
+    ``holds(x)`` is true, for a ``holds`` that is true at 0 and, once false, stays false
+    as ``x`` grows: the bits are settled from the top, one pass over the rows each."""
+    def settle(i, x):
+        trial = x | jnp.left_shift(jnp.ones((), dtype), (bits - 1 - i).astype(dtype))
+        return jnp.where(holds(trial), trial, x)
+
+    return jax.lax.fori_loop(0, bits, settle, jnp.zeros(rows, dtype))
+
+
+def select_keys(scores, start: int, top_k: int):
+    """Each query's key set as a mask: scores ``[..., Q, K]`` float32 of the query rows at
+    positions ``start .. start + Q - 1`` against the keys at ``0 .. K - 1`` -> (mask
+    ``[..., Q, K]`` bool: the ``top_k`` keys ``s <= t`` of largest score, equal scores to
+    the lower ``s``, all of them for a query with no more; ``[..., Q]`` bool: the queries
+    whose ``top_k``-th and next scores are equal). Exact, with no sort: the ``top_k``-th
+    largest score of a row is found bit by bit (32 counts of the row's scores at or above
+    a trial value, in the order-preserving integer form of the floats), and only where
+    some row's equal scores straddle it, the last position taken among them the same
+    way. Rows that all end at or before ``top_k`` get the causal mask with no pass."""
+    q, k = scores.shape[-2:]
+    position = jnp.arange(k, dtype=jnp.int32)
+    causal = position <= start + jnp.arange(q, dtype=jnp.int32)[:, None]
+    if start + q <= top_k:
+        return (jnp.broadcast_to(causal, scores.shape),
+                jnp.zeros(scores.shape[:-1], bool))
+    keys = jnp.where(causal, _ordered(scores), jnp.uint32(0))  # a real score is above 0
+    count = lambda which: jnp.sum(which, axis=-1, dtype=jnp.int32)  # noqa: E731
+    rows = keys.shape[:-1]
+    level = _bisect(32, jnp.uint32, rows,
+                    lambda x: count(keys >= x[..., None]) >= top_k)[..., None]
+    above, equal = keys > level, keys == level
+    wanted = top_k - count(above)  # of the keys at the level, lowest positions first
+    # a row with no more than top_k keys has level 0 (the masked ones): all of it is taken
+    straddles = (count(equal) > wanted) & (level[..., 0] > 0)
+
+    def lowest(_):
+        last = _bisect(max(k - 1, 1).bit_length(), jnp.int32, rows,
+                       lambda x: count(equal & (position < x[..., None])) < wanted)
+        return equal & (position <= last[..., None])
+
+    equal = jax.lax.cond(jnp.any(straddles), lowest, lambda _: equal, None)
+    return (above | equal) & causal, straddles
+
+
+@jax.custom_vjp
+def index_divergence(scores, mask, target):
+    """``KL(target || softmax of scores over the mask)`` of each row, ``[..., Q]``
+    float32: scores and target ``[..., Q, K]`` float32, the target zero off the mask.
+    Differentiated by the scores alone, as ``sum(target) x softmax - target``; the row
+    maximum and the row sum cross a barrier for :func:`_attend`'s reason."""
+    return _index_divergence(scores, mask, target)[0]
+
+
+def _index_softmax(scores, mask):
+    scores = jnp.where(mask, scores, -1e30)
+    top = jax.lax.optimization_barrier(jnp.max(scores, axis=-1, keepdims=True))
+    weights = jnp.where(mask, jnp.exp(scores - top), 0.0)
+    total = jax.lax.optimization_barrier(jnp.sum(weights, axis=-1, keepdims=True))
+    return scores - top, weights, total
+
+
+def _index_divergence(scores, mask, target):
+    shifted, _, total = _index_softmax(scores, mask)
+    seen = mask & (target > 0)
+    log_target = jnp.log(jnp.where(seen, target, 1.0))
+    rows = jnp.sum(jnp.where(seen, target * (log_target - shifted + jnp.log(total)), 0.0), axis=-1)
+    return rows, (scores, mask, target)
+
+
+def _index_divergence_bwd(res, g):
+    scores, mask, target = res
+    _, weights, total = _index_softmax(scores, mask)
+    mass = jax.lax.optimization_barrier(jnp.sum(target, axis=-1, keepdims=True))
+    return g[..., None] * (mass * (weights / total) - target), None, None
+
+
+index_divergence.defvjp(_index_divergence, _index_divergence_bwd)
+
+
+#: the query blocks of an indexed layer go in at most this many groups, each group against
+#: the keys up to its own end: one compiled body a group and stage, mapped over its blocks
+KEY_GROUPS = 4
+
+
+def key_groups(seq: int, block: int) -> list[tuple[int, int]]:
+    """(first query row, keys) of each group of query blocks of a sequence of ``seq``
+    rows (whole blocks of ``block``): the rows from ``first`` to ``keys`` see the keys
+    ``0 .. keys - 1``."""
+    blocks = seq // block
+    size = -(-blocks // KEY_GROUPS) * block
+    return [(first, min(first + size, seq)) for first in range(0, seq, size)]
+
+
+def _in_blocks(x, axis: int, n: int):
+    """``[..., n * rows, ...]`` on ``axis`` as ``[n, ..., rows, ...]``: the operand of a
+    ``jax.lax.map`` over ``n`` blocks of rows."""
+    return jnp.moveaxis(x.reshape(*x.shape[:axis], n, -1, *x.shape[axis + 1:]), axis, 0)
+
+
+def _whole(x, axis: int):
+    """``[n, ..., rows, ...]`` back to ``[..., n * rows, ...]`` on ``axis``."""
+    x = jnp.moveaxis(x, 0, axis)
+    return x.reshape(*x.shape[:axis], -1, *x.shape[axis + 2:])
+
+
+def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int):
+    """Causal attention over the keys an indexer selects: q ``[B, T, H, dh]``, k / v ``[B,
+    T, Hkv, dh]``, and the indexer's qi ``[B, T, J, di]``, wi ``[B, T, J]``, ki ``[B, T,
+    di]`` -> (the attention output ``[B, T, H * dh]``; per query ``[B, T]``: the divergence
+    of the indexer's softmax from the heads' mean probabilities over the selected keys, how
+    many keys were selected, and whether the ``top_k``-th score was tied with the next; and
+    each group's rows of the mask, ``[B, rows, keys]`` bool, for :func:`choices`).
+
+    The query rows go in groups (:func:`key_groups`), each against the causal prefix of
+    the keys up to its own end, so the last rows of a group score up to a quarter of the
+    sequence beyond their own position. A group computes its index scores by blocks of
+    ``block`` rows (:func:`index_scores`, one block's products of all the indexer's heads
+    alive at a time; made again in the backward pass), turns them into its rows of the
+    mask (:func:`select_keys`; named ``select_mask``, so a layer may keep it), runs
+    :func:`_attend_summed` under that mask by the same blocks, each head in turn, with the
+    heads' probabilities summed on the way, and takes the divergence. The loops over the
+    blocks are ``jax.lax.map``s: a layer compiles one body a group and stage. Nothing
+    differentiable passes through the mask or the probabilities. Scopes: ``indexer``,
+    ``select``, ``core``, each around its loop (an op's name holds a scope before the
+    loop's own components)."""
+    b, t, heads = q.shape[:3]
+    block = min(block, t)
+    q, k, v, qi, wi, ki = (_pad_rows(x, 1, block) for x in (q, k, v, qi, wi, ki))
+    q, k, v = _heads_first(q, k, v)
+    score_block = jax.checkpoint(index_scores)
+    outs, rows, masks = [], [], []
+    for first, keys in key_groups(q.shape[3], block):
+        n = (keys - first) // block
+        k_seen, v_seen, ki_seen = k[:, :, :keys], v[:, :, :keys], ki[:, :keys]
+        with jax.named_scope("indexer"):
+            scores = _whole(jax.lax.map(
+                lambda rows, ki_seen=ki_seen: score_block(*rows, ki_seen),
+                (_in_blocks(qi[:, first:keys], 1, n), _in_blocks(wi[:, first:keys], 1, n))), 1)
+        with jax.named_scope("select"):
+            mask, tied = select_keys(jax.lax.stop_gradient(scores), first, top_k)
+            if keys > top_k:  # else the causal mask, which nothing needs to keep
+                mask = checkpoint_name(mask, KEPT_GROUPS["selection"][0])
+            selected = jnp.sum(mask, axis=-1, dtype=jnp.int32)
+        with jax.named_scope("core"):
+            out, probs = jax.lax.map(
+                lambda rows, k_seen=k_seen, v_seen=v_seen: _over_heads_summed(
+                    rows[0], k_seen, v_seen, rows[1]),
+                (_in_blocks(q[:, :, :, first:keys], 3, n), _in_blocks(mask, 1, n)))
+        with jax.named_scope("indexer"):
+            divergence = index_divergence(scores, mask, _whole(probs, 1) / heads)
+        outs.append(_whole(out, 3))
+        rows.append((divergence, selected, tied))
+        masks.append(mask)
+    divergence, selected, tied = (jnp.concatenate(x, axis=1)[:, :t] for x in zip(*rows))
+    return _heads_last(jnp.concatenate(outs, axis=3), t), divergence, selected, tied, masks
+
+
 def _window(cfg: PatternConfig, kind: str) -> Optional[int]:
     return cfg.window if kind == SLIDING else None
 
@@ -470,14 +761,15 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     kernels of ``ops/attention.py``, ``{"path": "blocks", "block": rows}`` for the
     ``jax.numpy`` blocks. The kernels take one width for queries, keys and values, so a
     latent kind whose score width differs from its value width takes the blocks, and
-    says both widths."""
+    says both widths; and they compute their mask from positions, so an indexed kind,
+    whose mask is data, takes the blocks and says how many keys a query keeps."""
     paths = {}
     for kind in ATTENTION_KINDS:
         if not cfg.count(kind):
             continue
         window = _window(cfg, kind)
         score, value = _widths(cfg, kind)
-        if (jax.default_backend() == "tpu" and score == value
+        if (jax.default_backend() == "tpu" and score == value and kind != INDEXED
                 and attention.applies(seq, score, window)):
             paths[kind] = {"path": "kernel", "tile": attention.tile_of(seq, window)}
         else:
@@ -485,6 +777,8 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
                            "block": cfg.window if kind == SLIDING else min(cfg.attn_block, seq)}
         if kind == LATENT:
             paths[kind].update(score_width=score, value_width=value)
+        if kind == INDEXED:  # the kernels compute their mask from positions
+            paths[kind].update(selected=min(cfg.indexer.top_k, seq), selection="mask")
     return paths
 
 
@@ -554,6 +848,49 @@ def _latent_block(cfg: PatternConfig, x, lp: dict, cos, sin):
         return x + attn @ lp["wo"].astype(attn.dtype)
 
 
+def _indexed_block(cfg: PatternConfig, x, lp: dict, cos, sin, index_cos, index_sin):
+    """Pre-norm grouped-query attention over the keys its indexer selects, and the
+    residual: each head's q and k normed (one weight vector for all heads) before the
+    rotary, no gate. The indexer reads the normed input detached: its queries, its one
+    key a token (normed, with a weight) and its head weights, the rotary over all its
+    dimensions. Returns the stream, the layer's counts: ``index_kl`` (the mean over
+    the queries of :func:`index_divergence`, which is also the layer's term of the
+    loss), ``keys_selected``, ``select_ties``; and :func:`indexed_attention`'s rows of the
+    mask.
+
+    The scope is ``attn/full`` as the latent kind's is (it names the causal mask the
+    selection is made under), with ``/indexer`` (the indexer's projections, norm and
+    rotary, the index scores, the divergence), ``/select`` and ``/core`` inside it."""
+    with jax.named_scope("attn/full"):
+        b, t, _ = x.shape
+        h, hkv, dh, ix = cfg.heads(INDEXED), cfg.n_kv_heads, cfg.head_dim, cfg.indexer
+        y = tfm.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = (y @ lp["wq"].astype(y.dtype)).reshape(b, t, h, dh)
+        k = (y @ lp["wk"].astype(y.dtype)).reshape(b, t, hkv, dh)
+        v = (y @ lp["wv"].astype(y.dtype)).reshape(b, t, hkv, dh)
+        q = _rotate(tfm.rms_norm(q, lp["q_norm"], cfg.norm_eps), cos, sin)
+        k = _rotate(tfm.rms_norm(k, lp["k_norm"], cfg.norm_eps), cos, sin)
+        with jax.named_scope("indexer"):
+            detached = jax.lax.stop_gradient(y)
+            qi = (detached @ lp["wq_index"].astype(y.dtype)).reshape(b, t, ix.n_heads, ix.head_dim)
+            ki = tfm.rms_norm(detached @ lp["wk_index"].astype(y.dtype), lp["k_index_norm"],
+                              cfg.norm_eps)
+            wi = jnp.matmul(detached, lp["ww_index"].astype(y.dtype),
+                            preferred_element_type=jnp.float32) / np.sqrt(
+                                ix.n_heads * ix.head_dim)
+            qi = _rotate(qi, index_cos, index_sin)
+            ki = _rotate(ki[:, :, None], index_cos, index_sin)[:, :, 0]
+        names = (*KEPT_GROUPS["qkv"], *KEPT_GROUPS["index"])
+        q, k, v, qi, wi, ki = (checkpoint_name(a, name)
+                               for a, name in zip((q, k, v, qi, wi, ki), names))
+        attn, divergence, selected, tied, masks = indexed_attention(
+            q, k, v, qi, wi, ki, ix.top_k, cfg.attn_block)
+        attn = checkpoint_name(attn, attention.OUT_NAME)
+        counts = {"index_kl": jnp.mean(divergence), "keys_selected": jnp.sum(selected),
+                  "select_ties": jnp.sum(tied)}
+        return x + attn @ lp["wo"].astype(attn.dtype), counts, masks
+
+
 # ---------------------------------------------------------------------------------
 # the MLPs
 # ---------------------------------------------------------------------------------
@@ -579,8 +916,9 @@ _take_rows.defvjp(lambda x, index, inverse: (x[index], (index, inverse)),
 
 
 def route(cfg: PatternConfig, y, w_router, bias=None):
-    """Float32 routing of tokens ``y [N, D]`` over all ``n_experts``: sigmoid scores,
-    the ``top_k`` largest, their weights normalised to one and scaled. With a ``bias
+    """Float32 routing of tokens ``y [N, D]`` over all ``n_experts``: sigmoid scores (or
+    the softmax over all experts, as ``route_score`` says), the ``top_k`` largest, their
+    weights normalised to one and scaled. With a ``bias
     [E]`` (Wang et al., arXiv:2408.15664, as DeepSeek-V3's ``noaux_tc`` with one group)
     the experts are the ``top_k`` largest of score + ``route_bias_gain`` x bias and the
     weights are their scores: the bias enters the choice and nothing else, so the loss
@@ -594,7 +932,7 @@ def route(cfg: PatternConfig, y, w_router, bias=None):
     logits = checkpoint_name(
         jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
                    precision=jax.lax.Precision.HIGHEST), "route_logits")
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(logits) if cfg.route_score == SIGMOID else jax.nn.softmax(logits)
     if bias is None:
         weights, experts = jax.lax.top_k(scores, cfg.top_k)
         by_bias = balance = None
@@ -776,6 +1114,8 @@ def _mlp_block(cfg: PatternConfig, kind: str, x, lp: dict):
             return x + mlp, None, None
     b, t, d = y.shape
     routed, counts, balance = routed_experts(cfg, y.reshape(b * t, d), lp)
+    if not cfg.d_shared:
+        return x + routed.reshape(b, t, d), counts, balance
     with jax.named_scope("moe/shared"):
         shared = _swiglu(y, lp["ws_gate"], lp["ws_up"], lp["ws_down"], KEPT_GROUPS["shared"])
     return x + routed.reshape(b, t, d) + shared, counts, balance
@@ -792,12 +1132,17 @@ KEPT_GROUPS = {
     # the float32 router product, the sort of all pairs and the scatter that inverts it
     "routing": ("route_logits", "route_weights", "route_experts",
                 "dispatch_order", "dispatch_inverse"),
+    # the keys an indexer selected, as the mask its attention runs under: 32 counting
+    # passes over every block of index scores (and the scores themselves, once)
+    "selection": ("select_mask",),
     # x + attention @ wo: the output matrix's forward product
     "stream": ("attn_stream",),
     # the attention products' forward; without the log-sum-exp the kernel runs again
     "attention": (attention.OUT_NAME, attention.LSE_NAME),
     # q and k after the rotary, v: the projections and the rotary
     "qkv": ("attn_q", "attn_k", "attn_v"),
+    # an indexer's queries and key after the rotary, its head weights: the same of its own
+    "index": ("index_q", "index_w", "index_k"),
     # the gate and up products of the shared expert, then of the dense MLP
     "shared": ("shared_gate", "shared_up"),
     "dense": ("dense_gate", "dense_up"),
@@ -811,40 +1156,58 @@ def device_memory_bytes() -> Optional[int]:
     return stats.get("bytes_limit") if stats else None
 
 
-def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int) -> dict:
-    """Bytes of each group of :data:`KEPT_GROUPS` in one layer over ``n_tokens`` tokens."""
+def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int, seq: int) -> dict:
+    """Bytes of each group of :data:`KEPT_GROUPS` in one layer over ``n_tokens`` tokens
+    in sequences of ``seq``."""
     act = jnp.dtype(cfg.dtype).itemsize
     score, value = _widths(cfg, spec.attn)
     kv_heads = spec.n_heads if spec.attn == LATENT else cfg.n_kv_heads
-    # the kernels take one width of whole lane groups; only they make a log-sum-exp
-    lse = 4 * spec.n_heads if score == value and not score % attention.LANES else 0
+    indexed = spec.attn == INDEXED
+    # the kernels take one width of whole lane groups and a mask made from positions;
+    # only they make a log-sum-exp
+    kernels = score == value and not score % attention.LANES and not indexed
+    lse = 4 * spec.n_heads if kernels else 0
+    index = selection = 0
+    if indexed:
+        ix, block = cfg.indexer, min(cfg.attn_block, seq)
+        index = n_tokens * (act * (ix.n_heads + 1) * ix.head_dim + 4 * ix.n_heads)
+        # a byte a (query, key) of every group of query blocks that really selects,
+        # against the group's keys (:func:`indexed_attention`)
+        padded = -(-seq // block) * block
+        selection = n_tokens // seq * sum(
+            (keys - first) * keys for first, keys in key_groups(padded, block) if keys > ix.top_k)
     sparse = spec.mlp == SPARSE
     return {
         "routing": n_tokens * 4 * (cfg.n_experts + 4 * cfg.top_k) if sparse else 0,
+        "selection": selection,
         "stream": n_tokens * cfg.d_model * act,
         "attention": n_tokens * (spec.n_heads * value * act + lse),
         "qkv": n_tokens * act * (spec.n_heads * score + kv_heads * (score + value)),
+        "index": index,
         "shared": 2 * n_tokens * cfg.d_shared * act if sparse else 0,
         "dense": 0 if sparse else 2 * n_tokens * cfg.d_ff * act,
     }
 
 
-def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int]) -> dict:
+def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int],
+                   seq: Optional[int] = None) -> dict:
     """What each layer keeps for its backward pass beside its input, at ``n_tokens``
-    tokens a step on a device of ``memory_bytes`` (``None``: no limit stated, everything
-    is kept), from the configuration and the shapes alone: ``{"names": the names the
+    tokens a step (in sequences of ``seq``; ``None``: one sequence) on a device of
+    ``memory_bytes`` (``None``: no limit stated, everything is kept), from the
+    configuration and the shapes alone: ``{"names": the names the
     layers' ``jax.checkpoint`` keeps, "bytes": what they hold over all layers,
     "per_layer": {group: [bytes in each layer]}, "step_bytes": what the step holds
     anyway}``.
 
-    The groups of :data:`KEPT_GROUPS` are taken in order while the bytes kept and
-    ``step_bytes`` stay inside the memory, and the first that does not fit ends the
-    list: a longer step or a smaller device gets the shorter list, down to none
-    (``names`` empty: every layer recomputes its whole forward). ``step_bytes`` is
+    The groups of :data:`KEPT_GROUPS` that hold anything in some layer of this
+    description are taken in order while the bytes kept and ``step_bytes`` stay inside
+    the memory, and the first that does not fit ends the list: a longer step or a smaller
+    device gets the shorter list, down to none (``names`` empty: every layer recomputes
+    its whole forward). ``step_bytes`` is
     16 B a parameter (float32 weights, two moments, gradients), every layer's input,
     the float32 logits with their cotangent, and all the groups of the largest layer
     once (the layer whose backward pass runs holds them, kept or recomputed)."""
-    per_layer = [_group_bytes(cfg, spec, n_tokens) for spec in cfg.layers]
+    per_layer = [_group_bytes(cfg, spec, n_tokens, seq or n_tokens) for spec in cfg.layers]
     n_params = sum(math.prod(leaf.shape) for leaf in
                    jax.tree.leaves(describe_params(cfg), is_leaf=_is_leaf))
     step_bytes = (16 * n_params
@@ -854,6 +1217,8 @@ def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int
     kept = {"names": [], "bytes": 0, "per_layer": {}, "step_bytes": step_bytes}
     for group, names in KEPT_GROUPS.items():
         layers = [groups[group] for groups in per_layer]
+        if not any(layers):
+            continue
         if (memory_bytes is not None
                 and step_bytes + kept["bytes"] + sum(layers) > memory_bytes):
             break
@@ -867,58 +1232,121 @@ def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int
 # the model
 # ---------------------------------------------------------------------------------
 
-def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
-    """:func:`forward`, and the sum of the sparse layers' ``balance`` (:func:`route`)."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    t = tokens.shape[1]
+def _rope_tables(cfg: PatternConfig, t: int) -> dict:
+    """{kind: its layers' rotary tables at ``t`` positions}."""
     tables = {kind: rope_tables(cfg.rope(kind), cfg.rotary_width(kind), t)
               for kind in ATTENTION_KINDS if cfg.count(kind)}
+    if cfg.count(INDEXED):  # the indexer's heads turn by the same table at their own width
+        tables[INDEXED] += rope_tables(cfg.rope(INDEXED), cfg.indexer.head_dim, t)
+    return tables
 
-    def layer(x, attn_lp, mlp_lp, spec: Layer):
-        if spec.attn == LATENT:
-            x = _latent_block(cfg, x, attn_lp, *tables[LATENT])
-        else:
-            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
-        return _mlp_block(cfg, spec.mlp, checkpoint_name(x, "attn_stream"), mlp_lp)
 
-    # each layer keeps its input and the values named here for its backward pass, and
-    # recomputes the rest of its forward there
-    kept = kept_residuals(cfg, tokens.size, device_memory_bytes())
-    policy = jax.checkpoint_policies.save_only_these_names(*kept["names"])
-
+def _layer_params(params: dict, cfg: PatternConfig):
+    """(the layer's description, its attention leaves, its MLP leaves) of each layer in
+    turn, out of the per-kind stacks."""
     seen = dict.fromkeys((*ATTENTION_KINDS, DENSE, SPARSE), 0)
-    counts, balance = [], None
     for spec in cfg.layers:
         attn_lp = jax.tree.map(lambda w: w[seen[spec.attn]], params["attn"][spec.attn])
         mlp_lp = jax.tree.map(lambda w: w[seen[spec.mlp]], params["mlp"][spec.mlp])
         seen[spec.attn] += 1
         seen[spec.mlp] += 1
-        x, layer_counts, layer_balance = jax.checkpoint(
+        yield spec, attn_lp, mlp_lp
+
+
+def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
+    """:func:`forward`, and the sum of the sparse layers' ``balance`` (:func:`route`)."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    t = tokens.shape[1]
+    tables = _rope_tables(cfg, t)
+
+    def layer(x, attn_lp, mlp_lp, spec: Layer):
+        index_counts = None
+        if spec.attn == LATENT:
+            x = _latent_block(cfg, x, attn_lp, *tables[LATENT])
+        elif spec.attn == INDEXED:
+            x, index_counts, _ = _indexed_block(cfg, x, attn_lp, *tables[INDEXED])
+        else:
+            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
+        return (*_mlp_block(cfg, spec.mlp, checkpoint_name(x, "attn_stream"), mlp_lp),
+                index_counts)
+
+    # each layer keeps its input and the values named here for its backward pass, and
+    # recomputes the rest of its forward there
+    kept = kept_residuals(cfg, tokens.size, device_memory_bytes(), t)
+    policy = jax.checkpoint_policies.save_only_these_names(*kept["names"])
+
+    counts, index_counts, balance = [], [], None
+    for spec, attn_lp, mlp_lp in _layer_params(params, cfg):
+        x, layer_counts, layer_balance, layer_index = jax.checkpoint(
             functools.partial(layer, spec=spec), policy=policy)(x, attn_lp, mlp_lp)
         if layer_counts is not None:
             counts.append(layer_counts)
         if layer_balance is not None:
             balance = layer_balance if balance is None else balance + layer_balance
+        if layer_index is not None:
+            index_counts.append(layer_index)
     x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *counts) if counts else {}
+    if index_counts:
+        stacked.update(jax.tree.map(lambda *xs: jnp.stack(xs), *index_counts))
     return logits, stacked, balance
 
 
 def forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
-    """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32, routing counts: a dict of
-    ``[sparse layers]`` arrays, see :func:`routed_experts`)."""
+    """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32, counts: a dict of ``[sparse
+    layers]`` arrays of routing counts, see :func:`routed_experts`, and of ``[indexed
+    layers]`` arrays ``index_kl``, ``keys_selected``, ``select_ties``, see
+    :func:`_indexed_block`)."""
     return _forward(params, tokens, cfg)[:2]
+
+
+def choices(params: dict, tokens: jax.Array, cfg: PatternConfig) -> dict:
+    """What the forward pass of tokens ``[B, T]`` chose where it chooses: ``{"selected":
+    [indexed layers, B, T, T] bool`` (query by key: the keys each indexed layer's
+    attention read), ``"experts": [sparse layers, B, T, top_k] int32`` (of all experts of
+    the deployment, in :func:`route`'s order)}``, a key only where the pattern has such
+    layers. The arithmetic is the training step's own (the same blocks, the same types),
+    with nothing kept and nothing differentiated: for a comparison with other arithmetic
+    on the same choices, since near a tie a rounding decides the choice, and the choice
+    then moves every number downstream by far more than the rounding did."""
+    b, t = tokens.shape
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    tables = _rope_tables(cfg, t)
+    chose = {"selected": [], "experts": []}
+    for spec, attn_lp, mlp_lp in _layer_params(params, cfg):
+        if spec.attn == LATENT:
+            x = _latent_block(cfg, x, attn_lp, *tables[LATENT])
+        elif spec.attn == INDEXED:
+            x, _, masks = _indexed_block(cfg, x, attn_lp, *tables[INDEXED])
+            rows = [jnp.pad(m, ((0, 0), (0, 0), (0, masks[-1].shape[2] - m.shape[2])))
+                    for m in masks]
+            chose["selected"].append(jnp.concatenate(rows, axis=1)[:, :t, :t])
+        else:
+            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
+        if spec.mlp == SPARSE:
+            y = tfm.rms_norm(x, mlp_lp["mlp_norm"], cfg.norm_eps).reshape(b * t, -1)
+            experts = route(cfg, y, mlp_lp["w_router"], mlp_lp.get("b_router"))[1]
+            chose["experts"].append(experts.reshape(b, t, cfg.top_k))
+        x = _mlp_block(cfg, spec.mlp, x, mlp_lp)[0]
+    return {name: jnp.stack(layers) for name, layers in chose.items() if layers}
 
 
 def loss_and_counts(params: dict, tokens: jax.Array, cfg: PatternConfig):
     """Next-token cross-entropy over tokens ``[B, T]`` (the last position's logits
-    dropped, as in the dense model) and the routing counts of each sparse layer. Under a
-    selection bias the loss carries the layers' ``balance``: nothing in value, the
-    balancing rule in the gradient (:func:`route`)."""
+    dropped, as in the dense model) and the counts of each sparse and each indexed layer.
+    Under a selection bias the loss carries the layers' ``balance``: nothing in value, the
+    balancing rule in the gradient (:func:`route`). With indexed layers it carries the
+    mean of their ``index_kl``, the indexers' own loss: by the two ``stop_gradient``s of
+    :func:`_indexed_block` it is the only term the indexers' leaves get a gradient from,
+    and they are the only leaves it reaches."""
     logits, counts, balance = _forward(params, tokens, cfg)
     loss = tfm.token_nll(logits[:, :-1], tokens[:, 1:]).mean()
-    return (loss if balance is None else loss + balance), counts
+    if balance is not None:
+        loss = loss + balance
+    if "index_kl" in counts:
+        loss = loss + jnp.mean(counts["index_kl"])
+    return loss, counts
 
 
 def loss_fn(params: dict, tokens: jax.Array, cfg: PatternConfig) -> jax.Array:
