@@ -822,6 +822,22 @@ def test_kept_residuals_by_tokens_and_memory(description, tokens, memory, groups
     assert kept["step_bytes"] > 16 * n_params
 
 
+@pytest.mark.parametrize("backend,lse", [("cpu", 0), ("tpu", 4)])
+def test_the_indexed_kind_keeps_a_log_sum_exp_on_the_kernel_path_alone(monkeypatch, backend, lse):
+    """The "attention" group of ``keye-vl2-30b-a3b-l6-ep8`` at 8,192 tokens: 32 heads' output
+    in bf16 on the ``jax.numpy`` blocks (the CPU), and four bytes a head and token more
+    where ``attention_paths`` sends the kind to the kernels, which make a log-sum-exp (a
+    TPU); the selection is a byte a (query, key) of the three groups that select, on both."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = cell_config("indexed", 8192)
+    assert pattern.attention_paths(cfg, 8192)["indexed"]["path"] == (
+        "kernel" if lse else "blocks")
+    kept = pattern.kept_residuals(cfg, 8192, V5E_BYTES)
+    assert kept["per_layer"]["attention"] == [8192 * 32 * (128 * 2 + lse)] * 6
+    assert kept["per_layer"]["selection"] == [2048 * (4096 + 6144 + 8192)] * 6
+    assert "attn_lse" in kept["names"]  # the group's names are the same on both
+
+
 def test_kept_residuals_of_the_laguna_cell_layer_by_layer():
     """The bytes of each group in each layer of ``laguna-xs2-l5-ep8`` at 8,192 tokens:
     a full layer (48 heads) keeps 102 MB of output and log-sum-exp and 134 MB of q, k

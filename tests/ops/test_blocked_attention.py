@@ -1,9 +1,11 @@
 """The blocked attention kernels (ops/attention.py) under the Pallas interpreter on the
-CPU: value and the three gradients against plain masked attention, which path
-``models/pattern.py`` takes for which shapes, and that every kernel of a layer's
-forward, recomputed forward and backward carries the scope the benchmark's reader
-looks for."""
+CPU: value and the three gradients against plain masked attention, and under a selection
+against ``pattern._attend_summed`` with the heads' summed probabilities; which path
+``models/pattern.py`` takes for which shapes; that every kernel of a layer's forward,
+recomputed forward and backward carries the scope the benchmark's reader looks for; and
+that without a selection the three kernels are what they were before they took one."""
 
+import dataclasses
 import os
 import re
 import sys
@@ -30,6 +32,9 @@ HKV, DH = 2, 128
 #: blocks' largest and a fifth
 BF16_VALUE_GAP, BF16_GRAD_GAP = 0.0040, 0.0057
 F32_GAP = 2e-6
+#: the kernels under the causal mask as a selection against the kernels with none (read:
+#: 0.5e-7 to 1.5e-7 in float32, 0 to 4.2e-5 in bf16, where an output rounds the other way)
+SAME_GAP = {jnp.float32: 5e-7, jnp.bfloat16: 2e-4}
 
 
 def plain_attention(q, k, v, window=None):
@@ -87,6 +92,131 @@ def test_kernels_equal_plain_masked_attention(window, seq, groups, dtype):
     limits = (F32_GAP,) * 4 if dtype == jnp.float32 else (BF16_VALUE_GAP,) + (BF16_GRAD_GAP,) * 3
     gaps = [gap(a, b) for a, b in zip(got, want)]
     assert all(g < limit for g, limit in zip(gaps, limits)), gaps
+
+
+def selection(kind: str, seq: int, tile: int, top_k: int):
+    """``[1, seq, seq]`` bool, query by key, each row with at least one key and none after
+    its own position. ``random``: what ``pattern.select_keys`` keeps of random scores (the
+    first ``top_k`` rows keep every key up to their own: a query with fewer than ``top_k``
+    keys). ``late``: from the second tile on a query keeps keys of its own tile alone, so
+    every tile the kernels visit before the diagonal holds none of its keys. ``causal``:
+    the causal mask itself."""
+    i, j = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+    causal = j <= i
+    if kind == "causal":
+        return causal[None]
+    scores = jax.random.normal(jax.random.PRNGKey(seq + top_k), (1, seq, seq))
+    chosen = pattern.select_keys(scores, 0, top_k)[0]
+    if kind == "late":
+        chosen = (chosen & (j // tile == i // tile)[None]) | (i == j)[None]
+    return chosen
+
+
+def summed_reference(q, k, v, selected):
+    """``pattern._attend_summed`` under ``selected`` with every key at once: (output ``[B,
+    T, H * dh]``, the probabilities summed over all heads ``[B, T, T]``)."""
+    out, probs = pattern._attend_summed(*pattern._heads_first(q, k, v), selected)
+    return pattern._heads_last(out, q.shape[1]), probs.sum(axis=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("groups", [1, 8])
+@pytest.mark.parametrize("kind", ["random", "late", "causal"])
+def test_kernels_under_a_selection_equal_the_blocks_under_the_same_mask(
+        kind, groups, dtype, monkeypatch):
+    """Forward, the heads' summed probabilities, dQ, dK and dV of the kernels with the
+    selection as an operand, against ``_attend_summed`` in float32: 4 x 4 tiles of 128
+    rows, 10 of them visited. The probabilities are zero off the selection and in the tiles
+    no kernel visits, and add up to the number of heads on every row; under the causal
+    mask the kernels give what they give with no selection."""
+    monkeypatch.setattr(attention, "FULL_TILE", 128)
+    seq, top_k = 512, 96
+    q, k, v, weight = inputs(seq, groups)
+    selected = selection(kind, seq, 128, top_k)
+    assert bool(jnp.all(selected.sum(-1) >= 1)) and not bool(jnp.any(jnp.triu(selected[0], 1)))
+    if kind == "late":  # rows whose first visited tiles are empty, with far under top_k keys
+        assert not bool(jnp.any(selected[0, 128:, :128]))
+        assert 1 <= int(selected[0, 128:].sum(-1).max()) < top_k
+
+    def with_probs(fn):
+        def weighed(*qkv):
+            out, probs = fn(*qkv)
+            return jnp.sum(out.astype(jnp.float32) * weight), (out.astype(jnp.float32), probs)
+
+        (_, (out, probs)), grads = jax.jit(jax.value_and_grad(
+            weighed, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return out, probs, *grads
+
+    with jax.default_matmul_precision("highest"):
+        want = with_probs(lambda *a: summed_reference(*a, selected))
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    got = with_probs(lambda *a: attention.blocked_attention(*a, selected=selected))
+    assert got[1].shape == (1, seq, seq) and got[1].dtype == jnp.float32
+    assert float(jnp.abs(jnp.where(selected, 0.0, got[1])).max()) == 0.0
+    np.testing.assert_allclose(np.asarray(got[1].sum(-1)), HKV * groups, rtol=2e-3)
+    for a, b in zip(got[2:], (q, k, v)):
+        assert a.shape == b.shape and a.dtype == dtype
+    # the probabilities are sums of float32 exponentials of scores from rounded operands
+    limits = ((F32_GAP,) * 5 if dtype == jnp.float32 else
+              (BF16_VALUE_GAP, BF16_VALUE_GAP) + (BF16_GRAD_GAP,) * 3)
+    gaps = [gap(a, b) for a, b in zip(got, want)]
+    assert all(g < limit for g, limit in zip(gaps, limits)), gaps
+    if kind == "causal":  # the same arithmetic; the interpreter's two programs fuse apart
+        positional = value_and_grads(attention.blocked_attention, q, k, v, weight)
+        same = [gap(a, b) for a, b in zip((got[0], *got[2:]), positional)]
+        assert all(g < SAME_GAP[dtype] for g in same), same
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_indexed_attention_on_the_kernels_reads_what_it_reads_on_the_blocks(dtype, monkeypatch):
+    """``pattern.indexed_attention`` with ``kernels`` against itself without, four groups
+    of 128 rows that keep 96 keys: the same mask row for row, so the same ``keys_selected``
+    and ``select_ties`` to the digit; the output, each query's divergence (``index_kl``)
+    and the gradients the two losses send to q, k, v and to the indexer's q, w and k to
+    rounding."""
+    monkeypatch.setattr(attention, "FULL_TILE", 128)
+    seq, top_k, block = 512, 96, 128
+    q, k, v, weight = inputs(seq, 2)
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    qi = jax.random.normal(keys[0], (1, seq, 2, 64))
+    wi = jax.random.normal(keys[1], (1, seq, 2)) / 2
+    ki = jax.random.normal(keys[2], (1, seq, 64))
+    q, k, v, qi, ki = (x.astype(dtype) for x in (q, k, v, qi, ki))
+
+    def run(kernels):
+        def losses(*operands):
+            out, divergence, selected, tied, masks = pattern.indexed_attention(
+                *operands, top_k, block, kernels=kernels)
+            own = jnp.sum(out.astype(jnp.float32) * weight) + jnp.mean(divergence)
+            return own, (out.astype(jnp.float32), divergence, selected, tied, masks)
+
+        (_, aux), grads = jax.jit(jax.value_and_grad(
+            losses, argnums=tuple(range(6)), has_aux=True))(q, k, v, qi, wi, ki)
+        return aux, grads
+
+    (out, divergence, selected, tied, masks), grads = run(True)
+    (want_out, want_divergence, want_selected, want_tied, want_masks), want_grads = run(False)
+    np.testing.assert_array_equal(np.asarray(selected), np.asarray(want_selected))
+    np.testing.assert_array_equal(
+        np.asarray(selected[0]), np.minimum(np.arange(seq) + 1, top_k))
+    np.testing.assert_array_equal(np.asarray(tied), np.asarray(want_tied))
+    for a, b in zip(masks, want_masks):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    value, grad = ((F32_GAP, F32_GAP) if dtype == jnp.float32 else
+                   (BF16_VALUE_GAP, BF16_GRAD_GAP))
+    assert gap(out, want_out) < value
+    assert gap(divergence, want_divergence) < grad
+    gaps = [gap(a, b) for a, b in zip(grads, want_grads)]
+    assert all(g < grad for g in gaps), gaps
+
+
+def test_a_selection_takes_no_window_and_its_own_shape():
+    q, k, v, _ = inputs(256, 1)
+    causal = selection("causal", 256, 256, 0)
+    with pytest.raises(ValueError, match="takes no window"):
+        attention.blocked_attention(q, k, v, window=128, selected=causal)
+    with pytest.raises(ValueError, match="takes no window"):
+        attention.blocked_attention(q, k, v, selected=causal[:, :128])
 
 
 # latent attention's widths (a score 192 wide over values of 128), a score narrower than
@@ -170,20 +300,30 @@ def test_attention_paths_of_the_latent_kind_on_a_tpu_follow_the_shapes(as_on_a_t
 
 
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
-def test_attention_paths_of_the_indexed_kind_are_the_blocks_everywhere(monkeypatch, backend):
-    """The kernels compute their mask from positions and an indexed layer's is data: at
-    heads of 128 and 8,192 tokens, shapes the kernels tile, the kind still takes the
-    blocks, on a TPU too, and says how many keys a query keeps."""
+def test_attention_paths_of_the_indexed_kind_follow_the_backend_and_the_shapes(monkeypatch, backend):
+    """An indexed layer's mask is data, which the kernels take as an operand: at heads of
+    128 and 8,192 tokens, shapes the kernels tile, the kind takes them on a TPU (tiles of
+    512 rows) and the blocks on the CPU, and says on both how many keys a query keeps; at
+    the tests' tiny widths, or a sequence that is not whole tiles and whole blocks of the
+    indexer's rows, the blocks on a TPU too."""
     from benchmark import harness
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     config = harness.read_json(harness.HERE, "configs", "keye-vl2-30b-a3b-l6-ep8.json")
     cfg = harness.load_family(config).program_config(config, 8192)
     assert attention.applies(8192, cfg.head_dim, None)
+    path = {"cpu": {"path": "blocks", "block": 512},
+            "tpu": {"path": "kernel", "tile": attention.FULL_TILE}}[backend]
     assert pattern.attention_paths(cfg, 8192) == {"indexed": {
-        "path": "blocks", "block": 512, "selected": 2048, "selection": "mask"}}
+        **path, "selected": 2048, "selection": "mask"}}
     assert pattern.attention_paths(pattern.PatternConfig.tiny_indexed(), 8) == {"indexed": {
         "path": "blocks", "block": 8, "selected": 8, "selection": "mask"}}
+    # 8,192 + 128 rows are no whole tiles; 384 rows are one tile of the kernels' and one
+    # block of the indexer's, 1,024 rows are two tiles and, by blocks of 768, no whole blocks
+    assert pattern.attention_paths(cfg, 8192 + 128)["indexed"]["path"] == "blocks"
+    assert pattern.attention_paths(cfg, 384)["indexed"]["path"] == path["path"]
+    assert pattern.attention_paths(
+        dataclasses.replace(cfg, attn_block=768), 1024)["indexed"]["path"] == "blocks"
 
 
 def test_attention_paths_off_the_tpu_are_the_blocks():
@@ -280,3 +420,65 @@ def test_every_kernel_of_a_latent_layer_carries_the_core_scope_and_none_the_late
     products = [n for n in names.values() if latent.search(n) and n.endswith("dot_general")]
     for pass_ in ("jvp(attn/full)", "rematted_computation/attn/full", "transpose("):
         assert any(pass_ in n for n in products), (pass_, products)
+
+
+def mosaic_kernels(lowered_text: str) -> list:
+    """(name, operands, sha256 of the module printed without locations) of each Mosaic
+    kernel in a program lowered for the TPU. The payload of a kernel holds the file and
+    the line of every operation of its source, so the bytes themselves move with any edit
+    to the file; the operations do not."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    context = mlir.JaxIrContext()
+    tpu.register_dialect(context)
+    context.allow_unregistered_dialects = True
+    kernels = []
+    for operands, body in re.findall(
+            r'stablehlo\.custom_call @tpu_custom_call\(([^)]*)\).*?\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+            lowered_text):
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            text = module.operation.get_asm(enable_debug_info=False)
+        name = re.search(r"module @(\w+)", text).group(1)
+        kernels.append((name, operands.count("%"), hashlib.sha256(text.encode()).hexdigest()))
+    return kernels
+
+
+#: the three kernels of a laguna layer at the cell's shapes (8,192 tokens, 8 KV heads of
+#: 128) as they were lowered at the parent of PR 36, before the kernels took a selection
+AS_BEFORE_A_SELECTION = {
+    "full": [  # 48 query heads, tiles of 512 rows
+        ("blocked_attention_fwd", 3, "49f06cc0ad5e668833dc7cbdf43ca7ee9f6d81b4088c48e2b354c4f3836f7b32"),
+        ("blocked_attention_dq", 6, "4db3f5b67eb15450abaeaf29e5336c43be6abd2c16df6d3418c6ee4cd4849fd5"),
+        ("blocked_attention_dkv", 6, "0d4d70a3f0b6a48b5eb7fd539ba6fda5da966af779beced8b44356ac7bc5a88e")],
+    "sliding": [  # 64 query heads, a window of 512 in tiles of 256 rows
+        ("blocked_attention_fwd", 3, "9e8419de2dc59086f108baa1012abea51d065f9533f7e2c72245fc9c23f50c98"),
+        ("blocked_attention_dq", 6, "3b2b815c7b9372fb688df8df0f55f52b54142c9c3a4a66fee67dae604fd14897"),
+        ("blocked_attention_dkv", 6, "c2b41c4b520135fe03f80a8a24cf2364cd9f544f319b0f9ba74207e1c49f3cfc")],
+}
+
+
+@pytest.mark.parametrize("kind", ["full", "sliding"])
+def test_without_a_selection_the_kernels_lower_to_what_they_were(kind, as_on_a_tpu):
+    """``blocked_attention(..., selected=None)`` at laguna's shapes, lowered for the TPU
+    (nothing compiles or runs): three kernels by name, their operands (no selection among
+    them) and, location for location aside, the very operations of the parent of PR 36. An
+    edit that reaches the kernels laguna runs shows here, at no chip time; one that is
+    meant to changes these hashes with its own before and after."""
+    cfg = laguna_config(8192)
+    heads = {spec.attn: spec.n_heads for spec in cfg.layers}[kind]
+    window = cfg.window if kind == "sliding" else None
+    q = jax.ShapeDtypeStruct((1, 8192, heads, cfg.head_dim), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(attention.blocked_attention(q, k, v, window=window).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert mosaic_kernels(text) == AS_BEFORE_A_SELECTION[kind]

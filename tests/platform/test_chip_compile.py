@@ -288,14 +288,16 @@ def test_latent_attention_compiles_for_v5e_on_the_blocks_with_its_scopes(one_chi
     assert sum(1 for name in names if latent.search(name)) >= 8
 
 
-def test_indexed_attention_compiles_for_v5e_on_the_blocks_with_its_scopes(one_chip, as_on_a_tpu):
+def test_indexed_attention_compiles_for_v5e_on_the_kernels_with_its_scopes(one_chip, as_on_a_tpu):
     """One indexed attention sublayer at the keye configuration's own widths (8,192 tokens,
     32 heads over 4 KV heads of 128, an indexer of 16 heads of 64 that keeps 2,048 keys),
-    differentiated through the layer's ``jax.checkpoint`` with the selection kept: the
-    mask is an operand, so the products go by the ``jax.numpy`` blocks, no custom call,
-    four mapped bodies; ops under ``attn/full/core`` where ``attn.roofline`` looks and under
-    ``attn/full/indexer`` and ``attn/full/select`` where the two new readers do, forward
-    and backward, and the counting passes of the selection in the first forward alone."""
+    differentiated through the layer's ``jax.checkpoint`` with the selection, the
+    attention output and its log-sum-exp kept: the products go by the blocked kernels with
+    the selection as an operand, five custom calls (forward, the summed probabilities and
+    again for the backward pass, dQ, dK/dV), every one under ``attn/full/core`` where
+    ``attn.roofline`` looks; ops under ``attn/full/indexer`` and ``attn/full/select`` where
+    the two readers of PR 35 do, forward and backward, and the counting passes of the
+    selection in the first forward alone."""
     import re
 
     from benchmark import harness
@@ -304,13 +306,14 @@ def test_indexed_attention_compiles_for_v5e_on_the_blocks_with_its_scopes(one_ch
     config, cfg = cell_config("keye-vl2-30b-a3b-l6-ep8")
     seq = config["batch"][1]
     assert pattern.attention_paths(cfg, seq) == {"indexed": {
-        "path": "blocks", "block": 512, "selected": 2048, "selection": "mask"}}
+        "path": "kernel", "tile": 512, "selected": 2048, "selection": "mask"}}
     assert pattern.key_groups(seq, 512) == [(0, 2048), (2048, 4096), (4096, 6144), (6144, 8192)]
     params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
     lp = jax.tree.map(lambda w: sds(w.shape[1:], w.dtype, one_chip), params["attn"]["indexed"])
     tables = (pattern.rope_tables(cfg.rope_indexed, cfg.head_dim, seq)
               + pattern.rope_tables(cfg.rope_indexed, cfg.indexer.head_dim, seq))
-    policy = jax.checkpoint_policies.save_only_these_names(*pattern.KEPT_GROUPS["selection"])
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *pattern.KEPT_GROUPS["selection"], *pattern.KEPT_GROUPS["attention"])
 
     def loss(x, lp):
         layer = jax.checkpoint(lambda x, lp: pattern._indexed_block(cfg, x, lp, *tables)[:2],
@@ -324,14 +327,19 @@ def test_indexed_attention_compiles_for_v5e_on_the_blocks_with_its_scopes(one_ch
     names = re.findall(r'op_name="([^"]*)"', text)
     core = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
     own = harness.load_by_path("layer_metrics", "attn.indexer_ms").SCOPES
-    assert 'custom_call_target="tpu_custom_call"' not in text
-    assert sum(1 for name in names if core.search(name)) >= 8
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
+        "blocked_attention_dkv", "blocked_attention_dq", "blocked_attention_fwd",
+        "blocked_attention_probs", "blocked_attention_probs"], kernels
+    assert all(core.search(name) for name in kernels), kernels
     for scope, mark in own.items():
         under = [name for name in names if mark.search(name)]
         assert len(under) >= 8, scope
         assert any("jvp(" in name for name in under), scope
         # the mask is kept: the backward pass holds nothing of the selection but its reading
         assert any("transpose(" in name for name in under) == (scope == "indexer"), scope
+    # the float32 target of one layer and its cotangent's terms, not the blocks' scores
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
@@ -386,11 +394,18 @@ def test_laguna_train_step_fits_one_chip_and_runs_each_forward_kernel_once(one_c
 @pytest.mark.slow  # 160-210 s of compilation on every core: run by hand, with the chip's own check
 def test_keye_train_step_fits_one_chip(one_chip, as_on_a_tpu):
     """The whole donating step of ``keye-vl2-30b-a3b-l6-ep8`` at 1 x 8192, attention under
-    the indexer's mask on the blocks: 659,189,632 parameters, 7.91e9 B of f32 weights and
-    AdamW moments, and what the step needs beside them inside one v5e's 15.75 GiB, with its
-    six groups of residuals kept (the selection among them). The tier-1 run has the one
-    indexed layer above; this one takes as long as the rest of the file together."""
+    the indexer's selection on the kernels: 659,189,632 parameters, 7.91e9 B of f32 weights
+    and AdamW moments, and what the step needs beside them inside one v5e's 15.75 GiB, with
+    its six groups of residuals kept (the selection among them); a layer runs the forward,
+    dQ and dK/dV kernels once and the kernel of the summed probabilities twice, since the
+    indexer's target is not kept. The tier-1 run has the one indexed layer above; this one
+    takes as long as the rest of the file together."""
     compiled, n_params, needed = pattern_step(*cell_config("keye-vl2-30b-a3b-l6-ep8"), one_chip)
     assert n_params == 659_189_632
     assert 7.9e9 < compiled.memory_analysis().argument_size_in_bytes < 8.0e9
-    assert needed < 15.75 * 2 ** 30, needed  # 13.71e9 (compile, PR 35)
+    assert needed < 15.75 * 2 ** 30, needed  # 13.85e9 (compile, PR 36; 13.71e9 on the blocks, PR 35)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = {name: sum(1 for line in calls if f"blocked_attention_{name}" in line)
+               for name in ("fwd", "probs", "dq", "dkv")}
+    assert kernels == {"fwd": 6, "probs": 12, "dq": 6, "dkv": 6}, kernels

@@ -691,7 +691,7 @@ def _whole(x, axis: int):
     return x.reshape(*x.shape[:axis], -1, *x.shape[axis + 2:])
 
 
-def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int):
+def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool = False):
     """Causal attention over the keys an indexer selects: q ``[B, T, H, dh]``, k / v ``[B,
     T, Hkv, dh]``, and the indexer's qi ``[B, T, J, di]``, wi ``[B, T, J]``, ki ``[B, T,
     di]`` -> (the attention output ``[B, T, H * dh]``; per query ``[B, T]``: the divergence
@@ -700,47 +700,66 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int):
     each group's rows of the mask, ``[B, rows, keys]`` bool, for :func:`choices`).
 
     The query rows go in groups (:func:`key_groups`), each against the causal prefix of
-    the keys up to its own end, so the last rows of a group score up to a quarter of the
-    sequence beyond their own position. A group computes its index scores by blocks of
-    ``block`` rows (:func:`index_scores`, one block's products of all the indexer's heads
-    alive at a time; made again in the backward pass), turns them into its rows of the
-    mask (:func:`select_keys`; named ``select_mask``, so a layer may keep it), runs
-    :func:`_attend_summed` under that mask by the same blocks, each head in turn, with the
-    heads' probabilities summed on the way, and takes the divergence. The loops over the
-    blocks are ``jax.lax.map``s: a layer compiles one body a group and stage. Nothing
-    differentiable passes through the mask or the probabilities. Scopes: ``indexer``,
-    ``select``, ``core``, each around its loop (an op's name holds a scope before the
-    loop's own components)."""
+    the keys up to its own end. A group computes its index scores by blocks of ``block``
+    rows (:func:`index_scores`, one block's products of all the indexer's heads alive at
+    a time; made again in the backward pass) and turns them into its rows of the mask
+    (:func:`select_keys`; named ``select_mask``, so a layer may keep it). The products
+    under the mask, with the heads' probabilities summed on the way, go one of two ways
+    (:func:`attention_paths`). On the ``jax.numpy`` blocks each group runs
+    :func:`_attend_summed` by the same blocks of rows, each head in turn, so the last rows
+    of a group score up to a quarter of the sequence beyond their own position. With
+    ``kernels`` (a sequence of whole blocks that the kernels tile) the groups' rows are put
+    together into the sequence's selection, a byte a (query, key), and one call of
+    ``ops/attention.py:blocked_attention`` takes it as an operand: no key past a query
+    tile's diagonal tile is scored, the forward's output and log-sum-exp carry the names a
+    layer keeps, and a second kernel gives the summed probabilities. Then each group takes
+    its divergence from its rows of them. The loops over the blocks are ``jax.lax.map``s: a
+    layer compiles one body a group and stage. Nothing differentiable passes through the
+    mask or the probabilities. Scopes: ``indexer``, ``select``, ``core``, each around its
+    loop or its kernels (an op's name holds a scope before the loop's own components)."""
     b, t, heads = q.shape[:3]
     block = min(block, t)
     q, k, v, qi, wi, ki = (_pad_rows(x, 1, block) for x in (q, k, v, qi, wi, ki))
-    q, k, v = _heads_first(q, k, v)
+    groups = key_groups(q.shape[1], block)
     score_block = jax.checkpoint(index_scores)
-    outs, rows, masks = [], [], []
-    for first, keys in key_groups(q.shape[3], block):
+    scores, masks, rows = [], [], []
+    for first, keys in groups:
         n = (keys - first) // block
-        k_seen, v_seen, ki_seen = k[:, :, :keys], v[:, :, :keys], ki[:, :keys]
+        ki_seen = ki[:, :keys]
         with jax.named_scope("indexer"):
-            scores = _whole(jax.lax.map(
+            scores.append(_whole(jax.lax.map(
                 lambda rows, ki_seen=ki_seen: score_block(*rows, ki_seen),
-                (_in_blocks(qi[:, first:keys], 1, n), _in_blocks(wi[:, first:keys], 1, n))), 1)
+                (_in_blocks(qi[:, first:keys], 1, n), _in_blocks(wi[:, first:keys], 1, n))), 1))
         with jax.named_scope("select"):
-            mask, tied = select_keys(jax.lax.stop_gradient(scores), first, top_k)
+            mask, tied = select_keys(jax.lax.stop_gradient(scores[-1]), first, top_k)
             if keys > top_k:  # else the causal mask, which nothing needs to keep
                 mask = checkpoint_name(mask, KEPT_GROUPS["selection"][0])
-            selected = jnp.sum(mask, axis=-1, dtype=jnp.int32)
-        with jax.named_scope("core"):
-            out, probs = jax.lax.map(
-                lambda rows, k_seen=k_seen, v_seen=v_seen: _over_heads_summed(
-                    rows[0], k_seen, v_seen, rows[1]),
-                (_in_blocks(q[:, :, :, first:keys], 3, n), _in_blocks(mask, 1, n)))
-        with jax.named_scope("indexer"):
-            divergence = index_divergence(scores, mask, _whole(probs, 1) / heads)
-        outs.append(_whole(out, 3))
-        rows.append((divergence, selected, tied))
+            rows.append((jnp.sum(mask, axis=-1, dtype=jnp.int32), tied))
         masks.append(mask)
-    divergence, selected, tied = (jnp.concatenate(x, axis=1)[:, :t] for x in zip(*rows))
-    return _heads_last(jnp.concatenate(outs, axis=3), t), divergence, selected, tied, masks
+    with jax.named_scope("core"):
+        if kernels:
+            selection = jnp.concatenate([jnp.pad(m.astype(jnp.int8), (
+                (0, 0), (0, 0), (0, t - m.shape[2]))) for m in masks], axis=1)
+            out, probs = attention.blocked_attention(q, k, v, selected=selection)
+            probs = [probs[:, first:keys, :keys] for first, keys in groups]
+        else:
+            q, k, v = _heads_first(q, k, v)
+            outs, probs = [], []
+            for (first, keys), mask in zip(groups, masks):
+                n = (keys - first) // block
+                group_out, group_probs = jax.lax.map(
+                    lambda rows, seen=(k[:, :, :keys], v[:, :, :keys]): _over_heads_summed(
+                        rows[0], *seen, rows[1]),
+                    (_in_blocks(q[:, :, :, first:keys], 3, n), _in_blocks(mask, 1, n)))
+                outs.append(_whole(group_out, 3))
+                probs.append(_whole(group_probs, 1))
+            out = _heads_last(jnp.concatenate(outs, axis=3), t)
+    with jax.named_scope("indexer"):
+        divergence = [index_divergence(s, mask, p / heads)
+                      for s, mask, p in zip(scores, masks, probs)]
+    divergence, selected, tied = (jnp.concatenate(x, axis=1)[:, :t]
+                                  for x in (divergence, *zip(*rows)))
+    return out, divergence, selected, tied, masks
 
 
 def _window(cfg: PatternConfig, kind: str) -> Optional[int]:
@@ -761,23 +780,26 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     kernels of ``ops/attention.py``, ``{"path": "blocks", "block": rows}`` for the
     ``jax.numpy`` blocks. The kernels take one width for queries, keys and values, so a
     latent kind whose score width differs from its value width takes the blocks, and
-    says both widths; and they compute their mask from positions, so an indexed kind,
-    whose mask is data, takes the blocks and says how many keys a query keeps."""
+    says both widths. An indexed kind's mask is data, which the kernels take as an
+    operand (``selection: "mask"``: every key up to a query tile's diagonal tile is
+    scored and the unselected ones masked, on either path) where the sequence is also
+    whole blocks of the indexer's rows; it says how many keys a query keeps."""
     paths = {}
     for kind in ATTENTION_KINDS:
         if not cfg.count(kind):
             continue
         window = _window(cfg, kind)
         score, value = _widths(cfg, kind)
-        if (jax.default_backend() == "tpu" and score == value and kind != INDEXED
-                and attention.applies(seq, score, window)):
+        block = cfg.window if kind == SLIDING else min(cfg.attn_block, seq)
+        if (jax.default_backend() == "tpu" and score == value
+                and attention.applies(seq, score, window)
+                and not (kind == INDEXED and seq % block)):
             paths[kind] = {"path": "kernel", "tile": attention.tile_of(seq, window)}
         else:
-            paths[kind] = {"path": "blocks",
-                           "block": cfg.window if kind == SLIDING else min(cfg.attn_block, seq)}
+            paths[kind] = {"path": "blocks", "block": block}
         if kind == LATENT:
             paths[kind].update(score_width=score, value_width=value)
-        if kind == INDEXED:  # the kernels compute their mask from positions
+        if kind == INDEXED:
             paths[kind].update(selected=min(cfg.indexer.top_k, seq), selection="mask")
     return paths
 
@@ -884,11 +906,20 @@ def _indexed_block(cfg: PatternConfig, x, lp: dict, cos, sin, index_cos, index_s
         q, k, v, qi, wi, ki = (checkpoint_name(a, name)
                                for a, name in zip((q, k, v, qi, wi, ki), names))
         attn, divergence, selected, tied, masks = indexed_attention(
-            q, k, v, qi, wi, ki, ix.top_k, cfg.attn_block)
+            q, k, v, qi, wi, ki, ix.top_k, cfg.attn_block,
+            kernels=attention_paths(cfg, t)[INDEXED]["path"] == "kernel")
         attn = checkpoint_name(attn, attention.OUT_NAME)
+        out = x + attn @ lp["wo"].astype(attn.dtype)
+        # The indexer's loss hangs off the layer to one side and its value is read at the
+        # end of the step, so the compiler is free to leave a layer's divergence for much
+        # later, with the sequence's float32 target (268e6 B at 8,192 tokens) held for it.
+        # The stream leaves the layer with the divergence: 13.85e9 B for the step of the
+        # 8,192-token cell against 15.45e9 (compile for a v5e, PR 36), and 469 ms a step
+        # against 480 (chip run, PR 36).
+        out, divergence = jax.lax.optimization_barrier((out, divergence))
         counts = {"index_kl": jnp.mean(divergence), "keys_selected": jnp.sum(selected),
                   "select_ties": jnp.sum(tied)}
-        return x + attn @ lp["wo"].astype(attn.dtype), counts, masks
+        return out, counts, masks
 
 
 # ---------------------------------------------------------------------------------
@@ -1163,9 +1194,10 @@ def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int, seq: int) -> di
     score, value = _widths(cfg, spec.attn)
     kv_heads = spec.n_heads if spec.attn == LATENT else cfg.n_kv_heads
     indexed = spec.attn == INDEXED
-    # the kernels take one width of whole lane groups and a mask made from positions;
-    # only they make a log-sum-exp
-    kernels = score == value and not score % attention.LANES and not indexed
+    # the kernels take one width of whole lane groups, and an indexed kind goes by them
+    # where :func:`attention_paths` says so; only they make a log-sum-exp
+    kernels = score == value and not score % attention.LANES and (
+        not indexed or attention_paths(cfg, seq)[INDEXED]["path"] == "kernel")
     lse = 4 * spec.n_heads if kernels else 0
     index = selection = 0
     if indexed:
