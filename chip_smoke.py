@@ -136,38 +136,22 @@ def hbm_in_use() -> int | None:
     return int(stats["bytes_in_use"]) if stats else None
 
 
-class CacheCounter:
-    """Counts this process's persistent-compile-cache traffic from JAX's own
-    monitoring events: every compile that consulted the cache, and every hit."""
-
-    REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
-    HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self) -> None:
-        import jax
-
-        self.requests = self.hits = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_) -> None:
-        if event == self.REQUEST:
-            self.requests += 1
-        elif event == self.HIT:
-            self.hits += 1
-
-    def facts(self) -> dict:
-        return {"cache_requests": self.requests, "cache_hits": self.hits,
-                "cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR", "")}
+def cache_facts() -> dict:
+    """This process's persistent-compile-cache traffic, as the package's own compile
+    watcher counts it (``platform/compile_cache.py:watch``): every compile that
+    consulted the cache, and every hit."""
+    totals = compile_cache.compile_totals()
+    return {"cache_requests": totals["requests"], "cache_hits": totals["hits"],
+            "cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR", "")}
 
 
-def start_child_runtime(tiny: bool) -> tuple[dict, CacheCounter]:
+def start_child_runtime(tiny: bool) -> dict:
     """What every child does first: take the device — at full size that is a
     TPU or the child stops here, before it places anything — then the
     compile-cache sweep and event (the directory is the parent's
-    ``$JAX_COMPILATION_CACHE_DIR``)."""
+    ``$JAX_COMPILATION_CACHE_DIR``), which also starts the compile watcher."""
     from tpu_resiliency.platform.device import apply_compile_cache_env
 
-    counter = CacheCounter()
     device = device_facts()
     if not tiny and device["platform"] != "tpu":
         raise WrongPlatform(
@@ -175,7 +159,7 @@ def start_child_runtime(tiny: bool) -> tuple[dict, CacheCounter]:
             f"{device['platform']!r} ({device['kind']}, {device['count']} device(s))"
         )
     apply_compile_cache_env()
-    return device, counter
+    return device
 
 
 def model_config(tiny: bool):
@@ -268,7 +252,7 @@ def phase_telemetry(args) -> dict:
     from tpu_resiliency.telemetry.sharded import MeshTelemetry
 
     t_start = time.time()
-    device, cache = start_child_runtime(args.tiny)
+    device = start_child_runtime(args.tiny)
     on_tpu = device["platform"] == "tpu"
     sz = SIZES["tiny" if args.tiny else "full"]
     r, s, w = sz["ranks"], sz["signals"], sz["window"]
@@ -344,7 +328,7 @@ def phase_telemetry(args) -> dict:
         "medians_bit_equal": bool(np.array_equal(medians, reference)),
         "f1": round(f1, 4), "flagged": int(mask.sum()), "truth": int(truth.sum()),
         "compile_push_s": round(compile_push_s, 2), "window_s": round(window_s, 2),
-        "seconds": round(time.time() - t_start, 1), **cache.facts(),
+        "seconds": round(time.time() - t_start, 1), **cache_facts(),
     }
 
 
@@ -464,21 +448,21 @@ def phase_train_worker(args) -> dict:
 
     @record
     def main():
-        _, cache = start_child_runtime(args.tiny)
+        start_child_runtime(args.tiny)
         round_no = int(os.environ.get("TPU_FT_RESTART_COUNT", "0"))
 
         def kill_in_round_0(step_index, mgr):
             if round_no == 0 and step_index + 1 == TRAIN["kill_after"]:
                 mgr.maybe_finalize(blocking=True)
                 record_event("smoke", "smoke_kill", step=step_index,
-                             latest=mgr.find_latest(), round=round_no, **cache.facts())
+                             latest=mgr.find_latest(), round=round_no, **cache_facts())
                 os.kill(os.getpid(), signal.SIGKILL)
 
         out = run_incarnation(
             args, TRAIN, os.path.join(args.work, "ckpt"), {"round": round_no},
             fault=kill_in_round_0,
         )
-        record_event("smoke", "smoke_cache", round=round_no, **cache.facts())
+        record_event("smoke", "smoke_cache", round=round_no, **cache_facts())
         return out
 
     return main()
@@ -502,7 +486,7 @@ def phase_inprocess(args) -> dict:
     t_start = time.time()
     # The chip is taken BEFORE the wrapper forks its monitor daemon — the order a
     # user's script would have; the daemon closes every inherited descriptor.
-    device, cache = start_child_runtime(args.tiny)
+    device = start_child_runtime(args.tiny)
     ckpt_dir = os.path.join(args.work, "ckpt_inprocess")
     ran: list[str] = []
 
@@ -548,7 +532,7 @@ def phase_inprocess(args) -> dict:
     check(ran == ["AbortJaxDistributed", "AbortCompilationCache", "JaxHealthCheck"],
           f"restart chain ran {ran}")
     return {"ok": not check.failed, "failed": check.failed, "device": after,
-            "chain": ran, "seconds": round(time.time() - t_start, 1), **cache.facts()}
+            "chain": ran, "seconds": round(time.time() - t_start, 1), **cache_facts()}
 
 
 # -- phase multichip (--chips 4) ----------------------------------------------
@@ -573,7 +557,7 @@ def phase_multichip_worker(args) -> dict:
     @record
     def main():
         t_start = time.time()
-        device, cache = start_child_runtime(args.tiny)
+        device = start_child_runtime(args.tiny)
         on_tpu = device["platform"] == "tpu"
         check = Check()
         if not check(device["count"] >= 4, f"needs 4 devices, JAX found {device['count']}"):
@@ -722,7 +706,7 @@ def phase_multichip_worker(args) -> dict:
             "restore_s": round(restore_s, 2), "restored_byte_equal": crc_restored == crc_file,
             "telemetry_ranks_per_chip": r // 4, "use_pallas": mt4.use_pallas,
             "score_max_diff": score_diff, "same_straggler_set": same_set, "f1": round(f1, 4),
-            "seconds": round(time.time() - t_start, 1), **cache.facts(),
+            "seconds": round(time.time() - t_start, 1), **cache_facts(),
         }
         return result
 
@@ -1018,6 +1002,11 @@ def train_phase(args, work: str, env: dict, timeout: float, on_tpu_required: boo
     check(len(by_round) == 2 and by_round[1] == "hit", f"compile_cache outcome by round: {by_round}")
     check(cache_ev.get(1, {}).get("cache_hits", 0) >= 1,
           f"round 1 loaded nothing from the compile cache: {cache_ev.get(1)}")
+    # ... and the step itself, by the watcher's own event of it: which program, not how many
+    step_cache = [[c["cache"] for c in kinds("compile")
+                   if c["pid"] == e["pid"] and c["fun_name"] == "jit(train_step)"] for e in inc]
+    check(len(step_cache) == 2 and bool(step_cache[1]) and set(step_cache[1]) == {"hit"},
+          f"round 1 did not load the step from the compile cache: {step_cache}")
 
     worker_pids = {e["pid"] for e in inc}
     table = []
@@ -1052,7 +1041,7 @@ def train_phase(args, work: str, env: dict, timeout: float, on_tpu_required: boo
         if restored else None,
         "report_source": sources, "profile_source": psources, "reports": len(reports),
         "prog_signals": prog, "rings_native": inc[-1]["rings_native"] if inc else None,
-        "compile_cache_by_round": by_round,
+        "compile_cache_by_round": by_round, "step_cache_by_round": step_cache,
         "cache_counts_by_round": {str(r): {k: e[k] for k in ("cache_requests", "cache_hits")}
                                   for r, e in sorted(cache_ev.items())},
         "cache_dir": cache_ev.get(1, {}).get("cache_dir"),

@@ -213,3 +213,143 @@ def test_unset_entry_programs_share_one_fixed_checkout_path(tmp_path):
     assert outs[0] == outs[1] == [os.path.join(repo, ".jax_cache"), "None"]
     with open(os.path.join(repo, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+# -- the compile watcher: one ``compile`` event per executable -----------------
+
+WATCH_SNIPPET = """
+import json, sys
+from tpu_resiliency.platform import compile_cache, device
+import jax, jax.numpy as jnp
+device.apply_compile_cache_env()      # registers the watcher, directory or none
+device.apply_compile_cache_env()      # ... once
+inner = jax.jit(lambda x: jnp.sin(x) * 2)
+def train_step(x):
+    return (inner(x) + jnp.tanh(x @ x.T)).sum()
+step = jax.jit(train_step)
+x = jnp.ones((32, 32), jnp.float32)
+jax.block_until_ready(step(x))
+calls = compile_cache._watcher.calls
+for _ in range(100):
+    out = step(x)
+jax.block_until_ready(out)
+cached_dispatch_calls = compile_cache._watcher.calls - calls
+jax.clear_caches()
+jax.block_until_ready(step(x))
+from jax._src import monitoring
+listeners = sum(getattr(cb, "__self__", None) is compile_cache._watcher
+                for cb in monitoring.get_event_listeners()
+                + monitoring.get_event_duration_listeners())
+with open(sys.argv[1], "w") as fh:
+    json.dump({"cached_dispatch_calls": cached_dispatch_calls, "listeners": listeners,
+               "totals": compile_cache.compile_totals()}, fh)
+"""
+
+
+def _run_watch_worker(tmp_path, tag, cache_dir):
+    out = tmp_path / f"watch_{tag}.json"
+    events_file = tmp_path / f"watch_events_{tag}.jsonl"
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop(compile_cache.CACHE_DIR_ENV, None)
+    if cache_dir is not None:
+        env[compile_cache.CACHE_DIR_ENV] = str(cache_dir)
+    env["TPU_RESILIENCY_EVENTS_FILE"] = str(events_file)
+    r = subprocess.run([sys.executable, "-c", WATCH_SNIPPET, str(out)],
+                       capture_output=True, text=True, timeout=180, env=env)
+    assert r.returncode == 0, r.stderr[-3000:]
+    evs = [json.loads(ln) for ln in events_file.read_text().splitlines()]
+    return json.loads(out.read_text()), [e for e in evs if e.get("kind") == "compile"]
+
+
+@pytest.fixture(scope="module")
+def watched(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("watch")
+    return {"cached": _run_watch_worker(tmp, "cached", tmp / "cc"),
+            "uncached": _run_watch_worker(tmp, "uncached", None)}
+
+
+def _steps(compiles):
+    return [e for e in compiles if e["fun_name"] == "jit(train_step)"]
+
+
+@pytest.mark.parametrize("mode", ["cached", "uncached"])
+def test_watcher_gives_one_compile_event_a_program(watched, mode):
+    facts, compiles = watched[mode]
+    steps = _steps(compiles)
+    assert len(steps) == 2, [e["fun_name"] for e in compiles]  # the first call, and after clear_caches
+    for e in steps:
+        assert e["source"] == "platform"
+        assert all(isinstance(e[k], float) and e[k] >= 0 for k in ("trace_s", "lower_s", "backend_s"))
+        assert e["trace_s"] > 0 and e["backend_s"] > 0
+    # the inner jitted function is inlined: traced on the way, no executable, no event
+    assert not any("lambda" in e["fun_name"] for e in compiles)
+    assert facts["listeners"] == 2  # one watcher: JAX keeps plain and duration listeners apart
+
+
+def test_second_compile_after_clear_caches_is_a_hit(watched):
+    _, compiles = watched["cached"]
+    first, second = _steps(compiles)
+    assert first["cache"] == "miss" and "retrieval_s" not in first
+    assert second["cache"] == "hit" and 0 <= second["retrieval_s"] <= second["backend_s"]
+
+
+def test_without_a_directory_programs_are_uncached(watched):
+    facts, compiles = watched["uncached"]
+    assert compiles and {e["cache"] for e in compiles} == {"uncached"}
+    assert facts["totals"]["requests"] == facts["totals"]["hits"] == facts["totals"]["misses"] == 0
+    assert facts["totals"]["seconds"] > 0
+
+
+@pytest.mark.parametrize("mode", ["cached", "uncached"])
+def test_a_cached_dispatch_calls_no_listener(watched, mode):
+    """JAX fires none of the watched events when a compiled function is called again:
+    100 calls of the step, no call of the watcher."""
+    facts, _ = watched[mode]
+    assert facts["cached_dispatch_calls"] == 0
+
+
+def test_running_totals_follow_the_events(watched):
+    facts, compiles = watched["cached"]
+    last = compiles[-1]
+    assert {k: last[k] for k in ("requests", "hits", "misses", "seconds")} == facts["totals"]
+    assert facts["totals"]["requests"] == sum(e["cache"] != "uncached" for e in compiles)
+    assert facts["totals"]["hits"] == sum(e["cache"] == "hit" for e in compiles) >= 1
+    assert facts["totals"]["misses"] == facts["totals"]["requests"] - facts["totals"]["hits"]
+    seconds = sum(e["trace_s"] + e["lower_s"] + e["backend_s"] for e in compiles)
+    assert facts["totals"]["seconds"] == pytest.approx(seconds)
+    assert [e["seconds"] for e in compiles] == sorted(e["seconds"] for e in compiles)
+
+
+def test_watcher_folds_in_jaxs_order_and_drops_inner_traces():
+    """The fold itself, fed what JAX 0.9.0 emits for one program on one thread: inner
+    functions' trace durations first (dropped: the outer trace contains them), then
+    the program's own, its lowering, the cache's events, the backend's duration."""
+    from tpu_resiliency.utils import events
+
+    seen = []
+    sink = lambda ev: seen.append(ev.to_record())  # noqa: E731
+    events.add_sink(sink)
+    try:
+        w = compile_cache._CompileWatcher()
+        w.on_duration(compile_cache._TRACE, 0.25, fun_name="sin")
+        w.on_duration(compile_cache._TRACE, 0.5, fun_name="inner")
+        w.on_duration(compile_cache._TRACE, 2.0, fun_name="train_step")
+        w.on_duration(compile_cache._LOWER, 1.0, fun_name="jit(train_step)")
+        w.on_event(compile_cache._REQUEST)
+        w.on_event(compile_cache._HIT)
+        w.on_duration(compile_cache._RETRIEVAL, 3.0)
+        w.on_duration(compile_cache._BACKEND, 4.0, fun_name="jit(train_step)")
+        # the next program starts clean: no cache consulted, no trace of its name
+        w.on_duration(compile_cache._BACKEND, 0.5, fun_name="jit(other)")
+        w.on_event("/jax/some/other/event")
+        w.on_duration("/jax/some/other/duration", 9.0)
+    finally:
+        events.remove_sink(sink)
+    first, second = [e for e in seen if e["kind"] == "compile"]
+    assert (first["fun_name"], first["trace_s"], first["lower_s"], first["backend_s"],
+            first["retrieval_s"], first["cache"]) == ("jit(train_step)", 2.0, 1.0, 4.0, 3.0, "hit")
+    assert (second["trace_s"], second["lower_s"], second["cache"]) == (0.0, 0.0, "uncached")
+    assert "retrieval_s" not in second
+    assert w.totals() == {"requests": 1, "hits": 1, "misses": 0, "seconds": 7.5}
+    assert w.calls == 11

@@ -50,6 +50,11 @@ def test_tiny_rehearsal_runs_every_phase_and_prints_no_result(tmp_path):
     assert train["report_source"] == ["mesh"] and train["reports"] >= 2
     assert train["compile_cache_by_round"][1] == "hit"
     assert train["cache_counts_by_round"]["1"]["cache_hits"] >= 1
+    # the counts are the package's compile watcher's, and so is the step's own event
+    assert train["step_cache_by_round"][0] == ["miss"]
+    assert train["step_cache_by_round"][1] == ["hit"]
+    assert train["cache_counts_by_round"]["1"]["cache_requests"] >= \
+        train["cache_counts_by_round"]["1"]["cache_hits"]
     assert train["loss_first_last"][1] < train["loss_first_last"][0]
     # Round 1 is the parked spare, promoted; nobody but a worker has a backend.
     assert train["promotions"][-1] == "promoted"

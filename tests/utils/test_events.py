@@ -125,9 +125,106 @@ def test_debug_time_nesting_and_event(tmp_path, caplog):
     lines = [r.message for r in caplog.records if "ms" in r.message]
     assert any(m.startswith("  inner:") for m in lines)  # nested → indented
     assert any(m.startswith("outer:") for m in lines)
-    # Only the root scope reaches the event stream.
-    recs = events.read_events(path)
-    assert [r["name"] for r in recs if r["kind"] == "timing"] == ["outer"]
+    # Nested scopes reach the event stream too, each with where it sits.
+    recs = [r for r in events.read_events(path) if r["kind"] == "timing"]
+    assert [(r["name"], r["depth"], r["parent"]) for r in recs] == [
+        ("inner", 1, "outer"), ("outer", 0, None)]
+    assert recs[0]["duration_s"] <= recs[1]["duration_s"]
+
+
+def test_debug_time_payload_failure_and_sibling_scopes(tmp_path):
+    from tpu_resiliency.utils import events
+    from tpu_resiliency.utils.timers import debug_time
+
+    path = str(tmp_path / "t.jsonl")
+    events.add_sink(events.JsonlSink(path))
+    with pytest.raises(ValueError):
+        with debug_time("root", source="checkpoint", bytes=7):
+            with debug_time("first", source="checkpoint"):
+                pass
+            with debug_time("second", source="checkpoint", leaves=2):
+                raise ValueError("nope")
+    with debug_time("after", source="checkpoint"):  # the stack unwound: a root again
+        pass
+    recs = {r["name"]: r for r in events.read_events(path) if r["kind"] == "timing"}
+    assert recs["root"]["bytes"] == 7 and recs["second"]["leaves"] == 2
+    assert recs["first"]["ok"] is True and recs["first"]["parent"] == "root"
+    assert recs["second"]["ok"] is False and "nope" in recs["second"]["error"]
+    assert recs["root"]["ok"] is False and recs["root"]["depth"] == 0
+    assert recs["after"]["depth"] == 0 and recs["after"]["parent"] is None
+
+
+def test_debug_time_scopes_are_per_thread(tmp_path):
+    import threading
+
+    from tpu_resiliency.utils import events
+    from tpu_resiliency.utils.timers import debug_time
+
+    path = str(tmp_path / "t.jsonl")
+    events.add_sink(events.JsonlSink(path))
+
+    def other():
+        with debug_time("elsewhere", source="checkpoint"):
+            pass
+
+    with debug_time("here", source="checkpoint"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    recs = {r["name"]: r for r in events.read_events(path) if r["kind"] == "timing"}
+    assert recs["elsewhere"]["depth"] == 0 and recs["elsewhere"]["parent"] is None
+
+
+def test_debug_time_enters_the_annotation(tmp_path, profiler_window):
+    from tpu_resiliency.utils.timers import debug_time, SummedTime
+
+    with profiler_window() as names:
+        with debug_time("ckpt.outer", source="checkpoint"):
+            with debug_time("ckpt.inner", source="checkpoint"):
+                pass
+            pieces = SummedTime("ckpt.pieces", source="checkpoint")
+            for i in range(2):
+                with pieces.piece(i, 10):
+                    pass
+            pieces.close()
+    ours = [n for n in names if n.startswith("tpures/ckpt.")]
+    assert ours == ["tpures/ckpt.outer", "tpures/ckpt.inner", "tpures/ckpt.pieces",
+                    "tpures/ckpt.pieces"]
+
+
+def test_summed_time_is_one_record_of_many_pieces(tmp_path):
+    import time
+
+    from tpu_resiliency.utils import events
+    from tpu_resiliency.utils.timers import debug_time, SummedTime
+
+    path = str(tmp_path / "t.jsonl")
+    events.add_sink(events.JsonlSink(path))
+    with debug_time("root", source="checkpoint"):
+        reading = SummedTime("reading", source="checkpoint")
+        checking = SummedTime("checking", source="checkpoint")
+        never = SummedTime("never", source="checkpoint")
+        with pytest.raises(RuntimeError):
+            try:
+                for i, nbytes in enumerate((10, 30, 20)):
+                    with reading.piece(i, nbytes):
+                        time.sleep(0.02 if i == 1 else 0.0)
+                    with checking.piece(i, nbytes):
+                        if i == 2:
+                            raise RuntimeError("bad leaf")
+            finally:
+                reading.close()
+                checking.close()
+                never.close()
+    recs = {r["name"]: r for r in events.read_events(path) if r["kind"] == "timing"}
+    assert "never" not in recs  # no piece ran: nothing to say
+    assert recs["reading"]["leaves"] == 3 and recs["reading"]["bytes"] == 60
+    assert recs["reading"]["slowest_leaf"] == 1 and recs["reading"]["slowest_leaf_bytes"] == 30
+    assert 0.02 <= recs["reading"]["slowest_leaf_s"] <= recs["reading"]["duration_s"]
+    assert recs["reading"]["ok"] is True and recs["reading"]["parent"] == "root"
+    assert recs["checking"]["ok"] is False and "bad leaf" in recs["checking"]["error"]
+    assert recs["checking"]["leaves"] == 3 and recs["checking"]["depth"] == 1
 
 
 def test_debug_time_as_decorator():

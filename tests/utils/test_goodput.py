@@ -337,3 +337,57 @@ def test_metrics_dump_goodput_baseline_flag(tmp_path, capsys):
     assert doc["ratio_delta"] > 0
     # --baseline without --goodput is a usage error.
     assert metrics_dump.main([str(run), "--baseline", str(base)]) == 2
+
+
+# -- nested ``timing`` records (debug_time records every scope since PR 37) ----
+
+
+def _recorded_stream():
+    """A stream as the program wrote it before nested scopes were recorded: roots
+    only, no ``depth``."""
+    return [
+        _step(0, T0), _step(1, T0 + 1.0),
+        {"kind": "timing", "name": "ckpt.load", "ts": T0 + 4.0, "duration_s": 2.0,
+         "ok": True, "pid": 10, "rank": 0},
+        {"kind": "timing", "name": "ckpt.save.d2h", "ts": T0 + 5.0, "duration_s": 0.5,
+         "ok": True, "pid": 10, "rank": 0},
+        _step(2, T0 + 6.0), _step(3, T0 + 7.0),
+    ]
+
+
+def _summary(records):
+    led = GoodputLedger()
+    led.observe_many(records)
+    return led.summary()
+
+
+@pytest.mark.parametrize("nested", [
+    # a listed name that ran inside a listed root, and the restore's new phases
+    [{"kind": "timing", "name": "ckpt.local_load", "ts": T0 + 3.9, "duration_s": 1.8,
+      "ok": True, "pid": 10, "rank": 0, "depth": 1, "parent": "ckpt.load"}],
+    [{"kind": "timing", "name": "ckpt.load.read", "ts": T0 + 3.0, "duration_s": 0.7,
+      "ok": True, "pid": 10, "rank": 0, "depth": 2, "parent": "ckpt.local_load"},
+     {"kind": "timing", "name": "ckpt.local_load", "ts": T0 + 3.9, "duration_s": 1.8,
+      "ok": True, "pid": 10, "rank": 0, "depth": 1, "parent": "ckpt.load"},
+     {"kind": "timing", "name": "ckpt.load.place", "ts": T0 + 4.4, "duration_s": 0.2,
+      "ok": True, "pid": 10, "rank": 0, "depth": 0, "parent": None}],
+], ids=["listed-name-nested", "restore-phases"])
+def test_nested_timings_leave_the_recorded_totals_unchanged(nested):
+    """The ledger charges root scopes: a recorded stream gives what it gave, and the
+    same stream with the nested records a newer program adds gives the same, per
+    phase and per rank (a rank's ``ckpt_stall_s`` is a plain sum: a nested listed
+    name counted beside its root would double it)."""
+    before = _summary(_recorded_stream())
+    assert before["phases"]["ckpt_stall"] == pytest.approx(2.5)
+    assert before["ranks"]["0"]["ckpt_stall_s"] == pytest.approx(2.5)
+    stream = sorted(_recorded_stream() + nested, key=lambda r: r["ts"])
+    after = _summary(stream)
+    assert after["phases"] == before["phases"]
+    assert after["ranks"] == before["ranks"]
+    assert after["goodput_ratio"] == before["goodput_ratio"]
+
+
+def test_an_explicit_root_depth_counts_as_the_recorded_root_did():
+    with_depth = [dict(r, depth=0, parent=None) if r["kind"] == "timing" else r
+                  for r in _recorded_stream()]
+    assert _summary(with_depth) == _summary(_recorded_stream())

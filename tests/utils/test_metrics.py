@@ -228,6 +228,51 @@ def test_counter_thread_safety():
     assert c.value == 80_000
 
 
+def test_observe_record_maps_compile_events():
+    """One ``compile`` event per executable (platform/compile_cache.py:watch) becomes
+    seconds and programs by cache outcome."""
+    reg = MetricsRegistry()
+    aggregate([
+        {"kind": "compile", "fun_name": "jit(train_step)", "trace_s": 2.0, "lower_s": 1.0,
+         "backend_s": 30.0, "cache": "miss"},
+        {"kind": "compile", "fun_name": "jit(train_step)", "trace_s": 2.0, "lower_s": 1.0,
+         "backend_s": 4.0, "retrieval_s": 3.5, "cache": "hit"},
+        {"kind": "compile", "fun_name": "jit(init)", "trace_s": 0.25, "lower_s": 0.25,
+         "backend_s": 0.5, "cache": "hit"},
+        {"kind": "compile", "fun_name": "jit(add)", "backend_s": 0.125, "cache": "uncached"},
+    ], reg)
+    snap = reg.snapshot()["metrics"]
+    seconds = {e["labels"]["cache"]: e["value"] for e in snap["tpu_compile_seconds_total"]}
+    programs = {e["labels"]["cache"]: e["value"] for e in snap["tpu_compiles_total"]}
+    assert seconds == {"miss": 33.0, "hit": 8.0, "uncached": 0.125}
+    assert programs == {"miss": 1.0, "hit": 2.0, "uncached": 1.0}
+
+
+def test_nested_timings_are_series_of_their_own_name():
+    """Every scope is observed under its name; the recorded roots read what they
+    read, whatever nested records a newer program adds beside them."""
+    roots = [
+        {"kind": "timing", "name": "ckpt.local_load", "duration_s": 2.0, "ok": True},
+        {"kind": "timing", "name": "ckpt.local_load", "duration_s": 4.0, "ok": True},
+    ]
+    nested = [
+        {"kind": "timing", "name": "ckpt.load.read", "duration_s": 1.0, "ok": True,
+         "depth": 1, "parent": "ckpt.local_load"},
+        {"kind": "timing", "name": "ckpt.load.verify", "duration_s": 0.5, "ok": False,
+         "depth": 1, "parent": "ckpt.local_load"},
+    ]
+    before, after = MetricsRegistry(), MetricsRegistry()
+    aggregate(roots, before)
+    aggregate(roots + nested, after)
+    key = (("name", "ckpt.local_load"),)
+    for reg in (before, after):
+        h = reg.histograms("tpu_timing_seconds")[key]
+        assert (h.count, h.sum) == (2, 6.0)
+    assert after.histograms("tpu_timing_seconds")[(("name", "ckpt.load.read"),)].count == 1
+    failures = after.snapshot()["metrics"]["tpu_timing_failures_total"]
+    assert [(e["labels"]["name"], e["value"]) for e in failures] == [("ckpt.load.verify", 1.0)]
+
+
 def test_observe_record_mapping():
     reg = MetricsRegistry()
     recs = [

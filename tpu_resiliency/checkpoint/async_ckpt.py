@@ -419,7 +419,6 @@ class AsyncCheckpointer:
         self._inflight_paths[idx] = targets
         return req
 
-    @debug_time("ckpt.save_sync", source="checkpoint")
     def save(self, tree: Any, path: str, meta: Optional[dict] = None, rank: Optional[int] = None) -> None:
         sd = PyTreeStateDict(tree)
         sd.pop_tensors()

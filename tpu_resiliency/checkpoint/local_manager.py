@@ -172,6 +172,17 @@ def _persist_artifacts(items: list[tuple]) -> None:
         _write_blobs(plain)
 
 
+def _placing(tensors: list):
+    """The scope around a restore's placement (``PyTreeStateDict.from_hollow``,
+    which is outside :meth:`LocalCheckpointManager.load` and its
+    ``ckpt.local_load``): the seconds ``jax.device_put`` holds the host for. The
+    wait for the device is the caller's ``block_until_ready``."""
+    return debug_time(
+        "ckpt.load.place", source="checkpoint",
+        bytes=sum(int(t.nbytes) for t in tensors), leaves=len(tensors),
+    )
+
+
 def _items_nbytes(items: list[tuple]) -> int:
     total = 0
     for item in items:
@@ -1000,7 +1011,8 @@ class LocalCheckpointManager:
         Mirrors reference ``base_manager.py:156-203`` (all-gather available IDs, pick
         the max iteration every rank can be served for).
         """
-        covered = self._covered_iterations()
+        with debug_time("ckpt.load.find", source="checkpoint"):
+            covered = self._covered_iterations()
         return max(covered) if covered else -1
 
     # -- load --------------------------------------------------------------
@@ -1236,7 +1248,8 @@ class LocalCheckpointManager:
         from tpu_resiliency.checkpoint.state_dict import PyTreeStateDict
 
         hollow, tensors, meta = self.load(iteration)
-        sd = PyTreeStateDict.from_hollow(hollow, tensors, shardings=shardings, device=device)
+        with _placing(tensors):
+            sd = PyTreeStateDict.from_hollow(hollow, tensors, shardings=shardings, device=device)
         return sd.tree, meta
 
     def load_resharded_tree(
@@ -1257,9 +1270,10 @@ class LocalCheckpointManager:
         hollow, tensors, meta = self.load_resharded(
             target=target, iteration=iteration, axes=axes
         )
-        sd = PyTreeStateDict.from_hollow(
-            hollow, tensors, shardings=shardings, device=device
-        )
+        with _placing(tensors):
+            sd = PyTreeStateDict.from_hollow(
+                hollow, tensors, shardings=shardings, device=device
+            )
         return sd.tree, meta
 
     def load_shard(
@@ -1308,7 +1322,8 @@ class LocalCheckpointManager:
         """Unpickle a hollow skeleton; damage surfaces as CheckpointError
         naming the source (pickle raises half a dozen exception types)."""
         try:
-            return pickle.loads(hollow_b)
+            with debug_time("ckpt.load.unpickle", source="checkpoint", bytes=len(hollow_b)):
+                return pickle.loads(hollow_b)
         except Exception as e:
             raise CheckpointError(
                 f"{source}: corrupt hollow skeleton ({e!r})"
@@ -1595,11 +1610,6 @@ class LocalCheckpointManager:
         returned ``meta["layout"]`` describes the TARGET world, ready to pass
         back into the next ``save(..., layout=...)``.
         """
-        with debug_time("ckpt.reshard_load", source="checkpoint"):
-            return self._load_resharded(target, iteration, axes)
-
-    def _load_resharded(self, target, iteration, axes) -> tuple[Any, list, dict]:
-        t0 = time.perf_counter()
         held = sorted((i.iteration, i.owner) for i in self.local_ids())
         if self.comm is None:
             gathered = [(self.rank, held, self._cold_pairs())]
@@ -1703,11 +1713,6 @@ class LocalCheckpointManager:
                 "iteration": meta.get("iteration", it),
                 reshard_mod.LAYOUT_META_KEY: tgt.to_meta(),
             }
-            record_event(
-                "checkpoint", "timing", name="ckpt.reshard_load",
-                duration_s=time.perf_counter() - t0, ok=True,
-                bytes=summary["total_bytes"],
-            )
             hollow = self._loads_hollow(hollow_b, f"reshard(iter={it})")
             try:
                 from tpu_resiliency.checkpoint.state_dict import (

@@ -40,15 +40,24 @@ The manifest is refreshed by the launcher after every round (the one process
 that survives worker churn) and at worker interpreter exit — both
 best-effort: a missing or stale manifest only narrows detection, never
 correctness.
+
+**Which program compiled, and what it cost.** The ``compile_cache`` event is the
+state of the directory at start-up, not a hit. What each compilation really did
+is the ``compile`` event of the watcher below (:func:`watch`): one a program,
+from JAX's own monitoring stream, with the seconds of its trace, its lowering
+and its compile or load, and ``cache: hit | miss | uncached``.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
+import threading
 import zlib
 from typing import Optional
 
+from tpu_resiliency.utils.events import record as record_event
 from tpu_resiliency.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -69,6 +78,99 @@ _ENTRY_SUFFIX = "-cache"
 
 #: process-level latch: the cache is applied (and its event recorded) once
 _applied: Optional[dict] = None
+
+#: JAX's monitoring names the compile watcher folds (jax 0.9.0): three duration
+#: events a program, each with a ``fun_name``; the cache's two plain events and
+#: its one duration between the lowering and the backend's
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_HIT = "/jax/compilation_cache/cache_hits"
+
+
+class _CompileWatcher:
+    """Folds JAX's monitoring stream into one ``compile`` event per executable.
+
+    JAX calls a listener on the thread that compiles, in this order for one
+    program: the trace durations of every function traced on the way (inner
+    jitted functions and primitives first, the program's own last, each with the
+    bare ``fun_name``), the lowering's duration (``jit(<fun_name>)``), the
+    cache's request / hit / retrieval time where a cache is consulted, and the
+    backend's duration (``jit(<fun_name>)``), which closes the program. What is
+    pending on a thread is folded then: ``trace_s`` is the trace duration of the
+    function of the program's own name. **Trace durations of inner functions
+    that get no executable of their own are dropped, not summed:** the outer
+    function's trace ran while they did and already contains them. ``backend_s``
+    is the compile on a miss, and on a hit the fetch and deserialisation
+    (``retrieval_s``, JAX's own reading of the fetch, rides along). JAX fires
+    none of these on a cached dispatch, so nothing here is on a step's path."""
+
+    def __init__(self) -> None:
+        self._pending = threading.local()
+        self._lock = threading.Lock()
+        self._totals = {"requests": 0, "hits": 0, "misses": 0, "seconds": 0.0}
+        self.calls = 0  # every call of either listener: a test holds a cached dispatch to none
+
+    def _thread_state(self) -> dict:
+        """What this thread's open program has told so far."""
+        state = getattr(self._pending, "state", None)
+        if state is None:
+            state = self._pending.state = {
+                "traces": {}, "lower_s": 0.0, "requested": False, "hit": False,
+                "retrieval_s": None}
+        return state
+
+    def on_event(self, event: str, **_) -> None:
+        self.calls += 1
+        if event == _REQUEST:
+            self._thread_state()["requested"] = True
+        elif event == _HIT:
+            self._thread_state()["hit"] = True
+
+    def on_duration(self, event: str, seconds: float, fun_name: str = "", **_) -> None:
+        self.calls += 1
+        if event == _TRACE:
+            self._thread_state()["traces"][fun_name] = seconds
+        elif event == _LOWER:
+            self._thread_state()["lower_s"] = seconds
+        elif event == _RETRIEVAL:
+            self._thread_state()["retrieval_s"] = seconds
+        elif event == _BACKEND:
+            self._close(fun_name, seconds)
+
+    def _close(self, fun_name: str, backend_s: float) -> None:
+        state = self._thread_state()
+        bare = fun_name[fun_name.find("(") + 1:-1] if fun_name.endswith(")") else fun_name
+        trace_s = state["traces"].get(bare, 0.0)
+        lower_s = state["lower_s"]
+        # JAX announces a request whenever caching is not switched off, directory
+        # or none: with no directory nothing was consulted, and that is no miss.
+        jax = sys.modules.get("jax")
+        consulted = state["requested"] and jax and jax.config.jax_compilation_cache_dir
+        cache = "hit" if state["hit"] else "miss" if consulted else "uncached"
+        retrieval = {} if state["retrieval_s"] is None else {"retrieval_s": state["retrieval_s"]}
+        self._pending.state = None
+        with self._lock:
+            totals = self._totals
+            totals["requests"] += cache != "uncached"
+            totals["hits"] += cache == "hit"
+            totals["misses"] += cache == "miss"
+            totals["seconds"] += trace_s + lower_s + backend_s
+            snapshot = dict(totals)
+        record_event(
+            "platform", "compile", fun_name=fun_name, trace_s=trace_s, lower_s=lower_s,
+            backend_s=backend_s, cache=cache, **retrieval, **snapshot,
+        )
+
+    def totals(self) -> dict:
+        with self._lock:
+            return dict(self._totals)
+
+
+#: the process's one watcher, made by the first :func:`watch`
+_watcher: Optional[_CompileWatcher] = None
 
 
 def _entry_names(path: str) -> list[str]:
@@ -188,6 +290,33 @@ def outcome_of(stats: dict) -> str:
     return "hit" if stats.get("entries") else "miss"
 
 
+def watch() -> _CompileWatcher:
+    """Register the compile watcher on JAX's monitoring stream, once per process
+    (:func:`enable` and :func:`apply_from_env` call this, with or without a cache
+    directory). From then on every program this process compiles or loads is a
+    ``compile`` event: ``fun_name``, ``trace_s``, ``lower_s``, ``backend_s``,
+    ``retrieval_s`` on a hit, ``cache`` = ``hit`` | ``miss`` | ``uncached`` (no
+    persistent cache consulted), and the running totals of :func:`compile_totals`."""
+    global _watcher
+    if _watcher is None:
+        import jax
+
+        _watcher = _CompileWatcher()
+        jax.monitoring.register_event_listener(_watcher.on_event)
+        jax.monitoring.register_event_duration_secs_listener(_watcher.on_duration)
+    return _watcher
+
+
+def compile_totals() -> dict:
+    """``{"requests", "hits", "misses", "seconds"}`` of this process since
+    :func:`watch`: compilations that consulted the persistent cache, those it
+    served, those the backend really made, and the summed seconds (trace, lowering,
+    compile or load) of every program, cached or not. Zeros before any watcher."""
+    if _watcher is None:
+        return {"requests": 0, "hits": 0, "misses": 0, "seconds": 0.0}
+    return _watcher.totals()
+
+
 def checkout_cache_dir(checkout: str) -> str:
     """The one fixed cache directory of a checkout, for entry programs to export
     as :data:`CACHE_DIR_ENV` when the environment does not set it. Never made
@@ -206,6 +335,7 @@ def enable(path: str) -> dict:
     after its ``import jax``. No other directory is ever set. Every failure
     mode degrades to a cold compile: an unusable directory simply leaves
     caching off."""
+    watch()
     try:
         os.makedirs(path, exist_ok=True)
     except OSError:
@@ -232,17 +362,20 @@ def apply_from_env(record: bool = True) -> Optional[dict]:
     """Sweep and announce the directory :data:`CACHE_DIR_ENV` names, once per
     process; None when unset or when already applied. On first application
     records the ``compile_cache`` event (hit / miss / miss_corrupt + entry
-    count and bytes)."""
+    count and bytes). Registers the compile watcher (:func:`watch`) whether or
+    not a directory is set; with none set, only in a process that has imported
+    JAX already (the library imports it into no process that may never use it:
+    such an entry program calls :func:`watch` itself, after its ``import jax``)."""
     global _applied
     path = os.environ.get(CACHE_DIR_ENV, "")
+    if path or "jax" in sys.modules:
+        watch()
     if not path or _applied is not None:
         return None
     stats = enable(path)
     stats["outcome"] = outcome_of(stats)
     _applied = stats
     if record and stats.get("enabled"):
-        from tpu_resiliency.utils.events import record as record_event
-
         record_event(
             "platform", "compile_cache",
             outcome=stats["outcome"], entries=stats["entries"],

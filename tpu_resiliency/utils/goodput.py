@@ -237,7 +237,11 @@ class GoodputLedger:
         elif kind == "ckpt_foreground_blocked":
             self._stall(rec, ts, rank)
         elif kind == "timing" and rec.get("name") in CKPT_STALL_TIMINGS:
-            self._stall(rec, ts, rank)
+            # ``debug_time`` records nested scopes too: a listed name that ran
+            # inside another scope is already inside its root's window, so the
+            # roots are what is charged (a record from before ``depth`` is one).
+            if not rec.get("depth"):
+                self._stall(rec, ts, rank)
         elif kind == "span_end":
             span = rec.get("span")
             d = rec.get("duration_s")

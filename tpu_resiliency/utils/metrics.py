@@ -1141,7 +1141,26 @@ def observe_record(rec: dict, reg: MetricsRegistry) -> None:
             reg.histogram(
                 "tpu_heartbeat_gap_seconds", "per-session max heartbeat gap"
             ).observe(rec["max_gap_s"])
+    elif kind == "compile":
+        # One record per executable (platform/compile_cache.py:watch): what it
+        # cost this process to get the program, cached or compiled.
+        cache = str(rec.get("cache", "?"))
+        parts = [rec.get(k) for k in ("trace_s", "lower_s", "backend_s")]
+        reg.counter(
+            "tpu_compile_seconds_total",
+            "seconds tracing, lowering and compiling or loading programs, by "
+            "cache outcome (hit | miss | uncached)",
+            cache=cache,
+        ).inc(sum(p for p in parts if isinstance(p, (int, float))))
+        reg.counter(
+            "tpu_compiles_total",
+            "programs compiled or loaded, by cache outcome (hit | miss | uncached)",
+            cache=cache,
+        ).inc()
     elif kind == "timing":
+        # Every scope is observed under its own name, nested ones too (their
+        # ``depth`` / ``parent`` say where they sit): a sum over names would
+        # count a nested scope's seconds twice, a series by name does not.
         d = rec.get("duration_s")
         if isinstance(d, (int, float)):
             reg.histogram(
