@@ -27,7 +27,8 @@ first step, it emits an
 as the blocked kernels of ``ops/attention.py`` (on a TPU, at shapes that tile) or as
 the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block,
 for the latent kind its ``score_width`` and ``value_width``, for the indexed kind how many
-keys a query keeps;
+keys a query keeps and whether its index scores run as the kernels of
+``ops/index_scores.py`` (``scores``: ``kernel`` or ``blocks``);
 and a ``dispatch_path`` event: the rows the expert dispatch carries at this batch
 (``pattern.dispatch_rows``), ``bounded`` or ``full``; and a ``kept_residuals`` event:
 the named values each layer keeps for its backward pass at this batch on this device's

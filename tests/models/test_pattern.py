@@ -908,6 +908,91 @@ def test_forward_counts_and_causality():
     assert not np.allclose(np.asarray(l1[0, 30:]), np.asarray(l2[0, 30:]), atol=1e-3)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_index_scores_from_the_kernels_give_what_the_blocks_give(monkeypatch, dtype):
+    """The tiny indexed description with an indexer the kernels' layout takes (4 heads of
+    64, blocks of 128 rows, four groups of one tile): with the scores from
+    ``ops/index_scores.py`` the layers keep the same number of keys to the digit, and
+    ``select_ties``, ``index_kl``, the loss and every gradient leaf are the blocks' to
+    rounding (inside the tiny limits of the keye family by far: a bf16 product is exact in
+    float32 either way, so the scores differ by the order of a float32 sum alone)."""
+    from tpu_resiliency.ops import index_scores
+
+    monkeypatch.setattr(index_scores, "TILE", 128)
+    _, family, _ = tiny_file_of("indexed")
+    cfg = pattern.PatternConfig.tiny_indexed(
+        dtype=dtype, indexer=pattern.Indexer(n_heads=4, head_dim=64, top_k=96), attn_block=128)
+    seq = 512
+    assert pattern.attention_paths(cfg, seq)["indexed"]["scores"] == "blocks"
+    params = pattern.init_params(jax.random.PRNGKey(3), cfg)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(0, cfg.vocab_size, (1, seq)), jnp.int32)
+
+    def run():
+        value = lambda p: pattern.loss_and_counts(p, tokens, cfg)  # noqa: E731
+        (loss, counts), grads = jax.jit(jax.value_and_grad(value, has_aux=True))(params)
+        return float(loss), counts, grads
+
+    want = run()
+    # ``attention_paths`` asks the backend, which is the CPU here: send the scores to the
+    # kernels whatever it says (they then run under the interpreter)
+    paths = pattern.attention_paths
+    monkeypatch.setattr(pattern, "attention_paths", lambda cfg, seq: {
+        "indexed": {**paths(cfg, seq)["indexed"], "scores": "kernel"}})
+    got = run()
+    np.testing.assert_array_equal(np.asarray(got[1]["keys_selected"]),
+                                  np.asarray(want[1]["keys_selected"]))
+    assert int(got[1]["keys_selected"][0]) == sum(min(t + 1, 96) for t in range(seq))
+    np.testing.assert_array_equal(np.asarray(got[1]["select_ties"]),
+                                  np.asarray(want[1]["select_ties"]))
+    rounding = 1e-5 if dtype == jnp.float32 else 4e-3
+    np.testing.assert_allclose(np.asarray(got[1]["index_kl"]), np.asarray(want[1]["index_kl"]),
+                               rtol=rounding)
+    assert abs(got[0] - want[0]) < rounding < family.TINY["limits"]["loss_abs"]
+    gaps = jax.tree.map(lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)),
+                        got[2], want[2])
+    limit = 1e-4 if dtype == jnp.float32 else family.TINY["limits"]["grad_norm_gap"] / 4
+    assert max(jax.tree.leaves(gaps)) < limit, gaps
+
+
+@pytest.mark.parametrize("backend,indexer,block,seq,scores", [
+    ("cpu", pattern.Indexer(16, 64, 2048), 512, 8192, "blocks"),
+    ("tpu", pattern.Indexer(16, 64, 2048), 512, 8192, "kernel"),
+    ("tpu", pattern.Indexer(4, 8, 12), 512, 8192, "blocks"),  # the tests' tiny heads
+    ("tpu", pattern.Indexer(16, 64, 2048), 512, 8192 + 256, "blocks"),  # no whole blocks of rows
+    ("tpu", pattern.Indexer(16, 64, 2048), 512, 384, "kernel"),  # one tile of all 384 rows
+    ("tpu", pattern.Indexer(16, 64, 2048), 768, 3072, "blocks"),  # groups of 768 rows: 1.5 tiles
+])
+def test_attention_paths_say_which_way_the_index_scores_go(
+        monkeypatch, backend, indexer, block, seq, scores):
+    """``scores: "kernel"`` where the backend is a TPU, every group of query rows is whole
+    tiles and the indexer's heads fit the kernels' layout, else ``"blocks"``; the products'
+    own path is chosen apart (heads of 16 keep them on the blocks here)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = pattern.PatternConfig.tiny_indexed(indexer=indexer, attn_block=block)
+    path = pattern.attention_paths(cfg, seq)["indexed"]
+    assert path["scores"] == scores and path["path"] == "blocks"
+
+
+def test_the_attention_path_event_says_which_way_the_index_scores_go(tmp_path):
+    """``examples/pattern_training.py`` records ``attention_path`` before its first step:
+    the indexed kind carries ``scores``, on the CPU ``"blocks"``."""
+    import json
+    import subprocess
+
+    events_file = tmp_path / "events.jsonl"
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "examples", "pattern_training.py"), "--cpu",
+         "--description", "indexed", "--steps", "10", "--batch", "2", "32"],
+        env={**os.environ, "TPU_RESILIENCY_EVENTS_FILE": str(events_file)},
+        capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
+    records = [json.loads(line) for line in events_file.read_text().splitlines()]
+    (event,) = [r for r in records if r["kind"] == "attention_path"]
+    assert event["seq"] == 32
+    assert event["indexed"] == {"path": "blocks", "block": 16, "selected": 12,
+                                "selection": "mask", "scores": "blocks"}
+
+
 def test_indexed_counts_are_one_value_a_layer_and_the_keys_selected_are_what_the_shapes_fix():
     cfg = pattern.PatternConfig.tiny_indexed()
     params = pattern.init_params(jax.random.PRNGKey(0), cfg)

@@ -312,12 +312,12 @@ def test_attention_paths_of_the_indexed_kind_follow_the_backend_and_the_shapes(m
     config = harness.read_json(harness.HERE, "configs", "keye-vl2-30b-a3b-l6-ep8.json")
     cfg = harness.load_family(config).program_config(config, 8192)
     assert attention.applies(8192, cfg.head_dim, None)
-    path = {"cpu": {"path": "blocks", "block": 512},
-            "tpu": {"path": "kernel", "tile": attention.FULL_TILE}}[backend]
+    path = {"cpu": {"path": "blocks", "block": 512, "scores": "blocks"},
+            "tpu": {"path": "kernel", "tile": attention.FULL_TILE, "scores": "kernel"}}[backend]
     assert pattern.attention_paths(cfg, 8192) == {"indexed": {
         **path, "selected": 2048, "selection": "mask"}}
     assert pattern.attention_paths(pattern.PatternConfig.tiny_indexed(), 8) == {"indexed": {
-        "path": "blocks", "block": 8, "selected": 8, "selection": "mask"}}
+        "path": "blocks", "block": 8, "selected": 8, "selection": "mask", "scores": "blocks"}}
     # 8,192 + 128 rows are no whole tiles; 384 rows are one tile of the kernels' and one
     # block of the indexer's, 1,024 rows are two tiles and, by blocks of 768, no whole blocks
     assert pattern.attention_paths(cfg, 8192 + 128)["indexed"]["path"] == "blocks"

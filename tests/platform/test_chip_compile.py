@@ -295,9 +295,12 @@ def test_indexed_attention_compiles_for_v5e_on_the_kernels_with_its_scopes(one_c
     attention output and its log-sum-exp kept: the products go by the blocked kernels with
     the selection as an operand, five custom calls (forward, the summed probabilities and
     again for the backward pass, dQ, dK/dV), every one under ``attn/full/core`` where
-    ``attn.roofline`` looks; ops under ``attn/full/indexer`` and ``attn/full/select`` where
-    the two readers of PR 35 do, forward and backward, and the counting passes of the
-    selection in the first forward alone."""
+    ``attn.roofline`` looks; the index scores by the kernels of ``ops/index_scores.py``,
+    for each of the four groups of query rows the forward kernel (and again for the
+    backward pass: the scores are not kept), ``dqi`` with ``dwi``, and ``dki``, every one
+    under ``attn/full/indexer``; ops under ``attn/full/indexer`` and ``attn/full/select``
+    where the two readers of PR 35 do, forward and backward, and the counting passes of
+    the selection in the first forward alone."""
     import re
 
     from benchmark import harness
@@ -306,7 +309,7 @@ def test_indexed_attention_compiles_for_v5e_on_the_kernels_with_its_scopes(one_c
     config, cfg = cell_config("keye-vl2-30b-a3b-l6-ep8")
     seq = config["batch"][1]
     assert pattern.attention_paths(cfg, seq) == {"indexed": {
-        "path": "kernel", "tile": 512, "selected": 2048, "selection": "mask"}}
+        "path": "kernel", "tile": 512, "selected": 2048, "selection": "mask", "scores": "kernel"}}
     assert pattern.key_groups(seq, 512) == [(0, 2048), (2048, 4096), (4096, 6144), (6144, 8192)]
     params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
     lp = jax.tree.map(lambda w: sds(w.shape[1:], w.dtype, one_chip), params["attn"]["indexed"])
@@ -331,8 +334,12 @@ def test_indexed_attention_compiles_for_v5e_on_the_kernels_with_its_scopes(one_c
                if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
         "blocked_attention_dkv", "blocked_attention_dq", "blocked_attention_fwd",
-        "blocked_attention_probs", "blocked_attention_probs"], kernels
-    assert all(core.search(name) for name in kernels), kernels
+        "blocked_attention_probs", "blocked_attention_probs",
+        *["index_scores_dk"] * 4, *["index_scores_dq"] * 4, *["index_scores_fwd"] * 8], kernels
+    products = [name for name in kernels if "blocked_attention" in name]
+    assert all(core.search(name) for name in products), products
+    scores = [name for name in kernels if "index_scores" in name]
+    assert all(own["indexer"].search(name) and not core.search(name) for name in scores), scores
     for scope, mark in own.items():
         under = [name for name in names if mark.search(name)]
         assert len(under) >= 8, scope
@@ -398,14 +405,20 @@ def test_keye_train_step_fits_one_chip(one_chip, as_on_a_tpu):
     and AdamW moments, and what the step needs beside them inside one v5e's 15.75 GiB, with
     its six groups of residuals kept (the selection among them); a layer runs the forward,
     dQ and dK/dV kernels once and the kernel of the summed probabilities twice, since the
-    indexer's target is not kept. The tier-1 run has the one indexed layer above; this one
-    takes as long as the rest of the file together."""
+    indexer's target is not kept, and for each of its four groups of query rows the
+    index-score kernels: forward twice (the scores are not kept), ``dqi`` and ``dki`` once.
+    The tier-1 run has the one indexed layer above; this one takes as long as the rest of
+    the file together."""
     compiled, n_params, needed = pattern_step(*cell_config("keye-vl2-30b-a3b-l6-ep8"), one_chip)
     assert n_params == 659_189_632
     assert 7.9e9 < compiled.memory_analysis().argument_size_in_bytes < 8.0e9
-    assert needed < 15.75 * 2 ** 30, needed  # 13.85e9 (compile, PR 36; 13.71e9 on the blocks, PR 35)
+    # 13.70e9 (compile, PR 38; 13.85e9 with the index scores on the blocks, PR 36)
+    assert needed < 15.75 * 2 ** 30, needed
     calls = [line for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     kernels = {name: sum(1 for line in calls if f"blocked_attention_{name}" in line)
                for name in ("fwd", "probs", "dq", "dkv")}
     assert kernels == {"fwd": 6, "probs": 12, "dq": 6, "dkv": 6}, kernels
+    scores = {name: sum(1 for line in calls if f"index_scores_{name}/" in line)
+              for name in ("fwd", "dq", "dk")}
+    assert scores == {"fwd": 48, "dq": 24, "dk": 24}, scores

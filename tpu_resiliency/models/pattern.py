@@ -31,7 +31,9 @@ Built TPU-first, static shapes throughout:
   blocks against the causal prefix of the keys, and all loop over the KV heads with the
   block's scores recomputed in the backward pass. An indexed layer's key sets are a
   mask that is an operand of its blocks (one block of index scores, one of the mask
-  and one of the heads' mean probabilities at a time).
+  and one of the heads' mean probabilities at a time); on a TPU its index scores run
+  as the blocked kernels of ``ops/index_scores.py`` where its groups of query rows are
+  whole tiles, and never hold the per-head products of a block.
 - **Routing drops nothing.** Every (token, choice) pair whose expert this chip holds
   is computed: the pairs are sorted by expert and the three SwiGLU products run as
   grouped products over the ragged groups (``jax.lax.ragged_dot``). Pairs for
@@ -68,6 +70,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from tpu_resiliency.models import transformer as tfm
 from tpu_resiliency.ops import attention
+from tpu_resiliency.ops import index_scores as index_score_kernels
 
 FULL, SLIDING, LATENT, INDEXED = "full", "sliding", "latent", "indexed"
 ATTENTION_KINDS = (FULL, SLIDING, LATENT, INDEXED)
@@ -691,7 +694,8 @@ def _whole(x, axis: int):
     return x.reshape(*x.shape[:axis], -1, *x.shape[axis + 2:])
 
 
-def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool = False):
+def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool = False,
+                      score_kernels: bool = False):
     """Causal attention over the keys an indexer selects: q ``[B, T, H, dh]``, k / v ``[B,
     T, Hkv, dh]``, and the indexer's qi ``[B, T, J, di]``, wi ``[B, T, J]``, ki ``[B, T,
     di]`` -> (the attention output ``[B, T, H * dh]``; per query ``[B, T]``: the divergence
@@ -702,7 +706,10 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool
     The query rows go in groups (:func:`key_groups`), each against the causal prefix of
     the keys up to its own end. A group computes its index scores by blocks of ``block``
     rows (:func:`index_scores`, one block's products of all the indexer's heads alive at
-    a time; made again in the backward pass) and turns them into its rows of the mask
+    a time; made again in the backward pass) or, with ``score_kernels`` (groups of whole
+    tiles of an indexer the kernels' layout takes), by one call of
+    ``ops/index_scores.py:index_scores``, which bounds its own temporaries and leaves the
+    key tiles past a query tile's diagonal tile zero; and turns them into its rows of the mask
     (:func:`select_keys`; named ``select_mask``, so a layer may keep it). The products
     under the mask, with the heads' probabilities summed on the way, go one of two ways
     (:func:`attention_paths`). On the ``jax.numpy`` blocks each group runs
@@ -727,9 +734,13 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool
         n = (keys - first) // block
         ki_seen = ki[:, :keys]
         with jax.named_scope("indexer"):
-            scores.append(_whole(jax.lax.map(
-                lambda rows, ki_seen=ki_seen: score_block(*rows, ki_seen),
-                (_in_blocks(qi[:, first:keys], 1, n), _in_blocks(wi[:, first:keys], 1, n))), 1))
+            if score_kernels:
+                scores.append(index_score_kernels.index_scores(
+                    qi[:, first:keys], wi[:, first:keys], ki_seen))
+            else:
+                scores.append(_whole(jax.lax.map(
+                    lambda rows, ki_seen=ki_seen: score_block(*rows, ki_seen),
+                    (_in_blocks(qi[:, first:keys], 1, n), _in_blocks(wi[:, first:keys], 1, n))), 1))
         with jax.named_scope("select"):
             mask, tied = select_keys(jax.lax.stop_gradient(scores[-1]), first, top_k)
             if keys > top_k:  # else the causal mask, which nothing needs to keep
@@ -783,7 +794,10 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     says both widths. An indexed kind's mask is data, which the kernels take as an
     operand (``selection: "mask"``: every key up to a query tile's diagonal tile is
     scored and the unselected ones masked, on either path) where the sequence is also
-    whole blocks of the indexer's rows; it says how many keys a query keeps."""
+    whole blocks of the indexer's rows; it says how many keys a query keeps, and which way
+    its index scores go: ``scores: "kernel"`` for the blocked kernels of
+    ``ops/index_scores.py`` where the backend is a TPU, every group of query rows is whole
+    tiles and the indexer's heads fit their layout, else ``scores: "blocks"``."""
     paths = {}
     for kind in ATTENTION_KINDS:
         if not cfg.count(kind):
@@ -800,7 +814,12 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
         if kind == LATENT:
             paths[kind].update(score_width=score, value_width=value)
         if kind == INDEXED:
-            paths[kind].update(selected=min(cfg.indexer.top_k, seq), selection="mask")
+            ix = cfg.indexer
+            tiled = jax.default_backend() == "tpu" and not seq % block and all(
+                index_score_kernels.applies(keys - first, keys, ix.n_heads, ix.head_dim)
+                for first, keys in key_groups(seq, block))
+            paths[kind].update(selected=min(ix.top_k, seq), selection="mask",
+                               scores="kernel" if tiled else "blocks")
     return paths
 
 
@@ -905,9 +924,10 @@ def _indexed_block(cfg: PatternConfig, x, lp: dict, cos, sin, index_cos, index_s
         names = (*KEPT_GROUPS["qkv"], *KEPT_GROUPS["index"])
         q, k, v, qi, wi, ki = (checkpoint_name(a, name)
                                for a, name in zip((q, k, v, qi, wi, ki), names))
+        path = attention_paths(cfg, t)[INDEXED]
         attn, divergence, selected, tied, masks = indexed_attention(
             q, k, v, qi, wi, ki, ix.top_k, cfg.attn_block,
-            kernels=attention_paths(cfg, t)[INDEXED]["path"] == "kernel")
+            kernels=path["path"] == "kernel", score_kernels=path["scores"] == "kernel")
         attn = checkpoint_name(attn, attention.OUT_NAME)
         out = x + attn @ lp["wo"].astype(attn.dtype)
         # The indexer's loss hangs off the layer to one side and its value is read at the
