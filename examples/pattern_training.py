@@ -9,7 +9,11 @@ selection bias and weighs by the score, and two shared experts' width;
 ``--description indexed`` has in every layer grouped-query attention over the keys a
 small indexer selects for each query (an exact top-k of its scores; the indexer learns
 from a loss of its own, ``index_kl``), a softmax router, no shared expert and no dense
-layer. The first two have a leading dense MLP; all have sparse layers whose router
+layer; ``--description delta`` has a period of one full layer with no rotary and a
+sigmoid a channel for a gate and three delta-rule layers (a state of ``d_key x d_value`` a
+head carried along the sequence, a decay a key channel, short convolutions; this process
+holds 2 of the 8 heads: one chip's share of a tensor-parallel group, whose sum is not
+built). The first two have a leading dense MLP; all have sparse layers whose router
 scores every expert of a deployment
 while this process holds a contiguous range of them (``--experts-held FIRST COUNT``:
 one chip's share of an expert-parallel split; pairs routed to experts held elsewhere add
@@ -21,14 +25,18 @@ dispatch carried (twice the even share of the experts held, or every pair in a s
 whose router sent more than that here), and under a selection bias ``chosen_by_bias``,
 the pairs (of all of them) whose expert the scores alone would not have chosen; and for
 each indexed layer ``index_kl`` (its indexer's loss), ``keys_selected`` and
-``select_ties`` (queries whose last selected score equals the next). Once, before the
+``select_ties`` (queries whose last selected score equals the next). With delta layers the
+same forward gives a ``delta_state`` event: for each delta layer ``decay_mean`` (the mean
+of ``exp(g)``: how much of the state a token keeps), ``beta_mean`` (the write strength) and
+``state_rms`` (of the state after the last token). Once, before the
 first step, it emits an
 ``attention_path`` event: for each kind of attention layer, whether its products run
 as the blocked kernels of ``ops/attention.py`` (on a TPU, at shapes that tile) or as
 the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or block,
 for the latent kind its ``score_width`` and ``value_width``, for the indexed kind how many
 keys a query keeps and whether its index scores run as the kernels of
-``ops/index_scores.py`` (``scores``: ``kernel`` or ``blocks``);
+``ops/index_scores.py`` (``scores``: ``kernel`` or ``blocks``), for the delta kind
+``{path: chunks, chunk}`` (the rule by chunks in ``jax.numpy``; ``kernel`` once one exists);
 and a ``dispatch_path`` event: the rows the expert dispatch carries at this batch
 (``pattern.dispatch_rows``), ``bounded`` or ``full``; and a ``kept_residuals`` event:
 the named values each layer keeps for its backward pass at this batch on this device's
@@ -40,7 +48,8 @@ Run (CPU simulation)::
     python examples/pattern_training.py --cpu --steps 20
 
 Prints ``ATTENTION {...}``, ``DISPATCH {...}``, ``KEPT {...}``, one ``ROUTING step=<n>
-...`` line per routing event and ``DONE loss=<x>`` on success.
+...`` line per routing event (and one ``DELTA step=<n> ...`` line with delta layers) and
+``DONE loss=<x>`` on success.
 """
 
 from __future__ import annotations
@@ -54,12 +63,16 @@ _REPO_ROOT = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
 if _REPO_ROOT not in _sys.path:
     _sys.path.insert(0, _REPO_ROOT)
 
+#: the counts of a delta layer among what ``loss_and_counts`` returns: a ``delta_state``
+#: event's, not a ``moe_routing`` event's
+DELTA_COUNTS = ("decay_mean", "beta_mean", "state_rms")
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--cpu", action="store_true",
                     help="simulate on the CPU (without it $JAX_PLATFORMS / JAX decide)")
-    ap.add_argument("--description", choices=("mixed", "latent", "indexed"), default="mixed",
+    ap.add_argument("--description", choices=("mixed", "latent", "indexed", "delta"), default="mixed",
                     help="which pattern of layers to train (see the module docstring)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, nargs=2, default=(2, 64), metavar=("B", "T"))
@@ -79,7 +92,8 @@ def main() -> None:
     from tpu_resiliency.utils import events
 
     preset = {"mixed": pattern.PatternConfig.tiny, "latent": pattern.PatternConfig.tiny_latent,
-              "indexed": pattern.PatternConfig.tiny_indexed}
+              "indexed": pattern.PatternConfig.tiny_indexed,
+              "delta": pattern.PatternConfig.tiny_delta}
     cfg = preset[args.description](experts_held=tuple(args.experts_held))
     train_step, init_opt = pattern.make_train_step(cfg)
     step = jax.jit(train_step, donate_argnums=(0, 1))
@@ -99,16 +113,23 @@ def main() -> None:
     print(f"KEPT {kept}", flush=True)
 
     def tokens(i: int):
-        return jnp.asarray(np.random.default_rng([0, i]).integers(
+        # two batches in turn, which the model learns by heart: on a fresh batch of
+        # uniform ids every step a loss only settles near log(vocabulary)
+        return jnp.asarray(np.random.default_rng([0, i % 2]).integers(
             0, cfg.vocab_size, tuple(args.batch)), jnp.int32)
 
     def step_fn(state, i: int):
         batch = tokens(i)
         if i % args.routing_every == 0:
             counts = {k: np.asarray(v).tolist() for k, v in counts_of(state[0], batch).items()}
+            of_state = {k: counts.pop(k) for k in DELTA_COUNTS if k in counts}
             events.record("model", "moe_routing", step=i, experts_held=list(cfg.experts_held),
                           pairs=int(batch.size * cfg.top_k), **counts)
             print(f"ROUTING step={i} {counts}", flush=True)
+            if of_state:
+                events.record("model", "delta_state", step=i, head_ways=cfg.head_ways,
+                              **of_state)
+                print(f"DELTA step={i} {of_state}", flush=True)
         params, opt_state, loss = step(*state, batch)
         losses.append(float(loss))
         return params, opt_state

@@ -1,7 +1,9 @@
-"""Pattern-of-layers model (models/pattern.py), in the three descriptions the benchmark
+"""Pattern-of-layers model (models/pattern.py), in the four descriptions the benchmark
 runs (window and full grouped-query layers with a gate; latent layers under a router
 with a selection bias; indexed layers, whose keys an indexer selects and whose indexer
-learns from a loss of its own, under a softmax router with no shared expert): the program
+learns from a loss of its own, under a softmax router with no shared expert; a period of
+one full layer with no rotary and three delta-rule layers that carry a state, a share of
+the heads held): the program
 against the benchmark's plain references on loss and every gradient leaf; each attention
 kind against plain masked attention, the latent layer against attention written the long
 way, the indexed layer against its reference's and its selection against a sort; the
@@ -9,7 +11,9 @@ experts' shares against the uncut layer; a bias that changes the choice and neve
 weight; routing that drops nothing;
 what the layers keep for the backward pass against keeping nothing, and the list by
 tokens and memory; derived parameter specs on a mesh; the state through the local
-checkpoint; the scopes the benchmark's readers look for in the lowered step."""
+checkpoint; the scopes the benchmark's readers look for in the lowered step. The delta
+rule by chunks against the recurrence token by token and the heads' shares against the
+uncut sublayers are in ``test_pattern_delta.py``."""
 
 import dataclasses
 import functools
@@ -33,11 +37,12 @@ if ROOT not in sys.path:
 SEQ = 37  # longer than the window (8), a multiple of neither block (8, 16)
 
 
-#: the three descriptions: the configuration file whose family and reference go with
+#: the four descriptions: the configuration file whose family and reference go with
 #: each, and the model's own tiny preset of it
 DESCRIPTIONS = {"mixed": ("laguna-xs2-l5-ep8", pattern.PatternConfig.tiny),
                 "latent": ("kimi-vl-a3b-l6-ep8", pattern.PatternConfig.tiny_latent),
-                "indexed": ("keye-vl2-30b-a3b-l6-ep8", pattern.PatternConfig.tiny_indexed)}
+                "indexed": ("keye-vl2-30b-a3b-l6-ep8", pattern.PatternConfig.tiny_indexed),
+                "delta": ("solar-open2-250b-l4-ep40-tp8", pattern.PatternConfig.tiny_delta)}
 #: the leaves of an indexed layer that only the indexer's own loss reaches
 INDEXER_LEAVES = ("wq_index", "wk_index", "ww_index", "k_index_norm")
 
@@ -684,6 +689,7 @@ def test_a_router_over_its_bound_takes_the_full_width_and_drops_nothing(
     ((0, 4), 64, "bounded", 128),     # twice the share of 64: 128 of 256
     ((0, 1), 64, "bounded", 128),     # twice the share is 32: rounded up to the row tile
     ((0, 4), 1000, "bounded", 2048),  # 2,000 rounded up
+    ((0, 1), 1000, "bounded", 512),   # twice the share is 500, under the tokens
     ((0, 4), 32, "full", 128),        # the row tile is all 128 pairs
     ((0, 8), 64, "full", 256),        # twice a half share
     ((0, 16), 64, "full", 256),       # every expert held
@@ -732,7 +738,10 @@ def test_gradient_leaf_with_the_kept_list_equals_nothing_kept(description, path)
                  for kept in (True, False))
     key = next(k for k in want if jax.tree_util.keystr(k) == path)
     assert float(jnp.linalg.norm(want[key])) > 0
-    np.testing.assert_allclose(np.asarray(got[key]), np.asarray(want[key]), rtol=1e-6, atol=1e-9)
+    # a delta layer's scan compiles to other float32 sums where its states are kept than
+    # where they are made again: 4e-6 of a leaf's largest element, read
+    atol = 2e-5 * float(jnp.max(jnp.abs(want[key]))) if description == "delta" else 1e-9
+    np.testing.assert_allclose(np.asarray(got[key]), np.asarray(want[key]), rtol=1e-6, atol=atol)
 
 
 @pytest.mark.parametrize("description", list(DESCRIPTIONS))
@@ -756,10 +765,14 @@ def test_the_forward_names_what_the_groups_list(description):
     named = {eqn.params["name"] for eqn in equations(jaxpr) if eqn.primitive.name == "name"}
     listed = set(pattern.kept_residuals(cfg, 2 * SEQ, None, SEQ)["names"])
     assert named == listed - {"attn_lse"}  # the blocks make no log-sum-exp
+    indexer_makes = {"select_mask", "index_q", "index_w", "index_k"}
     if description == "indexed":  # no shared expert, no dense layer
-        assert listed == set(ALL_NAMES) - {"shared_gate", "shared_up", "dense_gate", "dense_up"}
-    else:  # every group but the two that only an indexer makes
-        assert listed == set(ALL_NAMES) - {"select_mask", "index_q", "index_w", "index_k"}
+        assert listed == set(ALL_NAMES) - DELTA_NAMES - {
+            "shared_gate", "shared_up", "dense_gate", "dense_up"}
+    elif description == "delta":  # no dense layer; its full layer names q, k, v and the output
+        assert listed == set(ALL_NAMES) - indexer_makes - {"dense_gate", "dense_up"}
+    else:  # every group but those that only an indexer or a delta layer makes
+        assert listed == set(ALL_NAMES) - indexer_makes - DELTA_NAMES
 
 
 def cell_config(description: str, seq: int) -> pattern.PatternConfig:
@@ -771,6 +784,8 @@ def cell_config(description: str, seq: int) -> pattern.PatternConfig:
 
 
 ALL_NAMES = [name for names in pattern.KEPT_GROUPS.values() for name in names]
+#: what only a delta layer names
+DELTA_NAMES = {*pattern.KEPT_GROUPS["states"], *pattern.KEPT_GROUPS["delta"]}
 V5E_BYTES = 16.9e9  # one v5e's ``bytes_limit``
 
 
@@ -801,14 +816,27 @@ V5E_BYTES = 16.9e9  # one v5e's ``bytes_limit``
     ("mixed", 8192, 12.64e9, 1, 37_748_736),
     ("mixed", 8192, 12.0e9, 0, 0),
     ("latent", 8192, 1e9, 0, 0),
+    # the fourth: 13.45e9 B of weights, moments and gradients, 1.6e9 of logits and 0.3e9 of
+    # the rule's float32 values leave room for six groups of its seven: a layer keeps 12 MB
+    # of routing and 67 of stream, the full layer 17 of output and log-sum-exp and 21 of q, k
+    # and v (one KV head), a delta layer 67 of states (128 chunks x 8 heads x 128 x 128
+    # float32), 17 of the rule's output and 84 of q, k, v, log-decays and write strengths;
+    # the shared expert's products (42 MB a layer) do not fit
+    ("delta", 8192, V5E_BYTES, 6, 4 * (11_534_336 + 67_108_864) + 17_039_360 + 20_971_520
+                                  + 3 * (67_108_864 + 16_777_216 + 8192 * 8 * (2 * 384 + 512 + 4))),
+    ("delta", 8192, 32e9, 7, 4 * (11_534_336 + 67_108_864 + 41_943_040) + 17_039_360 + 20_971_520
+                             + 3 * (67_108_864 + 16_777_216 + 8192 * 8 * (2 * 384 + 512 + 4))),
+    ("delta", 8192, 16.0e9, 1, 4 * 11_534_336),
+    ("delta", 8192, 15.9e9, 0, 0),
 ])
 def test_kept_residuals_by_tokens_and_memory(description, tokens, memory, groups, kept_bytes):
     cfg = cell_config(description, tokens)
     kept = pattern.kept_residuals(cfg, tokens, memory)
     # the groups that hold anything in this description, in the order of KEPT_GROUPS
-    holds = {"mixed": set(pattern.KEPT_GROUPS) - {"selection", "index"},
-             "latent": set(pattern.KEPT_GROUPS) - {"selection", "index"},
-             "indexed": set(pattern.KEPT_GROUPS) - {"shared", "dense"}}[description]
+    holds = {"mixed": set(pattern.KEPT_GROUPS) - {"selection", "index", "states", "delta"},
+             "latent": set(pattern.KEPT_GROUPS) - {"selection", "index", "states", "delta"},
+             "indexed": set(pattern.KEPT_GROUPS) - {"shared", "dense", "states", "delta"},
+             "delta": set(pattern.KEPT_GROUPS) - {"selection", "index", "dense"}}[description]
     taken = [(g, names) for g, names in pattern.KEPT_GROUPS.items() if g in holds][:groups]
     assert kept["names"] == [name for _, names in taken for name in names]
     assert list(kept["per_layer"]) == [group for group, _ in taken]
@@ -844,7 +872,8 @@ def test_kept_residuals_of_the_laguna_cell_layer_by_layer():
     and v, a sliding one (64 heads) 136 and 168; a sparse layer 9 MB of routing and 17 MB
     of the shared expert's products, the dense layer 268 MB of its own."""
     kept = pattern.kept_residuals(cell_config("mixed", 8192), 8192, V5E_BYTES)
-    assert kept["names"] == [name for name in ALL_NAMES if "select" not in name and "index" not in name]
+    assert kept["names"] == [name for name in ALL_NAMES if "select" not in name
+                             and "index" not in name and name not in DELTA_NAMES]
     assert kept["per_layer"] == {
         "routing": [0] + [8192 * 4 * (256 + 4 * 8)] * 4,
         "stream": [8192 * 2048 * 2] * 5,
@@ -884,6 +913,15 @@ def test_derived_specs_on_a_mesh_give_the_one_chip_loss(axes, description):
             assert all(axis is None for axis in indexed[name]), name
         assert indexed["wq"] == indexed["wk"] == jax.sharding.PartitionSpec(None, None, "tp")
         assert "ws_gate" not in specs["mlp"]["sparse"] and "dense" not in specs["mlp"]
+    if description == "delta":  # what is split by heads goes by heads; the low-rank downs replicate
+        delta = specs["attn"]["delta"]
+        for name in ("wq", "wk", "wv", "wf_b", "wg_b", "wb"):
+            assert delta[name] == jax.sharding.PartitionSpec(None, None, "tp"), name
+        for name in ("conv_q", "conv_k", "conv_v", "wo"):
+            assert delta[name] == jax.sharding.PartitionSpec(None, "tp", None), name
+        assert delta["a_log"] == delta["dt_bias"] == jax.sharding.PartitionSpec(None, "tp")
+        for name in ("wf_a", "wg_a", "o_norm", "attn_norm"):
+            assert all(axis is None for axis in delta[name]), name
     sharded = jax.device_put(params, pmesh.tree_shardings(mesh, specs))
     assert len(sharded["mlp"]["sparse"]["we_up"].sharding.device_set) == 8
     with mesh:
@@ -1052,9 +1090,20 @@ def test_description_rejects_what_cannot_be_stacked():
         pattern.PatternConfig.tiny_indexed(indexer=None)
     with pytest.raises(ValueError, match="router score"):
         pattern.PatternConfig.tiny_indexed(route_score="tanh")
+    with pytest.raises(ValueError, match="widths of `delta`"):
+        pattern.PatternConfig.tiny_delta(delta=None)
+    with pytest.raises(ValueError, match="whole KV groups"):  # 8 heads over 4 KV heads, 8 ways
+        pattern.PatternConfig.tiny_delta(head_ways=8)
+    with pytest.raises(ValueError, match="not held 3 ways"):
+        pattern.PatternConfig.tiny_delta(head_ways=3)
+    with pytest.raises(ValueError, match="not a count of shares"):
+        pattern.PatternConfig.tiny_delta(head_ways=0)
+    with pytest.raises(ValueError, match="output gate"):
+        pattern.PatternConfig.tiny(gate="row")
 
 
-@pytest.mark.parametrize("description,leaves", [("mixed", 27), ("latent", 22), ("indexed", 19)])
+@pytest.mark.parametrize("description,leaves", [("mixed", 27), ("latent", 22), ("indexed", 19),
+                                                ("delta", 33)])
 def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path, description, leaves):
     """Per-kind stacks and the held experts' leaves through
     ``checkpoint/local_manager.py``: the restored state gives the next loss exactly."""
@@ -1083,18 +1132,22 @@ def test_state_through_the_local_checkpoint_replays_the_next_loss(tmp_path, desc
     mgr.close()
 
 
-@pytest.mark.parametrize("description", ["latent", "indexed"])
+@pytest.mark.parametrize("description", ["latent", "indexed", "delta"])
 def test_the_lowered_step_carries_the_scopes_the_readers_look_for(description):
-    """The second and the third description's train step, lowered (nothing compiles): its
-    ops' names hold ``attn/full``, ``attn/full/core`` and ``moe/*`` as the accepted
-    readers' patterns want them, and ``attn/full/latent``, or ``attn/full/indexer`` and
-    ``attn/full/select``, as the new readers' do; what is under a new scope is under
+    """The second, the third and the fourth description's train step, lowered (nothing
+    compiles): its ops' names hold ``attn/full``, ``attn/full/core`` and ``moe/*`` as the
+    accepted readers' patterns want them, and ``attn/full/latent``, or
+    ``attn/full/indexer`` and ``attn/full/select``, or ``attn/full/delta`` with ``/rule``
+    and ``/rule/state``, as the new readers' do; what is under a new scope is under
     ``attn`` too and never under ``core``."""
     from benchmark import harness
 
     scopes = harness.load_by_path("layer_metrics", "scope_times").SCOPES
     if description == "latent":
         own = {"latent": harness.load_by_path("layer_metrics", "attn.latent_ms").SCOPE}
+    elif description == "delta":
+        own = harness.load_by_path("layer_metrics", "attn.delta_ms").SCOPES
+        assert list(own) == ["delta", "rule", "state"]
     else:
         own = harness.load_by_path("layer_metrics", "attn.indexer_ms").SCOPES
         assert set(own) == {"indexer", "select"}
@@ -1118,3 +1171,9 @@ def test_the_lowered_step_carries_the_scopes_the_readers_look_for(description):
     if description == "indexed":  # the mask is kept: the selection runs in the first forward alone
         assert all("jvp(" in n and "transpose(" not in n for n in under["select"])
         assert not under["select"] & under["indexer"]
+    if description == "delta":  # the scan is inside the rule, the rule inside the layer's own
+        assert under["state"] < under["rule"] < under["delta"]
+        assert any("/conv/" in n for n in under["delta"] - under["rule"])
+        assert any("/gates/" in n for n in under["delta"] - under["rule"])
+        for phase in ("jvp(", "transpose("):  # the scan forward, and the walk back over it
+            assert any(phase in n and "while" in n for n in under["state"]), phase

@@ -422,3 +422,74 @@ def test_keye_train_step_fits_one_chip(one_chip, as_on_a_tpu):
     scores = {name: sum(1 for line in calls if f"index_scores_{name}/" in line)
               for name in ("fwd", "dq", "dk")}
     assert scores == {"fwd": 48, "dq": 24, "dk": 24}, scores
+
+
+def test_solar_train_step_fits_one_chip(one_chip, as_on_a_tpu, monkeypatch):
+    """The whole donating step of ``solar-open2-250b-l4-ep40-tp8`` at 1 x 8192: 840,871,320
+    parameters, 10.09e9 B of f32 weights and AdamW moments (60% of the chip before any
+    activation), the softmax layer on the kernels (8 heads over the one KV head held: each
+    kernel once), the three delta layers' rule in ``jax.numpy`` by chunks of 64, and what the
+    step needs beside its state inside one v5e's 15.75 GiB with the six groups of residuals
+    that ``kept_residuals`` gives at that memory (stated here, where the CPU states none:
+    with no limit the shared expert's products would be kept too)."""
+    from tpu_resiliency.models import pattern
+
+    limit = int(15.75 * 2 ** 30)
+    monkeypatch.setattr(pattern, "device_memory_bytes", lambda: limit)
+    config, cfg = cell_config("solar-open2-250b-l4-ep40-tp8")
+    kept = pattern.kept_residuals(cfg, 8192, limit, 8192)
+    assert list(kept["per_layer"]) == ["routing", "stream", "attention", "states", "qkv", "delta"]
+    assert pattern.attention_paths(cfg, 8192) == {
+        "full": {"path": "kernel", "tile": 512}, "delta": {"path": "chunks", "chunk": 64}}
+    compiled, n_params, needed = pattern_step(config, cfg, one_chip)
+    assert n_params == 840_871_320
+    assert 10.0e9 < compiled.memory_analysis().argument_size_in_bytes < 10.2e9
+    assert needed < limit, needed  # 16.15e9 (compile, PR 39; 15.95e9 with four groups kept)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = {name: sum(1 for line in calls if f"blocked_attention_{name}" in line)
+               for name in ("fwd", "dq", "dkv")}
+    assert kernels == {"fwd": 1, "dq": 1, "dkv": 1}, kernels
+    # no [chunk, chunk, d_key] array of decay differences is written out: they live inside
+    # the fusions that sum over them (what ``pattern._rule_bytes`` leaves out)
+    import re
+    assert not re.search(r"= f32\[1,8,8,64,64,128\]\S* fusion\(", text)
+
+
+#: sha256 of each accepted configuration's donating step at its cell's batch, lowered for
+#: the described chip (StableHLO text, nothing compiled), as the parent of PR 39 lowers it,
+#: with each Mosaic kernel's serialized body left out: a body carries the source lines of
+#: its callers in ``models/pattern.py``, which move with any edit above them, while
+#: ``ops/attention.py`` and ``ops/index_scores.py`` themselves are the parent's files. A PR
+#: that changes one of these steps on purpose records its own.
+LOWERED_STEPS = {
+    "mistral-7b-l2": "3fa3a52f6b2c48d9",
+    "laguna-xs2-l5-ep8": "9a71b4a41782a45a",
+    "kimi-vl-a3b-l6-ep8": "21e86403b9123010",
+    "keye-vl2-30b-a3b-l6-ep8": "c27b597c76f394c1",
+}
+
+
+@pytest.mark.parametrize("name", list(LOWERED_STEPS))
+def test_an_accepted_configurations_lowered_step_is_what_it_was(one_chip, as_on_a_tpu, name):
+    """Heads held, the gate's form, a pattern without a rotary table and the delta kind
+    are all read from the description: with every head held, one sigmoid a head and a
+    table a kind, the four accepted configurations lower to the same program, byte for
+    byte, so none of their cells can have moved."""
+    import hashlib
+    import re
+
+    from benchmark import harness
+
+    config = harness.read_json(harness.HERE, "configs", f"{name}.json")
+    family = harness.load_family(config)
+    cfg = family.program_config(config, config["batch"][1])
+    train_step, init_opt = family.make_train_step(cfg)
+    params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg))
+    opt = jax.eval_shape(init_opt, params)
+    on_chip = lambda tree: placed(tree, jax.tree.map(lambda _: one_chip, tree))  # noqa: E731
+    text = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt), sds(tuple(config["batch"]), jnp.int32, one_chip)).as_text()
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == LOWERED_STEPS[name]

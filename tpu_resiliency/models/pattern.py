@@ -2,14 +2,18 @@
 
 Where ``transformer.py`` and ``moe.py`` scan one homogeneous stacked layer, this
 model is a list of layers, each an attention kind and an MLP kind. Attention:
-``full`` or ``sliding`` (grouped-query, with an output gate, each with its own head
-count and rotary table), ``latent`` (:class:`Latent`: keys and values of every head
+``full`` or ``sliding`` (grouped-query, with an output gate a head or a channel, each with
+its own head count and, unless the description gives the full kind none, its rotary
+table), ``latent`` (:class:`Latent`: keys and values of every head
 decompressed from one normed low-rank latent, one rotary key part shared by all heads,
 a score width that differs from the value width, no gate), or ``indexed``
 (:class:`Indexer`: grouped-query heads with a norm on each head's q and k and no gate,
 over the keys that a second, small set of heads chooses for each query from the data:
 an exact top-k of their scores, the same set for all heads; the indexer is taught by a
-loss of its own). MLP: ``dense`` SwiGLU, or ``sparse``: a float32 router over all
+loss of its own), or ``delta`` (:class:`Delta`: no softmax over keys at all, a state a
+head carried along the sequence by the gated delta rule, :func:`delta_rule`). A
+description may hold a share of every layer's heads, as it holds a share of the experts
+(:attr:`PatternConfig.head_ways`). MLP: ``dense`` SwiGLU, or ``sparse``: a float32 router over all
 experts of the deployment (sigmoid or softmax scores; chosen by score, or by score plus
 a selection bias that never enters a weight and that the loss-free balancing rule
 moves), the top-k routed experts that this chip holds, and one shared SwiGLU where the
@@ -34,6 +38,10 @@ Built TPU-first, static shapes throughout:
   and one of the heads' mean probabilities at a time); on a TPU its index scores run
   as the blocked kernels of ``ops/index_scores.py`` where its groups of query rows are
   whole tiles, and never hold the per-head products of a block.
+- **A state runs by chunks.** A delta layer's rule is a triangular system inside a chunk
+  of tokens, solved for all of them at once, and a scan of the state across the chunks
+  with a backward pass of its own that reads the states the forward kept; every exponent
+  is of a difference of running log-decays that is ``<= 0`` (:func:`delta_rule`).
 - **Routing drops nothing.** Every (token, choice) pair whose expert this chip holds
   is computed: the pairs are sorted by expert and the three SwiGLU products run as
   grouped products over the ragged groups (``jax.lax.ragged_dot``). Pairs for
@@ -45,7 +53,8 @@ Built TPU-first, static shapes throughout:
   Each layer runs under a ``jax.checkpoint`` whose policy keeps a list of named values
   beside the layer's input (the router's choices and the sort's indices, the keys an
   indexer selected, the stream after attention, the attention output and its
-  log-sum-exp, q, k and v, the gate and up products of the SwiGLUs) and recomputes the
+  log-sum-exp, a delta layer's states at each chunk's start, q, k and v, the gate and up
+  products of the SwiGLUs) and recomputes the
   rest; :func:`kept_residuals` chooses
   the list from the configuration, the tokens of a step and the device's memory.
 - **The description says how each leaf may be sharded** (:func:`describe_params`:
@@ -72,10 +81,12 @@ from tpu_resiliency.models import transformer as tfm
 from tpu_resiliency.ops import attention
 from tpu_resiliency.ops import index_scores as index_score_kernels
 
-FULL, SLIDING, LATENT, INDEXED = "full", "sliding", "latent", "indexed"
-ATTENTION_KINDS = (FULL, SLIDING, LATENT, INDEXED)
+FULL, SLIDING, LATENT, INDEXED, DELTA = "full", "sliding", "latent", "indexed", "delta"
+ATTENTION_KINDS = (FULL, SLIDING, LATENT, INDEXED, DELTA)
 DENSE, SPARSE = "dense", "sparse"
 SIGMOID, SOFTMAX = "sigmoid", "softmax"
+#: the output gate of a ``full`` or ``sliding`` layer: one sigmoid a head, or one a channel
+HEAD_GATE, CHANNEL_GATE = "head", "channel"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,9 +147,33 @@ class Indexer:
 
 
 @dataclasses.dataclass(frozen=True)
+class Delta:
+    """Gated delta-rule linear attention with a decay a key channel (Kimi Delta Attention,
+    Kimi Linear, arXiv:2510.26692): a head carries a state ``S [d_key, d_value]`` along
+    the sequence and no key of an earlier token. A token decays each key channel of the
+    state by a factor of its own, takes out what the state already holds for its key and
+    writes its value (:func:`delta_rule`). Around the rule: ``q``, ``k`` and ``v`` each
+    pass a depthwise causal convolution of ``conv_taps`` taps and a SiLU, ``q`` and ``k``
+    are scaled to unit length a head; the log-decay and the output gate are maps of the
+    normed input through ``gate_rank`` numbers (two matrices each); the write strength
+    ``beta`` is one sigmoid a head, doubled under ``neg_eigval`` so that a transition may
+    have a negative eigenvalue; each head's output is normed (one weight vector for all
+    heads) under the gate. No position enters: the order of the tokens is the state's."""
+
+    d_key: int
+    d_value: int
+    gate_rank: int
+    conv_taps: int = 4
+    #: tokens the rule takes at a time: inside a chunk a triangular system, across chunks
+    #: a scan of the state. A size of the computation: the result does not depend on it
+    chunk: int = 64
+    neg_eigval: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
 class Layer:
-    attn: str  # FULL | SLIDING | LATENT | INDEXED
-    n_heads: int
+    attn: str  # FULL | SLIDING | LATENT | INDEXED | DELTA
+    n_heads: int  # of the deployment (:attr:`PatternConfig.head_ways` says how many are here)
     mlp: str  # DENSE | SPARSE
 
 
@@ -156,10 +191,21 @@ class PatternConfig:
     top_k: int
     #: (first, count): the contiguous range of the ``n_experts`` whose weights are here
     experts_held: tuple[int, int]
+    #: how many equal shares every layer's heads are held in, one of them here: a chip
+    #: of a tensor-parallel group holds ``n_heads / head_ways`` query heads of a layer with
+    #: their ``n_kv_heads / head_ways`` KV heads, the columns of ``wq``, ``wk``, ``wv``
+    #: and of the gates and the rows of ``wo`` that are theirs, and adds the held heads'
+    #: part of ``wo``'s product to the stream. Nothing stands in for the other chips of
+    #: the group or for the sum over them, and no computation reads which share this is
+    #: (the weights are). ``1``: every head
+    head_ways: int = 1
     routed_scale: float = 1.0
     window: int = 512
-    rope_full: Rope = Rope()
+    #: ``None``: the full layers turn nothing (no position enters them)
+    rope_full: Optional[Rope] = Rope()
     rope_sliding: Rope = Rope()
+    #: HEAD_GATE: ``wg [d, heads]``; CHANNEL_GATE: ``wg [d, heads * head_dim]``
+    gate: str = HEAD_GATE
     #: the widths of the ``latent`` layers, and the rotary table of their ``d_rope`` part
     latent: Optional[Latent] = None
     rope_latent: Rope = Rope()
@@ -167,6 +213,8 @@ class PatternConfig:
     #: (made once for the attention heads' width and once for the indexer's)
     indexer: Optional[Indexer] = None
     rope_indexed: Rope = Rope()
+    #: the widths of the ``delta`` layers, which have no rotary table
+    delta: Optional[Delta] = None
     #: the router's scores over all experts: SIGMOID (each expert's own) or SOFTMAX
     route_score: str = SIGMOID
     #: the router chooses by score + a selection bias (seeded at this standard deviation)
@@ -195,27 +243,51 @@ class PatternConfig:
                                  "their weights cannot be stacked")
         if self.route_score not in (SIGMOID, SOFTMAX):
             raise ValueError(f"unknown router score {self.route_score!r}")
+        if self.gate not in (HEAD_GATE, CHANNEL_GATE):
+            raise ValueError(f"unknown output gate {self.gate!r}")
+        ways = self.head_ways
+        if ways < 1:
+            raise ValueError(f"head_ways {ways} is not a count of shares")
         for l in self.layers:
             if l.attn not in ATTENTION_KINDS or l.mlp not in (DENSE, SPARSE):
                 raise ValueError(f"unknown layer kind in {l}")
+            if l.n_heads % ways:
+                raise ValueError(f"{l.n_heads} heads are not held {ways} ways")
             if l.attn == LATENT:  # every head has keys of its own
                 if self.latent is None:
                     raise ValueError("latent layers need the widths of `latent`")
+            elif l.attn == DELTA:  # and here
+                if self.delta is None:
+                    raise ValueError("delta layers need the widths of `delta`")
             elif l.n_heads % self.n_kv_heads:
                 raise ValueError(f"{l.n_heads} heads do not group over {self.n_kv_heads}")
+            elif self.n_kv_heads % ways:
+                raise ValueError(f"a {ways}th of {l.n_heads} heads over {self.n_kv_heads} "
+                                 "KV heads is not whole KV groups")
             if l.attn == INDEXED and self.indexer is None:
                 raise ValueError("indexed layers need the heads of `indexer`")
 
-    def rope(self, kind: str) -> Rope:
+    def rope(self, kind: str) -> Optional[Rope]:
+        """The rotary table of the layers of ``kind``; ``None``: they have none."""
         return {FULL: self.rope_full, SLIDING: self.rope_sliding, LATENT: self.rope_latent,
-                INDEXED: self.rope_indexed}[kind]
+                INDEXED: self.rope_indexed, DELTA: None}[kind]
 
     def rotary_width(self, kind: str) -> int:
         """The dimensions of a head that :meth:`rope`'s table of ``kind`` is made for."""
         return self.latent.d_rope if kind == LATENT else self.head_dim
 
+    def held(self, heads: int) -> int:
+        """Of ``heads`` of the deployment, how many are held here."""
+        return heads // self.head_ways
+
     def heads(self, kind: str) -> int:
-        return next(l.n_heads for l in self.layers if l.attn == kind)
+        """The query heads held here of a layer of ``kind``."""
+        return self.held(next(l.n_heads for l in self.layers if l.attn == kind))
+
+    @property
+    def kv_heads(self) -> int:
+        """The KV heads held here (of the kinds that group their query heads over them)."""
+        return self.held(self.n_kv_heads)
 
     def count(self, kind: str) -> int:
         """Layers whose attention or MLP is of ``kind``."""
@@ -263,6 +335,22 @@ class PatternConfig:
         base.update(kw)
         return PatternConfig(**base)
 
+    @staticmethod
+    def tiny_delta(**kw) -> "PatternConfig":
+        """A period of four: one full layer with no rotary and a sigmoid a channel for a
+        gate, then three delta layers; all sparse; 2 of 8 heads held (with 1 of the 4 KV
+        heads of the full layer): the fourth description the tests train."""
+        base = dict(
+            vocab_size=256, d_model=64, head_dim=16, n_kv_heads=4,
+            layers=(Layer(FULL, 8, SPARSE), *(Layer(DELTA, 8, SPARSE),) * 3),
+            delta=Delta(d_key=16, d_value=16, gate_rank=8, chunk=16),
+            rope_full=None, gate=CHANNEL_GATE, head_ways=4,
+            d_ff=128, d_expert=32, d_shared=32, n_experts=16, top_k=4,
+            experts_held=(0, 4), attn_block=16, norm_eps=1e-5,
+        )
+        base.update(kw)
+        return PatternConfig(**base)
+
 
 # ---------------------------------------------------------------------------------
 # the parameters, described
@@ -271,13 +359,33 @@ class PatternConfig:
 class Leaf(NamedTuple):
     """One parameter leaf: its shape, the logical name of each dimension (what
     ``parallel/mesh.py`` maps to mesh axes; ``None`` is never sharded) and how it is
-    seeded: normal / sqrt(``fan_in``), or normal x ``std`` where that is given, or at
-    one (a norm: neither)."""
+    seeded: by the rule of :data:`SEEDINGS` that ``seeding`` names, else normal /
+    sqrt(``fan_in``), or normal x ``std`` where that is given, or at one (a norm:
+    none of them)."""
 
     shape: tuple[int, ...]
     axes: tuple[Optional[str], ...]
     fan_in: Optional[int]
     std: Optional[float] = None
+    seeding: Optional[str] = None
+
+
+def _decay_rate(key, shape):
+    """``log A`` with ``A`` uniform in [1, 16]: a head's decay rate."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def _decay_step(key, shape):
+    """The inverse softplus of a step drawn log-uniformly in [0.001, 0.1]: the bias under
+    a delta layer's softplus, so that at seeded weights a token's log-decay is about
+    ``-A x step``."""
+    step = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+#: Seedings of their own (the published implementation's of a delta layer's decay: with a
+#: normal draw here the state forgets either at once or never, and the rule does no work)
+SEEDINGS = {"decay_rate": _decay_rate, "decay_step": _decay_step}
 
 
 def describe_params(cfg: PatternConfig) -> dict:
@@ -290,8 +398,18 @@ def describe_params(cfg: PatternConfig) -> dict:
     ``ww_index``, ``k_index_norm``) is replicated like the router: every chip scores all
     the keys of its own tokens. ``b_router`` is there only where the
     router chooses by a bias, seeded so that the bias it stands for (``route_bias_gain``
-    times it) has ``route_bias_std``."""
-    d, dh, hkv = cfg.d_model, cfg.head_dim, cfg.n_kv_heads
+    times it) has ``route_bias_std``. A delta layer's leaves: ``wq``, ``wk``, ``wv`` and
+    a convolution for each (``conv_* [channels, taps]``), the two matrices of the log-decay
+    (``wf_a``, the same on every chip of a tensor-parallel group, and ``wf_b``) with a rate
+    a head (``a_log``) and a bias a channel (``dt_bias``), ``wb`` (the write strength),
+    the two matrices of the output gate (``wg_a``, ``wg_b``), the heads' norm ``o_norm``
+    and ``wo``.
+
+    Every leaf that is split by heads has the heads held here
+    (:attr:`PatternConfig.head_ways`); ``wo`` is seeded for the sum over all the heads
+    of the deployment, which its product here is a part of."""
+    d, dh, hkv = cfg.d_model, cfg.head_dim, cfg.kv_heads
+    ways = cfg.head_ways
     tree: dict = {
         "embed": Leaf((cfg.vocab_size, d), ("vocab", None), d),
         "final_norm": Leaf((d,), (None,), None),
@@ -308,8 +426,9 @@ def describe_params(cfg: PatternConfig) -> dict:
             "wq": Leaf((n, d, h * dh), (None, None, "heads"), d),
             "wk": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
             "wv": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
-            "wg": Leaf((n, d, h), (None, None, "heads"), d),
-            "wo": Leaf((n, h * dh, d), (None, "heads", None), h * dh),
+            "wg": Leaf((n, d, h * (dh if cfg.gate == CHANNEL_GATE else 1)),
+                       (None, None, "heads"), d),
+            "wo": Leaf((n, h * dh, d), (None, "heads", None), ways * h * dh),
         }
     n = cfg.count(LATENT)
     if n:
@@ -321,7 +440,7 @@ def describe_params(cfg: PatternConfig) -> dict:
             "kv_norm": Leaf((n, la.kv_rank), (None, None), None),
             "wkv_b": Leaf((n, la.kv_rank, h * (la.d_nope + la.d_value)),
                           (None, None, "heads"), la.kv_rank),
-            "wo": Leaf((n, h * la.d_value, d), (None, "heads", None), h * la.d_value),
+            "wo": Leaf((n, h * la.d_value, d), (None, "heads", None), ways * h * la.d_value),
         }
 
     n = cfg.count(INDEXED)
@@ -334,11 +453,34 @@ def describe_params(cfg: PatternConfig) -> dict:
             "wv": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
             "q_norm": Leaf((n, dh), (None, None), None),
             "k_norm": Leaf((n, dh), (None, None), None),
-            "wo": Leaf((n, h * dh, d), (None, "heads", None), h * dh),
+            "wo": Leaf((n, h * dh, d), (None, "heads", None), ways * h * dh),
             "wq_index": Leaf((n, d, ix.n_heads * ix.head_dim), (None, None, None), d),
             "wk_index": Leaf((n, d, ix.head_dim), (None, None, None), d),
             "ww_index": Leaf((n, d, ix.n_heads), (None, None, None), d),
             "k_index_norm": Leaf((n, ix.head_dim), (None, None), None),
+        }
+
+    n = cfg.count(DELTA)
+    if n:
+        h, de = cfg.heads(DELTA), cfg.delta
+        keys, values, r = h * de.d_key, h * de.d_value, de.gate_rank
+        conv = lambda channels: Leaf(  # noqa: E731
+            (n, channels, de.conv_taps), (None, "heads", None), de.conv_taps)
+        tree["attn"][DELTA] = {
+            "attn_norm": Leaf((n, d), (None, None), None),
+            "wq": Leaf((n, d, keys), (None, None, "heads"), d),
+            "wk": Leaf((n, d, keys), (None, None, "heads"), d),
+            "wv": Leaf((n, d, values), (None, None, "heads"), d),
+            "conv_q": conv(keys), "conv_k": conv(keys), "conv_v": conv(values),
+            "wf_a": Leaf((n, d, r), (None, None, None), d),
+            "wf_b": Leaf((n, r, keys), (None, None, "heads"), r),
+            "a_log": Leaf((n, h), (None, "heads"), None, seeding="decay_rate"),
+            "dt_bias": Leaf((n, keys), (None, "heads"), None, seeding="decay_step"),
+            "wb": Leaf((n, d, h), (None, None, "heads"), d),
+            "wg_a": Leaf((n, d, r), (None, None, None), d),
+            "wg_b": Leaf((n, r, values), (None, None, "heads"), r),
+            "o_norm": Leaf((n, de.d_value), (None, None), None),
+            "wo": Leaf((n, values, d), (None, "heads", None), ways * values),
         }
 
     def swiglu(prefix: str, lead: tuple, lead_axes: tuple, f: int) -> dict:
@@ -374,13 +516,15 @@ def _is_leaf(x) -> bool:
 
 
 def init_params(rng: jax.Array, cfg: PatternConfig) -> dict:
-    """Seeded weights: normal / sqrt(fan_in) or normal x std, norms at one
-    (:class:`Leaf`). One key a leaf, split from ``rng`` in the order the tree flattens
+    """Seeded weights: normal / sqrt(fan_in) or normal x std, norms at one, or by a rule
+    of :data:`SEEDINGS` (:class:`Leaf`). One key a leaf, split from ``rng`` in the order the tree flattens
     (sorted keys), so that anything that knows the description makes the same weights."""
     leaves, treedef = jax.tree.flatten(describe_params(cfg), is_leaf=_is_leaf)
     keys = jax.random.split(rng, len(leaves))
 
     def seeded(key, leaf: Leaf):
+        if leaf.seeding is not None:
+            return SEEDINGS[leaf.seeding](key, leaf.shape)
         if leaf.std is not None:
             return jax.random.normal(key, leaf.shape, jnp.float32) * leaf.std
         if leaf.fan_in is None:
@@ -797,10 +941,16 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     whole blocks of the indexer's rows; it says how many keys a query keeps, and which way
     its index scores go: ``scores: "kernel"`` for the blocked kernels of
     ``ops/index_scores.py`` where the backend is a TPU, every group of query rows is whole
-    tiles and the indexer's heads fit their layout, else ``scores: "blocks"``."""
+    tiles and the indexer's heads fit their layout, else ``scores: "blocks"``. A delta
+    kind has no products over keys: ``{"path": "chunks", "chunk": tokens}``, the rule of
+    :func:`delta_rule` in ``jax.numpy`` (``"kernel"`` is for a kernel of it, which there is
+    not)."""
     paths = {}
     for kind in ATTENTION_KINDS:
         if not cfg.count(kind):
+            continue
+        if kind == DELTA:  # no products over keys: the rule by chunks, in jax.numpy
+            paths[kind] = {"path": "chunks", "chunk": min(cfg.delta.chunk, seq)}
             continue
         window = _window(cfg, kind)
         score, value = _widths(cfg, kind)
@@ -839,20 +989,25 @@ def _products(cfg: PatternConfig, kind: str, q, k, v):
     return checkpoint_name(out, attention.OUT_NAME)
 
 
-def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos, sin):
-    """Pre-norm grouped-query attention of one kind with an output gate (one sigmoid
-    a head, from the normed input) and the residual."""
+def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos=None, sin=None):
+    """Pre-norm grouped-query attention of one kind with an output gate (from the normed
+    input: one sigmoid a head, or one a channel where the description says so) and the
+    residual; the rotary where the kind has a table (``cos`` given)."""
     with jax.named_scope(f"attn/{kind}"):
         b, t, _ = x.shape
-        h, hkv, dh = cfg.heads(kind), cfg.n_kv_heads, cfg.head_dim
+        h, hkv, dh = cfg.heads(kind), cfg.kv_heads, cfg.head_dim
         y = tfm.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q = (y @ lp["wq"].astype(y.dtype)).reshape(b, t, h, dh)
         k = (y @ lp["wk"].astype(y.dtype)).reshape(b, t, hkv, dh)
         v = (y @ lp["wv"].astype(y.dtype)).reshape(b, t, hkv, dh)
-        gate = jax.nn.sigmoid(y @ lp["wg"].astype(y.dtype))  # [B, T, H]
-        q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
+        gate = jax.nn.sigmoid(y @ lp["wg"].astype(y.dtype))  # [B, T, H] or [B, T, H * dh]
+        if cos is not None:
+            q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
         attn = _products(cfg, kind, q, k, v)
-        attn = (attn.reshape(b, t, h, dh) * gate[..., None]).reshape(b, t, h * dh)
+        if cfg.gate == CHANNEL_GATE:
+            attn = attn * gate
+        else:
+            attn = (attn.reshape(b, t, h, dh) * gate[..., None]).reshape(b, t, h * dh)
         return x + attn @ lp["wo"].astype(attn.dtype)
 
 
@@ -904,7 +1059,7 @@ def _indexed_block(cfg: PatternConfig, x, lp: dict, cos, sin, index_cos, index_s
     rotary, the index scores, the divergence), ``/select`` and ``/core`` inside it."""
     with jax.named_scope("attn/full"):
         b, t, _ = x.shape
-        h, hkv, dh, ix = cfg.heads(INDEXED), cfg.n_kv_heads, cfg.head_dim, cfg.indexer
+        h, hkv, dh, ix = cfg.heads(INDEXED), cfg.kv_heads, cfg.head_dim, cfg.indexer
         y = tfm.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q = (y @ lp["wq"].astype(y.dtype)).reshape(b, t, h, dh)
         k = (y @ lp["wk"].astype(y.dtype)).reshape(b, t, hkv, dh)
@@ -940,6 +1095,218 @@ def _indexed_block(cfg: PatternConfig, x, lp: dict, cos, sin, index_cos, index_s
         counts = {"index_kl": jnp.mean(divergence), "keys_selected": jnp.sum(selected),
                   "select_ties": jnp.sum(tied)}
         return out, counts, masks
+
+
+# ---------------------------------------------------------------------------------
+# the delta rule: a state along the sequence, by chunks
+# ---------------------------------------------------------------------------------
+
+#: chunks whose decayed Gram matrices one pass of :func:`delta_rule` makes together: a
+#: pass is over ``[B, H, GRAM_CHUNKS, chunk, chunk, d_key]`` float32 differences (134e6 B
+#: at 8 heads, chunks of 64 and keys of 128, were they ever written out)
+GRAM_CHUNKS = 8
+
+DELTA_STATES_NAME, DELTA_OUT_NAME = "delta_states", "delta_out"
+
+
+def _within_chunks(q, k, v, g, beta):
+    """What the scan over the chunks needs of each chunk, from the chunk alone: operands
+    ``[..., C, d]`` (a chunk of ``C`` tokens on the second-last axis), ``g`` float32 log-
+    decays, ``beta [..., C]`` float32. With ``G`` the running sum of ``g`` inside the chunk,
+    and every exponent a difference ``G_i - G_j <= 0`` of a later row and an earlier one:
+
+    - ``A[i, j] = beta_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` for ``j < i``, and the
+      solve ``T = (I + A)^-1`` applied to ``beta v`` and to ``beta k exp(G)``: a token's
+      write as if the state at the chunk's start were zero (``w_v``), and what that state
+      takes from it (``w_k``);
+    - ``B[i, j] = sum_d q_i[d] k_j[d] exp(G_i[d] - G_j[d])`` for ``j <= i``: what a query
+      reads of the writes of its own chunk;
+    - ``q exp(G)``: the query against the state at the chunk's start; ``k exp(G_C - G)``:
+      a write as it stands in the state at the chunk's end; ``exp(G_C)``: what is left of
+      the state by then.
+
+    All float32; the Gram matrices are sums on the vector unit over the channels, whose
+    decays differ (one matrix product would need ``exp(G_i) x exp(-G_j)``, which overflows
+    in a chunk whose decay is strong)."""
+    f32 = jnp.float32
+    c = q.shape[-2]
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    # the running sum as a product with a triangle of ones: a cumulative sum along an
+    # axis lowers to a reduce-window on a TPU
+    total = jnp.einsum("ij,...jd->...id", lower.astype(f32), g,
+                       precision=jax.lax.Precision.HIGHEST)
+    decay = jnp.exp(jnp.where(lower[..., None], total[..., :, None, :] - total[..., None, :, :],
+                              -jnp.inf))  # [..., C, C, dk], zero above the diagonal
+    gram_k = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
+    gram_q = jnp.sum(q[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    system = jnp.eye(c, dtype=f32) + jnp.where(strict, beta[..., None] * gram_k, 0.0)
+    seen = jnp.exp(total)
+    rhs = jnp.concatenate([v, k * seen], axis=-1) * beta[..., None]
+    solved = jax.lax.linalg.triangular_solve(
+        system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    w_v, w_k = solved[..., :v.shape[-1]], solved[..., v.shape[-1]:]
+    last = total[..., -1:, :]
+    return w_v, w_k, gram_q, q * seen, k * jnp.exp(last - total), jnp.exp(last[..., 0, :])
+
+
+def _chunk_step(state, chunk, dtype):
+    """One chunk of the scan: the state ``[B, H, dk, dv]`` float32 at the chunk's start and
+    :func:`_within_chunks`'s values of the chunk -> (the state at its end, the outputs
+    ``[B, H, C, dv]`` float32). The products take their operands in ``dtype`` and
+    accumulate in float32."""
+    w_v, w_k, gram_q, q_seen, k_left, left = chunk
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                          preferred_element_type=jnp.float32)
+
+    u = w_v - dot("bhck,bhkv->bhcv", w_k, state)  # the chunk's writes, the state known
+    out = dot("bhck,bhkv->bhcv", q_seen, state) + dot("bhcj,bhjv->bhcv", gram_q, u)
+    return left[..., None] * state + dot("bhck,bhcv->bhkv", k_left, u), out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _scan_chunks(chunks, dtype):
+    """The state carried over the chunks from zero: ``chunks`` are :func:`_within_chunks`'s
+    values with the chunks leading (``[N, B, H, ...]``) -> (outputs ``[N, B, H, C, dv]``
+    in ``dtype``, the final state ``[B, H, dk, dv]`` float32). The forward keeps the state at
+    each chunk's start (``[N, B, H, dk, dv]`` float32, named, as the outputs are, so that a
+    layer may keep both); the backward pass walks the chunks from the last with them, one
+    chunk's step differentiated at a time: it does not run the forward scan again, and
+    holds no chunk's intermediate but the one it is at."""
+    return _scan_chunks_fwd(chunks, dtype)[0]
+
+
+def _scan_chunks_fwd(chunks, dtype):
+    w_v, w_k = chunks[0], chunks[1]
+    zero = jnp.zeros((*w_k.shape[1:3], w_k.shape[-1], w_v.shape[-1]), jnp.float32)
+
+    def step(state, chunk):
+        after, out = _chunk_step(state, chunk, dtype)
+        return after, (out, state)
+
+    final, (out, states) = jax.lax.scan(step, zero, chunks)
+    out = checkpoint_name(out.astype(dtype), DELTA_OUT_NAME)
+    states = checkpoint_name(states, DELTA_STATES_NAME)
+    return (out, final), (chunks, states)
+
+
+def _scan_chunks_bwd(dtype, res, cotangents):
+    chunks, states = res
+    d_out, d_final = cotangents
+
+    def step(d_after, x):
+        chunk, state, d_out = x
+        _, pull = jax.vjp(lambda state, chunk: _chunk_step(state, chunk, dtype), state, chunk)
+        return pull((d_after, d_out.astype(jnp.float32)))
+
+    _, d_chunks = jax.lax.scan(step, d_final, (chunks, states, d_out), reverse=True)
+    return (d_chunks,)
+
+
+_scan_chunks.defvjp(_scan_chunks_fwd, _scan_chunks_bwd)
+
+
+def delta_rule(q, k, v, g, beta, chunk: int):
+    """The gated delta rule with a decay a key channel, by chunks: q, k ``[B, T, H, dk]``,
+    v ``[B, T, H, dv]``, g ``[B, T, H, dk]`` float32 (a token's log-decay of each key
+    channel, ``<= 0``), beta ``[B, T, H]`` float32 -> (o ``[B, T, H, dv]`` in ``v``'s type,
+    the final state ``[B, H, dk, dv]`` float32). Token by token, from ``S_0 = 0``::
+
+        S'_t = Diag(exp(g_t)) S_{t-1}
+        S_t  = S'_t + beta_t k_t (v_t - S'_t^T k_t)^T
+        o_t  = S_t^T q_t
+
+    Here ``chunk`` tokens at a time (a sequence is padded to whole chunks with tokens that
+    leave the state as it is; the result does not depend on the chunk): inside a chunk the
+    rule is a unit-lower-triangular system, solved for all its tokens at once
+    (:func:`_within_chunks`, :data:`GRAM_CHUNKS` chunks a pass, made again in the backward
+    pass), and a scan carries the state from chunk to chunk (:func:`_scan_chunks`). The
+    state, the Gram matrices and the solve are float32; the products with the state and
+    with the chunk's writes take their operands in ``q``'s type and accumulate in float32.
+    No exponent is taken of anything but a difference of running log-decays that is ``<=
+    0``. Scopes: ``state`` around the scan; the caller's around the rest."""
+    b, t, h, dk = k.shape
+    chunk = min(chunk, t)
+    dtype = q.dtype
+    q, k, v, g, beta = (_pad_rows(x, 1, chunk) for x in (q, k, v, g, beta))
+    n = q.shape[1] // chunk
+    per_pass = math.gcd(n, GRAM_CHUNKS)
+
+    def chunked(x):  # [B, T', H, ...] -> [passes, B, H, chunks a pass, C, ...]
+        x = x.reshape(b, n // per_pass, per_pass, chunk, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 4, 1), 2, 0)
+
+    within = jax.lax.map(jax.checkpoint(lambda xs: _within_chunks(*xs)),
+                         tuple(chunked(x) for x in (q, k, v, g, beta)))
+    # [passes, B, H, chunks a pass, ...] -> [N, B, H, ...]
+    chunks = tuple(jnp.moveaxis(x, 3, 1).reshape(n, b, h, *x.shape[4:]) for x in within)
+    with jax.named_scope("state"):
+        out, final = _scan_chunks(chunks, dtype)
+    out = jnp.moveaxis(out, 0, 2).reshape(b, h, n * chunk, -1)[:, :, :t]
+    return out.swapaxes(1, 2).astype(v.dtype), final
+
+
+def _causal_conv(x, taps):
+    """A depthwise causal convolution: x ``[B, T, channels]``, taps ``[channels, n]`` ->
+    ``sum_i taps[:, i] x[t - (n - 1) + i]`` in float32, zeros before the sequence."""
+    n = taps.shape[-1]
+    t = x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
+    return sum(padded[:, i:i + t] * taps[:, i].astype(jnp.float32) for i in range(n))
+
+
+def _unit(x):
+    """Each head's vector scaled to unit length (``x [..., d]``), in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _delta_block(cfg: PatternConfig, x, lp: dict):
+    """Pre-norm delta-rule linear attention (:class:`Delta`, :func:`delta_rule`) and the
+    residual. Returns the stream and the layer's counts: ``decay_mean`` (the mean over
+    tokens, heads and channels of ``exp(g)``: how much of the state a token keeps),
+    ``beta_mean`` and ``state_rms`` (of the state after the last token).
+
+    The scope is ``attn/full``, as the latent and the indexed kinds' is: a delta layer sees
+    the whole causal prefix, through its state. Everything but the four large projections
+    is under ``attn/full/delta``: ``/conv`` (the three convolutions), ``/gates`` (the
+    log-decay, the write strength and the output gate), ``/rule`` (the chunked rule) with
+    ``/rule/state`` (the scan across the chunks) inside it; no ``/core``."""
+    with jax.named_scope("attn/full"):
+        b, t, _ = x.shape
+        h, de = cfg.heads(DELTA), cfg.delta
+        dt = x.dtype
+        y = tfm.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = (y @ lp[name].astype(dt) for name in ("wq", "wk", "wv"))
+        with jax.named_scope("delta"):
+            with jax.named_scope("conv"):
+                q, k, v = (jax.nn.silu(_causal_conv(a, lp[name])) for a, name in (
+                    (q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+            q = (_unit(q.reshape(b, t, h, de.d_key)) / np.sqrt(de.d_key)).astype(dt)
+            k = _unit(k.reshape(b, t, h, de.d_key)).astype(dt)
+            v = v.reshape(b, t, h, de.d_value).astype(dt)
+            with jax.named_scope("gates"):
+                through = lambda a, b_: jnp.matmul(  # noqa: E731
+                    y @ lp[a].astype(dt), lp[b_].astype(dt), preferred_element_type=jnp.float32)
+                step = jax.nn.softplus(through("wf_a", "wf_b") + lp["dt_bias"])
+                g = -jnp.exp(lp["a_log"])[:, None] * step.reshape(b, t, h, de.d_key)
+                beta = jax.nn.sigmoid(jnp.matmul(y, lp["wb"].astype(dt),
+                                                 preferred_element_type=jnp.float32))
+                if de.neg_eigval:
+                    beta = 2.0 * beta
+                gate = jax.nn.sigmoid(through("wg_a", "wg_b")).reshape(b, t, h, de.d_value)
+            q, k, v, g, beta = (checkpoint_name(a, name) for a, name in zip(
+                (q, k, v, g, beta), KEPT_GROUPS["delta"]))
+            with jax.named_scope("rule"):
+                o, final = delta_rule(q, k, v, g, beta, de.chunk)
+            o = tfm.rms_norm(o, lp["o_norm"], cfg.norm_eps) * gate.astype(dt)
+            counts = {"decay_mean": jnp.mean(jnp.exp(g)), "beta_mean": jnp.mean(beta),
+                      "state_rms": jnp.sqrt(jnp.mean(jnp.square(final)))}
+        o = o.reshape(b, t, h * de.d_value)
+        return x + o @ lp["wo"].astype(dt), counts
 
 
 # ---------------------------------------------------------------------------------
@@ -1190,8 +1557,14 @@ KEPT_GROUPS = {
     "stream": ("attn_stream",),
     # the attention products' forward; without the log-sum-exp the kernel runs again
     "attention": (attention.OUT_NAME, attention.LSE_NAME),
+    # the states a delta layer's scan left at each chunk's start, and the rule's output:
+    # without them the backward pass runs the forward scan a second time
+    "states": (DELTA_STATES_NAME, DELTA_OUT_NAME),
     # q and k after the rotary, v: the projections and the rotary
     "qkv": ("attn_q", "attn_k", "attn_v"),
+    # a delta layer's q, k and v after the convolutions and the scaling, its log-decays
+    # and its write strengths: three projections, the convolutions and the gates' maps
+    "delta": ("delta_q", "delta_k", "delta_v", "delta_g", "delta_beta"),
     # an indexer's queries and key after the rotary, its head weights: the same of its own
     "index": ("index_q", "index_w", "index_k"),
     # the gate and up products of the shared expert, then of the dense MLP
@@ -1211,34 +1584,52 @@ def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int, seq: int) -> di
     """Bytes of each group of :data:`KEPT_GROUPS` in one layer over ``n_tokens`` tokens
     in sequences of ``seq``."""
     act = jnp.dtype(cfg.dtype).itemsize
+    heads = cfg.held(spec.n_heads)
+    sparse = spec.mlp == SPARSE
+    groups = dict.fromkeys(KEPT_GROUPS, 0)
+    groups.update(
+        routing=n_tokens * 4 * (cfg.n_experts + 4 * cfg.top_k) if sparse else 0,
+        stream=n_tokens * cfg.d_model * act,
+        shared=2 * n_tokens * cfg.d_shared * act if sparse else 0,
+        dense=0 if sparse else 2 * n_tokens * cfg.d_ff * act)
+    if spec.attn == DELTA:
+        de = cfg.delta
+        chunks = n_tokens // seq * -(-seq // min(de.chunk, seq))
+        groups.update(
+            states=heads * (chunks * de.d_key * de.d_value * 4 + n_tokens * de.d_value * act),
+            delta=n_tokens * heads * (act * (2 * de.d_key + de.d_value) + 4 * de.d_key + 4))
+        return groups
     score, value = _widths(cfg, spec.attn)
-    kv_heads = spec.n_heads if spec.attn == LATENT else cfg.n_kv_heads
+    kv_heads = heads if spec.attn == LATENT else cfg.kv_heads
     indexed = spec.attn == INDEXED
     # the kernels take one width of whole lane groups, and an indexed kind goes by them
     # where :func:`attention_paths` says so; only they make a log-sum-exp
     kernels = score == value and not score % attention.LANES and (
         not indexed or attention_paths(cfg, seq)[INDEXED]["path"] == "kernel")
-    lse = 4 * spec.n_heads if kernels else 0
-    index = selection = 0
+    lse = 4 * heads if kernels else 0
     if indexed:
         ix, block = cfg.indexer, min(cfg.attn_block, seq)
-        index = n_tokens * (act * (ix.n_heads + 1) * ix.head_dim + 4 * ix.n_heads)
+        groups["index"] = n_tokens * (act * (ix.n_heads + 1) * ix.head_dim + 4 * ix.n_heads)
         # a byte a (query, key) of every group of query blocks that really selects,
         # against the group's keys (:func:`indexed_attention`)
         padded = -(-seq // block) * block
-        selection = n_tokens // seq * sum(
+        groups["selection"] = n_tokens // seq * sum(
             (keys - first) * keys for first, keys in key_groups(padded, block) if keys > ix.top_k)
-    sparse = spec.mlp == SPARSE
-    return {
-        "routing": n_tokens * 4 * (cfg.n_experts + 4 * cfg.top_k) if sparse else 0,
-        "selection": selection,
-        "stream": n_tokens * cfg.d_model * act,
-        "attention": n_tokens * (spec.n_heads * value * act + lse),
-        "qkv": n_tokens * act * (spec.n_heads * score + kv_heads * (score + value)),
-        "index": index,
-        "shared": 2 * n_tokens * cfg.d_shared * act if sparse else 0,
-        "dense": 0 if sparse else 2 * n_tokens * cfg.d_ff * act,
-    }
+    groups.update(
+        attention=n_tokens * (heads * value * act + lse),
+        qkv=n_tokens * act * (heads * score + kv_heads * (score + value)))
+    return groups
+
+
+def _rule_bytes(cfg: PatternConfig, n_tokens: int, seq: int) -> int:
+    """The float32 values a delta layer's rule holds beside what the layer keeps, forward
+    as backward: :func:`_within_chunks`'s six values of every chunk and their cotangents.
+    (A pass's differences of running log-decays live inside the fusions that sum over them:
+    no ``[chunk, chunk, d_key]`` array reaches the device's memory; compile for a v5e,
+    PR 39.)"""
+    de = cfg.delta
+    per_token = 4 * (de.d_value + 3 * de.d_key + min(de.chunk, seq))
+    return cfg.heads(DELTA) * 2 * n_tokens * per_token
 
 
 def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int],
@@ -1258,14 +1649,18 @@ def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int
     its whole forward). ``step_bytes`` is
     16 B a parameter (float32 weights, two moments, gradients), every layer's input,
     the float32 logits with their cotangent, and all the groups of the largest layer
-    once (the layer whose backward pass runs holds them, kept or recomputed)."""
-    per_layer = [_group_bytes(cfg, spec, n_tokens, seq or n_tokens) for spec in cfg.layers]
+    once (the layer whose backward pass runs holds them, kept or recomputed), with the
+    float32 values of the rule where that layer is a delta layer (:func:`_rule_bytes`)."""
+    seq = seq or n_tokens
+    per_layer = [_group_bytes(cfg, spec, n_tokens, seq) for spec in cfg.layers]
     n_params = sum(math.prod(leaf.shape) for leaf in
                    jax.tree.leaves(describe_params(cfg), is_leaf=_is_leaf))
     step_bytes = (16 * n_params
                   + len(cfg.layers) * n_tokens * cfg.d_model * jnp.dtype(cfg.dtype).itemsize
                   + 2 * n_tokens * cfg.vocab_size * 4
-                  + max(sum(groups.values()) for groups in per_layer))
+                  + max(sum(groups.values())
+                        + (_rule_bytes(cfg, n_tokens, seq) if spec.attn == DELTA else 0)
+                        for spec, groups in zip(cfg.layers, per_layer)))
     kept = {"names": [], "bytes": 0, "per_layer": {}, "step_bytes": step_bytes}
     for group, names in KEPT_GROUPS.items():
         layers = [groups[group] for groups in per_layer]
@@ -1285,9 +1680,9 @@ def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int
 # ---------------------------------------------------------------------------------
 
 def _rope_tables(cfg: PatternConfig, t: int) -> dict:
-    """{kind: its layers' rotary tables at ``t`` positions}."""
+    """{kind: its layers' rotary tables at ``t`` positions}, of the kinds that have one."""
     tables = {kind: rope_tables(cfg.rope(kind), cfg.rotary_width(kind), t)
-              for kind in ATTENTION_KINDS if cfg.count(kind)}
+              for kind in ATTENTION_KINDS if cfg.count(kind) and cfg.rope(kind) is not None}
     if cfg.count(INDEXED):  # the indexer's heads turn by the same table at their own width
         tables[INDEXED] += rope_tables(cfg.rope(INDEXED), cfg.indexer.head_dim, t)
     return tables
@@ -1312,36 +1707,38 @@ def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
     tables = _rope_tables(cfg, t)
 
     def layer(x, attn_lp, mlp_lp, spec: Layer):
-        index_counts = None
+        attn_counts = None  # an indexed or a delta layer's own
         if spec.attn == LATENT:
             x = _latent_block(cfg, x, attn_lp, *tables[LATENT])
         elif spec.attn == INDEXED:
-            x, index_counts, _ = _indexed_block(cfg, x, attn_lp, *tables[INDEXED])
+            x, attn_counts, _ = _indexed_block(cfg, x, attn_lp, *tables[INDEXED])
+        elif spec.attn == DELTA:
+            x, attn_counts = _delta_block(cfg, x, attn_lp)
         else:
-            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
+            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables.get(spec.attn, ()))
         return (*_mlp_block(cfg, spec.mlp, checkpoint_name(x, "attn_stream"), mlp_lp),
-                index_counts)
+                attn_counts)
 
     # each layer keeps its input and the values named here for its backward pass, and
     # recomputes the rest of its forward there
     kept = kept_residuals(cfg, tokens.size, device_memory_bytes(), t)
     policy = jax.checkpoint_policies.save_only_these_names(*kept["names"])
 
-    counts, index_counts, balance = [], [], None
+    counts, attn_counts, balance = [], {}, None
     for spec, attn_lp, mlp_lp in _layer_params(params, cfg):
-        x, layer_counts, layer_balance, layer_index = jax.checkpoint(
+        x, layer_counts, layer_balance, layer_attn = jax.checkpoint(
             functools.partial(layer, spec=spec), policy=policy)(x, attn_lp, mlp_lp)
         if layer_counts is not None:
             counts.append(layer_counts)
         if layer_balance is not None:
             balance = layer_balance if balance is None else balance + layer_balance
-        if layer_index is not None:
-            index_counts.append(layer_index)
+        if layer_attn is not None:
+            attn_counts.setdefault(spec.attn, []).append(layer_attn)
     x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *counts) if counts else {}
-    if index_counts:
-        stacked.update(jax.tree.map(lambda *xs: jnp.stack(xs), *index_counts))
+    for of_kind in attn_counts.values():
+        stacked.update(jax.tree.map(lambda *xs: jnp.stack(xs), *of_kind))
     return logits, stacked, balance
 
 
@@ -1349,7 +1746,8 @@ def forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
     """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32, counts: a dict of ``[sparse
     layers]`` arrays of routing counts, see :func:`routed_experts`, and of ``[indexed
     layers]`` arrays ``index_kl``, ``keys_selected``, ``select_ties``, see
-    :func:`_indexed_block`)."""
+    :func:`_indexed_block`, and of ``[delta layers]`` arrays ``decay_mean``, ``beta_mean``,
+    ``state_rms``, see :func:`_delta_block`)."""
     return _forward(params, tokens, cfg)[:2]
 
 
@@ -1374,8 +1772,10 @@ def choices(params: dict, tokens: jax.Array, cfg: PatternConfig) -> dict:
             rows = [jnp.pad(m, ((0, 0), (0, 0), (0, masks[-1].shape[2] - m.shape[2])))
                     for m in masks]
             chose["selected"].append(jnp.concatenate(rows, axis=1)[:, :t, :t])
+        elif spec.attn == DELTA:
+            x = _delta_block(cfg, x, attn_lp)[0]
         else:
-            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables[spec.attn])
+            x = _attn_block(cfg, spec.attn, x, attn_lp, *tables.get(spec.attn, ()))
         if spec.mlp == SPARSE:
             y = tfm.rms_norm(x, mlp_lp["mlp_norm"], cfg.norm_eps).reshape(b * t, -1)
             experts = route(cfg, y, mlp_lp["w_router"], mlp_lp.get("b_router"))[1]
@@ -1386,7 +1786,8 @@ def choices(params: dict, tokens: jax.Array, cfg: PatternConfig) -> dict:
 
 def loss_and_counts(params: dict, tokens: jax.Array, cfg: PatternConfig):
     """Next-token cross-entropy over tokens ``[B, T]`` (the last position's logits
-    dropped, as in the dense model) and the counts of each sparse and each indexed layer.
+    dropped, as in the dense model) and the counts of each sparse, each indexed and each
+    delta layer.
     Under a selection bias the loss carries the layers' ``balance``: nothing in value, the
     balancing rule in the gradient (:func:`route`). With indexed layers it carries the
     mean of their ``index_kl``, the indexers' own loss: by the two ``stop_gradient``s of

@@ -142,7 +142,13 @@ def pattern_param_specs(cfg) -> dict:
     models/pattern.py), derived from the model's own description: every leaf names its
     dimensions, and this function holds no key of the tree. Vocabulary, heads and
     MLP widths shard over ``tp`` (column- then row-parallel, as :func:`param_specs`),
-    the held experts' ``[E]`` axis over ``ep``; norms and the router replicate."""
+    the held experts' ``[E]`` axis over ``ep``; norms and the router replicate. A delta
+    layer's convolutions, its decay's rate and bias, its write strength and the
+    up-projections of its two low-rank maps are split by heads like ``wq``; the maps'
+    down-projections and the heads' norm replicate. (This shards the heads a description
+    holds over the devices of one program; a description that holds a share of the heads,
+    ``PatternConfig.head_ways``, is one chip's part, and the sum over the parts is not
+    built: docs/parallelism.md.)"""
     import jax
     from jax.sharding import PartitionSpec as P
 
