@@ -36,7 +36,8 @@ the ``jax.numpy`` blocks (this script's tiny widths, anywhere), with the tile or
 for the latent kind its ``score_width`` and ``value_width``, for the indexed kind how many
 keys a query keeps and whether its index scores run as the kernels of
 ``ops/index_scores.py`` (``scores``: ``kernel`` or ``blocks``), for the delta kind
-``{path: chunks, chunk}`` (the rule by chunks in ``jax.numpy``; ``kernel`` once one exists);
+``{path: chunks, chunk, solve: blocks}`` (the rule by chunks in ``jax.numpy``, a chunk's
+triangular system inverted by blocks as matrix products; ``kernel`` once one exists);
 and a ``dispatch_path`` event: the rows the expert dispatch carries at this batch
 (``pattern.dispatch_rows``), ``bounded`` or ``full``; and a ``kept_residuals`` event:
 the named values each layer keeps for its backward pass at this batch on this device's

@@ -2,6 +2,7 @@
 holds, in a file of their own so that a test worker takes them beside ``test_pattern.py``
 and not after it: the rule by chunks against the recurrence token by token (value and
 every operand's gradient, at several chunks, and under a decay whose inverse overflows),
+the inverse by blocks of a chunk's triangular system against float64 and its own derivative,
 causality through convolution and state, the three counters against their definitions,
 ``kept_residuals`` and ``attention_paths`` for the kind, the head shares of a delta and of
 a softmax sublayer and the forty expert shares against the uncut reference, the seeding of
@@ -159,6 +160,80 @@ def test_the_rule_stays_finite_under_a_decay_whose_inverse_overflows(chunk):
                                    atol=1e-3 * max(float(jnp.max(jnp.abs(b))), 1.0))
 
 
+def chunk_system(case: str, c: int):
+    """``A = beta tril(decayed Gram matrix of the keys, -1)`` of one chunk of ``c`` tokens,
+    float32, as :func:`pattern._within_chunks` makes it."""
+    rng = np.random.default_rng(c)
+    dk = 16
+    keys = rng.normal(size=(c, dk))
+    beta = rng.uniform(0.0, 2.0, c)
+    g = -rng.uniform(0.01, 0.5, (c, dk))
+    if case == "equal keys, beta 2, no decay":  # A = 2 tril(1, -1): the worst that
+        keys = np.tile(keys[:1], (c, 1))        # ``kda_allow_neg_eigval`` allows
+        beta, g = np.full(c, 2.0), np.zeros((c, dk))
+    elif case == "positively correlated unit keys, beta 2":  # what a SiLU leaves
+        keys = np.abs(keys) + 1.0
+        beta, g = np.full(c, 2.0), np.zeros((c, dk))
+    elif case == "strong decay":
+        g = -rng.uniform(5.0, 300.0, (c, dk))
+    keys = keys / np.linalg.norm(keys, axis=-1, keepdims=True)
+    total = np.cumsum(g, axis=0)
+    below = np.tril(np.ones((c, c), bool), -1)[..., None]
+    decay = np.exp(np.where(below, total[:, None, :] - total[None, :, :], -np.inf))
+    gram = np.sum(keys[:, None, :] * keys[None, :, :] * decay, axis=-1)
+    return (beta[:, None] * gram).astype(np.float32)
+
+
+@pytest.mark.parametrize("c", [4, 16, 48, 64])
+@pytest.mark.parametrize("case", ["random", "equal keys, beta 2, no decay",
+                                  "positively correlated unit keys, beta 2", "strong decay"])
+def test_the_inverse_by_blocks_is_the_inverse(case, c):
+    """``pattern._unit_lower_inverse`` against ``numpy.linalg.inv`` in float64, at chunks
+    of 4, 16, 48 (no power of two: the last block of a level is short) and 64: under 1e-5
+    of the inverse's largest entry in float32 (it reads 0 on equal keys, where every entry
+    is a whole number, and up to 3e-6 elsewhere: keys that all point one way, written at
+    full strength and never forgotten), and nothing above the diagonal. What lies on or
+    above the diagonal of its operand is not read."""
+    a = chunk_system(case, c)
+    want = np.linalg.inv(np.eye(c) + a.astype(np.float64))
+    junk = np.triu(np.full((c, c), 7.0, np.float32))
+    got = np.asarray(jax.jit(pattern._unit_lower_inverse)(jnp.asarray(a + junk)))
+    assert got.dtype == np.float32
+    assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
+    np.testing.assert_array_equal(np.triu(got, 1), 0.0)
+    if case.startswith("equal"):
+        assert np.abs(a).max() == 2.0 and np.abs(want).max() == 2.0
+
+
+def test_the_solves_own_derivative_is_autodiffs_through_a_triangular_solve():
+    """``pattern._solve_unit_lower`` keeps ``T`` and ``X`` and returns ``d rhs = T^T dX``
+    and ``dA = -tril(d rhs X^T, -1)``: the same, to 1e-5 of each one's largest entry in
+    float32, as differentiating ``jax.lax.linalg.triangular_solve`` on ``I + tril(a, -1)``,
+    batched as the rule batches it, at a chunk of 48."""
+    rng = np.random.default_rng(0)
+    c, m = 48, 24
+    a = jnp.asarray(np.stack([chunk_system("random", c),
+                              chunk_system("positively correlated unit keys, beta 2", c)]))
+    rhs = jnp.asarray(rng.normal(size=(2, c, m)), jnp.float32)
+    weights = jnp.asarray(rng.normal(size=(2, c, m)), jnp.float32)
+
+    def by_xla(a, rhs):
+        system = jnp.eye(c, dtype=a.dtype) + jnp.tril(a, -1)
+        return jax.lax.linalg.triangular_solve(system, rhs, left_side=True, lower=True,
+                                               unit_diagonal=True)
+
+    value = lambda solve: lambda a, rhs: jnp.sum(weights * jnp.sin(solve(a, rhs)))  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want_x, want = by_xla(a, rhs), jax.grad(value(by_xla), (0, 1))(a, rhs)
+        got_x, got = pattern._solve_unit_lower(a, rhs), jax.grad(
+            value(pattern._solve_unit_lower), (0, 1))(a, rhs)
+    for x, y in ((got_x, want_x), *zip(got, want)):
+        scale = float(jnp.max(jnp.abs(y)))
+        assert scale > 0.1
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5 * scale)
+    np.testing.assert_array_equal(np.triu(np.asarray(got[0])), 0.0)
+
+
 def test_a_delta_model_is_causal_through_convolution_and_state():
     """Changing token ``t`` moves no logit before ``t`` (a convolution that read ahead, or
     a state that leaked backwards through a chunk's solve, would), and moves those from
@@ -213,8 +288,10 @@ def test_attention_paths_and_kept_residuals_know_the_delta_kind():
     sized by the heads held, and nothing under the names of the softmax products."""
     cfg = pattern.PatternConfig.tiny_delta()
     assert pattern.attention_paths(cfg, 64) == {
-        "full": {"path": "blocks", "block": 16}, "delta": {"path": "chunks", "chunk": 16}}
-    assert pattern.attention_paths(cfg, 8)["delta"] == {"path": "chunks", "chunk": 8}
+        "full": {"path": "blocks", "block": 16},
+        "delta": {"path": "chunks", "chunk": 16, "solve": "blocks"}}
+    assert pattern.attention_paths(cfg, 8)["delta"] == {
+        "path": "chunks", "chunk": 8, "solve": "blocks"}
     kept = pattern.kept_residuals(cfg, 2 * 48, None, 48)
     heads, de = cfg.heads(pattern.DELTA), cfg.delta
     assert heads == 2 and cfg.kv_heads == 1  # of 8 and 4
@@ -349,7 +426,7 @@ def test_the_example_records_the_delta_kinds_path_its_kept_group_and_its_state(t
     assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
     records = [json.loads(line) for line in events_file.read_text().splitlines()]
     (path,) = [r for r in records if r["kind"] == "attention_path"]
-    assert path["delta"] == {"path": "chunks", "chunk": 16}
+    assert path["delta"] == {"path": "chunks", "chunk": 16, "solve": "blocks"}
     assert path["full"] == {"path": "blocks", "block": 16}
     (kept,) = [r for r in records if r["kind"] == "kept_residuals"]
     assert DELTA_NAMES <= set(kept["names"])

@@ -428,10 +428,11 @@ def test_solar_train_step_fits_one_chip(one_chip, as_on_a_tpu, monkeypatch):
     """The whole donating step of ``solar-open2-250b-l4-ep40-tp8`` at 1 x 8192: 840,871,320
     parameters, 10.09e9 B of f32 weights and AdamW moments (60% of the chip before any
     activation), the softmax layer on the kernels (8 heads over the one KV head held: each
-    kernel once), the three delta layers' rule in ``jax.numpy`` by chunks of 64, and what the
-    step needs beside its state inside one v5e's 15.75 GiB with the six groups of residuals
-    that ``kept_residuals`` gives at that memory (stated here, where the CPU states none:
-    with no limit the shared expert's products would be kept too)."""
+    kernel once), the three delta layers' rule in ``jax.numpy`` by chunks of 64 with each
+    chunk's system inverted by blocks (matrix products: no triangular solve in the step),
+    and what the step needs beside its state inside one v5e's 15.75 GiB with the six groups
+    of residuals that ``kept_residuals`` gives at that memory (stated here, where the CPU
+    states none: with no limit the shared expert's products would be kept too)."""
     from tpu_resiliency.models import pattern
 
     limit = int(15.75 * 2 ** 30)
@@ -440,11 +441,14 @@ def test_solar_train_step_fits_one_chip(one_chip, as_on_a_tpu, monkeypatch):
     kept = pattern.kept_residuals(cfg, 8192, limit, 8192)
     assert list(kept["per_layer"]) == ["routing", "stream", "attention", "states", "qkv", "delta"]
     assert pattern.attention_paths(cfg, 8192) == {
-        "full": {"path": "kernel", "tile": 512}, "delta": {"path": "chunks", "chunk": 64}}
+        "full": {"path": "kernel", "tile": 512},
+        "delta": {"path": "chunks", "chunk": 64, "solve": "blocks"}}
     compiled, n_params, needed = pattern_step(config, cfg, one_chip)
     assert n_params == 840_871_320
     assert 10.0e9 < compiled.memory_analysis().argument_size_in_bytes < 10.2e9
-    assert needed < limit, needed  # 16.15e9 (compile, PR 39; 15.95e9 with four groups kept)
+    # 16.159e9 (compile, PR 40; 16.15e9 with XLA's triangular solve, PR 39; 15.95e9 with four
+    # groups kept)
+    assert needed < limit, needed
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -455,6 +459,9 @@ def test_solar_train_step_fits_one_chip(one_chip, as_on_a_tpu, monkeypatch):
     # the fusions that sum over them (what ``pattern._rule_bytes`` leaves out)
     import re
     assert not re.search(r"= f32\[1,8,8,64,64,128\]\S* fusion\(", text)
+    # nor does a chunk's system go to XLA's triangular solve (46 ms a step of unfused
+    # custom calls on 64 x 64 systems until PR 40): no such op, whatever it lowers to
+    assert not re.search(r"triangular[_-]?solve", text, re.IGNORECASE)
 
 
 #: sha256 of each accepted configuration's donating step at its cell's batch, lowered for

@@ -942,15 +942,17 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     its index scores go: ``scores: "kernel"`` for the blocked kernels of
     ``ops/index_scores.py`` where the backend is a TPU, every group of query rows is whole
     tiles and the indexer's heads fit their layout, else ``scores: "blocks"``. A delta
-    kind has no products over keys: ``{"path": "chunks", "chunk": tokens}``, the rule of
-    :func:`delta_rule` in ``jax.numpy`` (``"kernel"`` is for a kernel of it, which there is
-    not)."""
+    kind has no products over keys: ``{"path": "chunks", "chunk": tokens, "solve":
+    "blocks"}``, the rule of :func:`delta_rule` in ``jax.numpy`` (``"kernel"`` is for a
+    kernel of it, which there is not), every chunk's triangular system inverted by blocks
+    as matrix products (:func:`_unit_lower_inverse`; on every backend, at any chunk)."""
     paths = {}
     for kind in ATTENTION_KINDS:
         if not cfg.count(kind):
             continue
         if kind == DELTA:  # no products over keys: the rule by chunks, in jax.numpy
-            paths[kind] = {"path": "chunks", "chunk": min(cfg.delta.chunk, seq)}
+            paths[kind] = {"path": "chunks", "chunk": min(cfg.delta.chunk, seq),
+                           "solve": "blocks"}
             continue
         window = _window(cfg, kind)
         score, value = _widths(cfg, kind)
@@ -1109,16 +1111,76 @@ GRAM_CHUNKS = 8
 DELTA_STATES_NAME, DELTA_OUT_NAME = "delta_states", "delta_out"
 
 
+def _product(a, b):  # in float32: a TPU's default rounds a product's operands to bfloat16
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def _unit_lower_inverse(a):
+    """``T = (I + A)^-1`` for ``A`` the strictly lower triangle of ``a [..., C, C]``
+    float32 (what lies on or above the diagonal is not read): forward substitution by
+    blocks, the block doubled each level. With ``D`` the inverse of the block diagonal of
+    ``I + A`` at blocks of ``s`` rows (``D = I`` at ``s = 1``) and ``A_s`` the part of ``A``
+    in the lower-left ``s`` rows and columns of each diagonal block of ``2 s``, ``D - D A_s
+    D`` is the inverse of the block diagonal at ``2 s``: for one pair of blocks ``[[L11, 0],
+    [A21, L22]]^-1 = [[L11^-1, 0], [-L22^-1 A21 L11^-1, L22^-1]]``, whatever the blocks'
+    sizes, so a last block may be short and ``C`` is any length. ``ceil(log2 C)`` levels,
+    two products of whole ``[..., C, C]`` arrays a level but for the first (``I - A_1``),
+    every one at ``Precision.HIGHEST``: float32 is the precision the rule states, and a
+    float32 product at a TPU's default rounds its operands to bfloat16. **Not** the finite
+    product ``(I - A)(I + A^2)(I + A^4)...``, which is the same count of products and
+    wrong in float32: the powers of ``A`` grow where the inverse does not (equal keys
+    written at ``beta = 2``: ``A = 2 tril(1, -1)``, the inverse's entries are all 2 or
+    under and ``A^32`` has entries of 1e20)."""
+    c = a.shape[-1]
+    row, col = np.arange(c)[:, None], np.arange(c)[None, :]
+
+    def corner(s):  # the lower-left blocks of the diagonal blocks of 2 s
+        return (row // (2 * s) == col // (2 * s)) & (row % (2 * s) >= s) & (col % (2 * s) < s)
+
+    inverse = jnp.eye(c, dtype=a.dtype) - jnp.where(corner(1), a, 0.0)
+    s = 2
+    while s < c:
+        below = jnp.where(corner(s), a, 0.0)
+        inverse = inverse - _product(_product(inverse, below), inverse)
+        s *= 2
+    return inverse
+
+
+@jax.custom_vjp
+def _solve_unit_lower(a, rhs):
+    """``X`` of ``(I + A) X = rhs`` for ``A`` the strictly lower triangle of ``a [..., C,
+    C]`` and ``rhs [..., C, m]``, float32: ``T = (I + A)^-1`` by :func:`_unit_lower_inverse`
+    and one product ``T rhs``. Its derivative is its own: with ``T`` and ``X`` kept, ``d rhs
+    = T^T dX`` and ``dA = -tril(d rhs X^T, -1)``, two products and no pass through the
+    inverse's levels."""
+    return _solve_unit_lower_fwd(a, rhs)[0]
+
+
+def _solve_unit_lower_fwd(a, rhs):
+    inverse = _unit_lower_inverse(a)
+    x = _product(inverse, rhs)
+    return x, (inverse, x)
+
+
+def _solve_unit_lower_bwd(res, d_x):
+    inverse, x = res
+    d_rhs = _product(jnp.swapaxes(inverse, -1, -2), d_x)
+    return -jnp.tril(_product(d_rhs, jnp.swapaxes(x, -1, -2)), -1), d_rhs
+
+
+_solve_unit_lower.defvjp(_solve_unit_lower_fwd, _solve_unit_lower_bwd)
+
+
 def _within_chunks(q, k, v, g, beta):
     """What the scan over the chunks needs of each chunk, from the chunk alone: operands
     ``[..., C, d]`` (a chunk of ``C`` tokens on the second-last axis), ``g`` float32 log-
     decays, ``beta [..., C]`` float32. With ``G`` the running sum of ``g`` inside the chunk,
     and every exponent a difference ``G_i - G_j <= 0`` of a later row and an earlier one:
 
-    - ``A[i, j] = beta_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` for ``j < i``, and the
-      solve ``T = (I + A)^-1`` applied to ``beta v`` and to ``beta k exp(G)``: a token's
-      write as if the state at the chunk's start were zero (``w_v``), and what that state
-      takes from it (``w_k``);
+    - ``A[i, j] = beta_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` for ``j < i``, and
+      ``T = (I + A)^-1`` (:func:`_solve_unit_lower`: inverted by blocks, as matrix products)
+      applied to ``beta v`` and to ``beta k exp(G)``: a token's write as if the state at the
+      chunk's start were zero (``w_v``), and what that state takes from it (``w_k``);
     - ``B[i, j] = sum_d q_i[d] k_j[d] exp(G_i[d] - G_j[d])`` for ``j <= i``: what a query
       reads of the writes of its own chunk;
     - ``q exp(G)``: the query against the state at the chunk's start; ``k exp(G_C - G)``:
@@ -1140,12 +1202,9 @@ def _within_chunks(q, k, v, g, beta):
                               -jnp.inf))  # [..., C, C, dk], zero above the diagonal
     gram_k = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
     gram_q = jnp.sum(q[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
-    strict = jnp.tril(jnp.ones((c, c), bool), -1)
-    system = jnp.eye(c, dtype=f32) + jnp.where(strict, beta[..., None] * gram_k, 0.0)
     seen = jnp.exp(total)
     rhs = jnp.concatenate([v, k * seen], axis=-1) * beta[..., None]
-    solved = jax.lax.linalg.triangular_solve(
-        system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    solved = _solve_unit_lower(beta[..., None] * gram_k, rhs)
     w_v, w_k = solved[..., :v.shape[-1]], solved[..., v.shape[-1]:]
     last = total[..., -1:, :]
     return w_v, w_k, gram_q, q * seen, k * jnp.exp(last - total), jnp.exp(last[..., 0, :])
@@ -1223,9 +1282,11 @@ def delta_rule(q, k, v, g, beta, chunk: int):
     leave the state as it is; the result does not depend on the chunk): inside a chunk the
     rule is a unit-lower-triangular system, solved for all its tokens at once
     (:func:`_within_chunks`, :data:`GRAM_CHUNKS` chunks a pass, made again in the backward
-    pass), and a scan carries the state from chunk to chunk (:func:`_scan_chunks`). The
-    state, the Gram matrices and the solve are float32; the products with the state and
-    with the chunk's writes take their operands in ``q``'s type and accumulate in float32.
+    pass; the system inverted by blocks as matrix products, :func:`_solve_unit_lower`), and
+    a scan carries the state from chunk to chunk (:func:`_scan_chunks`). The state, the
+    Gram matrices, the system and the products of its inverse are float32
+    (``Precision.HIGHEST``); the products with the state and with the chunk's writes take
+    their operands in ``q``'s type and accumulate in float32.
     No exponent is taken of anything but a difference of running log-decays that is ``<=
     0``. Scopes: ``state`` around the scan; the caller's around the rest."""
     b, t, h, dk = k.shape
