@@ -174,12 +174,13 @@ def attention_core_cost(config: dict, batch: int, seq: int) -> tuple[float, floa
     """(operations, bytes) of one step's attention products over all layers, forward and
     backward, over the selected keys: the least work, the same products
     :func:`train_flops_per_token` counts, so a masked dense product reads well under what
-    a gathered one would. The heads' mean probabilities cost the program nothing of this
-    kind (it sums the probabilities it holds); a path that keeps a log-sum-exp and not the
-    scores would pay one more ``QK^T`` for them, which is not least work and is not
-    counted. Bytes as ``families/laguna.py`` counts them (q, k, v, the output and the
-    cotangents, bf16); the selection itself (a byte or four a selected key) is left out:
-    the products are compute-bound by a factor of seven either way."""
+    a gathered one would. The heads' mean probabilities cost the blocks nothing of this
+    kind (they sum the probabilities they hold); the kernel path a TPU runs since PR 36
+    keeps a log-sum-exp and not the scores, and pays one more ``QK^T`` a layer for them,
+    twice (``blocked_attention_probs``, forward and again in the backward pass), which is
+    not least work and is not counted. Bytes as ``families/laguna.py`` counts them (q, k,
+    v, the output and the cotangents, bf16); the selection itself (a byte or four a selected
+    key) is left out: the products are compute-bound by a factor of seven either way."""
     h, hkv, dh = config["num_attention_heads"], config["num_key_value_heads"], config["head_dim"]
     layers = config["num_hidden_layers"]
     ops = layers * batch * seq * attention_product_flops(config, seq)

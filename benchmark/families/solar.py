@@ -112,8 +112,20 @@ def program_config(config: dict, seq: int):
 
 # the program's side is the pattern-of-layers model's, as family ``laguna`` reaches it
 _laguna = harness.load_by_path("families", "laguna")
-init_params, make_train_step, param_specs = (
-    _laguna.init_params, _laguna.make_train_step, _laguna.param_specs)
+init_params, param_specs = _laguna.init_params, _laguna.param_specs
+
+
+def make_train_step(cfg, optimizer=None):
+    """``optimizer`` is a configuration's ``optimizer`` key, ``{"lr": <float>}``: AdamW as
+    the training contract has it (betas 0.9/0.999, eps 1e-8, decoupled weight decay 0.01 on
+    every leaf) at that rate; none, the contract's own 3e-4."""
+    from tpu_resiliency.models import pattern
+
+    if optimizer is not None:
+        import optax
+
+        optimizer = optax.adamw(float(optimizer["lr"]), weight_decay=0.01)
+    return pattern.make_train_step(cfg, optimizer)
 
 
 # -- operations and bytes, the least the algorithm needs ---------------------------

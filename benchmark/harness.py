@@ -55,7 +55,8 @@ def load_by_path(kind: str, name: str):
     return module
 
 
-#: what a family file has to expose (README.md, "A model family")
+#: what a family file has to expose (README.md, "A model family"); ``make_train_step``
+#: takes ``optimizer=`` only in a family whose configurations state an ``optimizer`` key
 FAMILY_CONTRACT = ("REFERENCE", "TINY", "program_config", "init_params", "make_train_step",
                    "param_specs", "train_flops_per_token")
 
@@ -343,7 +344,11 @@ class Session:
         self.batch, self.seq = c["batch"]
         self.family = load_family(c)
         self.cfg = self.family.program_config(c, self.seq)
-        self.train_step, self.init_opt = self.family.make_train_step(self.cfg)
+        optimizer = c.get("optimizer")  # {"lr": <float>}; a file without it: AdamW at 3e-4
+        if optimizer is not None and set(optimizer) != {"lr"}:
+            raise NoResult(f"a configuration's optimizer states one number, lr; got {optimizer}")
+        stated = {} if optimizer is None else {"optimizer": optimizer}
+        self.train_step, self.init_opt = self.family.make_train_step(self.cfg, **stated)
         self.mesh = None
         axes = {k: int(v) for k, v in c.get("mesh", {}).items()}
         if axes:

@@ -4,10 +4,11 @@ Follows the program's first steps on the same seeded weights and the same batche
 the weights and the loss of the reference module the configuration's family names
 (``harness.load_reference``: ``init_params(seed, config)`` and ``loss(params, tokens,
 config, precision)``): float32 everywhere, ``highest`` matmul precision, AdamW written
-out (learning rate 3e-4, betas 0.9/0.999, eps 1e-8, decoupled weight decay 0.01: the
-configurations' ``assumed`` optimizer). Returns, per step, the loss; after the first
-step the norm of every gradient leaf; after the last the norm of every parameter leaf's
-change.
+out (betas 0.9/0.999, eps 1e-8, decoupled weight decay 0.01: the configurations'
+``assumed`` optimizer; the learning rate is the configuration's ``optimizer.lr`` where
+the file states one, 3e-4 where it does not). Returns, per step, the loss; after the
+first step the norm of every gradient leaf; after the last the norm of every parameter
+leaf's change.
 
 It runs before the program's state exists. Only the parameters and one gradient live
 on the device; AdamW's two moments stay on the host between steps and cross leaf by
@@ -27,14 +28,14 @@ from .. import harness
 LR, B1, B2, EPS, WEIGHT_DECAY = 3e-4, 0.9, 0.999, 1e-8, 0.01
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 2, 3), static_argnums=(5,))
-def _adamw_leaf(p, g, mu, nu, count, decay: bool):
+@functools.partial(jax.jit, donate_argnums=(0, 2, 3), static_argnums=(5, 6))
+def _adamw_leaf(p, g, mu, nu, count, decay: bool, lr: float = LR):
     mu = B1 * mu + (1 - B1) * g
     nu = B2 * nu + (1 - B2) * g * g
     m_hat = mu / (1 - B1 ** count)
     v_hat = nu / (1 - B2 ** count)
     update = m_hat / (jnp.sqrt(v_hat) + EPS) + (WEIGHT_DECAY * p if decay else 0.0)
-    return p - LR * update, mu, nu
+    return p - lr * update, mu, nu
 
 
 @jax.jit
@@ -52,6 +53,7 @@ def follow(seed: int, cfg: dict, batches, precision: str = "f32", params=None) -
     """Train ``len(batches)`` steps from the seeded weights. ``batches`` are int32
     arrays [B, T]. ``precision`` other than ``"f32"`` is the control's."""
     model = harness.load_reference(cfg)
+    lr = float((cfg.get("optimizer") or {}).get("lr", LR))
     grad = jax.jit(jax.value_and_grad(
         lambda p, t: model.loss(p, t, cfg, precision)))
     if params is None:
@@ -80,7 +82,7 @@ def follow(seed: int, cfg: dict, batches, precision: str = "f32", params=None) -
             else:
                 mu, nu = (jnp.asarray(m) for m in moments[i])
             # AdamW as the program applies it decays every leaf, norms included.
-            p, mu, nu = _adamw_leaf(p, g, mu, nu, jnp.float32(step), True)
+            p, mu, nu = _adamw_leaf(p, g, mu, nu, jnp.float32(step), True, lr)
             leaves[i] = p
             moments[i] = (np.asarray(mu), np.asarray(nu)) if step < len(batches) else None
             del mu, nu
