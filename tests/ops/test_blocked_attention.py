@@ -482,3 +482,33 @@ def test_without_a_selection_the_kernels_lower_to_what_they_were(kind, as_on_a_t
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
         lowering_platforms=("tpu",)).as_text()
     assert mosaic_kernels(text) == AS_BEFORE_A_SELECTION[kind]
+
+
+#: the three kernels at the Ouro cell's shapes (4,096 tokens, 16 query heads over 16 KV
+#: heads of 128: one query head a KV head, tiles of 512 rows), as PR 43 first lowered and
+#: ran them on a v5e
+AT_ONE_QUERY_HEAD_A_KV_HEAD = [
+    ("blocked_attention_fwd", 3, "fca5a722c604a31a10587058e580caf78f0daed4b38b9bd71f7beb16b6449515"),
+    ("blocked_attention_dq", 6, "e6f372feaa873fa6921dbd85e2833a24c2d81c21a3df13d4072ec826d44ba196"),
+    ("blocked_attention_dkv", 6, "48668e62b2f0cbcf183bd165470a9385cfb22018b6761c2e00b6a8d40c71e02f")]
+
+
+def test_the_kernels_lower_at_one_query_head_a_kv_head(as_on_a_tpu):
+    """``blocked_attention`` at the shapes of ``ouro_2_6b_steady_noprof``
+    (``[1, 4096, 16, 128]`` over 16 KV heads), lowered for the TPU (nothing compiles or
+    runs): a group of one, so a row statistic is one value on the lanes, which no other
+    cell has (laguna's groups are 6 and 8, the tests' interpreter cases 1, 6 and 8). The
+    shapes are the configuration's own."""
+    from benchmark import harness
+
+    config = harness.read_json(harness.HERE, "configs", "ouro-2.6b-l8.json")
+    cfg = harness.load_family(config).program_config(config, config["batch"][1])
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (16, 16, 128)
+    q = jax.ShapeDtypeStruct((1, config["batch"][1], cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(attention.blocked_attention(q, k, v).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert mosaic_kernels(text) == AT_ONE_QUERY_HEAD_A_KV_HEAD

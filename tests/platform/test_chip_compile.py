@@ -464,6 +464,54 @@ def test_solar_train_step_fits_one_chip(one_chip, as_on_a_tpu, monkeypatch):
     assert not re.search(r"triangular[_-]?solve", text, re.IGNORECASE)
 
 
+def test_ouro_train_step_fits_one_chip(one_chip, as_on_a_tpu, monkeypatch):
+    """The whole donating step of ``ouro-2.6b-l8`` at 1 x 4096 through the dense model
+    (``models/transformer.py``): 612,438,017 parameters, 7.35e9 B of f32 weights and AdamW
+    moments, eight layers run four times under a scan over the passes around the scan over
+    the layers (so each attention kernel is in the program once), the products on the
+    kernels at one query head a KV head with no ``[B, H, T, T]`` array anywhere, each layer
+    of each pass rematerialized with the four groups that ``kept_residuals`` gives at the
+    chip's memory (stated here, where the CPU states none), and a peak inside one v5e's
+    15.75 GiB."""
+    import re
+
+    from benchmark import harness
+    from tpu_resiliency.models import transformer as tfm
+
+    limit = 16_909_336_064  # memory_stats()["bytes_limit"] on the chip
+    monkeypatch.setattr(tfm, "device_memory_bytes", lambda: limit)
+    config = harness.read_json(harness.HERE, "configs", "ouro-2.6b-l8.json")
+    family = harness.load_family(config)
+    batch, seq = config["batch"]
+    cfg = family.program_config(config, seq)
+    assert tfm.attention_path(cfg, seq) == {"path": "kernel", "tile": 512}
+    kept = tfm.kept_residuals(cfg, batch * seq, limit, seq)
+    assert list(kept["groups"]) == ["attention", "mlp_proj", "attn_proj", "v"]
+    train_step, init_opt = family.make_train_step(cfg)
+    params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg))
+    assert sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params)) == 612_438_017
+    opt = jax.eval_shape(init_opt, params)
+    on_chip = lambda tree: placed(tree, jax.tree.map(lambda _: one_chip, tree))  # noqa: E731
+    compiled = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt), sds((batch, seq), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 7.3e9 < mem.argument_size_in_bytes < 7.4e9
+    # 16.13e9 (compile, PR 43; 13.46e9 with nothing kept, 0.67e9 a group; a fifth group is
+    # refused). ``temp_size_in_bytes`` counts the loops' buffers more than once here
+    assert mem.peak_memory_in_bytes < limit - tfm.RESERVED_BYTES, mem.peak_memory_in_bytes
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = {name: sum(1 for line in calls if f"blocked_attention_{name}" in line)
+               for name in ("fwd", "dq", "dkv")}
+    assert kernels == {"fwd": 1, "dq": 1, "dkv": 1}, kernels
+    assert not re.search(r"\[1,16,4096,4096\]", text)
+    scopes = harness.load_by_path("layer_metrics", "model.exit_ms").SCOPES
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope, mark in scopes.items():  # what the two new readers look for is there
+        assert sum(1 for name in names if mark.search(name)) > 10, scope
+
+
 #: sha256 of each accepted configuration's donating step at its cell's batch, lowered for
 #: the described chip (StableHLO text, nothing compiled), as the parent of PR 39 lowers it,
 #: with each Mosaic kernel's serialized body left out: a body carries the source lines of

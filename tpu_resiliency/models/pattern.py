@@ -1634,11 +1634,8 @@ KEPT_GROUPS = {
 }
 
 
-def device_memory_bytes() -> Optional[int]:
-    """The memory of the device this process computes on, or ``None`` where the backend
-    states no limit (the CPU)."""
-    stats = jax.local_devices()[0].memory_stats()
-    return stats.get("bytes_limit") if stats else None
+#: the memory of the device this process computes on (the dense model's, whose rule is this one)
+device_memory_bytes = tfm.device_memory_bytes
 
 
 def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int, seq: int) -> dict:

@@ -88,11 +88,13 @@ def param_specs(cfg) -> dict:
 
     Layout follows the megatron-style convention: column-parallel then row-parallel —
     wq/wk/wv and w_gate/w_up shard their output dim over ``tp``; wo and w_down shard
-    their input dim over ``tp``; embeddings shard vocab over ``tp``; norms replicate.
+    their input dim over ``tp``; embeddings shard vocab over ``tp``; norms replicate,
+    as do the two further norms a layer of a description with sandwich norms and the
+    exit gate of one with exits.
     """
     from jax.sharding import PartitionSpec as P
 
-    return {
+    specs = {
         "embed": P(TP, None),  # [V, D]
         "layers": {
             "attn_norm": P(None, None),  # [L, D]
@@ -108,6 +110,12 @@ def param_specs(cfg) -> dict:
         "final_norm": P(None),  # [D]
         "lm_head": P(None, TP),  # [D, V]
     }
+    if cfg.sandwich_norms:
+        specs["layers"]["attn_post_norm"] = P(None, None)  # [L, D]
+        specs["layers"]["mlp_post_norm"] = P(None, None)  # [L, D]
+    if cfg.exit_beta is not None:
+        specs["exit_gate"] = {"w": P(None, None), "b": P(None)}  # [D, 1], [1]
+    return specs
 
 
 def moe_param_specs(cfg) -> dict:
