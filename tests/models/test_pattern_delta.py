@@ -2,7 +2,9 @@
 holds, in a file of their own so that a test worker takes them beside ``test_pattern.py``
 and not after it: the rule by chunks against the recurrence token by token (value and
 every operand's gradient, at several chunks, and under a decay whose inverse overflows),
-the inverse by blocks of a chunk's triangular system against float64 and its own derivative,
+a chunk's decayed Gram matrices by sub-blocks against the sum over the differences of
+whole chunks (value, gradients, every exponent it takes, what it lowers to), the inverse by
+blocks of a chunk's triangular system against float64 and its own derivative,
 causality through convolution and state, the three counters against their definitions,
 ``kept_residuals`` and ``attention_paths`` for the kind, the head shares of a delta and of
 a softmax sublayer and the forty expert shares against the uncut reference, the seeding of
@@ -158,6 +160,144 @@ def test_the_rule_stays_finite_under_a_decay_whose_inverse_overflows(chunk):
     for a, b in zip(grads, want_grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3 * max(float(jnp.max(jnp.abs(b))), 1.0))
+
+
+GRAM_CASES = ("random decays", "no decay", "a decay of -40 a token on half the channels")
+
+
+def gram_operands(case: str, c: int, dk=8):
+    """q, k ``[2, 3, c, dk]`` float32 and log-decays a token of one chunk of ``c`` tokens."""
+    rng = np.random.default_rng(c)
+    q, k = (jnp.asarray(rng.normal(size=(2, 3, c, dk)), jnp.float32) for _ in range(2))
+    g = -rng.uniform(0.01, 3.0, (2, 3, c, dk))
+    if case == "no decay":
+        g = np.zeros_like(g)
+    elif case.startswith("a decay of -40"):
+        g[..., ::2] = -40.0
+    return q, k, jnp.asarray(g, jnp.float32)
+
+
+def grams_by_differences(q, k, g):
+    """The two decayed Gram matrices as sums over the ``[c, c, dk]`` differences of the
+    running log-decays of a whole chunk: what ``pattern._within_chunks`` made until PR 45."""
+    c = k.shape[-2]
+    total = jnp.cumsum(g, axis=-2)
+    lower = np.tril(np.ones((c, c), bool))
+    decay = jnp.exp(jnp.where(lower[..., None], total[..., :, None, :] - total[..., None, :, :],
+                              -jnp.inf))
+    return (jnp.sum(k[..., :, None, :] * k[..., None, :, :] * decay, axis=-1),
+            jnp.sum(q[..., :, None, :] * k[..., None, :, :] * decay, axis=-1))
+
+
+def grams_by_sub_blocks(q, k, g):
+    return pattern._decayed_grams(q, k, jnp.cumsum(g, axis=-2))
+
+
+@pytest.mark.parametrize("c", [4, 16, 48, 64])
+@pytest.mark.parametrize("case", GRAM_CASES)
+def test_the_gram_matrices_by_sub_blocks_equal_the_sums_over_differences(case, c, monkeypatch):
+    """``pattern._decayed_grams`` (sub-blocks of ``GRAM_ROWS`` rows: matrix products left of
+    the diagonal blocks, sums over differences inside them) against the sum over the
+    differences of the whole chunk, in value and in the gradients of ``q``, ``k`` and the
+    log-decays, at chunks of 4 (shorter than a sub-block), 16, 48 and 64, and at sub-blocks
+    of 8, 16 and 32 rows (48 is no multiple of 32: the last sub-block is short): to 2e-5 of
+    each one's largest entry, and nothing above the diagonal. Under the strong decay half
+    of the channels are gone after one token and the others carry the sum."""
+    operands = gram_operands(case, c)
+    weights = [jnp.asarray(np.random.default_rng(1).normal(size=(2, 3, c, c)), jnp.float32)
+               for _ in range(2)]
+    value = lambda grams: lambda *xs: sum(  # noqa: E731
+        jnp.sum(w * jnp.sin(x)) for w, x in zip(weights, grams(*xs)))
+    with jax.default_matmul_precision("highest"):
+        want = grams_by_differences(*operands)
+        want_grads = jax.grad(value(grams_by_differences), range(3))(*operands)
+    for rows in (8, 16, 32):
+        monkeypatch.setattr(pattern, "GRAM_ROWS", rows)
+        got = grams_by_sub_blocks(*operands)
+        got_grads = jax.grad(value(grams_by_sub_blocks), range(3))(*operands)
+        for x, y in (*zip(got, want), *zip(got_grads, want_grads)):
+            assert x.shape == y.shape and x.dtype == jnp.float32
+            scale = float(jnp.max(jnp.abs(y)))
+            assert scale > 0.1
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5 * scale)
+        for x in got:
+            np.testing.assert_array_equal(np.triu(np.asarray(x), 1), 0.0)
+
+
+def exp_operands(jaxpr, consts, *args):
+    """Every operand an ``exp`` of ``jaxpr`` is given when it runs on ``args``, through its
+    nested jaxprs (``jit``, ``custom_vjp``)."""
+    seen = []
+
+    def run(jaxpr, consts, args):
+        env = dict(zip(jaxpr.constvars, consts)) | dict(zip(jaxpr.invars, args))
+        read = lambda v: v.val if hasattr(v, "val") else env[v]  # noqa: E731  (a literal)
+        for eqn in jaxpr.eqns:
+            values = [read(v) for v in eqn.invars]
+            inner = next((eqn.params[name] for name in ("jaxpr", "call_jaxpr", "fun_jaxpr")
+                          if name in eqn.params), None)
+            if eqn.primitive.name == "exp":
+                seen.append(values[0])
+            if inner is not None:
+                out = run(*((inner.jaxpr, inner.consts) if hasattr(inner, "consts")
+                            else (inner, ())), values)
+            else:
+                out = eqn.primitive.bind(*values, **eqn.params)
+                out = out if eqn.primitive.multiple_results else [out]
+            env.update(zip(eqn.outvars, out))
+        return [read(v) for v in jaxpr.outvars]
+
+    run(jaxpr, consts, args)
+    return seen
+
+
+@pytest.mark.parametrize("c", [16, 48, 64])
+def test_every_factor_of_the_gram_matrices_is_finite_and_at_most_one_under_a_strong_decay(c):
+    """Log-decays of -40 a token on half the channels: the chunk's running sum reaches
+    ``-40 c``, ``exp`` of its negative is no float32 from the third token on, and a factor
+    ``exp(G_r) x exp(-G_j)`` would be ``0 x inf``. Every exponent that
+    ``pattern._within_chunks`` takes on these operands, read off its jaxpr as it runs, is a
+    number ``<= 0``, so every factor it forms is finite and in [0, 1]: the differences
+    inside the sub-blocks (one ``exp`` for all), each sub-block's rows against its first and
+    its first against the rows before it (two a sub-block, the first sub-block's second of
+    no row), and the chunk's three own. Most of the strong channels' factors are zero: they
+    stand for terms that are smaller still."""
+    q, k, g = gram_operands(GRAM_CASES[2], c)
+    assert float(jnp.min(jnp.cumsum(g, axis=-2))) <= -40.0 * c  # and exp(89) is no float32
+    v = jnp.ones((2, 3, c, 6), jnp.float32)
+    beta = jnp.ones((2, 3, c), jnp.float32)
+    closed = jax.make_jaxpr(pattern._within_chunks)(q, k, v, g, beta)
+    exponents = exp_operands(closed.jaxpr, closed.consts, q, k, v, g, beta)
+    assert len(exponents) == 1 + 2 * -(-c // pattern.GRAM_ROWS) + 3
+    assert [x.size for x in exponents].count(0) == 1
+    for x in (x for x in exponents if x.size):
+        assert float(jnp.max(x)) <= 0.0 and not bool(jnp.any(jnp.isnan(x)))
+        factor = np.asarray(jnp.exp(x))
+        assert np.all(np.isfinite(factor)) and factor.min() >= 0.0 and factor.max() <= 1.0
+    strong = [x[..., ::2] for x in exponents if x.size and x.shape[-1] == 8]
+    assert float(np.mean([float(jnp.mean(jnp.exp(x) == 0.0)) for x in strong])) > 0.5
+
+
+def test_the_lowered_gram_matrices_are_products_and_hold_no_chunk_by_chunk_by_channel_array():
+    """``pattern._within_chunks`` lowered at the cell's chunk (64 tokens, keys of 128):
+    the columns left of each sub-block's diagonal block are a ``dot_general`` over the
+    channels at ``Precision.HIGHEST``, ``[2 S, dk] x [dk, r] -> [2 S, r]`` (keys over
+    queries) for ``r = S, 2 S, ...``, and no value of the program is ``[64, 64, 128]``: the
+    largest array of differences is ``[S, S, 128]`` a sub-block. The sum over whole chunks'
+    differences, lowered the same way, does hold one (the check can fail)."""
+    c, dk, s = 64, 128, pattern.GRAM_ROWS
+    shape = lambda *dims: jax.ShapeDtypeStruct((2, 8, *dims), jnp.float32)  # noqa: E731
+    text = jax.jit(pattern._within_chunks).lower(
+        shape(c, dk), shape(c, dk), shape(c, dk), shape(c, dk), shape(c)).as_text()
+    products = [line for line in text.splitlines() if "dot_general" in line]
+    for r in range(s, c, s):
+        strips = [line for line in products
+                  if f"(tensor<2x8x{2 * s}x{dk}xf32>, tensor<2x8x{dk}x{r}xf32>)" in line]
+        assert len(strips) == 1 and f"-> tensor<2x8x{2 * s}x{r}xf32>" in strips[0], (r, products)
+        assert "precision = [HIGHEST, HIGHEST]" in strips[0]
+    assert f"{c}x{c}x{dk}x" not in text and f"x{s}x{s}x{dk}xf32" in text
+    whole = jax.jit(grams_by_differences).lower(shape(c, dk), shape(c, dk), shape(c, dk)).as_text()
+    assert f"{c}x{c}x{dk}x" in whole
 
 
 def chunk_system(case: str, c: int):

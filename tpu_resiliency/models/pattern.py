@@ -1103,10 +1103,19 @@ def _indexed_block(cfg: PatternConfig, x, lp: dict, cos, sin, index_cos, index_s
 # the delta rule: a state along the sequence, by chunks
 # ---------------------------------------------------------------------------------
 
-#: chunks whose decayed Gram matrices one pass of :func:`delta_rule` makes together: a
-#: pass is over ``[B, H, GRAM_CHUNKS, chunk, chunk, d_key]`` float32 differences (134e6 B
-#: at 8 heads, chunks of 64 and keys of 128, were they ever written out)
+#: chunks whose values one pass of :func:`delta_rule` makes together
+#: (:func:`_within_chunks`): a pass's differences of running log-decays are ``[B, H,
+#: GRAM_CHUNKS, chunk / GRAM_ROWS, GRAM_ROWS, GRAM_ROWS, d_key]`` float32 inside the
+#: fusions that sum over them, and the factors of its products reach memory, 352 rows of
+#: ``d_key`` float32 a chunk at chunks of 64 in sub-blocks of 8 (11.5e6 B a pass at 8 heads
+#: and keys of 128). At 4 / 8 / 16 chunks a pass the rule alone, value and gradients of
+#: one layer, took 11.4 / 11.2 / 12.1 ms (chip run, PR 45), and at 32 a quarter more: there
+#: XLA splits the sums over the differences into a fusion an output, each with its own
+#: exponentials
 GRAM_CHUNKS = 8
+
+#: rows of a sub-block of a chunk's decayed Gram matrices (:func:`_decayed_grams`)
+GRAM_ROWS = 8
 
 DELTA_STATES_NAME, DELTA_OUT_NAME = "delta_states", "delta_out"
 
@@ -1171,6 +1180,54 @@ def _solve_unit_lower_bwd(res, d_x):
 _solve_unit_lower.defvjp(_solve_unit_lower_fwd, _solve_unit_lower_bwd)
 
 
+def _decayed_grams(q, k, total):
+    """The two decayed Gram matrices of a chunk, ``sum_d x_i[d] k_j[d] exp(G_i[d] -
+    G_j[d])`` for ``j <= i`` and zero above the diagonal, with ``x = k`` and with ``x = q``:
+    operands ``[..., C, dk]`` float32, ``total`` the running log-decays ``G``, which never
+    rise along the rows -> two ``[..., C, C]`` float32. The decays differ by channel, and
+    ``exp(G_i) x exp(-G_j)`` overflows where the decay is strong; around a row between the
+    two it factors without. By sub-blocks of ``S =`` :data:`GRAM_ROWS` rows (a chunk that
+    is no multiple of ``S`` is padded with rows without key, query or decay; one shorter
+    than ``S`` is one sub-block), with ``r`` a sub-block's first row:
+
+    - left of the sub-block's diagonal block, ``j < r <= i``: ``exp(G_i - G_j) = exp(G_i -
+      G_r) x exp(G_r - G_j)``, both exponents ``<= 0`` and both factors in [0, 1], so
+      those columns of both matrices are one product over the channels on the matrix unit,
+      ``[k_i exp(G_i - G_r); q_i exp(G_i - G_r)] [2 S, dk]`` by ``k_j exp(G_r - G_j) [dk,
+      r]``, float32 at ``Precision.HIGHEST``. A factor that underflows stands for a term
+      that is smaller still. The result does not depend on ``G_r``: no gradient goes
+      through it;
+    - the ``C / S`` diagonal blocks: sums on the vector unit over ``[S, S, dk]``
+      differences ``G_i - G_j <= 0``, ``S / C`` of what the whole chunk's would be.
+
+    A sub-block's rows are its strip, its diagonal block and zeros, side by side."""
+    c, dk = k.shape[-2:]
+    s = min(GRAM_ROWS, c)
+    n = -(-c // s)
+    if n * s > c:
+        rows = [(0, 0)] * (k.ndim - 2) + [(0, n * s - c), (0, 0)]
+        q, k, total = jnp.pad(q, rows), jnp.pad(k, rows), jnp.pad(total, rows, mode="edge")
+    qs, ks, totals = (x.reshape(*x.shape[:-2], n, s, dk) for x in (q, k, total))
+    within = jnp.exp(jnp.where(np.tril(np.ones((s, s), bool))[..., None],
+                               totals[..., :, None, :] - totals[..., None, :, :], -jnp.inf))
+    diagonal = jnp.concatenate(
+        [jnp.sum(x[..., :, None, :] * ks[..., None, :, :] * within, axis=-1) for x in (ks, qs)],
+        axis=-2)  # [..., n, 2 S, S]
+    rows = []
+    for r in range(0, n * s, s):  # the sub-block of rows r .. r + S
+        first = jax.lax.stop_gradient(total[..., r:r + 1, :])
+        since = jnp.exp(total[..., r:r + s, :] - first)
+        left = jnp.concatenate([k[..., r:r + s, :] * since, q[..., r:r + s, :] * since], axis=-2)
+        until = jnp.exp(first - total[..., :r, :])
+        strip = _product(left, jnp.swapaxes(k[..., :r, :] * until, -1, -2))  # [..., 2 S, r]
+        rows.append(jnp.concatenate(
+            [strip, diagonal[..., r // s, :, :],
+             jnp.zeros((*k.shape[:-2], 2 * s, n * s - r - s), k.dtype)], axis=-1))
+    rows = jnp.stack(rows, axis=-3)  # [..., n, 2 S, n S]: the keys' rows over the queries'
+    return tuple(x.reshape(*k.shape[:-2], n * s, n * s)[..., :c, :c]
+                 for x in (rows[..., :s, :], rows[..., s:, :]))
+
+
 def _within_chunks(q, k, v, g, beta):
     """What the scan over the chunks needs of each chunk, from the chunk alone: operands
     ``[..., C, d]`` (a chunk of ``C`` tokens on the second-last axis), ``g`` float32 log-
@@ -1187,21 +1244,16 @@ def _within_chunks(q, k, v, g, beta):
       a write as it stands in the state at the chunk's end; ``exp(G_C)``: what is left of
       the state by then.
 
-    All float32; the Gram matrices are sums on the vector unit over the channels, whose
-    decays differ (one matrix product would need ``exp(G_i) x exp(-G_j)``, which overflows
-    in a chunk whose decay is strong)."""
+    All float32; the two Gram matrices by sub-blocks (:func:`_decayed_grams`): matrix
+    products but for the blocks on the diagonal."""
     f32 = jnp.float32
     c = q.shape[-2]
     q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
-    lower = jnp.tril(jnp.ones((c, c), bool))
     # the running sum as a product with a triangle of ones: a cumulative sum along an
     # axis lowers to a reduce-window on a TPU
-    total = jnp.einsum("ij,...jd->...id", lower.astype(f32), g,
+    total = jnp.einsum("ij,...jd->...id", np.tril(np.ones((c, c), np.float32)), g,
                        precision=jax.lax.Precision.HIGHEST)
-    decay = jnp.exp(jnp.where(lower[..., None], total[..., :, None, :] - total[..., None, :, :],
-                              -jnp.inf))  # [..., C, C, dk], zero above the diagonal
-    gram_k = jnp.sum(k[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
-    gram_q = jnp.sum(q[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
+    gram_k, gram_q = _decayed_grams(q, k, total)
     seen = jnp.exp(total)
     rhs = jnp.concatenate([v, k * seen], axis=-1) * beta[..., None]
     solved = _solve_unit_lower(beta[..., None] * gram_k, rhs)
@@ -1282,13 +1334,16 @@ def delta_rule(q, k, v, g, beta, chunk: int):
     leave the state as it is; the result does not depend on the chunk): inside a chunk the
     rule is a unit-lower-triangular system, solved for all its tokens at once
     (:func:`_within_chunks`, :data:`GRAM_CHUNKS` chunks a pass, made again in the backward
-    pass; the system inverted by blocks as matrix products, :func:`_solve_unit_lower`), and
-    a scan carries the state from chunk to chunk (:func:`_scan_chunks`). The state, the
-    Gram matrices, the system and the products of its inverse are float32
-    (``Precision.HIGHEST``); the products with the state and with the chunk's writes take
-    their operands in ``q``'s type and accumulate in float32.
+    pass; its decayed Gram matrices by sub-blocks of :data:`GRAM_ROWS` rows, matrix
+    products but for the blocks on the diagonal, :func:`_decayed_grams`; the system
+    inverted by blocks as matrix products, :func:`_solve_unit_lower`), and a scan carries
+    the state from chunk to chunk (:func:`_scan_chunks`). The state, the Gram matrices, the
+    system and the products of its inverse are float32 (``Precision.HIGHEST``); the
+    products with the state and with the chunk's writes take their operands in ``q``'s
+    type and accumulate in float32.
     No exponent is taken of anything but a difference of running log-decays that is ``<=
-    0``. Scopes: ``state`` around the scan; the caller's around the rest."""
+    0``, of a later row and an earlier one. Scopes: ``state`` around the scan; the
+    caller's around the rest."""
     b, t, h, dk = k.shape
     chunk = min(chunk, t)
     dtype = q.dtype
@@ -1681,13 +1736,19 @@ def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int, seq: int) -> di
 
 def _rule_bytes(cfg: PatternConfig, n_tokens: int, seq: int) -> int:
     """The float32 values a delta layer's rule holds beside what the layer keeps, forward
-    as backward: :func:`_within_chunks`'s six values of every chunk and their cotangents.
-    (A pass's differences of running log-decays live inside the fusions that sum over them:
-    no ``[chunk, chunk, d_key]`` array reaches the device's memory; compile for a v5e,
-    PR 39.)"""
+    as backward: :func:`_within_chunks`'s six values of every chunk and their cotangents,
+    and the factors of one pass's Gram products with theirs (:func:`_decayed_grams`: of
+    each chunk of the pass the keys and queries against their sub-block's first row, ``2
+    chunk`` rows of ``d_key``, and the keys before each sub-block against its first row).
+    A pass's differences of running log-decays live inside the fusions that sum over
+    them: no ``[GRAM_ROWS, GRAM_ROWS, d_key]`` array reaches the device's memory (compile
+    for a v5e, PR 45; PR 39 for whole chunks')."""
     de = cfg.delta
-    per_token = 4 * (de.d_value + 3 * de.d_key + min(de.chunk, seq))
-    return cfg.heads(DELTA) * 2 * n_tokens * per_token
+    chunk = min(de.chunk, seq)
+    per_token = 4 * (de.d_value + 3 * de.d_key + chunk)
+    blocks = -(-chunk // GRAM_ROWS)
+    factors = 4 * GRAM_CHUNKS * (2 * chunk + GRAM_ROWS * blocks * (blocks - 1) // 2) * de.d_key
+    return cfg.heads(DELTA) * 2 * (n_tokens * per_token + n_tokens // seq * factors)
 
 
 def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int],
