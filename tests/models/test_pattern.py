@@ -15,6 +15,7 @@ checkpoint; the scopes the benchmark's readers look for in the lowered step. The
 rule by chunks against the recurrence token by token and the heads' shares against the
 uncut sublayers are in ``test_pattern_delta.py``."""
 
+import collections
 import dataclasses
 import functools
 import os
@@ -709,29 +710,72 @@ def test_dispatch_rows_is_what_the_layer_carries_and_full_width_has_no_cond(
     assert int(counts["rows_carried"]) == rows and int(counts["dropped"]) == 0
 
 
+#: the tiny indexed description at shapes the kernels tile: heads of 128, an indexer of 4
+#: heads of 64, blocks and tiles of 128 rows, one sequence of 512 (four groups of one tile)
+KERNELS = "indexed-kernels"
+KEPT_CASES = [*DESCRIPTIONS, KERNELS]
+
+
+def passes(grad, params) -> collections.Counter:
+    """The matrix products in a differentiated program, and its kernel calls by name."""
+    count = collections.Counter()
+    for eqn in equations(jax.make_jaxpr(grad)(params).jaxpr):
+        if eqn.primitive.name == "dot_general":
+            count["products"] += 1
+        elif eqn.primitive.name == "pallas_call":
+            count[eqn.params["name"]] += 1
+    return count
+
+
 @functools.cache
 def kept_and_not(description: str):
     """Loss, routing counts and gradient of one batch (float32 activations) with the
     list ``kept_residuals`` gives where no memory limit is stated, and with nothing kept
-    (a device too small for any group): ``{kept: (value, gradient, matrix products in
-    the differentiated program)}``."""
-    cfg = DESCRIPTIONS[description][1](dtype=jnp.float32)
+    (a device too small for any group): ``{kept: (value, gradient, the differentiated
+    program's :func:`passes`)}``; for an indexed description also the passes with every
+    group kept but the indexer's target, and but its scores (``"target"``, ``"scores"``).
+    ``KERNELS`` sends the attention and the index scores to the kernels whatever
+    ``attention_paths`` says of the CPU (they then run under the interpreter)."""
+    from tpu_resiliency.ops import attention, index_scores
+
+    if description == KERNELS:
+        cfg = pattern.PatternConfig.tiny_indexed(
+            dtype=jnp.float32, head_dim=128, attn_block=128,
+            indexer=pattern.Indexer(n_heads=4, head_dim=64, top_k=96))
+        shape = (1, 512)
+    else:
+        cfg, shape = DESCRIPTIONS[description][1](dtype=jnp.float32), (2, SEQ)
     params = pattern.init_params(jax.random.PRNGKey(3), cfg)
-    tokens = jnp.asarray(np.random.default_rng(3).integers(0, cfg.vocab_size, (2, SEQ)), jnp.int32)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(0, cfg.vocab_size, shape), jnp.int32)
+    # a function of its own for every list: a trace is cached by the function traced
+    grad = lambda: jax.value_and_grad(  # noqa: E731
+        lambda p: pattern.loss_and_counts(p, tokens, cfg), has_aux=True)
+    paths, rule = pattern.attention_paths, pattern.kept_residuals
     out = {}
-    for kept, memory in ((True, None), (False, 0)):
-        with pytest.MonkeyPatch.context() as patch:
+    with pytest.MonkeyPatch.context() as patch:
+        if description == KERNELS:
+            patch.setattr(attention, "FULL_TILE", 128)
+            patch.setattr(index_scores, "TILE", 128)
+            patch.setattr(pattern, "attention_paths", lambda cfg, seq: {"indexed": {
+                **paths(cfg, seq)["indexed"], "path": "kernel", "scores": "kernel"}})
+        for kept, memory in ((True, None), (False, 0)):
             patch.setattr(pattern, "device_memory_bytes", lambda: memory)
             assert bool(pattern.kept_residuals(cfg, tokens.size, memory)["names"]) == kept
-            grad = jax.value_and_grad(
-                lambda p: pattern.loss_and_counts(p, tokens, cfg), has_aux=True)
-            products = sum(eqn.primitive.name == "dot_general"
-                           for eqn in equations(jax.make_jaxpr(grad)(params).jaxpr))
-            out[kept] = (*jax.jit(grad)(params), products)
+            out[kept] = (*jax.jit(grad())(params), passes(grad(), params))
+        for group in ("target", "scores") if cfg.count(pattern.INDEXED) else ():
+            def but_one(*args, group=group):
+                kept = rule(*args)
+                return {**kept, "names": [name for name in kept["names"]
+                                          if name not in pattern.KEPT_GROUPS[group]]}
+
+            patch.setattr(pattern, "device_memory_bytes", lambda: None)
+            patch.setattr(pattern, "kept_residuals", but_one)
+            out[group] = passes(grad(), params)
     return out
 
 
-@pytest.mark.parametrize("description,path", leaf_paths())
+@pytest.mark.parametrize("description,path", leaf_paths() + [
+    (KERNELS, path) for description, path in leaf_paths() if description == "indexed"])
 def test_gradient_leaf_with_the_kept_list_equals_nothing_kept(description, path):
     both = kept_and_not(description)
     got, want = (dict(jax.tree_util.tree_flatten_with_path(both[kept][1])[0])
@@ -744,16 +788,35 @@ def test_gradient_leaf_with_the_kept_list_equals_nothing_kept(description, path)
     np.testing.assert_allclose(np.asarray(got[key]), np.asarray(want[key]), rtol=1e-6, atol=atol)
 
 
-@pytest.mark.parametrize("description", list(DESCRIPTIONS))
+@pytest.mark.parametrize("description", KEPT_CASES)
 def test_the_kept_list_gives_the_same_loss_and_counts_with_fewer_products(description):
     both = kept_and_not(description)
-    (kept, _, kept_dots), (bare, _, bare_dots) = both[True], both[False]
+    (kept, _, kept_passes), (bare, _, bare_passes) = both[True], both[False]
     assert float(kept[0]) == float(bare[0])
     for name, want in bare[1].items():
         np.testing.assert_array_equal(np.asarray(kept[1][name]), np.asarray(want), err_msg=name)
     # kept: the forward products of q, k, v, the gate, the output matrix, the router and
     # the gate and up of every SwiGLU outside the dispatch are not made a second time
-    assert kept_dots < bare_dots, (kept_dots, bare_dots)
+    assert kept_passes["products"] < bare_passes["products"], (kept_passes, bare_passes)
+    if description == "indexed":
+        # three layers, three groups of query rows (37 rows in blocks of 16): without the
+        # target a group scores its keys once more (``QK^T`` alone: the output is kept),
+        # without the scores it makes the indexer's products once more
+        assert both["target"] - kept_passes == {"products": 3 * 3}
+        assert both["scores"] - kept_passes == {"products": 3 * 3}
+    if description == KERNELS:
+        # three layers, four groups: every kernel once a layer or a group, the kernel of the
+        # summed probabilities and the index scores' forward twice where their value is not
+        # kept, and the attention's forward twice where nothing is
+        kernels = lambda passes: {k: n for k, n in passes.items() if k != "products"}  # noqa: E731
+        once = {**dict.fromkeys(("blocked_attention_fwd", "blocked_attention_probs",
+                                 "blocked_attention_dq", "blocked_attention_dkv"), 3),
+                **dict.fromkeys(("index_scores_fwd", "index_scores_dq", "index_scores_dk"), 12)}
+        assert kernels(kept_passes) == once
+        assert kernels(both["target"]) == {**once, "blocked_attention_probs": 6}
+        assert kernels(both["scores"]) == {**once, "index_scores_fwd": 24}
+        assert kernels(bare_passes) == {**once, "blocked_attention_fwd": 6,
+                                        "blocked_attention_probs": 6, "index_scores_fwd": 24}
 
 
 @pytest.mark.parametrize("description", list(DESCRIPTIONS))
@@ -765,7 +828,7 @@ def test_the_forward_names_what_the_groups_list(description):
     named = {eqn.params["name"] for eqn in equations(jaxpr) if eqn.primitive.name == "name"}
     listed = set(pattern.kept_residuals(cfg, 2 * SEQ, None, SEQ)["names"])
     assert named == listed - {"attn_lse"}  # the blocks make no log-sum-exp
-    indexer_makes = {"select_mask", "index_q", "index_w", "index_k"}
+    indexer_makes = {"select_mask", "index_q", "index_w", "index_k", "index_target", "index_scores"}
     if description == "indexed":  # no shared expert, no dense layer
         assert listed == set(ALL_NAMES) - DELTA_NAMES - {
             "shared_gate", "shared_up", "dense_gate", "dense_up"}
@@ -787,22 +850,32 @@ ALL_NAMES = [name for names in pattern.KEPT_GROUPS.values() for name in names]
 #: what only a delta layer names
 DELTA_NAMES = {*pattern.KEPT_GROUPS["states"], *pattern.KEPT_GROUPS["delta"]}
 V5E_BYTES = 16.9e9  # one v5e's ``bytes_limit``
+#: float32 a (query, key) of the keye cell's four groups of 2,048 query rows against their keys
+INDEX_ROWS_BYTES = 4 * 2048 * (2048 + 4096 + 6144 + 8192)
+#: what the keye cell's six layers keep in the six groups before the indexer's target
+INDEXED_SIX_BYTES = 6 * (37_748_736 + 5_242_880 + 33_554_432 + 67_108_864 + 83_886_080
+                         + 18_350_080)
 
 
 @pytest.mark.parametrize("description,tokens,memory,groups,kept_bytes", [
     # the two cells: every group, 1.93e9 and 2.05e9 B beside 12.6e9 and 12.8e9 of step
     ("mixed", 8192, V5E_BYTES, 6, 1_926_234_112),
     ("latent", 8192, V5E_BYTES, 6, 2_052_849_664),
-    # the third: its six groups (no shared expert, no dense layer), 1.48e9 B beside 12.2e9:
+    # the third: its eight groups (no shared expert, no dense layer), 3.49e9 B beside 12.6e9:
     # a layer keeps 38 MB of mask (three groups of 2,048 rows against 4,096, 6,144 and 8,192
     # keys; the first 2,048 rows keep every key), 5 of routing, 34 of stream, 67 of output,
-    # 84 of q, k, v and 18 of the indexer's q, k and weights
-    ("indexed", 8192, V5E_BYTES, 6, 6 * (37_748_736 + 5_242_880 + 33_554_432 + 67_108_864
-                                         + 83_886_080 + 18_350_080)),
-    ("indexed", 8192, 12.5e9, 2, 6 * (37_748_736 + 5_242_880)),  # routing, then the selection
-    # one sequence of 16,384: four groups of 4,096 rows, all of which select
-    ("indexed", 16384, V5E_BYTES, 4, 6 * (10_485_760 + 4096 * (4096 + 8192 + 12288 + 16384)
-                                          + 67_108_864 + 134_217_728)),
+    # 84 of q, k, v and 18 of the indexer's q, k and weights, and then, float32 a (query,
+    # key) of the four groups' rows against their keys, 168 of the indexer's target and 168
+    # of its scores
+    ("indexed", 8192, V5E_BYTES, 8, INDEXED_SIX_BYTES + 6 * 2 * INDEX_ROWS_BYTES),
+    # a chip's memory 1e9 B smaller: the target, and no room for the scores behind it
+    ("indexed", 8192, V5E_BYTES - 1e9, 7, INDEXED_SIX_BYTES + 6 * INDEX_ROWS_BYTES),
+    ("indexed", 8192, V5E_BYTES - 2e9, 6, INDEXED_SIX_BYTES),  # 2e9 B smaller: neither
+    ("indexed", 8192, 12.9e9, 2, 6 * (37_748_736 + 5_242_880)),  # routing, then the selection
+    # one sequence of 16,384: four groups of 4,096 rows, all of which select; the output no
+    # longer fits, with one layer's target and scores (0.67e9 B each) in what the step holds
+    ("indexed", 16384, V5E_BYTES, 3, 6 * (10_485_760 + 4096 * (4096 + 8192 + 12288 + 16384)
+                                          + 67_108_864)),
     ("mixed", 8192, None, 6, 1_926_234_112),  # no limit stated (the CPU): as the chip
     # twice the tokens on the same chip: q, k, v and the SwiGLUs' products no longer fit
     # (laguna compiles to 15.08e9 B so, and to 16.89e9 of the chip's 16.91e9 with all kept)
@@ -833,10 +906,11 @@ def test_kept_residuals_by_tokens_and_memory(description, tokens, memory, groups
     cfg = cell_config(description, tokens)
     kept = pattern.kept_residuals(cfg, tokens, memory)
     # the groups that hold anything in this description, in the order of KEPT_GROUPS
-    holds = {"mixed": set(pattern.KEPT_GROUPS) - {"selection", "index", "states", "delta"},
-             "latent": set(pattern.KEPT_GROUPS) - {"selection", "index", "states", "delta"},
+    indexer = {"selection", "index", "target", "scores"}  # what only an indexed layer holds
+    holds = {"mixed": set(pattern.KEPT_GROUPS) - indexer - {"states", "delta"},
+             "latent": set(pattern.KEPT_GROUPS) - indexer - {"states", "delta"},
              "indexed": set(pattern.KEPT_GROUPS) - {"shared", "dense", "states", "delta"},
-             "delta": set(pattern.KEPT_GROUPS) - {"selection", "index", "dense"}}[description]
+             "delta": set(pattern.KEPT_GROUPS) - indexer - {"dense"}}[description]
     taken = [(g, names) for g, names in pattern.KEPT_GROUPS.items() if g in holds][:groups]
     assert kept["names"] == [name for _, names in taken for name in names]
     assert list(kept["per_layer"]) == [group for group, _ in taken]
@@ -855,7 +929,8 @@ def test_the_indexed_kind_keeps_a_log_sum_exp_on_the_kernel_path_alone(monkeypat
     """The "attention" group of ``keye-vl2-30b-a3b-l6-ep8`` at 8,192 tokens: 32 heads' output
     in bf16 on the ``jax.numpy`` blocks (the CPU), and four bytes a head and token more
     where ``attention_paths`` sends the kind to the kernels, which make a log-sum-exp (a
-    TPU); the selection is a byte a (query, key) of the three groups that select, on both."""
+    TPU); the selection is a byte a (query, key) of the three groups that select, on both;
+    the indexer's target and scores, float32, are the last two groups of the list."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = cell_config("indexed", 8192)
     assert pattern.attention_paths(cfg, 8192)["indexed"]["path"] == (
@@ -864,6 +939,11 @@ def test_the_indexed_kind_keeps_a_log_sum_exp_on_the_kernel_path_alone(monkeypat
     assert kept["per_layer"]["attention"] == [8192 * 32 * (128 * 2 + lse)] * 6
     assert kept["per_layer"]["selection"] == [2048 * (4096 + 6144 + 8192)] * 6
     assert "attn_lse" in kept["names"]  # the group's names are the same on both
+    # the indexer's target and its scores are the groups' rows against their keys on both
+    # paths, and fit at the chip's memory after the six groups before them
+    assert kept["per_layer"]["target"] == kept["per_layer"]["scores"] == [INDEX_ROWS_BYTES] * 6
+    assert list(kept["per_layer"])[-3:] == ["index", "target", "scores"]
+    assert kept["names"][-2:] == ["index_target", "index_scores"]
 
 
 def test_kept_residuals_of_the_laguna_cell_layer_by_layer():

@@ -205,12 +205,15 @@ def test_the_three_kernels_by_name_operands_and_results(kernel, monkeypatch, as_
     assert calls[kernel] == PINNED[kernel]
 
 
-def test_every_score_kernel_of_a_layer_carries_the_indexer_scope(monkeypatch, as_on_a_tpu):
+@pytest.mark.parametrize("kept,forwards", [(("index",), 2), (("index", "scores"), 1)],
+                         ids=["operands", "operands+scores"])
+def test_every_score_kernel_of_a_layer_carries_the_indexer_scope(kept, forwards, as_on_a_tpu):
     """One indexed layer lowered for the TPU through its ``jax.checkpoint`` with the
     indexer's operands kept, four groups of one tile: each group's forward kernel twice
-    (the scores are not kept: the backward pass makes them again for the divergence's own
-    backward), its ``dqi`` and its ``dki`` kernel once, every one under
-    ``attn/full/indexer`` where ``attn.indexer_ms`` looks, and none of the blocks' loops."""
+    where the scores are not kept (the backward pass makes them again for the divergence's
+    own backward) and once where they are, its ``dqi`` and its ``dki`` kernel once, every
+    one under ``attn/full/indexer`` where ``attn.indexer_ms`` looks, and none of the
+    blocks' loops."""
     from benchmark import harness
 
     mark = harness.load_by_path("layer_metrics", "attn.indexer_ms").SCOPES["indexer"]
@@ -225,7 +228,8 @@ def test_every_score_kernel_of_a_layer_carries_the_indexer_scope(monkeypatch, as
     x = jax.ShapeDtypeStruct((1, seq, cfg.d_model), cfg.dtype)
     tables = (pattern.rope_tables(cfg.rope_indexed, cfg.head_dim, seq)
               + pattern.rope_tables(cfg.rope_indexed, cfg.indexer.head_dim, seq))
-    policy = jax.checkpoint_policies.save_only_these_names(*pattern.KEPT_GROUPS["index"])
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *(name for group in kept for name in pattern.KEPT_GROUPS[group]))
 
     def loss(x, lp):
         layer = jax.checkpoint(lambda x, lp: pattern._indexed_block(cfg, x, lp, *tables)[:2],
@@ -239,8 +243,9 @@ def test_every_score_kernel_of_a_layer_carries_the_indexer_scope(monkeypatch, as
     kernels = [names[ref] for ref in re.findall(
         r"stablehlo\.custom_call @tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
     assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
-        *["index_scores_dk"] * 4, *["index_scores_dq"] * 4, *["index_scores_fwd"] * 8], kernels
+        *["index_scores_dk"] * 4, *["index_scores_dq"] * 4,
+        *["index_scores_fwd"] * 4 * forwards], kernels
     assert all(mark.search(name) for name in kernels), kernels
-    assert sum("rematted_computation" in name for name in kernels) == 4
+    assert sum("rematted_computation" in name for name in kernels) == 4 * (forwards - 1)
     under = [name for name in names.values() if mark.search(name)]
     assert not any("while" in name for name in under), under

@@ -54,7 +54,8 @@ Built TPU-first, static shapes throughout:
   beside the layer's input (the router's choices and the sort's indices, the keys an
   indexer selected, the stream after attention, the attention output and its
   log-sum-exp, a delta layer's states at each chunk's start, q, k and v, the gate and up
-  products of the SwiGLUs) and recomputes the
+  products of the SwiGLUs, and last an indexer's target and its scores: float32 a (query,
+  key), 168e6 B a layer each at 8,192 tokens) and recomputes the
   rest; :func:`kept_residuals` chooses
   the list from the configuration, the tokens of a step and the device's memory.
 - **The description says how each leaf may be sharded** (:func:`describe_params`:
@@ -853,7 +854,8 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool
     a time; made again in the backward pass) or, with ``score_kernels`` (groups of whole
     tiles of an indexer the kernels' layout takes), by one call of
     ``ops/index_scores.py:index_scores``, which bounds its own temporaries and leaves the
-    key tiles past a query tile's diagonal tile zero; and turns them into its rows of the mask
+    key tiles past a query tile's diagonal tile zero (named ``index_scores`` either way);
+    and turns them into its rows of the mask
     (:func:`select_keys`; named ``select_mask``, so a layer may keep it). The products
     under the mask, with the heads' probabilities summed on the way, go one of two ways
     (:func:`attention_paths`). On the ``jax.numpy`` blocks each group runs
@@ -864,7 +866,8 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool
     ``ops/attention.py:blocked_attention`` takes it as an operand: no key past a query
     tile's diagonal tile is scored, the forward's output and log-sum-exp carry the names a
     layer keeps, and a second kernel gives the summed probabilities. Then each group takes
-    its divergence from its rows of them. The loops over the blocks are ``jax.lax.map``s: a
+    its divergence from its rows of them (named ``index_target`` on either path). The loops
+    over the blocks are ``jax.lax.map``s: a
     layer compiles one body a group and stage. Nothing differentiable passes through the
     mask or the probabilities. Scopes: ``indexer``, ``select``, ``core``, each around its
     loop or its kernels (an op's name holds a scope before the loop's own components)."""
@@ -879,12 +882,13 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool
         ki_seen = ki[:, :keys]
         with jax.named_scope("indexer"):
             if score_kernels:
-                scores.append(index_score_kernels.index_scores(
-                    qi[:, first:keys], wi[:, first:keys], ki_seen))
+                group_scores = index_score_kernels.index_scores(
+                    qi[:, first:keys], wi[:, first:keys], ki_seen)
             else:
-                scores.append(_whole(jax.lax.map(
+                group_scores = _whole(jax.lax.map(
                     lambda rows, ki_seen=ki_seen: score_block(*rows, ki_seen),
-                    (_in_blocks(qi[:, first:keys], 1, n), _in_blocks(wi[:, first:keys], 1, n))), 1))
+                    (_in_blocks(qi[:, first:keys], 1, n), _in_blocks(wi[:, first:keys], 1, n))), 1)
+            scores.append(checkpoint_name(group_scores, KEPT_GROUPS["scores"][0]))
         with jax.named_scope("select"):
             mask, tied = select_keys(jax.lax.stop_gradient(scores[-1]), first, top_k)
             if keys > top_k:  # else the causal mask, which nothing needs to keep
@@ -896,7 +900,11 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool
             selection = jnp.concatenate([jnp.pad(m.astype(jnp.int8), (
                 (0, 0), (0, 0), (0, t - m.shape[2]))) for m in masks], axis=1)
             out, probs = attention.blocked_attention(q, k, v, selected=selection)
-            probs = [probs[:, first:keys, :keys] for first, keys in groups]
+            # the name on each group's rows, not on the kernel's whole square: kept whole it
+            # is copied whole (4.9 ms a step at 8,192 tokens), the rows come out of the
+            # fusion that reads them first (3.6 ms), and hold 0.6 of its bytes (chip run, PR 46)
+            probs = [checkpoint_name(probs[:, first:keys, :keys], KEPT_GROUPS["target"][0])
+                     for first, keys in groups]
         else:
             q, k, v = _heads_first(q, k, v)
             outs, probs = [], []
@@ -907,7 +915,7 @@ def indexed_attention(q, k, v, qi, wi, ki, top_k: int, block: int, kernels: bool
                         rows[0], *seen, rows[1]),
                     (_in_blocks(q[:, :, :, first:keys], 3, n), _in_blocks(mask, 1, n)))
                 outs.append(_whole(group_out, 3))
-                probs.append(_whole(group_probs, 1))
+                probs.append(checkpoint_name(_whole(group_probs, 1), KEPT_GROUPS["target"][0]))
             out = _heads_last(jnp.concatenate(outs, axis=3), t)
     with jax.named_scope("indexer"):
         divergence = [index_divergence(s, mask, p / heads)
@@ -1686,6 +1694,13 @@ KEPT_GROUPS = {
     # the gate and up products of the shared expert, then of the dense MLP
     "shared": ("shared_gate", "shared_up"),
     "dense": ("dense_gate", "dense_up"),
+    # the heads' summed probabilities an indexer is taught by: the kernel that gives them
+    # (on the blocks, every head's ``QK^T`` and softmax) a second time. Float32 a (query,
+    # key), each group's rows against its keys: 168e6 B a layer at 8,192 tokens
+    "target": ("index_target",),
+    # an indexer's scores, which its divergence reads again in the backward pass: their
+    # forward kernel (or blocks) a second time. Float32 and of the target's shapes
+    "scores": ("index_scores",),
 }
 
 
@@ -1726,8 +1741,12 @@ def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int, seq: int) -> di
         # a byte a (query, key) of every group of query blocks that really selects,
         # against the group's keys (:func:`indexed_attention`)
         padded = -(-seq // block) * block
+        rows = key_groups(padded, block)
         groups["selection"] = n_tokens // seq * sum(
-            (keys - first) * keys for first, keys in key_groups(padded, block) if keys > ix.top_k)
+            (keys - first) * keys for first, keys in rows if keys > ix.top_k)
+        # float32 a (query, key) of every group's rows against the group's keys, each
+        groups["target"] = groups["scores"] = 4 * n_tokens // seq * sum(
+            (keys - first) * keys for first, keys in rows)
     groups.update(
         attention=n_tokens * (heads * value * act + lse),
         qkv=n_tokens * act * (heads * score + kv_heads * (score + value)))
