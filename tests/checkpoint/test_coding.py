@@ -7,7 +7,6 @@ import concurrent.futures as cf
 import itertools
 import os
 import pickle
-import struct
 
 import numpy as np
 import pytest
@@ -409,7 +408,7 @@ def _delta_frame_fixture(tmp_path, dirty=128):
         b"hollow", [arr], meta={"iteration": 1})
     base_path = str(tmp_path / "base.ckpt")
     ckpt_format.write_parts(base_path, [prefix, *views])
-    info = ckpt_format.parse_trailer_v3(views[-1])
+    info = ckpt_format.parse_trailer(views[-1])
     base = {
         "iteration": 1,
         "leaf_sizes": [arr.nbytes],
@@ -618,7 +617,7 @@ class TestDeltaFrames:
         p1, v1 = self._container(base_arr, 1)
         base_path = str(tmp_path / "base.ckpt")
         ckpt_format.write_parts(base_path, [p1, *v1])
-        info = ckpt_format.parse_trailer_v3(v1[-1])
+        info = ckpt_format.parse_trailer(v1[-1])
         base = {
             "iteration": 1,
             "leaf_sizes": [base_arr.nbytes],
@@ -643,7 +642,7 @@ class TestDeltaFrames:
         p1, v1 = self._container(arr, 1)
         base_path = str(tmp_path / "base.ckpt")
         ckpt_format.write_parts(base_path, [p1, *v1])
-        info = ckpt_format.parse_trailer_v3(v1[-1])
+        info = ckpt_format.parse_trailer(v1[-1])
         base = {
             "iteration": 1,
             "leaf_sizes": [arr.nbytes],
@@ -759,37 +758,11 @@ class TestDeltaE2E:
             os.path.join(root, "s0", "r1", CkptID(2, 0).filename()))
 
 
-# -- TPURES03 chunk manifest + version skew ----------------------------------
+# -- the chunk manifest, and heads of other formats at the retrieve rung ------
 
 
-def _write_v2(path, arrays, meta=None):
-    """Hand-built TPURES02 container — what pre-chunk code wrote."""
-    views = [ckpt_format._raw_view(np.ascontiguousarray(a)) for a in arrays]
-    leaf_crcs = [ckpt_format.crc32c(v) for v in views]
-    header = {
-        "hollow": pickle.dumps("v2-skeleton"),
-        "leaves": [
-            {"shape": a.shape, "dtype": a.dtype.name, "nbytes": a.nbytes,
-             "crc32c": c}
-            for a, c in zip(arrays, leaf_crcs)
-        ],
-        "meta": meta or {},
-    }
-    hb = pickle.dumps(header, protocol=pickle.HIGHEST_PROTOCOL)
-    prefix = ckpt_format.MAGIC_V2 + struct.pack("<Q", len(hb)) + hb
-    trailer = ckpt_format.build_trailer(
-        leaf_crcs, ckpt_format._container_crc(prefix, leaf_crcs)
-    )
-    with open(path, "wb") as f:
-        f.write(prefix)
-        for v in views:
-            f.write(v)
-        f.write(trailer)
-    return b"".join([prefix, *[bytes(v) for v in views], trailer])
-
-
-class TestFormatSkew:
-    def test_v3_writers_and_chunk_manifest(self, tmp_path):
+class TestChunkManifest:
+    def test_writers_and_chunk_manifest(self, tmp_path):
         path = str(tmp_path / "c.ckpt")
         arr = np.arange(ckpt_format.DEFAULT_CHUNK // 2, dtype=np.uint8)
         ckpt_format.write_payload(path, b"h", [arr, arr[: 100]])
@@ -805,13 +778,8 @@ class TestFormatSkew:
 
     def test_chunk_corruption_located(self, tmp_path):
         path = str(tmp_path / "c.ckpt")
-        cs = 4096
-        os.environ[ckpt_format.CHUNK_ENV] = str(cs)
-        try:
-            arr = np.zeros(cs * 3, dtype=np.uint8)
-            ckpt_format.write_payload(path, b"h", [arr])
-        finally:
-            del os.environ[ckpt_format.CHUNK_ENV]
+        cs = ckpt_format.DEFAULT_CHUNK
+        ckpt_format.write_payload(path, b"h", [np.zeros(cs * 3, dtype=np.uint8)])
         header, prefix_len, info = ckpt_format.read_trailer(path)
         assert info.chunk_size == cs and len(info.chunk_crcs) == 3
         with open(path, "r+b") as f:
@@ -822,56 +790,48 @@ class TestFormatSkew:
         rep = ckpt_format.chunk_report(path)
         assert rep["leaves"][0]["bad"] == [1]
 
-    def test_v2_container_loads_fully_verified(self, tmp_path, sink):
-        path = str(tmp_path / "v2.ckpt")
-        arr = np.arange(5000, dtype=np.float32)
-        _write_v2(path, [arr], meta={"iteration": 3})
-        hollow, tensors, meta = ckpt_format.read_payload(path)
-        np.testing.assert_array_equal(tensors[0], arr)
-        assert meta == {"iteration": 3}
+    def test_streamed_chunk_size_rides_in_the_trailer(self, tmp_path):
+        """A writer that names its chunk size (``Checksummer(chunk_size=)``)
+        is read back at that size: the trailer, not the reader's default,
+        decides the geometry."""
+        path = str(tmp_path / "s.ckpt")
+        cs = 4096
+        arr = np.arange(cs * 3 + 5, dtype=np.uint8)
+        prefix = ckpt_format.header_prefix(
+            b"h", [{"shape": arr.shape, "dtype": "uint8", "nbytes": arr.nbytes}]
+        )
+        ck = ckpt_format.Checksummer(prefix, chunk_size=cs)
+        ck.add_leaf(arr)
+        trailer = ck.trailer()
+        assert len(trailer) == ckpt_format.trailer_size_for([arr.nbytes], cs)
+        ckpt_format.write_stream(path, [prefix, arr, trailer])
         assert ckpt_format.verify_file(path)[0] == "ok"
-        # No unverified event: v2 is verified at leaf granularity.
-        assert not [e for e in sink if e.kind == "ckpt_unverified"]
-        # ...but it has no chunk manifest.
         _, _, info = ckpt_format.read_trailer(path)
-        assert info.chunk_crcs is None
-        assert ckpt_format.chunk_report(path)["chunk_size"] is None
-        # And a corrupted v2 payload is still caught (whole-leaf CRC).
-        with open(path, "r+b") as f:
-            f.seek(-300, 2)
-            f.write(b"\x00\x01\x02")
-        assert ckpt_format.verify_file(path)[0] == "corrupt"
+        assert info.chunk_size == cs and len(info.chunk_crcs) == 4
+        _, tensors, _ = ckpt_format.read_payload(path)
+        np.testing.assert_array_equal(tensors[0], arr)
 
-    def test_v2_blob_replicates_and_verifies_on_receive(self, tmp_path):
-        arr = np.arange(999, dtype=np.int32)
-        blob = _write_v2(str(tmp_path / "x.ckpt"), [arr])
-        assert ckpt_format.verify_container(blob) is True
-        bad = bytearray(blob)
-        bad[len(blob) - 100] ^= 0x40  # payload byte
-        with pytest.raises(CheckpointError):
-            ckpt_format.verify_container(bytes(bad))
-
-    def test_mixed_clique_v2_mirror_retrieves_byte_identical(
-        self, tmp_path, make_store
+    @pytest.mark.parametrize("head", [b"TPURES01", b"TPURES02"], ids=["v1", "v2"])
+    def test_old_format_mirror_is_refused_at_the_retrieve_rung(
+        self, tmp_path, make_store, sink, head
     ):
-        """TPURES03 ↔ TPURES02 skew: rank 1 holds rank 0's shard as a v2
-        container (written by old code); the retrieve rung serves it and the
-        round-trip is byte-identical."""
+        """Rank 1 holds rank 0's shard as a container of an old format; rank
+        0's disk is empty. The retrieve rung moves the bytes (to the receive
+        side they are no container), the parser refuses their head with the
+        rung's ``ckpt_integrity_failure`` event, nothing is loaded or
+        persisted, and with no older rung every rank's load raises. (At the
+        parent ``TPURES02`` was served and re-persisted.)"""
+        from tests.checkpoint.test_integrity import _other_head
+
         root = str(tmp_path / "ckpt")
-        arr = np.arange(20000, dtype=np.float32)
-        # Seed the disk layout an old-code clique left behind: rank 1 holds
-        # its OWN v3 container plus a v2 mirror of rank 0's shard; rank 0's
-        # disk is empty (the lost rank).
         r1 = os.path.join(root, "s0", "r1")
         os.makedirs(r1, exist_ok=True)
-        v2_blob = _write_v2(
-            os.path.join(r1, CkptID(1, 0).filename()), [arr],
-            meta={"iteration": 1},
-        )
-        own = np.full((64,), 11.0, np.float32)
+        with open(os.path.join(r1, CkptID(5, 0).filename()), "wb") as f:
+            f.write(_other_head(head))
         ckpt_format.write_payload(
-            os.path.join(r1, CkptID(1, 1).filename()),
-            pickle.dumps("own-skeleton"), [own], meta={"iteration": 1},
+            os.path.join(r1, CkptID(5, 1).filename()),
+            pickle.dumps("own-skeleton"), [np.full((64,), 11.0, np.float32)],
+            meta={"iteration": 5},
         )
 
         def body(rank):
@@ -883,14 +843,18 @@ class TestFormatSkew:
                     comm, ex, replication_jump=1, replication_factor=2)
                 mgr = LocalCheckpointManager(
                     root, rank=rank, comm=comm, replication=strat)
-                hollow_t, tensors, meta = mgr.load()
-                mgr.close()
-                return np.asarray(tensors[0]).copy()
+                try:
+                    with pytest.raises(CheckpointError, match="no intact"):
+                        mgr.load()
+                finally:
+                    mgr.close()
             finally:
                 ex.close()
 
-        out = run_ranks([0, 1], body)
-        np.testing.assert_array_equal(out[0], arr)
-        # The retrieved v2 shard was re-persisted byte-identically.
-        p0 = os.path.join(root, "s0", "r0", CkptID(1, 0).filename())
-        assert open(p0, "rb").read() == v2_blob
+        run_ranks([0, 1], body)
+        failures = [e for e in sink if e.kind == "ckpt_integrity_failure"]
+        assert [e.payload["stage"] for e in failures] == ["peer-retrieve"]
+        assert "bad magic" in failures[0].payload["error"]
+        assert not [e for e in sink if e.kind == "ckpt_unverified"]
+        assert not os.path.exists(
+            os.path.join(root, "s0", "r0", CkptID(5, 0).filename()))

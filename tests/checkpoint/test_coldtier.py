@@ -10,8 +10,6 @@ ObjectStore implementation.
 import concurrent.futures as cf
 import json
 import os
-import pickle
-import struct
 
 import numpy as np
 import pytest
@@ -417,53 +415,37 @@ class TestColdReshard:
 
 
 class TestVersionSkew:
-    def test_v2_era_workdir_restores_from_v3_cold_tier(self, tmp_path, sink):
-        """Skew: a workdir whose local containers predate chunk manifests
-        (TPURES02) coexists with a cold tier written by v3 code — coverage
-        merges both rungs and the cold iteration restores cleanly."""
-        # v3-era job wrote iteration 2 to the cold tier.
+    @pytest.mark.parametrize("head", [b"TPURES01", b"TPURES02"], ids=["v1", "v2"])
+    def test_old_format_workdir_under_a_cold_iteration(self, tmp_path, sink, head):
+        """A workdir whose one local container is of an old format beside a
+        cold tier holding a newer iteration: coverage counts both names, the
+        cold iteration restores cleanly, and the local one is refused and
+        quarantined, never loaded. (At the parent it loaded.)"""
+        from tests.checkpoint.test_integrity import _other_head
+
         cold = _cold(tmp_path)
         mgr = LocalCheckpointManager(str(tmp_path / "work"), rank=0, cold=cold)
         mgr.save(2, PyTreeStateDict(_tree(0)), is_async=False)
         assert cold.flush(timeout=30.0)
         mgr.close()
 
-        # v2-era workdir: hand-built TPURES02 container at iteration 1.
-        old_root = str(tmp_path / "old")
-        arr = np.full((8,), 9.25, dtype=np.float32)
-        views = [ckpt_format._raw_view(np.ascontiguousarray(arr))]
-        leaf_crcs = [ckpt_format.crc32c(v) for v in views]
-        header = {
-            "hollow": pickle.dumps("v2-skeleton"),
-            "leaves": [
-                {"shape": arr.shape, "dtype": arr.dtype.name,
-                 "nbytes": arr.nbytes, "crc32c": leaf_crcs[0]}
-            ],
-            "meta": {"iteration": 1},
-        }
-        hb = pickle.dumps(header, protocol=pickle.HIGHEST_PROTOCOL)
-        prefix = ckpt_format.MAGIC_V2 + struct.pack("<Q", len(hb)) + hb
-        trailer = ckpt_format.build_trailer(
-            leaf_crcs, ckpt_format._container_crc(prefix, leaf_crcs)
-        )
         mgr2 = LocalCheckpointManager(
-            old_root, rank=0, cold=_cold(tmp_path)
+            str(tmp_path / "old"), rank=0, cold=_cold(tmp_path)
         )
-        v2_path = mgr2._path(CkptID(1, 0))
-        os.makedirs(os.path.dirname(v2_path), exist_ok=True)
-        with open(v2_path, "wb") as f:
-            f.write(prefix)
-            for v in views:
-                f.write(v)
-            f.write(trailer)
+        old_path = mgr2._path(CkptID(1, 0))
+        os.makedirs(os.path.dirname(old_path), exist_ok=True)
+        with open(old_path, "wb") as f:
+            f.write(_other_head(head))
 
-        # Coverage sees the local v2 iteration AND the cold v3 iteration.
         assert mgr2.find_latest() == 2
         hollow, tensors, meta = mgr2.load(2)
         np.testing.assert_array_equal(np.asarray(tensors[0]), _tree(0)["w"])
-        # The v2-era local container still loads below it.
-        hollow1, tensors1, meta1 = mgr2.load(1)
-        np.testing.assert_array_equal(np.asarray(tensors1[0]), arr)
+        with pytest.raises(CheckpointError, match="no intact"):
+            mgr2.load(1)
+        (q,) = [e for e in sink if e.kind == "ckpt_quarantined"]
+        assert q.payload["iteration"] == 1 and "bad magic" in q.payload["error"]
+        assert not [e for e in sink if e.kind == "ckpt_unverified"]
+        assert not os.path.exists(old_path)
         mgr2.close()
 
 

@@ -1,5 +1,5 @@
-"""Checkpoint integrity plane: v2 container checksums, mixed-version loads,
-quarantine, and the load() recovery ladder (local → peer retrieve → group
+"""Checkpoint integrity plane: container checksums, heads of other formats
+refused, quarantine, and the load() recovery ladder (local → peer retrieve → group
 fallback)."""
 
 import concurrent.futures as cf
@@ -60,24 +60,40 @@ def _flip(path, offset, mask=0x10):
         f.write(bytes([b[0] ^ mask]))
 
 
-def _write_v1(path, hollow=b"old", meta=None):
-    """Hand-built TPURES01 container — what pre-integrity code wrote."""
+def _other_head(head, hollow=None):
+    """A whole, well-formed container under a head that is not ``TPURES03``,
+    as bytes: ``TPURES01`` as pre-integrity code wrote it (head, header,
+    payload, nothing after), ``TPURES02`` as PR 5's code did (leaf CRCs in a
+    ``TPURESCK`` trailer, no chunk manifest) — both loaded before PR 47 — and
+    any other eight bytes in front of a current container."""
     arr = np.arange(16, dtype=np.float32)
+    hollow = pickle.dumps({"w": 0}) if hollow is None else hollow
+    if head not in (b"TPURES01", b"TPURES02"):
+        blob = ckpt_format.serialize_to_bytes(hollow, [arr], meta={"iteration": 5})
+        return head + blob[len(head):]
+    crc = ckpt_format.crc32c(arr.tobytes())
+    spec = {"shape": (16,), "dtype": "float32", "nbytes": 64}
+    if head == b"TPURES02":
+        spec["crc32c"] = crc
     header = pickle.dumps(
-        {
-            "hollow": hollow,
-            "leaves": [{"shape": (16,), "dtype": "float32", "nbytes": 64}],
-            "meta": meta or {},
-        },
+        {"hollow": hollow, "leaves": [spec], "meta": {"iteration": 5}},
         protocol=pickle.HIGHEST_PROTOCOL,
     )
-    with open(path, "wb") as f:
-        f.write(ckpt_format.MAGIC_V1 + struct.pack("<Q", len(header)) + header)
-        f.write(arr.tobytes())
-    return arr
+    prefix = head + struct.pack("<Q", len(header)) + header
+    if head == b"TPURES01":
+        return prefix + arr.tobytes()
+    digest = ckpt_format.crc32c(struct.pack("<I", crc), ckpt_format.crc32c(prefix))
+    trailer = (
+        b"TPURESCK" + ckpt_format._ALGO_TAG
+        + struct.pack("<3I", 1, crc, digest)
+    )
+    return prefix + arr.tobytes() + trailer
 
 
-class TestFormatV2:
+OTHER_HEADS = [b"TPURES01", b"TPURES02", b"\x00\xffNOTCKP"]
+
+
+class TestFormat:
     def test_roundtrip_verifies_and_header_carries_crcs(self, tmp_path):
         path = str(tmp_path / "a.ckpt")
         written = ckpt_format.write_payload(path, b"hollow", _arrays(), meta={"it": 7})
@@ -106,7 +122,7 @@ class TestFormatV2:
             ckpt_format.read_payload(path)
 
     def test_truncation_rejected_cleanly(self, tmp_path):
-        """The satellite size-truncation check: a torn v2 file fails with a
+        """The satellite size-truncation check: a torn file fails with a
         classified CheckpointError naming the size delta, not a pickle/struct
         leak or a silently short tree."""
         path = str(tmp_path / "a.ckpt")
@@ -118,23 +134,86 @@ class TestFormatV2:
         with pytest.raises(CheckpointError, match="size mismatch"):
             ckpt_format.read_payload(path)
 
-    def test_striped_write_is_byte_identical_and_verifies(self, tmp_path):
-        p1, p4 = str(tmp_path / "s1.ckpt"), str(tmp_path / "s4.ckpt")
-        ckpt_format.write_payload(p1, b"h", _arrays(), stripes=1)
-        ckpt_format.write_payload(p4, b"h", _arrays(), stripes=4)
-        assert open(p1, "rb").read() == open(p4, "rb").read()
-        assert ckpt_format.verify_file(p4)[0] == "ok"
+    @pytest.mark.parametrize("reader", ["file", "buffer", "ranges"])
+    @pytest.mark.parametrize("head", OTHER_HEADS, ids=["v1", "v2", "arbitrary"])
+    def test_other_head_refused_on_every_read_path(
+        self, tmp_path, sink, head, reader
+    ):
+        """One format: a head that is not ``TPURES03`` is a corrupt head on
+        every read path — the bad-magic :class:`CheckpointError`, the
+        manager's ``ckpt_quarantined`` event, no ``ckpt_unverified`` event,
+        nothing loaded. (At the parent the two old heads loaded.)"""
+        blob = _other_head(head)
+        mgr = LocalCheckpointManager(str(tmp_path), rank=0)
+        path = _shard_path(tmp_path, 0, 5, 0)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "wb") as f:
+            f.write(blob)
+        if reader == "buffer":
+            with pytest.raises(CheckpointError, match="bad magic"):
+                ckpt_format.deserialize_from_buffer(memoryview(blob))
+            # to the receive side it is not a container at all
+            assert ckpt_format.verify_container(memoryview(blob)) is False
+        else:
+            with pytest.raises(CheckpointError, match="bad magic"):
+                if reader == "file":
+                    ckpt_format.read_payload(path)
+                else:
+                    ckpt_format.read_trailer(path)
+            assert ckpt_format.verify_file(path)[0] == "corrupt"
+            with pytest.raises(
+                CheckpointError,
+                match="no intact" if reader == "file" else "corrupt container",
+            ):
+                if reader == "file":
+                    mgr.load(5)
+                else:
+                    mgr._read_ranges(5, 0, [(0, 0, 4)])
+            stage = "local-read" if reader == "file" else "reshard-verify"
+            q = [e for e in sink if e.kind == "ckpt_quarantined"]
+            assert [e.payload["stage"] for e in q] == [stage]
+            assert "bad magic" in q[0].payload["error"]
+            assert not os.path.exists(path)  # out of the inventory
+        assert not [e for e in sink if e.kind == "ckpt_unverified"]
+        mgr.close()
 
-    def test_v1_container_loads_with_unverified_event(self, tmp_path, sink):
-        """Mixed-version load: a container written by pre-integrity code still
-        loads under new code — verification skipped, ckpt_unverified emitted."""
-        path = str(tmp_path / "v1.ckpt")
-        arr = _write_v1(path, meta={"it": 3})
-        hollow, tensors, meta = ckpt_format.read_payload(path)
-        assert hollow == b"old" and meta == {"it": 3}
-        np.testing.assert_array_equal(tensors[0], arr)
-        assert any(e.kind == "ckpt_unverified" for e in sink)
-        assert ckpt_format.verify_file(path)[0] == "unverified"
+    @pytest.mark.parametrize("reader", ["file", "buffer", "receive", "ranges"])
+    def test_foreign_algorithm_loads_unverified_with_event(
+        self, tmp_path, sink, reader
+    ):
+        """The one load that skips a CRC comparison: a container signed by a
+        checksum algorithm this host lacks (a mixed fleet) loads, and says so
+        with one ``ckpt_unverified`` event whose reason names the tag."""
+        mgr = LocalCheckpointManager(str(tmp_path), rank=0)
+        mgr.save(5, PyTreeStateDict(_tree(0, 5)), is_async=False)
+        path = _shard_path(tmp_path, 0, 5, 0)
+        blob = bytearray(open(path, "rb").read())
+        at = blob.rindex(ckpt_format.TRAILER_MAGIC_V3) + len(ckpt_format.TRAILER_MAGIC_V3)
+        assert bytes(blob[at : at + 4]) == ckpt_format._ALGO_TAG
+        blob[at : at + 4] = b"xx99"
+        with open(path, "wb") as f:
+            f.write(blob)
+        if reader == "file":
+            _, tensors, meta = mgr.load(5)
+            assert meta["iteration"] == 5
+            np.testing.assert_array_equal(np.asarray(tensors[0]), _tree(0, 5)["w"])
+        elif reader == "buffer":
+            _, tensors, _ = ckpt_format.deserialize_from_buffer(blob)
+            np.testing.assert_array_equal(tensors[0], _tree(0, 5)["w"])
+        elif reader == "receive":
+            assert ckpt_format.verify_container(blob) is False
+        else:
+            (part,) = mgr._read_ranges(5, 0, [(0, 8, 8)])
+            assert part == _tree(0, 5)["w"].tobytes()[8:16]
+            assert ckpt_format.verify_file(path)[0] == "unverified"
+        if reader == "ranges":
+            # the range reader's verdict is verify_file's, which records none
+            assert not [e for e in sink if e.kind == "ckpt_unverified"]
+        else:
+            (e,) = [e for e in sink if e.kind == "ckpt_unverified"]
+            assert e.payload["reason"] == "algo:b'xx99'"
+        assert not [e for e in sink if e.kind == "ckpt_quarantined"]
+        mgr.close()
 
     def test_serialize_parts_carries_trailer_and_verifies(self):
         prefix, views = ckpt_format.serialize_parts(b"h", _arrays(), meta={"k": 1})
@@ -294,6 +373,31 @@ class TestRecoveryLadder:
         )
         mgr.close()
 
+    @pytest.mark.parametrize("head", OTHER_HEADS[:2], ids=["v1", "v2"])
+    def test_newest_container_of_an_old_format_falls_to_next_rung(
+        self, tmp_path, sink, head
+    ):
+        """``find_latest`` counts names, so it offers the newest iteration;
+        ``load`` then refuses its head, quarantines it and says why, and the
+        ladder lands on the next rung."""
+        mgr = LocalCheckpointManager(str(tmp_path), rank=0, keep=2)
+        mgr.save(1, PyTreeStateDict(_tree(0, 1)), is_async=False)
+        mgr.save(2, PyTreeStateDict(_tree(0, 2)), is_async=False)
+        with open(_shard_path(tmp_path, 0, 2, 0), "wb") as f:
+            f.write(_other_head(head))
+        assert mgr.find_latest() == 2
+        _, tensors, meta = mgr.load()
+        assert meta["iteration"] == 1
+        np.testing.assert_array_equal(np.asarray(tensors[0]), _tree(0, 1)["w"])
+        (q,) = [e for e in sink if e.kind == "ckpt_quarantined"]
+        assert q.payload["iteration"] == 2 and q.payload["stage"] == "local-read"
+        assert "bad magic" in q.payload["error"]
+        (fb,) = [e for e in sink if e.kind == "ckpt_fallback"]
+        assert (fb.payload["from_iteration"], fb.payload["to_iteration"]) == (2, 1)
+        assert not [e for e in sink if e.kind == "ckpt_unverified"]
+        assert mgr.find_latest() == 1
+        mgr.close()
+
     def test_single_rank_all_corrupt_raises_checkpoint_error(self, tmp_path):
         mgr = LocalCheckpointManager(str(tmp_path), rank=0, keep=2)
         mgr.save(1, PyTreeStateDict(_tree(0, 1)), is_async=False)
@@ -306,7 +410,7 @@ class TestRecoveryLadder:
 
     def test_pipelined_save_produces_verifiable_container(self, tmp_path):
         """The leaf-streaming save path (thread caller, async) must emit the
-        same verifiable v2 container as the materialized path."""
+        same verifiable container as the materialized path."""
         mgr = LocalCheckpointManager(str(tmp_path), rank=0)
         assert mgr.pipelined
         mgr.save(4, PyTreeStateDict(_tree(0, 4)), is_async=True)
@@ -368,20 +472,13 @@ class TestUniformErrorClassification:
         mgr.close()
 
     def test_corrupt_hollow_pickle_classified(self, tmp_path):
-        """A v1 container whose hollow bytes are damaged must fail as
-        CheckpointError naming the path (pickle raises half a dozen types)."""
+        """A container that verifies but whose hollow bytes are no pickle must
+        fail as CheckpointError naming the path (pickle raises half a dozen
+        types)."""
         mgr = LocalCheckpointManager(str(tmp_path), rank=0)
         path = _shard_path(tmp_path, 0, 6, 0)
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        header = pickle.dumps(
-            {
-                "hollow": b"\x80\x04corrupt-pickle",
-                "leaves": [],
-                "meta": {},
-            }
-        )
-        with open(path, "wb") as f:
-            f.write(ckpt_format.MAGIC_V1 + struct.pack("<Q", len(header)) + header)
+        ckpt_format.write_payload(path, b"\x80\x04corrupt-pickle", [])
+        assert ckpt_format.verify_file(path)[0] == "ok"
         with pytest.raises(CheckpointError, match="corrupt hollow skeleton"):
             mgr._read_local_shard(6, 0)
         mgr.close()

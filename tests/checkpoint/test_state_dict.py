@@ -224,39 +224,60 @@ class TestAsyncCheckpointer:
         ckpt.close()
 
 
-class TestStripedWrites:
-    def test_striped_container_byte_identical(self, tmp_path):
-        """A 4-way striped write produces the SAME file as the sequential one
-        (pwrite-at-offset into one container), so readers never change."""
+def _roundtrip(tmp_path, arrays, meta=None):
+    """The one sequential writer: written, verified, read back equal, and
+    byte-identical to the in-memory serialization of the same leaves."""
+    path = str(tmp_path / "c.ckpt")
+    written = ckpt_format.write_payload(path, b"hollow", arrays, meta=meta)
+    assert written == os.path.getsize(path)
+    assert ckpt_format.verify_file(path)[0] == "ok"
+    with open(path, "rb") as f:
+        assert f.read() == ckpt_format.serialize_to_bytes(b"hollow", arrays, meta)
+    hollow, tensors, got_meta = ckpt_format.read_payload(path)
+    assert hollow == b"hollow" and got_meta == (meta or {})
+    assert len(tensors) == len(arrays)
+    for got, want in zip(tensors, arrays):
+        np.testing.assert_array_equal(got, want)
+
+
+class TestSequentialWriter:
+    def test_mixed_leaves_roundtrip(self, tmp_path):
         rng = np.random.default_rng(0)
         arrays = [
             np.asarray(rng.standard_normal(s), np.float32)
             for s in [(64, 64), (7,), (128, 3), (1,), (33, 5), (256,)]
         ]
-        p1 = str(tmp_path / "seq.ckpt")
-        p4 = str(tmp_path / "striped.ckpt")
-        ckpt_format.write_payload(p1, b"hollow", arrays, meta={"it": 1}, stripes=1)
-        ckpt_format.write_payload(p4, b"hollow", arrays, meta={"it": 1}, stripes=4)
-        with open(p1, "rb") as f1, open(p4, "rb") as f4:
-            assert f1.read() == f4.read()
-        hollow, tensors, meta = ckpt_format.read_payload(p4)
-        assert hollow == b"hollow" and meta == {"it": 1}
-        for got, want in zip(tensors, arrays):
-            np.testing.assert_array_equal(got, want)
+        _roundtrip(tmp_path, arrays, meta={"it": 1})
 
-    def test_stripes_env_knob(self, tmp_path, monkeypatch):
-        monkeypatch.setenv(ckpt_format.STRIPES_ENV, "3")
-        assert ckpt_format._effective_stripes(None) == 3
-        monkeypatch.setenv(ckpt_format.STRIPES_ENV, "bogus")
-        assert ckpt_format._effective_stripes(None) == 1
-        assert ckpt_format._effective_stripes(4) == 4
+    @pytest.mark.parametrize(
+        "name,value",
+        [("TPU_RESILIENCY_CKPT_STRIPES", "4"), ("TPU_RESILIENCY_CKPT_CHUNK", "4096")],
+    )
+    def test_environment_does_not_steer_the_writer(
+        self, tmp_path, monkeypatch, name, value
+    ):
+        """PR 47 deleted both variables: with either set, the container's
+        bytes are those of a clean environment (at the parent the chunk
+        variable changed the manifest and the trailer's size)."""
+        arrays = [np.arange(1 << 16, dtype=np.float32), np.ones((3, 5), np.int32)]
+        clean = str(tmp_path / "clean.ckpt")
+        ckpt_format.write_payload(clean, b"h", arrays, meta={"it": 2})
+        clean_trailer = ckpt_format.trailer_size_for([a.nbytes for a in arrays])
+        monkeypatch.setenv(name, value)
+        path = str(tmp_path / "set.ckpt")
+        ckpt_format.write_payload(path, b"h", arrays, meta={"it": 2})
+        with open(path, "rb") as f, open(clean, "rb") as g:
+            assert f.read() == g.read()
+        assert ckpt_format.read_trailer(path)[2].chunk_size == ckpt_format.DEFAULT_CHUNK
+        assert ckpt_format.trailer_size_for([a.nbytes for a in arrays]) == clean_trailer
 
-    def test_striped_blob_roundtrip(self, tmp_path):
+    def test_blob_roundtrip(self, tmp_path):
         blob = np.random.default_rng(1).integers(0, 255, 3 << 20, np.uint8).tobytes()
         path = str(tmp_path / "blob.bin")
-        ckpt_format.write_blob(path, blob, stripes=4)
+        ckpt_format.write_blob(path, blob)
         with open(path, "rb") as f:
             assert f.read() == blob
+        assert not os.path.exists(path + ckpt_format.DIRTY_SUFFIX)
 
 
 class TestSeparationHint:
@@ -292,21 +313,15 @@ class TestSeparationHint:
             ckpt.async_save({"a": 1}, str(tmp_path / "x.ckpt"), separation_hint="b")
 
 
-class TestStripedDominantLeaf:
-    def test_single_huge_leaf_stripes_byte_identical(self, tmp_path):
-        """Byte-range striping works when one leaf dominates the payload
-        (whole-leaf grouping would leave all but one writer idle)."""
+class TestDominantLeaf:
+    def test_single_huge_leaf_roundtrip(self, tmp_path):
+        """One leaf of several chunks dominates the payload beside a tiny one."""
         rng = np.random.default_rng(2)
         arrays = [
             np.asarray(rng.standard_normal((1 << 20,)), np.float32),  # ~4 MiB
             np.asarray([1.0], np.float32),
         ]
-        p1 = str(tmp_path / "seq.ckpt")
-        p4 = str(tmp_path / "striped.ckpt")
-        ckpt_format.write_payload(p1, b"h", arrays, stripes=1)
-        ckpt_format.write_payload(p4, b"h", arrays, stripes=4)
-        with open(p1, "rb") as f1, open(p4, "rb") as f4:
-            assert f1.read() == f4.read()
+        _roundtrip(tmp_path, arrays)
 
 
 class TestTornPairDetection:
@@ -423,23 +438,15 @@ class TestTornPairDetection:
         np.testing.assert_array_equal(merged["a"]["x"], tree["a"]["x"])
 
 
-class TestStripedEdgeCases:
-    def test_single_leaf_payload_stripes(self, tmp_path):
-        """Byte-range striping splits WITHIN one fused-parameter leaf."""
-        arr = [np.arange(1 << 20, dtype=np.float32)]
-        p1, p4 = str(tmp_path / "s1.ckpt"), str(tmp_path / "s4.ckpt")
-        ckpt_format.write_payload(p1, b"h", arr, stripes=1)
-        ckpt_format.write_payload(p4, b"h", arr, stripes=4)
-        with open(p1, "rb") as f1, open(p4, "rb") as f4:
-            assert f1.read() == f4.read()
+class TestWriterEdgeCases:
+    def test_single_leaf_payload(self, tmp_path):
+        """A payload that is one fused-parameter leaf of four whole chunks."""
+        _roundtrip(tmp_path, [np.arange(1 << 20, dtype=np.float32)])
 
-    def test_all_empty_leaves_striped(self, tmp_path):
-        path = str(tmp_path / "e.ckpt")
-        ckpt_format.write_payload(
-            path, b"h", [np.zeros((0,), np.float32), np.zeros((0,), np.int32)],
-            stripes=4,
-        )
-        hollow, tensors, _ = ckpt_format.read_payload(path)
+    def test_all_empty_leaves(self, tmp_path):
+        arrays = [np.zeros((0,), np.float32), np.zeros((0,), np.int32)]
+        _roundtrip(tmp_path, arrays)
+        _, tensors, _ = ckpt_format.read_payload(str(tmp_path / "c.ckpt"))
         assert [t.size for t in tensors] == [0, 0]
 
     def test_direct_load_strips_pair_token(self, tmp_path):

@@ -99,9 +99,9 @@ def test_ring_backends_agree(capacity, samples):
 
 
 @st.composite
-def radix_case(draw):
+def window_case(draw):
     """Like telemetry_case but the window size also samples the LARGE regime
-    (past the quadratic cap) where auto_mode actually selects radix."""
+    (past the auto-selection cap), where a caller may still name the kernel."""
     r = draw(st.integers(2, 6))
     s = draw(st.integers(1, 3))
     w = draw(st.one_of(st.integers(1, 10), st.integers(129, 260)))
@@ -119,28 +119,28 @@ def radix_case(draw):
     )
 
 
-@given(radix_case())
-def test_radix_kernel_matches_loop_kernel(case):
-    """The O(32*W) radix-select formulation is bit-identical to rank-counting
-    on arbitrary windows/counts (ties, empties, single samples, tiny/huge
-    magnitudes, and windows past the quadratic cap) — the invariant that lets
-    auto-selection switch modes by size without changing results."""
+@given(window_case())
+def test_median_kernel_matches_numpy(case):
+    """Rank counting picks exactly NumPy's order statistics on arbitrary
+    windows/counts (ties, empties, single samples, tiny/huge magnitudes, and
+    windows past the cap): medians bit-identical to the mean of the two middle
+    elements of the sorted valid prefix, weights the masked totals."""
     import jax.numpy as jnp
 
     from tpu_resiliency.ops.scoring_pallas import fused_median_weights
 
     data, counts = case
-    r = data.shape[0]
-    loop = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), rank_tile=r, interpret=True,
-        mode="loop",
+    r, s, _ = data.shape
+    med, wt = fused_median_weights(
+        jnp.asarray(data), jnp.asarray(counts), rank_tile=r, interpret=True
     )
-    radix = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), rank_tile=r, interpret=True,
-        mode="radix",
-    )
-    # Bit-identical, weights included: both kernels share the masked-sum
-    # expression today, and a divergence introduced by a future edit must not
-    # hide behind a tolerance (mode auto-switching relies on identity).
-    np.testing.assert_array_equal(np.asarray(loop[0]), np.asarray(radix[0]))
-    np.testing.assert_array_equal(np.asarray(loop[1]), np.asarray(radix[1]))
+    med, wt = np.asarray(med), np.asarray(wt)
+    for i in range(r):
+        for j in range(s):
+            n = counts[i, j]
+            if n == 0:
+                assert med[i, j] == np.inf and wt[i, j] == 0.0
+                continue
+            v = np.sort(data[i, j, :n])
+            assert med[i, j] == np.float32(0.5) * (v[(n - 1) // 2] + v[n // 2])
+            np.testing.assert_allclose(wt[i, j], data[i, j, :n].sum(dtype=np.float64), rtol=1e-5)

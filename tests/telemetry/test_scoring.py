@@ -154,203 +154,116 @@ def test_pallas_kernel_with_duplicates():
     np.testing.assert_allclose(np.asarray(wt), 24.0)
 
 
-def test_pallas_pairwise_mode_matches_loop_mode():
-    """The all-pairs formulation is the same function as the rank-counting loop —
-    including empty windows, single samples, and ties."""
-    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
-
-    rng = np.random.default_rng(9)
-    r, s, w = 16, 8, 16
-    data, counts = _mk_windows(rng, r, s, w)
-    counts[0, 0] = 5
-    counts[2, 3] = 0
-    counts[5, 1] = 1
-    data[7, 2, :] = 1.5  # ties across the whole window
-
-    loop = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), interpret=True, mode="loop"
-    )
-    pair = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), interpret=True, mode="pairwise"
-    )
-    np.testing.assert_allclose(np.asarray(loop[0]), np.asarray(pair[0]), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(loop[1]), np.asarray(pair[1]), rtol=1e-6)
+def _numpy_median_weights(data, counts):
+    """NumPy's median over each window's valid prefix; +inf and 0 for an empty one."""
+    r, s, _ = data.shape
+    med = np.full((r, s), np.inf, np.float32)
+    wt = np.zeros((r, s), np.float32)
+    for i in range(r):
+        for j in range(s):
+            n = counts[i, j]
+            wt[i, j] = data[i, j, :n].sum()
+            if n:
+                v = np.sort(data[i, j, :n])
+                med[i, j] = np.float32(0.5 * (v[(n - 1) // 2] + v[n // 2]))
+    return med, wt
 
 
-def test_pallas_pairwise_large_s_fold_matches_numpy():
-    """S>32 routes pairwise through the signal→rank fold (Mosaic rejects the 4-D
-    all-pairs block past S=32); the double reshape must keep every (rank, signal)
-    group's median in place — for the production S=64 and a non-divisible S=48
-    (folded at the largest divisor ≤32, here 24)."""
-    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
-
-    rng = np.random.default_rng(11)
-    for s in (64, 48):
-        r, w = 8, 16
-        data, counts = _mk_windows(rng, r, s, w)
-        counts[0, 0] = 3
-        counts[1, s - 1] = 0
-        med, wt = fused_median_weights(
-            jnp.asarray(data), jnp.asarray(counts), interpret=True, mode="pairwise"
-        )
-        exp_med = np.full((r, s), np.inf, np.float32)
-        exp_wt = np.zeros((r, s), np.float32)
-        for i in range(r):
-            for j in range(s):
-                n = counts[i, j]
-                exp_wt[i, j] = data[i, j, :n].sum()
-                if n > 0:
-                    exp_med[i, j] = np.median(data[i, j, :n])
-        np.testing.assert_allclose(np.asarray(med), exp_med, rtol=1e-6)
-        np.testing.assert_allclose(np.asarray(wt), exp_wt, rtol=1e-5)
+def _edge_windows(seed, r, s, w):
+    """Random windows with the edges the deleted kernels' tests carried: a short
+    prefix, an empty window, a single sample, a whole window of ties and
+    subnormal-adjacent magnitudes."""
+    rng = np.random.default_rng(seed)
+    data = rng.uniform(0.5, 2.0, (r, s, w)).astype(np.float32)
+    counts = rng.integers(0, w + 1, (r, s)).astype(np.int32)
+    counts[0, 0] = min(5, w)
+    counts[1, s - 1] = 0
+    counts[min(5, r - 1), 0] = 1
+    data[r - 1, s // 2, :] = 1.5
+    data[r // 2, 0, :] = np.float32(1e-30)
+    return data, counts
 
 
-def test_pallas_pairwise_prime_s_rejected():
-    """A near-prime S>32 would fold to single-signal blocks — rejected loudly
-    rather than silently running a pathological grid."""
-    import pytest
-
-    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
-
-    data = jnp.ones((8, 37, 8), jnp.float32)
-    counts = jnp.full((8, 37), 8, jnp.int32)
-    with pytest.raises(ValueError, match="divisor"):
-        fused_median_weights(data, counts, interpret=True, mode="pairwise")
-
-
-def test_pallas_radix_mode_matches_loop_mode():
-    """The radix-select formulation is the same function as the rank-counting
-    loop — including empty windows, single samples, and whole-window ties."""
-    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
-
-    rng = np.random.default_rng(11)
-    r, s, w = 16, 8, 16
-    data, counts = _mk_windows(rng, r, s, w)
-    counts[0, 0] = 5
-    counts[2, 3] = 0
-    counts[5, 1] = 1
-    data[7, 2, :] = 1.5  # ties across the whole window
-    data[3, 4, :] = np.float32(1e-30)  # subnormal-adjacent magnitudes
-
-    loop = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), interpret=True, mode="loop"
-    )
-    radix = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), interpret=True, mode="radix"
-    )
-    np.testing.assert_array_equal(np.asarray(loop[0]), np.asarray(radix[0]))
-    np.testing.assert_allclose(np.asarray(loop[1]), np.asarray(radix[1]), rtol=1e-6)
-
-
-def test_pallas_radix_large_window_matches_numpy():
-    """W=128/W=192 (beyond the quadratic cap, incl. non-power-of-two): the radix
-    kernel must agree with numpy's median exactly on the valid prefix."""
-    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
-
-    rng = np.random.default_rng(12)
-    for w in (128, 192):
-        r, s = 8, 4
-        data = rng.uniform(0.5, 2.0, (r, s, w)).astype(np.float32)
-        counts = rng.integers(0, w + 1, (r, s)).astype(np.int32)
-        med, wt = fused_median_weights(
-            jnp.asarray(data), jnp.asarray(counts), rank_tile=8,
-            interpret=True, mode="radix",
-        )
-        med, wt = np.asarray(med), np.asarray(wt)
-        for i in range(r):
-            for j in range(s):
-                n = counts[i, j]
-                if n == 0:
-                    assert med[i, j] == np.inf
-                    assert wt[i, j] == 0.0
-                else:
-                    valid = np.sort(data[i, j, :n])
-                    expect = 0.5 * (valid[(n - 1) // 2] + valid[n // 2])
-                    assert med[i, j] == np.float32(expect), (i, j, n)
-                    np.testing.assert_allclose(wt[i, j], data[i, j, :n].sum(), rtol=1e-5)
-
-
-def test_radix_block_budget_shrinks_default_tile():
-    """The radix default rank tile halves until the [RT, S, W] block fits the
-    proven element budget (v5e compile fails at 32x64x256 blocks; 32x64x128 is
-    proven), and the shrunk tile preserves the caller-checked divisibility.
-    Explicit rank_tile is honored unchanged."""
+@pytest.mark.parametrize(
+    "r,s,w,tile",
+    [
+        (16, 8, 16, None),    # the shape the three kernels were compared at
+        (4, 2, 8, 4),         # every window a run of ties (set below)
+        (8, 64, 16, None),    # the production signal count
+        (8, 48, 16, None),    # a signal count that is no power of two
+        (2, 256, 16, None),   # S = 256
+        (8, 4, 128, 8),       # W = 128, the cap
+        (8, 4, 192, 8),       # a named kernel past the cap, W no power of two
+        (24, 64, 512, None),  # R = 24: the budget tile of 16 snaps to 12
+        (14, 64, 1024, None), # R = 14: the budget tile of 8 snaps to 7
+        (32, 64, 256, None),  # the largest block that ran on v5e, whole
+    ],
+)
+def test_pallas_kernel_matches_numpy_median(r, s, w, tile):
+    """The one kernel against NumPy's masked median, bit for bit, over the
+    shapes the deleted kernels' tests used."""
     from tpu_resiliency.ops import scoring_pallas as sp
-    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
 
-    assert sp.mode_rank_tile("radix", 64, 128) == 32  # largest proven block: no shrink
-    assert sp.mode_rank_tile("radix", 64, 256) == 16  # one halving
-    assert sp.mode_rank_tile("radix", 64, 512) == 8
-    assert sp.mode_rank_tile("radix", 1, 32) == 32  # tiny shapes never shrink
-    assert sp.mode_rank_tile("radix", 64, 2**20) == 1  # halving helper floors at 1...
-    # ...but a single rank-row over budget is rejected outright: no tile fits.
-    assert sp._snap_tile("radix", 32, 64, 8192) is None
-    assert not sp.pallas_supported(32, mode="radix", window=8192, signals=64)
-    with pytest.raises(ValueError, match="radix mode at window 8192"):
-        fused_median_weights(
-            jnp.zeros((2, 64, 8192), jnp.float32),
-            jnp.zeros((2, 64), jnp.int32),
+    data, counts = _edge_windows(100 + r + s + w, r, s, w)
+    if (r, s, w) == (4, 2, 8):
+        data[:] = 3.0
+        counts[:] = 8
+    if tile is None:
+        want = {(24, 64, 512): 12, (14, 64, 1024): 7, (32, 64, 256): 32}
+        assert sp._snap_tile(r, s, w) == want.get((r, s, w), min(r, 32))
+    med, wt = sp.fused_median_weights(
+        jnp.asarray(data), jnp.asarray(counts), rank_tile=tile, interpret=True
+    )
+    exp_med, exp_wt = _numpy_median_weights(data, counts)
+    np.testing.assert_array_equal(np.asarray(med), exp_med)
+    np.testing.assert_allclose(np.asarray(wt), exp_wt, rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "r,s,w,why",
+    [
+        (31, 64, 512, "shatter"),    # tile 16 has no divisor of 31 within 4x
+        (2, 64, 16384, "exceeds"),   # one rank-row over the block budget
+        (2, 4100, 128, "exceeds"),
+    ],
+)
+def test_pallas_kernel_refuses_shapes_outside_its_budget(r, s, w, why):
+    """Shapes that arrive from outside: the gate (when it knows S) and the
+    kernel both say no, loudly, where a default tile would shatter the grid
+    or no tile fits; an explicit tile is honoured or fails on divisibility."""
+    from tpu_resiliency.ops import scoring_pallas as sp
+
+    assert sp._snap_tile(r, s, w) is None
+    assert not sp.pallas_supported(r, window=w, signals=s)
+    with pytest.raises(ValueError, match=f"median kernel at window {w}.*{why}"):
+        sp.fused_median_weights(
+            jnp.zeros((r, s, w), jnp.float32), jnp.zeros((r, s), jnp.int32),
             interpret=True,
-            mode="radix",
         )
 
-    # A rank count the gate admits (R % min(32, R) == 0) but the shrunk tile
-    # does not divide: the default path snaps to the largest dividing tile
-    # instead of raising at score time (gate has no S to mirror the shrink
-    # unless told the signal count).
-    r, s, w = 24, 64, 256
-    assert sp.pallas_supported(r, mode="radix", window=w)
-    assert sp.pallas_supported(r, mode="radix", window=w, signals=s)
-    rng24 = np.random.default_rng(5)
-    d24 = rng24.uniform(0.5, 2.0, (r, s, w)).astype(np.float32)
-    c24 = rng24.integers(0, w + 1, (r, s)).astype(np.int32)
-    m24, _ = fused_median_weights(
-        jnp.asarray(d24), jnp.asarray(c24), interpret=True, mode="radix",
-    )
-    n = c24[17, 33]
-    v = np.sort(d24[17, 33, :n])
-    assert np.asarray(m24)[17, 33] == np.float32(0.5 * (v[(n - 1) // 2] + v[n // 2]))
 
-    # Near-prime R past the budget: the snap would shatter the grid into
-    # [1, S, W] blocks — both gate (when it knows S) and kernel reject loudly
-    # instead of silently running far slower than the XLA sort.
-    assert sp._snap_tile("radix", 31, 64, 256) is None
-    assert not sp.pallas_supported(31, mode="radix", window=256, signals=64)
-    assert sp.pallas_supported(31, mode="radix", window=256)  # S unknown: permissive
-    with pytest.raises(ValueError, match="radix mode at window 256"):
-        fused_median_weights(
-            jnp.zeros((31, 64, 256), jnp.float32),
-            jnp.zeros((31, 64), jnp.int32),
-            interpret=True,
-            mode="radix",
-        )
-    # Small worlds are NOT degenerate: one whole-R block is a single grid step.
-    assert sp._snap_tile("radix", 4, 64, 256) == 4
-    assert sp.pallas_supported(4, mode="radix", window=256, signals=64)
+def test_pallas_budget_tile_and_explicit_tile():
+    from tpu_resiliency.ops import scoring_pallas as sp
 
-    # Explicit rank_tile is honored unchanged through the budget path: the
-    # caller asked for 32-rank blocks at W=256 and must get them.
-    r32 = 32
-    d32 = rng24.uniform(0.5, 2.0, (r32, s, w)).astype(np.float32)
-    c32 = rng24.integers(0, w + 1, (r32, s)).astype(np.int32)
-    m_def, _ = fused_median_weights(
-        jnp.asarray(d32), jnp.asarray(c32), interpret=True, mode="radix",
-    )
-    m_exp, _ = fused_median_weights(
-        jnp.asarray(d32), jnp.asarray(c32), interpret=True, mode="radix",
-        rank_tile=32,
-    )
-    np.testing.assert_array_equal(np.asarray(m_def), np.asarray(m_exp))
-    # ...and an explicit non-dividing tile hits the divisibility error — the
-    # snap (which would have repaired 16 -> 12 at R=24) must not touch it.
+    assert sp.budget_rank_tile(64, 256) == 32  # the proven block: no shrink
+    assert sp.budget_rank_tile(64, 512) == 16  # one halving
+    assert sp.budget_rank_tile(1, 32) == 32  # tiny shapes never shrink
+    assert sp.budget_rank_tile(64, 2**20) == 1  # the halving floors at 1
+    # Small worlds are not degenerate: one whole-R block is a single grid step.
+    assert sp._snap_tile(4, 64, 256) == 4
+    # The gate says what the kernel at its default tile will say.
+    assert sp.pallas_supported(31, window=128, signals=64)  # one block of 31
+    assert not sp.pallas_supported(31, window=128, signals=256)  # tile 16 -> 1
+    # An explicit non-dividing tile hits the divisibility error: the snap
+    # (which would have repaired 16 -> 12 at R = 24) must not touch it.
     with pytest.raises(ValueError, match="not divisible"):
-        fused_median_weights(
-            jnp.asarray(d24), jnp.asarray(c24), interpret=True, mode="radix",
-            rank_tile=16,
+        sp.fused_median_weights(
+            jnp.zeros((24, 64, 256), jnp.float32), jnp.zeros((24, 64), jnp.int32),
+            interpret=True, rank_tile=16,
         )
 
 
-def test_loop_block_budget_mirrors_radix_guard():
+def test_loop_block_budget_at_many_signals():
     """The loop kernel's proven block is 32x64x256; a many-signal config at the
     raised W=128 cap would exceed it at the default tile, so the same shrink /
     loud-reject machinery applies (the cap raise must not re-open an unproven
@@ -359,14 +272,14 @@ def test_loop_block_budget_mirrors_radix_guard():
     from tpu_resiliency.ops.scoring_pallas import fused_median_weights
 
     # 32*256*128 = 2x the proven loop block: tile halves to 16.
-    assert sp._snap_tile("loop", 32, 256, 128) == 16
-    assert sp.pallas_supported(32, mode="loop", window=128, signals=256)
+    assert sp._snap_tile(32, 256, 128) == 16
+    assert sp.pallas_supported(32, window=128, signals=256)
     rng = np.random.default_rng(21)
     r, s, w = 32, 256, 128
     data = rng.uniform(0.5, 2.0, (r, s, w)).astype(np.float32)
     counts = rng.integers(0, w + 1, (r, s)).astype(np.int32)
     med, _ = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), interpret=True, mode="loop"
+        jnp.asarray(data), jnp.asarray(counts), interpret=True
     )
     med = np.asarray(med)
     for i in range(0, r, 11):
@@ -378,107 +291,70 @@ def test_loop_block_budget_mirrors_radix_guard():
             else:
                 assert med[i, j] == np.inf
 
-    # A single rank-row past the loop budget (S*W > 32*64*256): gate rejects
-    # (with signals), kernel raises loudly.
-    assert sp._snap_tile("loop", 8, 4100, 128) is None
-    assert not sp.pallas_supported(8, mode="loop", window=128, signals=4100)
-    with pytest.raises(ValueError, match="loop mode at window"):
-        fused_median_weights(
-            jnp.zeros((2, 4100, 128), jnp.float32),
-            jnp.zeros((2, 4100), jnp.int32),
-            interpret=True,
-            mode="loop",
-        )
 
-
-def test_radix_default_tile_end_to_end_at_failing_shape():
-    """End-to-end at the shape whose compile failed on-device (32x64x256):
-    the default radix tile must shrink to 16 and the kernel must still match
-    numpy (interpret mode)."""
-    from tpu_resiliency.ops.scoring_pallas import fused_median_weights
-
-    rng = np.random.default_rng(3)
-    r, s, w = 32, 64, 256
-    data = rng.uniform(0.5, 2.0, (r, s, w)).astype(np.float32)
-    counts = rng.integers(0, w + 1, (r, s)).astype(np.int32)
-    med, wt = fused_median_weights(
-        jnp.asarray(data), jnp.asarray(counts), interpret=True, mode="radix",
-    )
-    med = np.asarray(med)
-    for i in range(0, r, 7):
-        for j in range(0, s, 13):
-            n = counts[i, j]
-            if n == 0:
-                assert med[i, j] == np.inf
-            else:
-                valid = np.sort(data[i, j, :n])
-                expect = 0.5 * (valid[(n - 1) // 2] + valid[n // 2])
-                assert med[i, j] == np.float32(expect), (i, j, n)
-
-
-def test_pallas_window_gate(monkeypatch):
-    """Auto-selection must not hand a large-window user an O(W^2) kernel: the
-    quadratic modes cap at the measured crossover (env-overridable once the
-    per-device sweep has run); mode-auto switches to radix instead of
-    falling back to the XLA sort."""
-    from tpu_resiliency.ops import scoring_pallas as sp
-
-    # Shape gating alone (no window): unchanged behavior.
-    assert sp.pallas_supported(32)
-    assert not sp.pallas_supported(33)
-    # Mode-auto: past the cap (measured at 128 on v5e) the mode would be
-    # radix, but auto-selection requires the device-measured opt-in;
-    # explicit radix always works.
-    assert sp.pallas_supported(32, window=32)
-    assert not sp.pallas_supported(32, window=256)
-    assert sp.auto_mode(128) == "loop"
-    assert sp.auto_mode(256) == "radix"
-    monkeypatch.setenv(sp.RADIX_ENV, "on")
-    assert sp.pallas_supported(32, window=256)
-    assert sp.pallas_supported(32, window=512)
-    monkeypatch.delenv(sp.RADIX_ENV)
-    # Explicit quadratic modes stay capped.
-    assert sp.pallas_supported(32, mode="loop", window=128)
-    assert not sp.pallas_supported(32, mode="loop", window=256)
-    # Pairwise carries its own measured bound (compiles only at W=32 on v5e),
-    # independent of the loop cap.
-    assert sp.pallas_supported(32, mode="pairwise", window=32)
-    assert not sp.pallas_supported(32, mode="pairwise", window=64)
-    assert not sp.pallas_supported(32, mode="pairwise", window=256)
-    # ...and, when the gate knows S, the kernel's near-prime S-fold rejection
-    # too (S=37 has no fold divisor in [8, 32]; S=48 folds at 24).
-    assert not sp.pallas_supported(32, mode="pairwise", window=32, signals=37)
-    assert sp.pallas_supported(32, mode="pairwise", window=32, signals=48)
-    assert sp.pallas_supported(32, mode="radix", window=256)
-    # Operator encoded a smaller measured crossover for their device: the
-    # loop kernel's reach shrinks and auto-select hands W=64 to radix.
-    monkeypatch.setenv(sp.MAX_WINDOW_ENV, "32")
-    assert sp.auto_mode(64) == "radix"
-    assert sp.pallas_supported(32, mode="loop", window=32)
-    assert not sp.pallas_supported(32, mode="loop", window=64)
-    monkeypatch.setenv(sp.MAX_WINDOW_ENV, "junk")
-    assert sp.max_auto_window() == sp.DEFAULT_MAX_WINDOW
-
-
-def test_mesh_telemetry_autoselect_large_window(monkeypatch):
-    """MeshTelemetry(use_pallas=None) at large windows: XLA until the radix
-    kernel's device measurement is opted in, then the Pallas radix path."""
+@pytest.mark.parametrize("window", [32, 128, 129, 256, 512])
+def test_pallas_window_gate(window, monkeypatch):
+    """Auto-selection must not hand a large-window user an O(W^2) kernel: up
+    to the cap the sweep set (128) the kernel, past it the XLA sort — and the
+    scores of the path taken equal the host path's. No environment variable
+    moves the cap (PR 47 deleted the two that did)."""
     import jax
-    import numpy as np
     from jax.sharding import Mesh
 
     from tpu_resiliency.ops import scoring_pallas as sp
     from tpu_resiliency.telemetry.sharded import MeshTelemetry
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("TPU_RESILIENCY_PALLAS_RADIX", "1")
+    monkeypatch.setenv("TPU_RESILIENCY_PALLAS_MAX_WINDOW", "32")
+    admitted = window <= 128
+    assert sp.MAX_WINDOW == 128
+    assert sp.pallas_supported(32, window=window, signals=4) is admitted
+    assert sp.pallas_supported(33, window=window, signals=4) is admitted  # 3 x 11
+    assert not sp.pallas_supported(37, window=window, signals=4)  # prime: shatters
+
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("rank",))
-    try:
-        mt_small = MeshTelemetry(mesh, "rank", n_ranks=32, window=32)
-        mt_large = MeshTelemetry(mesh, "rank", n_ranks=32, window=256)
-        monkeypatch.setenv(sp.RADIX_ENV, "on")
-        mt_large_opted = MeshTelemetry(mesh, "rank", n_ranks=32, window=256)
-    finally:
-        monkeypatch.undo()
-    assert mt_small.use_pallas is True
-    assert mt_large.use_pallas is False
-    assert mt_large_opted.use_pallas is True
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        mt = MeshTelemetry(mesh, "rank", n_ranks=8, window=window,
+                           signal_names=("a", "b", "c", "d"))
+    assert mt.use_pallas is admitted
+
+    rng = np.random.default_rng(window)
+    r, s = 8, 4
+    data = rng.uniform(0.5, 2.0, (r, s, window)).astype(np.float32)
+    data[3] *= 2.0
+    counts = rng.integers(1, window + 1, (r, s)).astype(np.int32)
+    args = (jnp.asarray(data), jnp.asarray(counts), jnp.ones(r),
+            jnp.full((r, s), jnp.inf))
+    host = scoring.score_round(*args)
+    scorer = scoring.make_sharded_scorer(mesh, "rank", use_pallas=mt.use_pallas)
+    got = scorer(*args)
+    np.testing.assert_allclose(np.asarray(got.perf), np.asarray(host.perf), rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(got.straggler), np.asarray(host.straggler))
+    assert np.asarray(got.straggler)[3]
+
+
+def test_mesh_telemetry_autoselect_large_window(monkeypatch):
+    """MeshTelemetry(use_pallas=None) on a TPU: the kernel at the default
+    window, the XLA sort at a large one — whatever the environment says
+    (``$TPU_RESILIENCY_PALLAS_RADIX`` opened the large window to a second
+    kernel until PR 47)."""
+    import jax
+    from jax.sharding import Mesh
+
+    from tpu_resiliency.telemetry.sharded import MeshTelemetry
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("rank",))
+    picked = {}
+    for env in (None, "1"):
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            if env is not None:
+                m.setenv("TPU_RESILIENCY_PALLAS_RADIX", env)
+            picked[env] = [
+                MeshTelemetry(mesh, "rank", n_ranks=32, window=w).use_pallas
+                for w in (32, 256)
+            ]
+    assert picked[None] == [True, False]
+    assert picked["1"] == picked[None]

@@ -119,7 +119,7 @@ def _write_containers_stream(writes, snapshot, cleanup=()) -> None:
     def chunks(prefix, indices):
         # One pass feeds both the file and the integrity trailer: each leaf's
         # CRC is taken from the same resolved view the writer streams, so the
-        # v2 checksums cost no extra payload read.
+        # checksums cost no extra payload read.
         ck = ckpt_format.Checksummer(prefix)
         yield prefix
         for i in indices:

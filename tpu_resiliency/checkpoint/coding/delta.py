@@ -142,7 +142,7 @@ def encode_delta(
     Raises :class:`CheckpointError` when the new container is not chain-
     compatible with the base (manifest geometry moved) — callers fall back
     to a keyframe."""
-    info = ckpt_format.parse_trailer_v3(trailer, source="delta-encode")
+    info = ckpt_format.parse_trailer(trailer, source="delta-encode")
     leaf_sizes = [memoryview(v).nbytes for v in leaf_views]
     if (
         info.chunk_size != base["chunk_size"]
@@ -234,10 +234,6 @@ def apply_delta(frame, base_path: str, out_path: str) -> int:
         raise CheckpointError(
             f"delta: base container {base_path} unusable ({e})"
         ) from e
-    if base_info is None or base_info.chunk_crcs is None:
-        raise CheckpointError(
-            f"delta: base container {base_path} carries no chunk manifest"
-        )
     if base_info.container_crc != header["base_container_crc"]:
         raise CheckpointError(
             f"delta: base container {base_path} is not the frame's base "
@@ -250,7 +246,7 @@ def apply_delta(frame, base_path: str, out_path: str) -> int:
         raise CheckpointError(
             f"delta: base container {base_path} geometry mismatch"
         )
-    new_info = ckpt_format.parse_trailer_v3(
+    new_info = ckpt_format.parse_trailer(
         header["trailer"], source=os.path.basename(out_path)
     )
     new_chunks = new_info.leaf_chunk_crcs(leaf_sizes)

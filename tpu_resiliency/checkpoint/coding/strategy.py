@@ -226,9 +226,8 @@ def reconstruct_container(
             f"{source}: reconstructed container failed verification ({e})"
         ) from e
     if not ok:
-        # Unverifiable (v1 container / foreign algo): fall back on the digest
-        # the artifacts recorded — the last 4 trailer bytes are the container
-        # digest in every signed format version.
+        # Unverifiable (foreign algo): fall back on the digest the artifacts
+        # recorded — the last 4 trailer bytes are the container digest.
         if len(blob) < 4 or struct.unpack("<I", blob[-4:])[0] != ref[
             "container_crc"
         ]:

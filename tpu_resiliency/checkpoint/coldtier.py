@@ -408,10 +408,10 @@ class ColdTier:
         The manifest is written LAST — it is the visibility point, so any
         torn/failed artifact upload leaves nothing a reader would trust."""
         header, prefix_len, info = ckpt_format.read_trailer(path)
-        if info is None or not info.verifiable:
+        if not info.verifiable:
             raise CheckpointError(
                 f"{path}: container carries no verifiable integrity record "
-                f"(v1 or foreign algorithm) — refusing unverifiable archive"
+                f"(foreign algorithm) — refusing unverifiable archive"
             )
         leaf_sizes = [int(s["nbytes"]) for s in header["leaves"]]
         akey = artifact_key(self.session, iteration, owner)
@@ -459,11 +459,7 @@ class ColdTier:
                 f"cold tier: {akey} landed torn ({landed} of "
                 f"{crc_state['total']} bytes)"
             )
-        chunk_lists = (
-            info.leaf_chunk_crcs(leaf_sizes)
-            if info.chunk_crcs is not None
-            else [None] * len(leaf_sizes)
-        )
+        chunk_lists = info.leaf_chunk_crcs(leaf_sizes)
         manifest = {
             "format": MANIFEST_FORMAT,
             "session": self.session,
@@ -477,8 +473,7 @@ class ColdTier:
             "chunk_size": info.chunk_size,
             "leaves": [
                 {"nbytes": n, "crc32c": int(info.leaf_crcs[i]),
-                 **({"chunks": [int(c) for c in chunk_lists[i]]}
-                    if chunk_lists[i] is not None else {})}
+                 "chunks": [int(c) for c in chunk_lists[i]]}
                 for i, n in enumerate(leaf_sizes)
             ],
             "keyframe": True,
@@ -634,7 +629,7 @@ class ColdTier:
             raise CheckpointError(
                 f"cold tier: {doc['key']} header fails manifest digest"
             )
-        _, header, _ = ckpt_format._read_prefix(
+        header, _ = ckpt_format._read_prefix(
             io.BytesIO(prefix), str(doc["key"])
         )
         return doc, header
@@ -645,8 +640,8 @@ class ColdTier:
         """Ranged payload fetch: ``ranges`` are leaf-relative ``(leaf, off,
         nbytes)`` like the peer serve path. Each request pulls only the
         covering chunk span and verifies every covering chunk against the
-        manifest before slicing — O(needed bytes), fail-closed. Containers
-        archived without a chunk manifest (v2-era) fall back to whole-leaf
+        manifest before slicing — O(needed bytes), fail-closed. A manifest
+        that lists no chunks for a leaf falls back to whole-leaf
         fetch+verify."""
         doc = self.manifest(iteration, owner)
         if doc is None:

@@ -17,7 +17,7 @@ that re-checks cross-rank coverage and prunes superseded iterations
 (``base_manager.py:277-304``).
 
 **Recovery ladder.** ``load`` no longer trusts disk: every shard read is
-checksum-verified (container format v2, ``checkpoint/format.py``), and a rank
+checksum-verified (``checkpoint/format.py``), and a rank
 whose copy fails climbs a ladder instead of raising —
 
 1. **quarantine** the damaged file (rename to ``*.corrupt-<ts>``, one
@@ -71,6 +71,9 @@ import pickle
 
 log = get_logger(__name__)
 
+#: Bounded worker count for the reshard hot path (serve-side pread +
+#: chunk-verify fan-out, load-side peer-fetch overlap).
+RESHARD_WORKERS = 4
 _FILE_RE = re.compile(r"^iter_(\d{7})_(\d+)_local\.ckpt$")
 #: Erasure block artifact (``checkpoint/coding/strategy.py``): the filename
 #: self-describes ``(iteration, owner, index, k, m)`` so coverage math and
@@ -104,10 +107,7 @@ def _write_blobs(paths_and_blobs: list[tuple[str, Any]]) -> None:
 
     Each value is a single bytes-like (a receive buffer) or a list of parts (a
     ``serialize_parts`` result) — either way the payload streams to disk with no
-    joined copy (``format.write_parts``). Writer parallelism for single blobs
-    follows the ``$TPU_RESILIENCY_CKPT_STRIPES`` storage-class knob
-    (``format.write_blob``); default is single-stream, the measured winner on
-    plain host storage."""
+    joined copy (``format.write_parts``, ``format.write_blob``)."""
     import time as _time
 
     t0 = _time.perf_counter()
@@ -767,7 +767,7 @@ class LocalCheckpointManager:
         if not self._delta.enabled or repl is None:
             return
         try:
-            info = ckpt_format.parse_trailer_v3(
+            info = ckpt_format.parse_trailer(
                 memoryview(views[-1]).cast("B"), source="delta-base"
             )
         except CheckpointError:
@@ -1346,14 +1346,14 @@ class LocalCheckpointManager:
         """Parse (once per file version) a held container's geometry: header
         prefix length, per-leaf payload offsets/specs, hollow bytes and meta.
 
-        Integrity is version-aware: a ``TPURES03`` container's chunk manifest
-        loads here in O(trailer) — two small reads — and every byte the
-        reshard path later serves or slices is verified CHUNK-GRANULAR on
-        first touch (``_read_ranges``), so serving a 4 KB range never pays a
-        whole-container CRC scan (the serve-side stall of format v2).
-        Pre-chunk containers (``TPURES02``/v1/foreign algo) keep
-        the one-time full streaming pass. A corrupt container is quarantined
-        and surfaces as CheckpointError either way."""
+        The container's chunk manifest loads here in O(trailer) — two small
+        reads — and every byte the reshard path later serves or slices is
+        verified CHUNK-GRANULAR on first touch (``_read_ranges``), so serving
+        a 4 KB range never pays a whole-container CRC scan. A container
+        signed by a checksum algorithm this host lacks takes one full
+        streaming pass instead, which records its ``ckpt_unverified``
+        verdict. A corrupt container (a head of another format included) is
+        quarantined and surfaces as CheckpointError either way."""
         path = self._path(CkptID(iteration, owner, self.session))
         try:
             st = os.stat(path)
@@ -1375,12 +1375,10 @@ class LocalCheckpointManager:
             raise CheckpointError(f"{path}: corrupt container ({e})") from e
         except OSError as e:
             raise CheckpointError(f"{path}: unreadable shard ({e!r})") from e
-        chunked = (
-            info is not None and info.chunk_crcs is not None and info.verifiable
-        )
+        chunked = info.verifiable
         if not chunked:
-            # No chunk manifest to verify ranges against: fall back to the
-            # one-time whole-file pass (old behavior, cached per file version).
+            # No manifest this host can verify ranges against: fall back to
+            # the one-time whole-file pass (cached per file version).
             status, detail = ckpt_format.verify_file(path)
             if status == "corrupt":
                 self._quarantine(
@@ -1419,28 +1417,16 @@ class LocalCheckpointManager:
         self._reshard_cache[path] = (key, geom)
         return geom
 
-    @staticmethod
-    def _reshard_io_threads() -> int:
-        """Bounded worker count for the reshard hot path (serve-side pread +
-        chunk-verify fan-out, load-side peer-fetch overlap). Tunable via
-        ``TPU_RESILIENCY_RESHARD_IO_THREADS``; ``1`` restores the serial
-        path exactly."""
-        try:
-            n = int(os.environ.get("TPU_RESILIENCY_RESHARD_IO_THREADS", "4"))
-        except ValueError:
-            n = 4
-        return max(1, n)
-
     def _read_ranges(
         self, iteration: int, owner: int, ranges: list
     ) -> list[bytes]:
         """pread leaf-relative byte ranges out of a locally-held container;
         ``ranges`` items are ``(leaf, src_off, nbytes)``.
 
-        Verification is O(range) on chunked (``TPURES03``) containers: only
-        the chunks covering each requested range are CRC-checked, on first
-        touch (verdicts cached per file version). Pre-chunk containers were
-        verified whole by ``_container_geometry``. A chunk that fails its CRC
+        Verification is O(range): only the chunks covering each requested
+        range are CRC-checked, on first touch (verdicts cached per file
+        version). A container of a foreign checksum algorithm was passed
+        whole by ``_container_geometry``. A chunk that fails its CRC
         quarantines the container and raises — the caller's degraded-holder /
         recovery machinery owns the retry.
 
@@ -1480,7 +1466,7 @@ class LocalCheckpointManager:
                     )
                 return buf
 
-            workers = min(self._reshard_io_threads(), len(checked))
+            workers = min(RESHARD_WORKERS, len(checked))
             if workers > 1:
                 with concurrent.futures.ThreadPoolExecutor(
                     max_workers=workers, thread_name_prefix="reshard-io"
@@ -1569,7 +1555,7 @@ class LocalCheckpointManager:
                 f"not {session}"
             )
         parts = self._read_ranges(iteration, owner, ranges)
-        workers = min(self._reshard_io_threads(), max(1, len(ranges)))
+        workers = min(RESHARD_WORKERS, max(1, len(ranges)))
         record_event(
             "checkpoint", "reshard_serve", rank=self.rank, iteration=iteration,
             owner=owner, ranges=len(ranges),
@@ -2001,7 +1987,7 @@ class LocalCheckpointManager:
                 futs = []
                 if batches:
                     if pool is None:
-                        workers = min(self._reshard_io_threads(), len(batches))
+                        workers = min(RESHARD_WORKERS, len(batches))
                         pool = concurrent.futures.ThreadPoolExecutor(
                             max_workers=max(1, workers),
                             thread_name_prefix="reshard-fetch",

@@ -31,12 +31,13 @@ log = get_logger(__name__)
 
 
 def _verify_received(payload, src: int, stage: str) -> bool:
-    """Verify-on-receive: checksum a peer-delivered container against its v2
+    """Verify-on-receive: checksum a peer-delivered container against its
     trailer. Returns True to keep the payload; False (after one
     ``ckpt_integrity_failure`` event → ``tpu_ckpt_integrity_failures_total``)
     to treat the frame like a degraded peer's — dropped, never loaded.
-    Payloads that aren't v2 containers (v1 format, raw blobs) pass through
-    unverified; the format layer records those separately."""
+    Payloads that aren't containers (raw blobs) pass through unverified, as
+    does a container signed by a foreign checksum algorithm, which the format
+    layer records."""
     try:
         ckpt_format.verify_container(payload, source=f"{stage}<-rank{src}")
         return True

@@ -2,7 +2,7 @@
 
 The local checkpoint tier saves one container per rank, each holding that
 rank's *local* block of every global array (``state_dict.py`` pops leaves in
-tree order; ``format.py`` records their shapes in the ``TPURES02`` header).
+tree order; ``format.py`` records their shapes in the container header).
 Until this module, a resumed world had to match the saving world's sharding
 exactly — losing part of a slice meant "restart blocked until capacity
 returns" (the scenario the reference's elastic agent gestures at but never

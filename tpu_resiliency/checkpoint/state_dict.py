@@ -271,7 +271,7 @@ class PyTreeStateDict:
         if n_ph != len(tensors):
             raise CheckpointError(f"expected {n_ph} tensors, got {len(tensors)}")
         # A hollow skeleton that deserialized but carries out-of-range indices
-        # (a corrupt-but-unpicklable-looking v1 container, a hand-built tree)
+        # (a corrupt-but-unpicklable-looking container, a hand-built tree)
         # must fail as a classified checkpoint error, not an IndexError.
         bad = [
             leaf.index

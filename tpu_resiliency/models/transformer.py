@@ -118,13 +118,6 @@ class TransformerConfig:
         return TransformerConfig.tiny(
             **{"n_passes": 3, "sandwich_norms": True, "exit_beta": 0.05, "norm_eps": 1e-6, **kw})
 
-    @staticmethod
-    def llama3_8b() -> "TransformerConfig":
-        return TransformerConfig(
-            vocab_size=128256, d_model=4096, n_layers=32, n_heads=32, n_kv_heads=8,
-            d_ff=14336, max_seq_len=8192, rope_theta=500000.0,
-        )
-
 
 def init_params(rng: jax.Array, cfg: TransformerConfig, *, with_mlp: bool = True) -> dict:
     """Parameter pytree with layer weights stacked on a leading [L] axis.

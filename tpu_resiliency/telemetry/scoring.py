@@ -121,7 +121,7 @@ def perf_scores(section_scores: jax.Array, weights: jax.Array, valid: jax.Array)
 def robust_z(x: jax.Array, axis_name: Optional[str] = None) -> jax.Array:
     """Median/MAD z-score along the rank axis.
 
-    The median is not a pairwise reduction, so the sharded path all-gathers the per-
+    The median is not an associative reduction, so the sharded path all-gathers the per-
     rank perf vector — R floats over ICI, the one unavoidable full-exchange, and tiny
     (16 KB at 4096 ranks) next to the [R,S,W] telemetry it replaces on the host path.
     """

@@ -129,10 +129,8 @@ class MeshTelemetry:
             # TPU at the default window (2026-07-31 capture, BASELINE.md);
             # other backends can't run the kernel, and the kernel tiles the
             # rank axis so incompatible per-shard rank counts fall back to the
-            # shape-generic XLA path. Windows past the O(W²) crossover
-            # (scoring_pallas.DEFAULT_MAX_WINDOW) auto-select the radix kernel
-            # once it is device-measured/opted-in ($TPU_RESILIENCY_PALLAS_RADIX),
-            # else stay on XLA.
+            # shape-generic XLA path, as do windows past the O(W²) crossover
+            # (scoring_pallas.MAX_WINDOW).
             from tpu_resiliency.ops.scoring_pallas import pallas_supported
 
             use_pallas = (

@@ -16,7 +16,7 @@ empty workdir, and ``--verify`` re-checks every archived artifact against its
 cold manifest's whole-file digest.
 
 ``--verify`` additionally stream-verifies every container's checksums
-(format v2 per-leaf CRCs + trailer digest, ``checkpoint/format.py``), prints a
+(per-leaf and per-chunk CRCs + trailer digest, ``checkpoint/format.py``), prints a
 per-file verdict, and exits 1 on any mismatch — an operator preflight before
 trusting a root for restart, and a CI gate after fault-injection runs.
 
@@ -350,7 +350,7 @@ def render_chunks(sessions: list[SessionInfo], out=None) -> int:
                     tag = "CORRUPT"
                 print(
                     f"  [{tag}] {path}: {rep['detail']} "
-                    f"(pre-chunk container — whole-file verdict only)",
+                    f"(no verifiable manifest — whole-file verdict only)",
                     file=out,
                 )
                 continue

@@ -729,7 +729,7 @@ def observe_record(rec: dict, reg: MetricsRegistry) -> None:
         reg.counter(
             "tpu_ckpt_unverified_total",
             "containers loaded/received without checksum verification "
-            "(v1 format or foreign checksum algorithm)",
+            "(foreign checksum algorithm)",
         ).inc()
     elif kind == "ckpt_fallback":
         reg.counter(
