@@ -512,3 +512,175 @@ def test_the_kernels_lower_at_one_query_head_a_kv_head(as_on_a_tpu):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(
         lowering_platforms=("tpu",)).as_text()
     assert mosaic_kernels(text) == AT_ONE_QUERY_HEAD_A_KV_HEAD
+
+
+# -- the block-diffusion form: a doubled stream under a mask from positions alone ------
+
+def noised_mask(block: int, clean: int) -> np.ndarray:
+    """``[2 clean, 2 clean]`` bool, query by key, built whole from ``//`` and comparisons."""
+    position = np.arange(2 * clean)
+    late, of_block = position >= clean, position % clean // block
+    q_late, k_late, q_block, k_block = late[:, None], late[None, :], of_block[:, None], of_block[None, :]
+    return ((~q_late & ~k_late & (k_block <= q_block)) | (q_late & ~k_late & (k_block < q_block))
+            | (q_late & k_late & (k_block == q_block)))
+
+
+def plain_noised_attention(q, k, v, block: int, clean: int):
+    b, t, h, dh = q.shape
+    k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(dh)
+    probs = jax.nn.softmax(jnp.where(noised_mask(block, clean), scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, t, h * dh)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("block,clean,groups", [
+    (4, 256, 1), (4, 256, 8), (16, 256, 6), (16, 384, 1), (128, 256, 6), (4, 128, 8)])
+def test_noised_kernels_equal_plain_attention_under_the_whole_mask(
+        block, clean, groups, dtype, monkeypatch):
+    """Value and the three gradients of the kernels' ``noised`` form against plain attention
+    under the whole ``[2L, 2L]`` mask, at tiles of 128 rows: a stream of 4 and of 6 tiles
+    (2 and 3 a half), blocks of 4 and of 16, a block that is a whole tile (every diagonal
+    tile all or nothing), and a half that is one tile."""
+    monkeypatch.setattr(attention, "FULL_TILE", 128)
+    q, k, v, weight = inputs(2 * clean, groups)
+    with jax.default_matmul_precision("highest"):
+        want = value_and_grads(lambda *a: plain_noised_attention(*a, block, clean), q, k, v, weight)
+    got = value_and_grads(lambda *a: attention.blocked_attention(*a, noised=(block, clean)),
+                          *(x.astype(dtype) for x in (q, k, v)), weight)
+    assert got[0].shape == (1, 2 * clean, HKV * groups * DH)
+    for a, b in zip(got[1:], (q, k, v)):
+        assert a.shape == b.shape and a.dtype == dtype
+    limits = (F32_GAP,) * 4 if dtype == jnp.float32 else (BF16_VALUE_GAP,) + (BF16_GRAD_GAP,) * 3
+    gaps = [gap(a, b) for a, b in zip(got, want)]
+    assert all(g < limit for g, limit in zip(gaps, limits)), gaps
+
+
+@pytest.mark.parametrize("path", ["kernel", "blocks"])
+def test_no_clean_row_reads_a_noised_key_and_a_noised_row_of_block_0_reads_its_own_block_alone(
+        path, monkeypatch):
+    """Bit for bit: other keys and values in the noised half leave every clean row's
+    output as it was; other keys and values everywhere but in block 0 of the noised half
+    leave the noised rows of block 0 as they were (their first visited tile holds none of
+    their keys: what it leaves in the running sums is wiped), and change every other
+    noised row."""
+    monkeypatch.setattr(attention, "FULL_TILE", 128)
+    block, clean = 4, 256
+    q, k, v, _ = inputs(2 * clean, 2)
+    other_k, other_v = (x + 1.0 for x in (k, v))
+    if path == "kernel":
+        attend = jax.jit(lambda q, k, v: attention.blocked_attention(q, k, v, noised=(block, clean)))
+    else:
+        attend = jax.jit(lambda q, k, v: pattern.noised_attention(q, k, v, block, clean, 96))
+    base = np.asarray(attend(q, k, v))
+    late = jnp.arange(2 * clean)[None, :, None, None] >= clean
+    noised_changed = np.asarray(attend(q, jnp.where(late, other_k, k), jnp.where(late, other_v, v)))
+    np.testing.assert_array_equal(noised_changed[:, :clean], base[:, :clean])
+    assert not np.array_equal(noised_changed[:, clean:], base[:, clean:])
+    own = (jnp.arange(2 * clean) >= clean) & (jnp.arange(2 * clean) < clean + block)
+    own = own[None, :, None, None]
+    rest_changed = np.asarray(attend(q, jnp.where(own, k, other_k), jnp.where(own, v, other_v)))
+    np.testing.assert_array_equal(rest_changed[:, clean:clean + block], base[:, clean:clean + block])
+    differs = np.any(rest_changed != base, axis=(0, 2))
+    assert differs[clean + block:].all() and differs[:clean].all()
+
+
+def visited_tiles(half: int) -> dict:
+    """The (query tile, key tile) pairs each kernel of the block-diffusion form takes at
+    ``half`` tiles a half, by the kernels' own walks over their grids: the forward's and
+    dQ's ``(2 half, half + 1)`` steps by query tile, dK/dV's ``(2 half, 2 half)`` by key
+    tile."""
+    by_query = [(i, int(kj)) for i in range(2 * half) for j in range(half + 1)
+                for kj, seen in [attention._noised_key_tile(np.int32(i), np.int32(j), half)]
+                if seen]
+    by_key = [(int(qi), j) for j in range(2 * half) for i in range(2 * half)
+              for qi, seen in [attention._noised_query_tile(np.int32(j), np.int32(i), half)]
+              if seen]
+    return {"fwd": by_query, "dq": by_query, "dkv": by_key}
+
+
+@pytest.mark.parametrize("half", [1, 2, 3, 8])
+def test_the_noised_walk_visits_the_tile_pairs_the_mask_leaves_and_no_other(half):
+    """The three kernels' walks over their grids, as the kernels compute them, against the
+    tiles of the whole mask that hold a pair: ``half (half + 1) + half`` of the ``4
+    half^2``, 80 of 256 at the cell's eight tiles a half (a causal walk over the doubled
+    stream would visit 136), forward, dQ and dK/dV alike; the tiles that skip the mask are
+    the ones the mask leaves whole."""
+    tile, block = 8, 4  # the walk knows tiles, not rows
+    mask = noised_mask(block, half * tile).reshape(2 * half, tile, 2 * half, tile)
+    holds_a_pair = {(i, j) for i in range(2 * half) for j in range(2 * half)
+                    if mask[i, :, j].any()}
+    whole = {(i, j) for i in range(2 * half) for j in range(2 * half) if mask[i, :, j].all()}
+    visited = visited_tiles(half)
+    assert set(visited) == {"fwd", "dq", "dkv"}
+    for kernel, pairs in visited.items():
+        assert len(pairs) == len(set(pairs)) == half * (half + 1) + half, kernel
+        assert set(pairs) == holds_a_pair, kernel
+    if half == 8:
+        assert len(visited["fwd"]) == 80 and (2 * half) * (2 * half + 1) // 2 == 136
+    uncut = {(i, j) for i, j in holds_a_pair
+             if bool(attention._noised_uncut(np.int32(i), np.int32(j), half))}
+    assert uncut == whole and len(uncut) == half * (half - 1)
+    for i, j in holds_a_pair - whole:  # the three kinds of diagonal tile, from iotas
+        for q_axis in (0, 1):
+            keep = np.asarray(attention._noised_keep(np.int32(i), np.int32(j), tile, block,
+                                                     half, q_axis))
+            want = mask[i, :, j]
+            np.testing.assert_array_equal(keep, want if q_axis == 0 else want.T)
+
+
+def test_the_noised_form_takes_the_shapes_that_tile_and_refuses_the_rest():
+    assert attention.applies_noised(8192, 128, 4, 4096)
+    assert attention.applies_noised(256, 128, 16, 128)  # a half that is one short tile
+    assert not attention.applies_noised(8192, 128, 3, 4096)  # a block's number is a shift
+    assert not attention.applies_noised(8192, 64, 4, 4096)  # heads of no whole lane group
+    assert not attention.applies_noised(8192 + 512, 128, 4, 4096)  # not two halves
+    assert not attention.applies_noised(2 * 4000, 128, 4, 4000)  # halves of no whole tiles
+    assert not attention.applies_noised(8192, 128, 1024, 4096)  # a block a tile would cut
+    q = jnp.zeros((1, 512, 2, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 512, 1, 128), jnp.bfloat16)
+    for kwargs in ({"window": 128}, {"selected": jnp.ones((1, 512, 512), bool)}):
+        with pytest.raises(ValueError, match="doubled stream"):
+            attention.blocked_attention(q, kv, kv, noised=(4, 256), **kwargs)
+    with pytest.raises(ValueError, match="doubled stream"):
+        attention.blocked_attention(q, kv, kv, noised=(3, 256))
+
+
+def test_every_kernel_of_a_diffused_layer_carries_the_core_scope(as_on_a_tpu):
+    """Lowered for the TPU (nothing compiles or runs): a full layer with head norms and no
+    gate over a doubled stream, differentiated through a ``jax.checkpoint`` that keeps the
+    kernel's two residuals: the forward once and the two backward kernels, each a custom
+    call under ``attn/full/core``, and no operand beside q, k, v and the backward's four:
+    the mask is no array."""
+    from benchmark import harness
+
+    mark = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
+    cfg = pattern.PatternConfig.tiny_diffusion(head_dim=128)
+    stream = 2048
+    assert pattern.attention_paths(cfg, stream)["full"] == {
+        "path": "kernel", "tile": 512, "walk": "noised", "block_length": 4, "clean": 1024}
+    params = jax.eval_shape(lambda: pattern.init_params(jax.random.PRNGKey(0), cfg))
+    lp = jax.tree.map(lambda w: jax.ShapeDtypeStruct(w.shape[1:], w.dtype), params["attn"]["full"])
+    x = jax.ShapeDtypeStruct((1, stream, cfg.d_model), cfg.dtype)
+    tables = pattern._rope_tables(cfg, stream)["full"]
+
+    def loss(x, lp):
+        layer = jax.checkpoint(
+            lambda x, lp: pattern._attn_block(cfg, "full", x, lp, *tables),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                attention.OUT_NAME, attention.LSE_NAME))
+        return jnp.sum(layer(x, lp).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(x, lp).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    kernels = [names[ref] for ref in re.findall(
+        r"stablehlo\.custom_call @tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
+    assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
+        "blocked_attention_dkv", "blocked_attention_dq", "blocked_attention_fwd"], kernels
+    assert all(mark.search(name) and "attn/full" in name for name in kernels), kernels
+    assert [(name, operands) for name, operands, _ in mosaic_kernels(
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(x, lp).lower(
+            lowering_platforms=("tpu",)).as_text())] == [
+        ("blocked_attention_fwd", 3), ("blocked_attention_dq", 6), ("blocked_attention_dkv", 6)]
+    assert not re.search(r"tensor<(\d+x)*2048x2048x", text)  # no [2L, 2L] value of any type

@@ -521,6 +521,58 @@ def test_ouro_train_step_fits_one_chip(one_chip, as_on_a_tpu, monkeypatch):
         assert sum(1 for name in names if mark.search(name)) > 10, scope
 
 
+def test_sdar_train_step_fits_one_chip_and_walks_the_doubled_stream_on_the_kernels(
+        one_chip, as_on_a_tpu, monkeypatch):
+    """The whole donating step of ``sdar-30b-a3b-l6-ep8`` at 1 x 4096 ids, a stream of 8,192
+    positions ``[clean ; noised]`` through every layer: 645,623,296 parameters, 7.75e9 B of
+    f32 weights and AdamW moments, AdamW at the file's 3e-6, the four groups of residuals
+    that ``kept_residuals`` gives at the chip's memory (stated here, where the CPU states
+    none) and what the step needs beside its state inside the chip's ``bytes_limit``; a
+    layer runs the forward, dQ and dK/dV kernels of the ``noised`` form once each, every one
+    under ``attn/full/core`` where ``attn.roofline`` looks; the mask is no array: no
+    ``[8192, 8192]`` value of any type is in the program; and ops stand under ``diffuse/``
+    where ``model.diffuse_ms`` looks."""
+    import re
+
+    from benchmark import harness
+    from tpu_resiliency.models import pattern
+
+    limit = 16_909_336_064  # memory_stats()["bytes_limit"] on the chip
+    monkeypatch.setattr(pattern, "device_memory_bytes", lambda: limit)
+    config, cfg = cell_config("sdar-30b-a3b-l6-ep8")
+    batch, ids = config["batch"]
+    assert pattern.attention_paths(cfg, cfg.stream(ids)) == {"full": {
+        "path": "kernel", "tile": 512, "walk": "noised", "block_length": 4, "clean": 4096}}
+    kept = pattern.kept_residuals(cfg, cfg.stream(batch * ids), limit, cfg.stream(ids))
+    assert list(kept["per_layer"]) == ["routing", "stream", "attention", "qkv"]
+    family = harness.load_family(config)
+    train_step, init_opt = family.make_train_step(cfg, optimizer=config["optimizer"])
+    params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg))
+    assert sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params)) == 645_623_296
+    opt = jax.eval_shape(init_opt, params)
+    on_chip = lambda tree: placed(tree, jax.tree.map(lambda _: one_chip, tree))  # noqa: E731
+    compiled = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt), sds((batch, ids), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 7.7e9 < mem.argument_size_in_bytes < 7.8e9
+    # 12.50e9 (compile, PR 48: 7.75e9 of arguments + 4.54e9 of temporaries + 0.21e9 of code)
+    needed = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+              + mem.generated_code_size_in_bytes)
+    assert needed < limit, needed
+    text = compiled.as_text()
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and "blocked_attention" in line]
+    kernels = {name: sum(1 for call in calls if f"blocked_attention_{name}" in call)
+               for name in ("fwd", "dq", "dkv")}
+    assert kernels == {"fwd": 6, "dq": 6, "dkv": 6} and len(calls) == 18, kernels
+    core = harness.load_by_path("layer_metrics", "scope_times").SCOPES["attn_core"]
+    assert all(core.search(call) for call in calls), calls
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    diffuse = harness.load_by_path("layer_metrics", "model.diffuse_ms").SCOPE
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert sum(1 for name in names if diffuse.search(name)) >= 4
+
+
 #: sha256 of each accepted configuration's donating step at its cell's batch, lowered for
 #: the described chip (StableHLO text, nothing compiled), as the parent of PR 39 lowers it,
 #: with each Mosaic kernel's serialized body left out: a body carries the source lines of
@@ -532,15 +584,18 @@ LOWERED_STEPS = {
     "laguna-xs2-l5-ep8": "9a71b4a41782a45a",
     "kimi-vl-a3b-l6-ep8": "21e86403b9123010",
     "keye-vl2-30b-a3b-l6-ep8": "ec74763a15e8bbb2",  # PR 46: two more names a layer
+    # as the parent of PR 48 lowers them, with the file's optimizer where it states one
+    "solar-open2-250b-l4-ep40-tp8": "8cd4db54ebcfc42c",
+    "ouro-2.6b-l8": "6d659a066881258b",
 }
 
 
 @pytest.mark.parametrize("name", list(LOWERED_STEPS))
 def test_an_accepted_configurations_lowered_step_is_what_it_was(one_chip, as_on_a_tpu, name):
-    """Heads held, the gate's form, a pattern without a rotary table and the delta kind
-    are all read from the description: with every head held, one sigmoid a head and a
-    table a kind, the four accepted configurations lower to the same program, byte for
-    byte, so none of their cells can have moved."""
+    """Heads held, the gate's form (or none), the head norms, a pattern without a rotary
+    table, the delta kind and the objective are all read from the description: with
+    next-token loss over the batch's own stream the six accepted configurations lower to
+    the same program, byte for byte, so none of their cells can have moved."""
     import hashlib
     import re
 
@@ -549,7 +604,8 @@ def test_an_accepted_configurations_lowered_step_is_what_it_was(one_chip, as_on_
     config = harness.read_json(harness.HERE, "configs", f"{name}.json")
     family = harness.load_family(config)
     cfg = family.program_config(config, config["batch"][1])
-    train_step, init_opt = family.make_train_step(cfg)
+    stated = {"optimizer": config["optimizer"]} if "optimizer" in config else {}
+    train_step, init_opt = family.make_train_step(cfg, **stated)
     params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg))
     opt = jax.eval_shape(init_opt, params)
     on_chip = lambda tree: placed(tree, jax.tree.map(lambda _: one_chip, tree))  # noqa: E731

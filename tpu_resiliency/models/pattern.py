@@ -13,7 +13,9 @@ an exact top-k of their scores, the same set for all heads; the indexer is taugh
 loss of its own), or ``delta`` (:class:`Delta`: no softmax over keys at all, a state a
 head carried along the sequence by the gated delta rule, :func:`delta_rule`). A
 description may hold a share of every layer's heads, as it holds a share of the experts
-(:attr:`PatternConfig.head_ways`). MLP: ``dense`` SwiGLU, or ``sparse``: a float32 router over all
+(:attr:`PatternConfig.head_ways`); a description may give its ``full`` and ``sliding``
+layers a norm on each head's q and k and no gate (:attr:`PatternConfig.head_norms`,
+``gate=None``). MLP: ``dense`` SwiGLU, or ``sparse``: a float32 router over all
 experts of the deployment (sigmoid or softmax scores; chosen by score, or by score plus
 a selection bias that never enters a weight and that the loss-free balancing rule
 moves), the top-k routed experts that this chip holds, and one shared SwiGLU where the
@@ -22,13 +24,30 @@ Parameters are
 stacked per kind; the layers run in the order the description gives (a Python loop:
 the kinds differ in shape, so there is no single body to scan).
 
+A description also states the objective. Without more it is next-token cross-entropy over
+a causal stream. With a :class:`Diffusion` it is block-diffusion training (SDAR-30B-A3B-Chat
+is the description the benchmark runs: hidden 2048, layers all alike, ``x <- x +
+Attn(norm(x))``, ``x <- x + MoE(norm(x))``, RMS norms with eps 1e-6, a final norm and an
+untied head; 32 query heads over 4 KV heads of 128, no bias, a norm on each head's q and k
+before the rotary, rotary theta 1e6 on the whole head, scale ``128^-0.5``, no output gate;
+a float32 softmax over 128 router outputs, top 8 renormalised, SwiGLU experts of width 768,
+no shared expert, no auxiliary loss): a step draws a noise level a block of ``block``
+positions and masks each position with that probability, runs the stack once on the clean
+copy beside the noised copy of each sequence (``2 L`` positions, the same rotary positions
+twice) under a mask that is neither causal nor a band, reads the head on the noised half
+alone, and weighs each masked position's cross-entropy against its own id by ``1 / t``.
+The draws are a function of the sequence's ids, so a resume and a plain reference replay
+them; :class:`Diffusion` states the step to the letter.
+
 Built TPU-first, static shapes throughout:
 
 - **Attention never holds a T x T array.** On a TPU, at heads of whole lane groups
   and sequences of whole tiles, a kind whose queries, keys and values have one width
   runs as the blocked kernels of ``ops/attention.py``: a tile of scores lives in VMEM,
   the key tiles a query tile cannot see are skipped (:func:`attention_paths` says which
-  path a shape takes). Off the TPU, at shapes that do not tile, or where the score
+  path a shape takes); under :class:`Diffusion` the walk over the doubled stream's tiles
+  comes from positions alone, and the mask is no array of any size. Off the TPU, at
+  shapes that do not tile, or where the score
   width differs from the value width (latent attention), the blocks below are plain
   ``jax.numpy``: sliding layers compute the band (query blocks of one window against
   their own and the previous key block), full, latent and indexed layers go by query
@@ -172,6 +191,40 @@ class Delta:
 
 
 @dataclasses.dataclass(frozen=True)
+class Diffusion:
+    """Block-diffusion training (BD3-LM's vectorised form, arXiv:2503.09573, which SDAR
+    adopts): the second objective beside next-token loss. A sequence ``x0`` of ``L`` ids is
+    cut into blocks of ``block`` consecutive positions, ``b(i) = i // block``, and a step
+    is one pass over ``2 L`` positions:
+
+    - **the draws**, a function of the sequence's own ids (no generator state lives in a
+      checkpoint: the same batch gives the same masks in a resumed run, in a re-entered
+      ``train`` function and in the reference, which writes them again from these lines):
+      ``key = fold_in(jax.random.key(noise_seed, impl="threefry2x32"), sum of the
+      sequence's ids as uint32)``; ``key_b, key_i = jax.random.split(key)``; ``u_b =
+      uniform(key_b, [ceil(L / block)], float32)``, ``u_i = uniform(key_i, [L],
+      float32)``; the level of block ``b`` is ``t_b = eps + (1 - eps) u_b`` (the linear
+      schedule of masked diffusion, a level a block); position ``i`` is masked where ``u_i
+      < t_b(i)``; ``xt = where(masked, mask_id, x0)``;
+    - **the stream** is ``[x0 ; xt]``, the clean copy beside the noised copy, both halves
+      at the rotary positions ``0 .. L - 1``, through every layer;
+    - **the mask**: a clean query ``i`` reads the clean keys ``j`` with ``b(j) <= b(i)``; a
+      noised query ``i`` reads the clean keys with ``b(j) < b(i)`` and the noised keys with
+      ``b(j) == b(i)``; nothing else is read (``ops/attention.py``'s ``noised`` form on a
+      TPU at shapes that tile, :func:`noised_attention` elsewhere);
+    - **the loss**: the final norm and the head on the noised half alone, and ``sum_i
+      masked_i / t_b(i) x NLL(logits_i, x0_i) / (B L)``: position ``i``'s logits predict
+      token ``i`` (no shift), and whether a position counts is the draw's ``masked_i``,
+      never ``xt_i == mask_id`` (``mask_id`` may turn up as data)."""
+
+    block: int
+    eps: float
+    noise_seed: int
+    #: the id a masked position carries: a row of the vocabulary held here
+    mask_id: int
+
+
+@dataclasses.dataclass(frozen=True)
 class Layer:
     attn: str  # FULL | SLIDING | LATENT | INDEXED | DELTA
     n_heads: int  # of the deployment (:attr:`PatternConfig.head_ways` says how many are here)
@@ -205,8 +258,15 @@ class PatternConfig:
     #: ``None``: the full layers turn nothing (no position enters them)
     rope_full: Optional[Rope] = Rope()
     rope_sliding: Rope = Rope()
-    #: HEAD_GATE: ``wg [d, heads]``; CHANNEL_GATE: ``wg [d, heads * head_dim]``
-    gate: str = HEAD_GATE
+    #: HEAD_GATE: ``wg [d, heads]``; CHANNEL_GATE: ``wg [d, heads * head_dim]``; ``None``:
+    #: the ``full`` and ``sliding`` layers have no output gate and no ``wg``
+    gate: Optional[str] = HEAD_GATE
+    #: the ``full`` and ``sliding`` layers norm each head's q and k before the rotary (one
+    #: weight vector each for all heads, ``q_norm`` and ``k_norm``), as the indexed kind does
+    head_norms: bool = False
+    #: the objective: ``None`` is next-token loss over a causal stream; a :class:`Diffusion`
+    #: is its masked-token loss over noised blocks on a doubled stream (every layer ``full``)
+    diffusion: Optional[Diffusion] = None
     #: the widths of the ``latent`` layers, and the rotary table of their ``d_rope`` part
     latent: Optional[Latent] = None
     rope_latent: Rope = Rope()
@@ -244,8 +304,14 @@ class PatternConfig:
                                  "their weights cannot be stacked")
         if self.route_score not in (SIGMOID, SOFTMAX):
             raise ValueError(f"unknown router score {self.route_score!r}")
-        if self.gate not in (HEAD_GATE, CHANNEL_GATE):
+        if self.gate not in (HEAD_GATE, CHANNEL_GATE, None):
             raise ValueError(f"unknown output gate {self.gate!r}")
+        if self.diffusion is not None:
+            noise = self.diffusion
+            if any(l.attn != FULL for l in self.layers):
+                raise ValueError("the block-diffusion mask is the full layers' alone")
+            if noise.block < 1 or not 0 < noise.eps < 1 or not 0 <= noise.mask_id < self.vocab_size:
+                raise ValueError(f"{noise} is no noise over a vocabulary of {self.vocab_size}")
         ways = self.head_ways
         if ways < 1:
             raise ValueError(f"head_ways {ways} is not a count of shares")
@@ -294,6 +360,12 @@ class PatternConfig:
         """Layers whose attention or MLP is of ``kind``."""
         return sum(1 for l in self.layers if kind in (l.attn, l.mlp))
 
+    def stream(self, ids: int) -> int:
+        """The positions that ``ids`` ids of a batch (or of a sequence) are in the stream
+        the layers see: twice as many under :class:`Diffusion`. What
+        :func:`attention_paths`, :func:`dispatch_rows` and :func:`kept_residuals` take."""
+        return ids if self.diffusion is None else 2 * ids
+
     @staticmethod
     def tiny(**kw) -> "PatternConfig":
         base = dict(
@@ -331,6 +403,23 @@ class PatternConfig:
             layers=(Layer(INDEXED, 8, SPARSE),) * 3,
             indexer=Indexer(n_heads=4, head_dim=8, top_k=12), rope_indexed=Rope(1e7),
             route_score=SOFTMAX, d_ff=128, d_expert=32, d_shared=0, n_experts=16, top_k=4,
+            experts_held=(0, 4), attn_block=16,
+        )
+        base.update(kw)
+        return PatternConfig(**base)
+
+    @staticmethod
+    def tiny_diffusion(**kw) -> "PatternConfig":
+        """Block diffusion (:class:`Diffusion`) over blocks of 4: full attention throughout
+        with a norm on each head's q and k and no gate, softmax routing, no shared expert,
+        no dense layer; the last row of the vocabulary is the mask's: the fifth
+        description the tests train."""
+        base = dict(
+            vocab_size=256, d_model=64, head_dim=16, n_kv_heads=2,
+            layers=(Layer(FULL, 8, SPARSE),) * 3, gate=None, head_norms=True,
+            rope_full=Rope(1e6), route_score=SOFTMAX,
+            diffusion=Diffusion(block=4, eps=1e-3, noise_seed=0, mask_id=255),
+            d_ff=128, d_expert=32, d_shared=0, n_experts=16, top_k=4,
             experts_held=(0, 4), attn_block=16,
         )
         base.update(kw)
@@ -392,7 +481,10 @@ SEEDINGS = {"decay_rate": _decay_rate, "decay_step": _decay_step}
 def describe_params(cfg: PatternConfig) -> dict:
     """The parameter tree as :class:`Leaf` descriptions. Layer weights are stacked on a
     leading axis per kind: ``attn/<full|sliding>`` and ``mlp/<dense|sparse>``, in the
-    order the layers of that kind appear. A latent layer's down-projection ``wkv_a`` and
+    order the layers of that kind appear. A full or sliding layer has ``wg`` where the
+    description has a gate, and ``q_norm`` and ``k_norm`` (one weight vector each for all
+    heads, never sharded) where it norms the heads. A latent layer's down-projection
+    ``wkv_a`` and
     its latent norm serve all heads and are never sharded; ``wq`` and the up-projection
     ``wkv_b`` have a head's columns together. An indexed layer's ``q_norm`` and ``k_norm``
     are one weight vector for all heads; its indexer (``wq_index``, ``wk_index``,
@@ -427,10 +519,14 @@ def describe_params(cfg: PatternConfig) -> dict:
             "wq": Leaf((n, d, h * dh), (None, None, "heads"), d),
             "wk": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
             "wv": Leaf((n, d, hkv * dh), (None, None, "heads"), d),
-            "wg": Leaf((n, d, h * (dh if cfg.gate == CHANNEL_GATE else 1)),
-                       (None, None, "heads"), d),
             "wo": Leaf((n, h * dh, d), (None, "heads", None), ways * h * dh),
         }
+        if cfg.gate is not None:
+            tree["attn"][kind]["wg"] = Leaf(
+                (n, d, h * (dh if cfg.gate == CHANNEL_GATE else 1)), (None, None, "heads"), d)
+        if cfg.head_norms:
+            tree["attn"][kind].update(q_norm=Leaf((n, dh), (None, None), None),
+                                      k_norm=Leaf((n, dh), (None, None), None))
     n = cfg.count(LATENT)
     if n:
         h, la = cfg.heads(LATENT), cfg.latent
@@ -716,6 +812,38 @@ def full_attention(q, k, v, block: int):
     return _heads_last(jnp.concatenate(outs, axis=3), t)
 
 
+def noised_attention(q, k, v, block: int, clean: int, rows: int):
+    """Attention over a doubled stream ``[clean ; noised]`` of ``2 clean`` rows under the
+    block-diffusion mask (:class:`Diffusion`), by query blocks of ``rows`` rows of either
+    half: a block of clean queries against the clean keys up to the end of its last
+    block; a block of noised queries against those and the noised keys of its own blocks,
+    side by side, so a row's softmax is over all its keys at once. The masks come from
+    positions alone (numpy: constants of the program, a block's own ``[rows, keys]`` and
+    never the stream's square). (The path taken off the TPU or at shapes that do not
+    tile; the kernel path walks tiles, ``ops/attention.py``.)"""
+    t = q.shape[1]
+    q, k, v = _heads_first(q, k, v)
+    of_block = np.arange(clean) // block
+    outs = []
+    for late in (False, True):
+        for start in range(0, clean, rows):
+            end = min(start + rows, clean)
+            first, last = start // block * block, min(-(-end // block) * block, clean)
+            own = of_block[start:end, None]
+            if not late:
+                mask = of_block[None, :last] <= own
+                keys, values = k[:, :, :last], v[:, :, :last]
+            else:
+                mask = np.concatenate(
+                    [of_block[None, :last] < own, of_block[None, first:last] == own], axis=1)
+                keys, values = (jnp.concatenate(
+                    [x[:, :, :last], x[:, :, clean + first:clean + last]], axis=2) for x in (k, v))
+            at = clean * late
+            outs.append(_over_kv_heads(q[:, :, :, at + start:at + end], keys, values,
+                                       jnp.asarray(mask)))
+    return _heads_last(jnp.concatenate(outs, axis=3), t)
+
+
 def index_scores(q, w, k):
     """An indexer's score of every key for every query: q ``[B, Q, J, di]`` (``J`` small
     heads), w ``[B, Q, J]`` float32 (a head's weight for the query, scaled), k
@@ -938,7 +1066,8 @@ def _widths(cfg: PatternConfig, kind: str) -> tuple[int, int]:
 
 def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     """Which path the attention products of each kind of layer take at sequences of
-    ``seq``, from what the code can see (the backend, the widths, whether the sequence
+    ``seq`` positions in the stream (:meth:`PatternConfig.stream`), from what the code can
+    see (the backend, the widths, whether the sequence
     is whole tiles): ``{kind: {"path": "kernel", "tile": rows}}`` for the blocked
     kernels of ``ops/attention.py``, ``{"path": "blocks", "block": rows}`` for the
     ``jax.numpy`` blocks. The kernels take one width for queries, keys and values, so a
@@ -953,10 +1082,23 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
     kind has no products over keys: ``{"path": "chunks", "chunk": tokens, "solve":
     "blocks"}``, the rule of :func:`delta_rule` in ``jax.numpy`` (``"kernel"`` is for a
     kernel of it, which there is not), every chunk's triangular system inverted by blocks
-    as matrix products (:func:`_unit_lower_inverse`; on every backend, at any chunk)."""
+    as matrix products (:func:`_unit_lower_inverse`; on every backend, at any chunk). Under
+    :class:`Diffusion` the full kind says the walk: ``{"walk": "noised",
+    "block_length": b, "clean": L}`` beside its path, the kernels of the ``noised`` form
+    where the backend is a TPU and both halves are whole tiles that no block crosses
+    (``attention.applies_noised``), else :func:`noised_attention` by blocks of rows."""
     paths = {}
     for kind in ATTENTION_KINDS:
         if not cfg.count(kind):
+            continue
+        if cfg.diffusion is not None:  # every layer is full: one walk, from positions
+            block, clean = cfg.diffusion.block, seq // 2
+            if jax.default_backend() == "tpu" and attention.applies_noised(
+                    seq, cfg.head_dim, block, clean):
+                paths[kind] = {"path": "kernel", "tile": attention.tile_of(clean, None)}
+            else:
+                paths[kind] = {"path": "blocks", "block": min(cfg.attn_block, clean)}
+            paths[kind].update(walk="noised", block_length=block, clean=clean)
             continue
         if kind == DELTA:  # no products over keys: the rule by chunks, in jax.numpy
             paths[kind] = {"path": "chunks", "chunk": min(cfg.delta.chunk, seq),
@@ -984,15 +1126,22 @@ def attention_paths(cfg: PatternConfig, seq: int) -> dict:
 
 
 def _products(cfg: PatternConfig, kind: str, q, k, v):
-    """Causal softmax attention of one layer by the path :func:`attention_paths` names:
+    """Softmax attention of one layer (causal; under :class:`Diffusion` its own mask over
+    the doubled stream) by the path :func:`attention_paths` names:
     q ``[B, T, H, dk]``, k ``[B, T, Hkv, dk]``, v ``[B, T, Hkv, dv]`` -> ``[B, T, H * dv]``.
     The operands and the result carry the names of :data:`KEPT_GROUPS` (the kernel names
     its own output and log-sum-exp where it makes them)."""
     q, k, v = (checkpoint_name(x, name) for x, name in zip((q, k, v), KEPT_GROUPS["qkv"]))
     with jax.named_scope("core"):
-        if attention_paths(cfg, q.shape[1])[kind]["path"] == "kernel":
+        path = attention_paths(cfg, q.shape[1])[kind]
+        if cfg.diffusion is not None:
+            noised = (path["block_length"], path["clean"])
+            if path["path"] == "kernel":
+                return attention.blocked_attention(q, k, v, noised=noised)
+            out = noised_attention(q, k, v, *noised, path["block"])
+        elif path["path"] == "kernel":
             return attention.blocked_attention(q, k, v, window=_window(cfg, kind))
-        if kind == SLIDING:
+        elif kind == SLIDING:
             out = sliding_attention(q, k, v, cfg.window)
         else:
             out = full_attention(q, k, v, cfg.attn_block)
@@ -1001,8 +1150,9 @@ def _products(cfg: PatternConfig, kind: str, q, k, v):
 
 def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos=None, sin=None):
     """Pre-norm grouped-query attention of one kind with an output gate (from the normed
-    input: one sigmoid a head, or one a channel where the description says so) and the
-    residual; the rotary where the kind has a table (``cos`` given)."""
+    input: one sigmoid a head, or one a channel where the description says so, or none)
+    and the residual; each head's q and k normed where the description says so, then the
+    rotary where the kind has a table (``cos`` given)."""
     with jax.named_scope(f"attn/{kind}"):
         b, t, _ = x.shape
         h, hkv, dh = cfg.heads(kind), cfg.kv_heads, cfg.head_dim
@@ -1010,13 +1160,17 @@ def _attn_block(cfg: PatternConfig, kind: str, x, lp: dict, cos=None, sin=None):
         q = (y @ lp["wq"].astype(y.dtype)).reshape(b, t, h, dh)
         k = (y @ lp["wk"].astype(y.dtype)).reshape(b, t, hkv, dh)
         v = (y @ lp["wv"].astype(y.dtype)).reshape(b, t, hkv, dh)
-        gate = jax.nn.sigmoid(y @ lp["wg"].astype(y.dtype))  # [B, T, H] or [B, T, H * dh]
+        if cfg.gate is not None:
+            gate = jax.nn.sigmoid(y @ lp["wg"].astype(y.dtype))  # [B, T, H] or [B, T, H * dh]
+        if cfg.head_norms:
+            q = tfm.rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = tfm.rms_norm(k, lp["k_norm"], cfg.norm_eps)
         if cos is not None:
             q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
         attn = _products(cfg, kind, q, k, v)
         if cfg.gate == CHANNEL_GATE:
             attn = attn * gate
-        else:
+        elif cfg.gate == HEAD_GATE:
             attn = (attn.reshape(b, t, h, dh) * gate[..., None]).reshape(b, t, h * dh)
         return x + attn @ lp["wo"].astype(attn.dtype)
 
@@ -1539,7 +1693,8 @@ ROW_TILE = 128
 
 def dispatch_rows(cfg: PatternConfig, n_tokens: int) -> dict:
     """How many (token, choice) pairs the expert dispatch of a sparse layer carries for
-    ``n_tokens`` tokens, from the configuration and the shapes alone: ``{"path":
+    ``n_tokens`` positions of the stream (:meth:`PatternConfig.stream` of a batch's ids),
+    from the configuration and the shapes alone: ``{"path":
     "bounded", "rows": C, "pairs": n_tokens * top_k}`` where twice the even-routing
     share of the experts held, rounded up to :data:`ROW_TILE`, is under all the pairs
     (a step whose router sends more than ``C`` pairs here carries all of them, see
@@ -1709,8 +1864,8 @@ device_memory_bytes = tfm.device_memory_bytes
 
 
 def _group_bytes(cfg: PatternConfig, spec: Layer, n_tokens: int, seq: int) -> dict:
-    """Bytes of each group of :data:`KEPT_GROUPS` in one layer over ``n_tokens`` tokens
-    in sequences of ``seq``."""
+    """Bytes of each group of :data:`KEPT_GROUPS` in one layer over ``n_tokens`` positions
+    of the stream in sequences of ``seq`` of them."""
     act = jnp.dtype(cfg.dtype).itemsize
     heads = cfg.held(spec.n_heads)
     sparse = spec.mlp == SPARSE
@@ -1773,7 +1928,8 @@ def _rule_bytes(cfg: PatternConfig, n_tokens: int, seq: int) -> int:
 def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int],
                    seq: Optional[int] = None) -> dict:
     """What each layer keeps for its backward pass beside its input, at ``n_tokens``
-    tokens a step (in sequences of ``seq``; ``None``: one sequence) on a device of
+    positions of the stream a step (:meth:`PatternConfig.stream` of the batch's ids; in
+    sequences of ``seq``; ``None``: one sequence) on a device of
     ``memory_bytes`` (``None``: no limit stated, everything is kept), from the
     configuration and the shapes alone: ``{"names": the names the
     layers' ``jax.checkpoint`` keeps, "bytes": what they hold over all layers,
@@ -1786,7 +1942,8 @@ def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int
     device gets the shorter list, down to none (``names`` empty: every layer recomputes
     its whole forward). ``step_bytes`` is
     16 B a parameter (float32 weights, two moments, gradients), every layer's input,
-    the float32 logits with their cotangent, and all the groups of the largest layer
+    the float32 logits with their cotangent (over the noised half alone under
+    :class:`Diffusion`), and all the groups of the largest layer
     once (the layer whose backward pass runs holds them, kept or recomputed), with the
     float32 values of the rule where that layer is a delta layer (:func:`_rule_bytes`)."""
     seq = seq or n_tokens
@@ -1795,7 +1952,7 @@ def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int
                    jax.tree.leaves(describe_params(cfg), is_leaf=_is_leaf))
     step_bytes = (16 * n_params
                   + len(cfg.layers) * n_tokens * cfg.d_model * jnp.dtype(cfg.dtype).itemsize
-                  + 2 * n_tokens * cfg.vocab_size * 4
+                  + 2 * (n_tokens // 2 if cfg.diffusion else n_tokens) * cfg.vocab_size * 4
                   + max(sum(groups.values())
                         + (_rule_bytes(cfg, n_tokens, seq) if spec.attn == DELTA else 0)
                         for spec, groups in zip(cfg.layers, per_layer)))
@@ -1818,7 +1975,12 @@ def kept_residuals(cfg: PatternConfig, n_tokens: int, memory_bytes: Optional[int
 # ---------------------------------------------------------------------------------
 
 def _rope_tables(cfg: PatternConfig, t: int) -> dict:
-    """{kind: its layers' rotary tables at ``t`` positions}, of the kinds that have one."""
+    """{kind: its layers' rotary tables at the ``t`` positions of the stream}, of the kinds
+    that have one. Under :class:`Diffusion` the stream's two halves are the same positions:
+    the table of ``t / 2`` positions, twice."""
+    if cfg.diffusion is not None and cfg.rope(FULL) is not None:
+        cos, sin = rope_tables(cfg.rope(FULL), cfg.rotary_width(FULL), t // 2)
+        return {FULL: (jnp.concatenate([cos, cos]), jnp.concatenate([sin, sin]))}
     tables = {kind: rope_tables(cfg.rope(kind), cfg.rotary_width(kind), t)
               for kind in ATTENTION_KINDS if cfg.count(kind) and cfg.rope(kind) is not None}
     if cfg.count(INDEXED):  # the indexer's heads turn by the same table at their own width
@@ -1838,8 +2000,47 @@ def _layer_params(params: dict, cfg: PatternConfig):
         yield spec, attn_lp, mlp_lp
 
 
+class Noise(NamedTuple):
+    """One step's draws (:class:`Diffusion`), each ``[B, L]``: the noised copy ``xt``
+    (int32), which positions the draw masked (bool), and the level ``t_b`` of each
+    position's block (float32)."""
+
+    noised: jax.Array
+    masked: jax.Array
+    level: jax.Array
+
+
+def draw_noise(tokens: jax.Array, noise: Diffusion) -> Noise:
+    """The draws of :class:`Diffusion` for tokens ``[B, L]``, to its letter: a key a
+    sequence from the sequence's own ids, a level a block, a uniform a position."""
+    length = tokens.shape[1]
+    base = jax.random.key(noise.noise_seed, impl="threefry2x32")
+
+    def of_sequence(ids):
+        key_b, key_i = jax.random.split(jax.random.fold_in(base, jnp.sum(ids.astype(jnp.uint32))))
+        level = noise.eps + (1.0 - noise.eps) * jax.random.uniform(
+            key_b, (-(-length // noise.block),), jnp.float32)
+        level = jnp.repeat(level, noise.block)[:length]
+        return jax.random.uniform(key_i, (length,), jnp.float32) < level, level
+
+    masked, level = jax.vmap(of_sequence)(tokens)
+    return Noise(jnp.where(masked, jnp.asarray(noise.mask_id, tokens.dtype), tokens), masked, level)
+
+
+def _stream(tokens: jax.Array, cfg: PatternConfig):
+    """(the ids of the stream the layers see, the step's :class:`Noise` or ``None``): the
+    batch itself, or under :class:`Diffusion` ``[x0 ; xt]`` (scope ``diffuse/noise``)."""
+    if cfg.diffusion is None:
+        return tokens, None
+    with jax.named_scope("diffuse/noise"):
+        noise = draw_noise(tokens, cfg.diffusion)
+        return jnp.concatenate([tokens, noise.noised], axis=1), noise
+
+
 def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
-    """:func:`forward`, and the sum of the sparse layers' ``balance`` (:func:`route`)."""
+    """:func:`forward`, the sum of the sparse layers' ``balance`` (:func:`route`), and the
+    step's :class:`Noise` (``None`` without :class:`Diffusion`)."""
+    tokens, noise = _stream(tokens, cfg)
     x = params["embed"].astype(cfg.dtype)[tokens]
     t = tokens.shape[1]
     tables = _rope_tables(cfg, t)
@@ -1872,16 +2073,19 @@ def _forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
             balance = layer_balance if balance is None else balance + layer_balance
         if layer_attn is not None:
             attn_counts.setdefault(spec.attn, []).append(layer_attn)
+    if noise is not None:  # the head reads the noised half alone
+        x = x[:, t // 2:]
     x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *counts) if counts else {}
     for of_kind in attn_counts.values():
         stacked.update(jax.tree.map(lambda *xs: jnp.stack(xs), *of_kind))
-    return logits, stacked, balance
+    return logits, stacked, balance, noise
 
 
 def forward(params: dict, tokens: jax.Array, cfg: PatternConfig):
-    """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32, counts: a dict of ``[sparse
+    """tokens ``[B, T]`` -> (logits ``[B, T, V]`` float32 (under :class:`Diffusion` of the
+    noised copy of the step's own draws), counts: a dict of ``[sparse
     layers]`` arrays of routing counts, see :func:`routed_experts`, and of ``[indexed
     layers]`` arrays ``index_kl``, ``keys_selected``, ``select_ties``, see
     :func:`_indexed_block`, and of ``[delta layers]`` arrays ``decay_mean``, ``beta_mean``,
@@ -1894,10 +2098,12 @@ def choices(params: dict, tokens: jax.Array, cfg: PatternConfig) -> dict:
     [indexed layers, B, T, T] bool`` (query by key: the keys each indexed layer's
     attention read), ``"experts": [sparse layers, B, T, top_k] int32`` (of all experts of
     the deployment, in :func:`route`'s order)}``, a key only where the pattern has such
-    layers. The arithmetic is the training step's own (the same blocks, the same types),
+    layers; under :class:`Diffusion` over the ``T = 2 L`` positions of the step's own stream.
+    The arithmetic is the training step's own (the same blocks, the same types),
     with nothing kept and nothing differentiated: for a comparison with other arithmetic
     on the same choices, since near a tie a rounding decides the choice, and the choice
     then moves every number downstream by far more than the rounding did."""
+    tokens = _stream(tokens, cfg)[0]
     b, t = tokens.shape
     x = params["embed"].astype(cfg.dtype)[tokens]
     tables = _rope_tables(cfg, t)
@@ -1930,9 +2136,33 @@ def loss_and_counts(params: dict, tokens: jax.Array, cfg: PatternConfig):
     balancing rule in the gradient (:func:`route`). With indexed layers it carries the
     mean of their ``index_kl``, the indexers' own loss: by the two ``stop_gradient``s of
     :func:`_indexed_block` it is the only term the indexers' leaves get a gradient from,
-    and they are the only leaves it reaches."""
-    logits, counts, balance = _forward(params, tokens, cfg)
-    loss = tfm.token_nll(logits[:, :-1], tokens[:, 1:]).mean()
+    and they are the only leaves it reaches.
+
+    Under :class:`Diffusion` the cross-entropy is its masked-token loss over the noised
+    blocks, each position's logits against its own id with the weight ``1 / t`` of its
+    block (scope ``diffuse/loss``), and the counts gain ``masked_share`` (of the
+    positions), ``weight_mean`` and ``weight_max`` (of ``1 / t`` over the masked
+    positions), ``loss_unweighted`` (the mean NLL over the masked positions) and
+    ``pairs_read`` (the mean number of keys a query of the stream reads, from positions:
+    ``(L + block) / 2`` at whole blocks)."""
+    logits, counts, balance, noise = _forward(params, tokens, cfg)
+    if noise is None:
+        loss = tfm.token_nll(logits[:, :-1], tokens[:, 1:]).mean()
+    else:
+        with jax.named_scope("diffuse/loss"):
+            nll = tfm.token_nll(logits, tokens)
+            weight = jnp.where(noise.masked, 1.0 / noise.level, 0.0)
+            loss = jnp.sum(weight * nll) / tokens.size
+            n_masked = jnp.maximum(jnp.sum(noise.masked), 1)
+            block = cfg.diffusion.block
+            counts = {
+                **counts, "masked_share": jnp.mean(noise.masked),
+                "weight_mean": jnp.sum(weight) / n_masked, "weight_max": jnp.max(weight),
+                "loss_unweighted": jnp.sum(jnp.where(noise.masked, nll, 0.0)) / n_masked,
+                # a query of either half reads the positions up to the end of its block
+                "pairs_read": jnp.float32(np.minimum(
+                    (np.arange(tokens.shape[1]) // block + 1) * block, tokens.shape[1]).mean()),
+            }
     if balance is not None:
         loss = loss + balance
     if "index_kl" in counts:

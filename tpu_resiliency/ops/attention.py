@@ -14,6 +14,22 @@ body, the bytes unpacked once for the ``G`` heads of the group, and no mask is m
 positions. A query's keys may all lie in its later tiles: the masked score is finite,
 so what the first tiles leave in the running sums is wiped by the first real maximum.
 
+A fourth form walks the tiles from positions again, under a mask that is neither causal
+nor a band (``noised``: block diffusion over a doubled stream ``[clean ; noised]``, both
+halves of ``clean`` rows cut into blocks of ``block`` positions, BD3-LM's vectorised
+training, arXiv:2503.09573). With ``b(i) = i // block``: a clean query ``i`` reads the
+clean keys ``j`` with ``b(j) <= b(i)``; a noised query ``i`` reads the clean keys with
+``b(j) < b(i)`` and the noised keys with ``b(j) == b(i)``; nothing else is read. Query
+tile ``r`` of the clean half visits the clean key tiles ``0 .. r``, query tile ``r`` of
+the noised half the clean key tiles ``0 .. r`` and the noised key tile ``r``, and no
+other: with ``n`` tiles a half, ``n (n + 1) + n`` of the ``4 n^2`` pairs (80 of 256 at
+``n = 8``). Tiles wholly below a diagonal skip the mask; the three kinds of diagonal
+tile (clean by clean: ``b(j) <= b(i)``; noised by clean: ``b(j) < b(i)``; noised by
+noised: ``b(j) == b(i)``) make theirs from iotas. A noised row of a tile's first block
+reads nothing of the clean tile on its diagonal, and a row of block 0 no clean key at
+all: every row reads its own block of the noised tile, the last it visits, so no row is
+empty (the masked score is finite, as under a selection).
+
 Arithmetic, as ``models/pattern.py:_attend`` has it: the operands go into the MXU as
 they come (bf16 in training), the scores, the row maximum, the row sum and every
 accumulator are float32, the softmax scale multiplies the float32 scores, the
@@ -87,6 +103,15 @@ def applies(seq: int, head_dim: int, window: Optional[int]) -> bool:
     return head_dim % LANES == 0 and tile % LANES == 0 and seq % tile == 0
 
 
+def applies_noised(stream: int, head_dim: int, block: int, clean: int) -> bool:
+    """Whether the kernels tile a doubled stream of ``stream`` rows whose clean half ends
+    at ``clean``, in blocks of ``block``: two halves of whole tiles, blocks that are a
+    power of two (a position's block is a shift) and that no tile cuts."""
+    tile = min(FULL_TILE, clean)
+    return (stream == 2 * clean and applies(clean, head_dim, None)
+            and block & (block - 1) == 0 and 0 < block <= tile)
+
+
 # -- which tiles see each other: with square tiles, key tile kj is seen by query tile qi
 # iff 0 <= qi - kj <= reach
 
@@ -132,17 +157,92 @@ def _visit(sel, uncut, keep, tile_body):
         pl.when(jnp.logical_not(uncut))(lambda: tile_body(keep))
 
 
+# -- the block-diffusion form: ``half`` tiles of clean rows, then ``half`` of noised rows
+
+def _halves(i, half: int):
+    """(tile ``i``'s place within its half, whether that is the noised half)."""
+    late = i >= half
+    return jnp.where(late, i - half, i), late
+
+
+def _noised_key_tile(i, j, half: int):
+    """Step ``j`` of query tile ``i``: (the key tile, whether the step is taken). Steps
+    ``0 .. r`` are the clean key tiles up to the query tile's own place ``r``; a noised
+    query tile takes one more, its own noised key tile. A step not taken names the tile
+    of the last one taken, so nothing is fetched for it."""
+    r, late = _halves(i, half)
+    kj = jnp.where(j > r, jnp.where(late, half + r, r), j)
+    return kj, (j <= r) | (late & (j == r + 1))
+
+
+def _noised_query_tile(j, i, half: int):
+    """Step ``i`` of key tile ``j``: (the query tile, whether the step is taken). A clean
+    key tile at place ``c`` is seen by the clean query tiles ``c .. half - 1``, then by the
+    noised ones at the same places; a noised key tile by the noised query tile it is."""
+    c, late = _halves(j, half)
+    qi = jnp.minimum(jnp.where(i < half - c, c + i, 2 * c + i), 2 * half - 1)
+    return jnp.where(late, j, qi), jnp.where(late, i == 0, i < 2 * (half - c))
+
+
+def _noised_uncut(qi, kj, half: int):
+    """No score of tile (qi, kj) is masked: a clean key tile before the query tile's place."""
+    return _halves(kj, half)[0] < _halves(qi, half)[0]
+
+
+def _noised_keep(qi, kj, tile, block, half: int, q_axis: int):
+    """The mask of a diagonal tile (qi, kj), ``[tile, tile]`` with the queries on
+    ``q_axis``, from the blocks' numbers within the tile (``block`` is a power of two): a
+    query's block is at or after the key's (clean by clean), after it (noised by clean),
+    or the key's own (noised by noised)."""
+    def of_block(axis):  # a row's or a column's block within the tile
+        return jax.lax.shift_right_logical(
+            jax.lax.broadcasted_iota(jnp.int32, (tile, tile), axis), block.bit_length() - 1)
+
+    ahead = of_block(q_axis) - of_block(1 - q_axis)
+    own = kj >= half
+    least = jnp.where((qi >= half) & jnp.logical_not(own), 1, 0)
+    return (ahead >= least) & (ahead <= jnp.where(own, 0, tile))
+
+
 def _lanes(x, width: int):
     """A lane-replicated ``[rows, 128]`` statistic against ``width`` columns."""
     return x if width == LANES else jnp.tile(x, (1, width // LANES))
 
 
+def _query_walk(i, j, tile, window, reach, noised):
+    """Step ``j`` of query tile ``i`` in the forward and the dQ kernel, as three functions
+    of no argument, each traced where the kernel calls it: whether the step is taken,
+    whether its tile is whole, and the tile's mask with the queries on the sublanes."""
+    if noised is None:
+        kj = jnp.maximum(i - reach, 0) + j
+        return (lambda: kj <= i, lambda: _uncut(i, kj, tile, window),
+                lambda: _keep(i, kj, tile, window, 0))
+    block, half = noised
+    kj, taken = _noised_key_tile(i, j, half)
+    return (lambda: taken, lambda: _noised_uncut(i, kj, half),
+            lambda: _noised_keep(i, kj, tile, block, half, 0))
+
+
+def _key_walk(j, i, tile, window, n, noised):
+    """Step ``i`` of key tile ``j`` in the dK/dV kernel, as :func:`_query_walk`'s three
+    functions, the masks with the queries on the lanes."""
+    if noised is None:
+        qi = j + i  # the first query tile that sees key tile j is the one on the diagonal
+        return (lambda: qi < n, lambda: _uncut(qi, j, tile, window),
+                lambda: _keep(qi, j, tile, window, 1))
+    block, half = noised
+    qi, taken = _noised_query_tile(j, i, half)
+    return (lambda: taken, lambda: _noised_uncut(qi, j, half),
+            lambda: _noised_keep(qi, j, tile, block, half, 1))
+
+
 # -- forward ----------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, groups, dh, tile, window, scale, reach):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, groups, dh, tile, window, scale, reach,
+                noised=None):
     *sel, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     i, j = pl.program_id(2), pl.program_id(3)
-    kj = jnp.maximum(i - reach, 0) + j
+    seen, uncut, keep = _query_walk(i, j, tile, window, reach, noised)
 
     @pl.when(j == 0)
     def _():
@@ -168,10 +268,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, groups, dh, tile, window, scale, rea
             acc_scr[:, head] = acc_scr[:, head] * _lanes(alpha, dh) + jnp.dot(
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    @pl.when(kj <= i)
+    @pl.when(seen())
     def _():
-        _visit(sel, _uncut(i, kj, tile, window), lambda: _keep(i, kj, tile, window, 0),
-               tile_body)
+        _visit(sel, uncut(), keep, tile_body)
 
     @pl.when(j == reach)
     def _():
@@ -205,11 +304,15 @@ class _Plan(NamedTuple):
     n: int  # tiles in the sequence
     reach: int  # a query tile sees this many key tiles before its own
     window: Optional[int]  # None where it covers the sequence
+    #: (block, tiles a half) of the block-diffusion form, whose query tile takes up to
+    #: ``reach + 1 = n / 2 + 1`` steps: the clean key tiles up to its place and its own
+    noised: Optional[tuple] = None
 
     @property
     def static(self) -> dict:
-        return dict(groups=self.groups, dh=self.dh, tile=self.tile, window=self.window,
-                    scale=float(self.dh) ** -0.5, reach=self.reach)
+        static = dict(groups=self.groups, dh=self.dh, tile=self.tile, window=self.window,
+                      scale=float(self.dh) ** -0.5, reach=self.reach)
+        return static if self.noised is None else {**static, "noised": self.noised}
 
     @property
     def grid(self):
@@ -220,6 +323,10 @@ class _Plan(NamedTuple):
                             lambda b, h, i, j: (b, i, h))
 
     def kv_block(self):  # the j-th key tile that query tile i sees
+        if self.noised is not None:
+            return pl.BlockSpec(
+                (None, self.tile, self.dh),
+                lambda b, h, i, j: (b, _noised_key_tile(i, j, self.noised[1])[0], h))
         return pl.BlockSpec(
             (None, self.tile, self.dh),
             lambda b, h, i, j: (b, jnp.minimum(jnp.maximum(i - self.reach, 0) + j, i), h))
@@ -236,9 +343,14 @@ class _Plan(NamedTuple):
         return tuple(x.reshape(self.b, self.t, -1) for x in arrays)
 
 
-def _plan(q, k, window) -> _Plan:
+def _plan(q, k, window, noised=None) -> _Plan:
     b, t, h, dh = q.shape
     hkv = k.shape[2]
+    if noised is not None:
+        block, clean = noised
+        tile = tile_of(clean, None)
+        half = clean // tile
+        return _Plan(b, t, hkv, h // hkv, dh, tile, 2 * half, half, None, (block, half))
     tile = tile_of(t, window)
     if window is not None and window >= t:
         window = None
@@ -251,8 +363,8 @@ def _given(selected) -> tuple:
     return () if selected is None else (selected,)
 
 
-def _forward(q, k, v, window, selected=None):
-    p = _plan(q, k, window)
+def _forward(q, k, v, window, selected=None, noised=None):
+    p = _plan(q, k, window, noised)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **p.static),
         grid=p.grid,
@@ -313,10 +425,10 @@ def _probs(q, k, lse, selected):
 # -- backward ---------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-               groups, dh, tile, window, scale, reach):
+               groups, dh, tile, window, scale, reach, noised=None):
     *sel, dq_ref, dq_scr = rest
     i, j = pl.program_id(2), pl.program_id(3)
-    kj = jnp.maximum(i - reach, 0) + j
+    seen, uncut, keep = _query_walk(i, j, tile, window, reach, noised)
 
     @pl.when(j == 0)
     def _():
@@ -338,10 +450,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             dq_scr[:, head] += jnp.dot(ds.astype(k.dtype), k,
                                        preferred_element_type=jnp.float32)
 
-    @pl.when(kj <= i)
+    @pl.when(seen())
     def _():
-        _visit(sel, _uncut(i, kj, tile, window), lambda: _keep(i, kj, tile, window, 0),
-               tile_body)
+        _visit(sel, uncut(), keep, tile_body)
 
     @pl.when(j == reach)
     def _():
@@ -349,10 +460,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                groups, dh, tile, window, scale, reach, n):
+                groups, dh, tile, window, scale, reach, n, noised=None):
     *sel, dk_ref, dv_ref, dk_scr, dv_scr = rest
     j, i = pl.program_id(2), pl.program_id(3)
-    qi = j + i  # the first query tile that sees key tile j is the one on the diagonal
+    seen, uncut, keep = _key_walk(j, i, tile, window, n, noised)
+    last = reach if noised is None else n - 1  # the grid's last step for a key tile
 
     @pl.when(i == 0)
     def _():
@@ -375,19 +487,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds = p * (dp - delta_ref[g:g + 1, :])
             dk_scr[...] += jnp.dot(ds.astype(q.dtype), q, preferred_element_type=jnp.float32)
 
-    @pl.when(qi < n)
+    @pl.when(seen())
     def _():
-        _visit(sel, _uncut(qi, j, tile, window), lambda: _keep(qi, j, tile, window, 1),
-               tile_body)
+        _visit(sel, uncut(), keep, tile_body)
 
-    @pl.when(i == reach)
+    @pl.when(i == last)
     def _():
         dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _backward(q, k, v, out, lse, do, window, selected=None):
-    p = _plan(q, k, window)
+def _backward(q, k, v, out, lse, do, window, selected=None, noised=None):
+    p = _plan(q, k, window, noised)
     # each row's sum of (output x its cotangent): what the softmax's backward subtracts
     delta = jnp.sum((out.astype(jnp.float32) * do.astype(jnp.float32))
                     .reshape(p.b, p.t, p.hkv, p.groups, p.dh), axis=-1).transpose(0, 2, 1, 3)
@@ -406,8 +517,11 @@ def _backward(q, k, v, out, lse, do, window, selected=None):
 
     # the dK/dV kernel's grid is (B, Hkv, key tile, query tile that sees it); its scores
     # have the queries on the lanes, so the row statistics go in with the sequence last,
-    # and a selection key by query
+    # and a selection key by query. In the block-diffusion form a clean key tile is seen
+    # by up to all the query tiles of both halves
     def seen(j, i):
+        if p.noised is not None:
+            return _noised_query_tile(j, i, p.noised[1])[0]
         return jnp.minimum(j + i, p.n - 1)
 
     q_seen = pl.BlockSpec((None, p.tile, p.groups * p.dh), lambda b, h, j, i: (b, seen(j, i), h))
@@ -417,7 +531,7 @@ def _backward(q, k, v, out, lse, do, window, selected=None):
         pl.BlockSpec((None, p.tile, p.tile), lambda b, h, j, i: (b, j, seen(j, i)))]
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, n=p.n, **p.static),
-        grid=p.grid,
+        grid=p.grid if p.noised is None else (p.b, p.hkv, p.n, p.n),
         in_specs=[q_seen, kv_own, kv_own, q_seen, stats, stats, *sel_seen],
         out_specs=[kv_own, kv_own],
         out_shape=[jax.ShapeDtypeStruct(operands[1].shape, k.dtype)] * 2,
@@ -446,6 +560,24 @@ def _attention_bwd(window, residuals, do):
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _noised_attention(q, k, v, noised):
+    return _forward(q, k, v, None, noised=noised)[0]
+
+
+def _noised_attention_fwd(q, k, v, noised):
+    out, lse = _forward(q, k, v, None, noised=noised)
+    out, lse = checkpoint_name(out, OUT_NAME), checkpoint_name(lse, LSE_NAME)
+    return out, (q, k, v, out, lse)
+
+
+def _noised_attention_bwd(noised, residuals, do):
+    return _backward(*residuals, do, None, noised=noised)
+
+
+_noised_attention.defvjp(_noised_attention_fwd, _noised_attention_bwd)
+
+
 @jax.custom_vjp
 def _selected_attention(q, k, v, selected):
     return _selected_attention_fwd(q, k, v, selected)[0]
@@ -465,7 +597,7 @@ def _selected_attention_bwd(residuals, cotangents):
 _selected_attention.defvjp(_selected_attention_fwd, _selected_attention_bwd)
 
 
-def blocked_attention(q, k, v, *, window: Optional[int] = None, selected=None):
+def blocked_attention(q, k, v, *, window: Optional[int] = None, selected=None, noised=None):
     """Causal attention of q ``[B, T, H, dh]`` over k / v ``[B, T, Hkv, dh]``
     (``H % Hkv == 0``: query head ``h`` reads KV head ``h // (H / Hkv)``) ->
     ``[B, T, H * dh]``. ``window=None`` sees every key up to the query's own;
@@ -475,8 +607,19 @@ def blocked_attention(q, k, v, *, window: Optional[int] = None, selected=None):
     With ``selected`` (``[B, T, T]`` bool or int8, query by key: the keys each query
     reads, at least one and none after the query) the result is a pair: the attention over
     those keys, and the heads' summed probabilities ``[B, T, T]`` float32, zero off the
-    selection, which pass no gradient."""
+    selection, which pass no gradient.
+
+    With ``noised = (block, clean)`` the ``T = 2 clean`` rows are a doubled stream, the
+    clean copy of a sequence beside its noised copy, under the block-diffusion mask of the
+    module's docstring, from positions alone; it takes no window and no selection, and the
+    shapes have to tile (:func:`applies_noised`)."""
     t, dh = q.shape[1], q.shape[3]
+    if noised is not None:
+        if (window is not None or selected is not None or q.shape[2] % k.shape[2]
+                or not applies_noised(t, dh, *noised)):
+            raise ValueError(f"blocked_attention does not tile q {q.shape}, k {k.shape} as a "
+                             f"doubled stream {noised}: see attention.applies_noised")
+        return _noised_attention(q, k, v, tuple(noised))
     if q.shape[2] % k.shape[2] or not applies(t, dh, window):
         raise ValueError(f"blocked_attention does not tile q {q.shape}, k {k.shape}, "
                          f"window {window}: see attention.applies")
