@@ -810,7 +810,7 @@ def test_the_kept_list_gives_the_same_loss_and_counts_with_fewer_products(descri
         # kept, and the attention's forward twice where nothing is
         kernels = lambda passes: {k: n for k, n in passes.items() if k != "products"}  # noqa: E731
         once = {**dict.fromkeys(("blocked_attention_fwd", "blocked_attention_probs",
-                                 "blocked_attention_dq", "blocked_attention_dkv"), 3),
+                                 "blocked_attention_bwd"), 3),
                 **dict.fromkeys(("index_scores_fwd", "index_scores_dq", "index_scores_dk"), 12)}
         assert kernels(kept_passes) == once
         assert kernels(both["target"]) == {**once, "blocked_attention_probs": 6}

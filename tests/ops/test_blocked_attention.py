@@ -2,8 +2,9 @@
 CPU: value and the three gradients against plain masked attention, and under a selection
 against ``pattern._attend_summed`` with the heads' summed probabilities; which path
 ``models/pattern.py`` takes for which shapes; that every kernel of a layer's forward,
-recomputed forward and backward carries the scope the benchmark's reader looks for; and
-that without a selection the three kernels are what they were before they took one."""
+recomputed forward and backward carries the scope the benchmark's reader looks for; that
+without a selection the two kernels lower to recorded operations; and that the backward
+kernel's call fits the VMEM it is given at every cell's shapes."""
 
 import dataclasses
 import os
@@ -117,6 +118,79 @@ def summed_reference(q, k, v, selected):
     T, H * dh]``, the probabilities summed over all heads ``[B, T, T]``)."""
     out, probs = pattern._attend_summed(*pattern._heads_first(q, k, v), selected)
     return pattern._heads_last(out, q.shape[1]), probs.sum(axis=0)
+
+
+def plain_selected_attention(q, k, v, selected):
+    b, t, h, dh = q.shape
+    k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(dh)
+    probs = jax.nn.softmax(jnp.where(selected[:, None], scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, t, h * v.shape[-1])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("form", ["causal", "window", "selected", "noised"])
+def test_two_batch_rows_of_two_kv_heads_each_start_from_an_empty_accumulator(
+        form, dtype, monkeypatch):
+    """The backward kernel holds dQ of a whole sequence in VMEM for one (batch row, KV head)
+    and zeroes it at that pair's first step: with two rows of two KV heads, four tiles of
+    128 rows each and groups of two, every pair but the first starts where another ended.
+    Value and gradients of all four forms against plain attention under the whole mask,
+    and each row's against the same row alone, bit for bit."""
+    monkeypatch.setattr(attention, "FULL_TILE", 128)
+    monkeypatch.setattr(attention, "WINDOW_TILE", 128)
+    seq, groups = 512, 2
+    keys = jax.random.split(jax.random.PRNGKey(49), 4)
+    q = jax.random.normal(keys[0], (2, seq, HKV * groups, DH))
+    k, v = (jax.random.normal(key, (2, seq, HKV, DH)) for key in keys[1:3])
+    weight = jax.random.normal(keys[3], (2, seq, HKV * groups * DH))
+    if form == "selected":
+        selected = jnp.concatenate([selection("random", seq, 128, 96),
+                                    selection("late", seq, 128, 96)])
+        plain = lambda *a: plain_selected_attention(*a, selected)  # noqa: E731
+        blocked = lambda *a: attention.blocked_attention(*a, selected=selected)[0]  # noqa: E731
+    elif form == "noised":
+        plain = lambda *a: plain_noised_attention(*a, 16, seq // 2)  # noqa: E731
+        blocked = lambda *a: attention.blocked_attention(*a, noised=(16, seq // 2))  # noqa: E731
+    else:
+        window = 200 if form == "window" else None
+        plain = lambda *a: plain_attention(*a, window)  # noqa: E731
+        blocked = lambda *a: attention.blocked_attention(*a, window=window)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = value_and_grads(plain, q, k, v, weight)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    got = value_and_grads(blocked, q, k, v, weight)
+    limits = (F32_GAP,) * 4 if dtype == jnp.float32 else (BF16_VALUE_GAP,) + (BF16_GRAD_GAP,) * 3
+    gaps = [gap(a, b) for a, b in zip(got, want)]
+    assert all(g < limit for g, limit in zip(gaps, limits)), gaps
+    if form == "selected":
+        return  # a row alone takes its own selection: another closure, compared above
+    for row in range(2):
+        alone = value_and_grads(blocked, *(x[row:row + 1] for x in (q, k, v, weight)))
+        for a, b in zip(got, alone):
+            np.testing.assert_array_equal(np.asarray(a[row:row + 1]), np.asarray(b))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("window,seq,groups", [(128, 512, 2), (200, 512, 2), (129, 640, 1),
+                                               (300, 1024, 6)])
+def test_a_window_whose_reach_is_under_the_tile_count(window, seq, groups, dtype, monkeypatch):
+    """Tiles of 128 rows, so that a query tile sees 1, 2 or 3 tiles back of 4, 5 or 8: a
+    query tile's contributions to dQ end before the last key tile, and a key tile's walk
+    runs past the last query tile (the steps not taken). At the cells' 256-row tiles the
+    cases above have two tiles, where the reach is the whole sequence."""
+    monkeypatch.setattr(attention, "WINDOW_TILE", 128)
+    plan = attention._plan(jax.ShapeDtypeStruct((1, seq, HKV * groups, DH), dtype),
+                           jax.ShapeDtypeStruct((1, seq, HKV, DH), dtype), window)
+    assert plan.tile == 128 and 1 <= plan.reach < plan.n - 1
+    q, k, v, weight = inputs(seq, groups)
+    with jax.default_matmul_precision("highest"):
+        want = value_and_grads(lambda *a: plain_attention(*a, window), q, k, v, weight)
+    got = value_and_grads(lambda *a: attention.blocked_attention(*a, window=window),
+                          *(x.astype(dtype) for x in (q, k, v)), weight)
+    limits = (F32_GAP,) * 4 if dtype == jnp.float32 else (BF16_VALUE_GAP,) + (BF16_GRAD_GAP,) * 3
+    gaps = [gap(a, b) for a, b in zip(got, want)]
+    assert all(g < limit for g, limit in zip(gaps, limits)), gaps
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -357,7 +431,7 @@ def test_attention_paths_on_a_tpu_follow_the_shapes(as_on_a_tpu):
 def test_every_kernel_of_a_layer_carries_the_core_scope(kind, kept, forwards, as_on_a_tpu):
     """Lowered for the TPU (nothing compiles or runs): the forward, the forward that the
     layer's ``jax.checkpoint`` recomputes unless its policy keeps both residuals the
-    kernel names, and the two backward kernels are custom calls whose ``op_name`` the
+    kernel names, and the backward kernel are custom calls whose ``op_name`` the
     benchmark's ``attn.roofline`` reader puts under ``attn/<kind>/core``."""
     from benchmark import harness
 
@@ -381,8 +455,7 @@ def test_every_kernel_of_a_layer_carries_the_core_scope(kind, kept, forwards, as
     kernels = [names[ref] for ref in re.findall(
         r"stablehlo\.custom_call @tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
     assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
-        "blocked_attention_dkv", "blocked_attention_dq",
-        *["blocked_attention_fwd"] * forwards], kernels
+        "blocked_attention_bwd", *["blocked_attention_fwd"] * forwards], kernels
     assert all(mark.search(name) and f"attn/{kind}" in name for name in kernels), kernels
     assert sum("rematted_computation" in name for name in kernels) == forwards - 1
 
@@ -390,7 +463,7 @@ def test_every_kernel_of_a_layer_carries_the_core_scope(kind, kept, forwards, as
 def test_every_kernel_of_a_latent_layer_carries_the_core_scope_and_none_the_latent_one(
         as_on_a_tpu):
     """As above for latent attention of equal widths (the shapes the kernels take): the
-    four kernels are under ``attn/full/core``, and the projections' products under
+    three kernels are under ``attn/full/core``, and the projections' products under
     ``attn/full/latent``, where the new reader looks."""
     from benchmark import harness
 
@@ -414,8 +487,7 @@ def test_every_kernel_of_a_latent_layer_carries_the_core_scope_and_none_the_late
     kernels = [names[ref] for ref in re.findall(
         r"stablehlo\.custom_call @tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
     assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
-        "blocked_attention_dkv", "blocked_attention_dq",
-        "blocked_attention_fwd", "blocked_attention_fwd"], kernels
+        "blocked_attention_bwd", "blocked_attention_fwd", "blocked_attention_fwd"], kernels
     assert all(core.search(name) and not latent.search(name) for name in kernels), kernels
     products = [n for n in names.values() if latent.search(n) and n.endswith("dot_general")]
     for pass_ in ("jvp(attn/full)", "rematted_computation/attn/full", "transpose("):
@@ -449,27 +521,33 @@ def mosaic_kernels(lowered_text: str) -> list:
     return kernels
 
 
-#: the three kernels of a laguna layer at the cell's shapes (8,192 tokens, 8 KV heads of
-#: 128) as they were lowered at the parent of PR 36, before the kernels took a selection
+#: the two kernels of a laguna layer at the cell's shapes (8,192 tokens, 8 KV heads of
+#: 128). The forward is as it was lowered at the parent of PR 36, before the kernels took a
+#: selection; the backward is PR 49's one kernel, where the parent of PR 36 (and of PR 49)
+#: lowered two, in the full layers
+#:   ("blocked_attention_dq", 6, "4db3f5b67eb15450abaeaf29e5336c43be6abd2c16df6d3418c6ee4cd4849fd5")
+#:   ("blocked_attention_dkv", 6, "0d4d70a3f0b6a48b5eb7fd539ba6fda5da966af779beced8b44356ac7bc5a88e")
+#: and in the sliding ones
+#:   ("blocked_attention_dq", 6, "3b2b815c7b9372fb688df8df0f55f52b54142c9c3a4a66fee67dae604fd14897")
+#:   ("blocked_attention_dkv", 6, "c2b41c4b520135fe03f80a8a24cf2364cd9f544f319b0f9ba74207e1c49f3cfc")
 AS_BEFORE_A_SELECTION = {
     "full": [  # 48 query heads, tiles of 512 rows
         ("blocked_attention_fwd", 3, "49f06cc0ad5e668833dc7cbdf43ca7ee9f6d81b4088c48e2b354c4f3836f7b32"),
-        ("blocked_attention_dq", 6, "4db3f5b67eb15450abaeaf29e5336c43be6abd2c16df6d3418c6ee4cd4849fd5"),
-        ("blocked_attention_dkv", 6, "0d4d70a3f0b6a48b5eb7fd539ba6fda5da966af779beced8b44356ac7bc5a88e")],
+        ("blocked_attention_bwd", 6, "539089f52a8abc6499e00d4654b70fd0031b5e12642fbcf8cb2638ce7075603a")],
     "sliding": [  # 64 query heads, a window of 512 in tiles of 256 rows
         ("blocked_attention_fwd", 3, "9e8419de2dc59086f108baa1012abea51d065f9533f7e2c72245fc9c23f50c98"),
-        ("blocked_attention_dq", 6, "3b2b815c7b9372fb688df8df0f55f52b54142c9c3a4a66fee67dae604fd14897"),
-        ("blocked_attention_dkv", 6, "c2b41c4b520135fe03f80a8a24cf2364cd9f544f319b0f9ba74207e1c49f3cfc")],
+        ("blocked_attention_bwd", 6, "21ffb18976ba6ca74b9e4d2a0d0f44d0b727b87f047d1a6a80b0fcb8d737c184")],
 }
 
 
 @pytest.mark.parametrize("kind", ["full", "sliding"])
 def test_without_a_selection_the_kernels_lower_to_what_they_were(kind, as_on_a_tpu):
     """``blocked_attention(..., selected=None)`` at laguna's shapes, lowered for the TPU
-    (nothing compiles or runs): three kernels by name, their operands (no selection among
-    them) and, location for location aside, the very operations of the parent of PR 36. An
-    edit that reaches the kernels laguna runs shows here, at no chip time; one that is
-    meant to changes these hashes with its own before and after."""
+    (nothing compiles or runs): two kernels by name, their operands (no selection among
+    them) and, location for location aside, the forward's very operations at the parent of
+    PR 36 and the backward's as PR 49 made it. An edit that reaches the kernels laguna runs
+    shows here, at no chip time; one that is meant to changes these hashes with its own
+    before and after."""
     cfg = laguna_config(8192)
     heads = {spec.attn: spec.n_heads for spec in cfg.layers}[kind]
     window = cfg.window if kind == "sliding" else None
@@ -484,13 +562,14 @@ def test_without_a_selection_the_kernels_lower_to_what_they_were(kind, as_on_a_t
     assert mosaic_kernels(text) == AS_BEFORE_A_SELECTION[kind]
 
 
-#: the three kernels at the Ouro cell's shapes (4,096 tokens, 16 query heads over 16 KV
-#: heads of 128: one query head a KV head, tiles of 512 rows), as PR 43 first lowered and
-#: ran them on a v5e
+#: the two kernels at the Ouro cell's shapes (4,096 tokens, 16 query heads over 16 KV
+#: heads of 128: one query head a KV head, tiles of 512 rows): the forward as PR 43 first
+#: lowered and ran it on a v5e, the backward as PR 49 did, where PR 43 had
+#:   ("blocked_attention_dq", 6, "e6f372feaa873fa6921dbd85e2833a24c2d81c21a3df13d4072ec826d44ba196")
+#:   ("blocked_attention_dkv", 6, "48668e62b2f0cbcf183bd165470a9385cfb22018b6761c2e00b6a8d40c71e02f")
 AT_ONE_QUERY_HEAD_A_KV_HEAD = [
     ("blocked_attention_fwd", 3, "fca5a722c604a31a10587058e580caf78f0daed4b38b9bd71f7beb16b6449515"),
-    ("blocked_attention_dq", 6, "e6f372feaa873fa6921dbd85e2833a24c2d81c21a3df13d4072ec826d44ba196"),
-    ("blocked_attention_dkv", 6, "48668e62b2f0cbcf183bd165470a9385cfb22018b6761c2e00b6a8d40c71e02f")]
+    ("blocked_attention_bwd", 6, "651acce005584bad9a3f4ccb22239cfd30f8515bb0b5e3619dc93b6b946aa2fb")]
 
 
 def test_the_kernels_lower_at_one_query_head_a_kv_head(as_on_a_tpu):
@@ -512,6 +591,67 @@ def test_the_kernels_lower_at_one_query_head_a_kv_head(as_on_a_tpu):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(
         lowering_platforms=("tpu",)).as_text()
     assert mosaic_kernels(text) == AT_ONE_QUERY_HEAD_A_KV_HEAD
+
+
+def cell_attention_shapes(name: str, kind: str):
+    """(q, k as shapes, the keywords of ``attention.backward_vmem_bytes``) of one layer
+    kind of an accepted configuration at its cell's batch, read from its file."""
+    from benchmark import harness
+
+    config = harness.read_json(harness.HERE, "configs", f"{name}.json")
+    batch, seq = config["batch"]
+    cfg = harness.load_family(config).program_config(config, seq)
+    how = {}
+    if kind == "dense":  # models/transformer.py: one kind, no pattern of layers
+        heads = cfg.n_heads
+    else:
+        heads = {spec.attn: spec.n_heads for spec in cfg.layers}[kind]
+        seq = cfg.stream(seq)
+        path = pattern.attention_paths(cfg, seq)[kind]
+        if path.get("walk") == "noised":
+            how["noised"] = (path["block_length"], path["clean"])
+        how["selection"] = kind == "indexed"
+        if kind == "sliding":
+            how["window"] = cfg.window
+    q = jax.ShapeDtypeStruct((batch, seq, heads, cfg.head_dim), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((batch, seq, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    return q, k, how
+
+
+#: (configuration, layer kind) -> (query heads a KV head, tiles, the float32 dQ of one
+#: (batch row, KV head)'s whole sequence in bytes)
+BACKWARD_IN_VMEM = {
+    ("keye-vl2-30b-a3b-l6-ep8", "indexed"): (8, 16, 33_554_432),
+    ("sdar-30b-a3b-l6-ep8", "full"): (8, 16, 33_554_432),
+    ("laguna-xs2-l5-ep8", "full"): (6, 16, 25_165_824),
+    ("laguna-xs2-l5-ep8", "sliding"): (8, 32, 33_554_432),
+    ("ouro-2.6b-l8", "dense"): (1, 8, 2_097_152),
+}
+
+
+@pytest.mark.parametrize("name,kind", list(BACKWARD_IN_VMEM))
+def test_the_backward_call_fits_the_vmem_it_is_given(name, kind, as_on_a_tpu):
+    """Sizes alone, nothing lowered or run: the backward kernel keeps dQ of one (batch row,
+    KV head)'s whole sequence in float32 scratch beside dK's and dV's tile, and Pallas holds
+    two buffers of every block. At the shapes of every cell on the kernels, read from the
+    configurations' own files, that is under ``VMEM_LIMIT_BYTES`` with room for Mosaic's own
+    temporaries (eight ``[tile, tile]`` float32 arrays: a tile's scores, probabilities and
+    their cotangents, twice), so a tile, a group or a sequence that would not fit fails
+    here and not in Mosaic on the chip."""
+    q, k, how = cell_attention_shapes(name, kind)
+    plan = attention._plan(q, k, how.get("window"), how.get("noised"))
+    groups, tiles, accumulator = BACKWARD_IN_VMEM[name, kind]
+    assert (plan.groups, plan.n) == (groups, tiles)
+    assert plan.n * plan.tile * plan.groups * plan.dh * 4 == accumulator
+    asked = attention.backward_vmem_bytes(q, k, **how)
+    width = plan.groups * plan.dh
+    blocks = (2 * plan.tile * width * 2 + 2 * plan.tile * plan.dh * 2  # q, do; k, v
+              + 2 * plan.groups * plan.tile * 4 + how.get("selection", False) * plan.tile ** 2
+              + plan.tile * width * 2 + 2 * plan.tile * plan.dh * 2)  # dq; dk, dv
+    assert asked == accumulator + 2 * plan.tile * plan.dh * 4 + 2 * blocks
+    assert asked + 8 * plan.tile ** 2 * 4 < attention.VMEM_LIMIT_BYTES < 128 * 2 ** 20
+    assert attention.applies(plan.t if "noised" not in how else how["noised"][1], plan.dh,
+                             how.get("window"))
 
 
 # -- the block-diffusion form: a doubled stream under a mask from positions alone ------
@@ -587,8 +727,8 @@ def test_no_clean_row_reads_a_noised_key_and_a_noised_row_of_block_0_reads_its_o
 
 def visited_tiles(half: int) -> dict:
     """The (query tile, key tile) pairs each kernel of the block-diffusion form takes at
-    ``half`` tiles a half, by the kernels' own walks over their grids: the forward's and
-    dQ's ``(2 half, half + 1)`` steps by query tile, dK/dV's ``(2 half, 2 half)`` by key
+    ``half`` tiles a half, by the kernels' own walks over their grids: the forward's
+    ``(2 half, half + 1)`` steps by query tile, the backward's ``(2 half, 2 half)`` by key
     tile."""
     by_query = [(i, int(kj)) for i in range(2 * half) for j in range(half + 1)
                 for kj, seen in [attention._noised_key_tile(np.int32(i), np.int32(j), half)]
@@ -596,26 +736,31 @@ def visited_tiles(half: int) -> dict:
     by_key = [(int(qi), j) for j in range(2 * half) for i in range(2 * half)
               for qi, seen in [attention._noised_query_tile(np.int32(j), np.int32(i), half)]
               if seen]
-    return {"fwd": by_query, "dq": by_query, "dkv": by_key}
+    return {"fwd": by_query, "bwd": by_key}
 
 
 @pytest.mark.parametrize("half", [1, 2, 3, 8])
 def test_the_noised_walk_visits_the_tile_pairs_the_mask_leaves_and_no_other(half):
-    """The three kernels' walks over their grids, as the kernels compute them, against the
+    """The two kernels' walks over their grids, as the kernels compute them, against the
     tiles of the whole mask that hold a pair: ``half (half + 1) + half`` of the ``4
     half^2``, 80 of 256 at the cell's eight tiles a half (a causal walk over the doubled
-    stream would visit 136), forward, dQ and dK/dV alike; the tiles that skip the mask are
-    the ones the mask leaves whole."""
+    stream would visit 136), forward and backward alike; the tiles that skip the mask are
+    the ones the mask leaves whole; and in the backward's walk a query tile's own key tile,
+    after which its dQ leaves the kernel, is the last key tile that holds a pair of it."""
     tile, block = 8, 4  # the walk knows tiles, not rows
     mask = noised_mask(block, half * tile).reshape(2 * half, tile, 2 * half, tile)
     holds_a_pair = {(i, j) for i in range(2 * half) for j in range(2 * half)
                     if mask[i, :, j].any()}
     whole = {(i, j) for i in range(2 * half) for j in range(2 * half) if mask[i, :, j].all()}
     visited = visited_tiles(half)
-    assert set(visited) == {"fwd", "dq", "dkv"}
+    assert set(visited) == {"fwd", "bwd"}
     for kernel, pairs in visited.items():
         assert len(pairs) == len(set(pairs)) == half * (half + 1) + half, kernel
         assert set(pairs) == holds_a_pair, kernel
+    # the backward walks the key tiles in ascending order, each from its own query tile
+    assert [pair for pair in visited["bwd"] if pair[0] == pair[1]] == [
+        (j, j) for j in range(2 * half)]
+    assert all(j <= i for i, j in visited["bwd"])
     if half == 8:
         assert len(visited["fwd"]) == 80 and (2 * half) * (2 * half + 1) // 2 == 136
     uncut = {(i, j) for i, j in holds_a_pair
@@ -649,8 +794,8 @@ def test_the_noised_form_takes_the_shapes_that_tile_and_refuses_the_rest():
 def test_every_kernel_of_a_diffused_layer_carries_the_core_scope(as_on_a_tpu):
     """Lowered for the TPU (nothing compiles or runs): a full layer with head norms and no
     gate over a doubled stream, differentiated through a ``jax.checkpoint`` that keeps the
-    kernel's two residuals: the forward once and the two backward kernels, each a custom
-    call under ``attn/full/core``, and no operand beside q, k, v and the backward's four:
+    kernel's two residuals: the forward once and the backward kernel, each a custom call
+    under ``attn/full/core``, and no operand beside q, k, v and the backward's three more:
     the mask is no array."""
     from benchmark import harness
 
@@ -677,10 +822,10 @@ def test_every_kernel_of_a_diffused_layer_carries_the_core_scope(as_on_a_tpu):
     kernels = [names[ref] for ref in re.findall(
         r"stablehlo\.custom_call @tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
     assert sorted(name.rsplit("/", 2)[-2] for name in kernels) == [
-        "blocked_attention_dkv", "blocked_attention_dq", "blocked_attention_fwd"], kernels
+        "blocked_attention_bwd", "blocked_attention_fwd"], kernels
     assert all(mark.search(name) and "attn/full" in name for name in kernels), kernels
     assert [(name, operands) for name, operands, _ in mosaic_kernels(
         jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(x, lp).lower(
             lowering_platforms=("tpu",)).as_text())] == [
-        ("blocked_attention_fwd", 3), ("blocked_attention_dq", 6), ("blocked_attention_dkv", 6)]
+        ("blocked_attention_fwd", 3), ("blocked_attention_bwd", 6)]
     assert not re.search(r"tensor<(\d+x)*2048x2048x", text)  # no [2L, 2L] value of any type

@@ -36,21 +36,35 @@ accumulator are float32, the softmax scale multiplies the float32 scores, the
 exponentials are cast to the operands' type only as the operand of the product with
 ``v``, and the division by the row sum is exact. The backward pass (a ``custom_vjp``)
 keeps the output and each row's log-sum-exp, recomputes a tile's scores from them, and
-runs as two kernels: one walks the query tiles that see a key tile and accumulates
-``dk`` and ``dv``, the other walks the key tiles a query tile sees and accumulates
-``dq``. Under a selection a fourth kernel gives a second result, as
-``models/pattern.py:_attend_summed`` does: the probabilities ``exp(score - lse)`` of
-every head summed in float32, ``[B, T, T]`` (one more ``QK^T`` a tile, from the
-forward's log-sum-exp; zero off the selection and in the tiles past the diagonal), which
-passes no gradient.
+runs as one kernel (PR 49; two until then, which made every tile's ``s``, ``p``, ``dp``
+and ``ds`` twice: seven products a (tile, head) where this one has five). It walks the
+query tiles that see a key tile, the key tiles on the grid's outer axis in ascending
+order; in a visited tile each head of the group makes ``s = k q^T``, ``p = exp(s - lse)``,
+``dp = v do^T`` and ``ds = p (dp - delta)`` once, with the keys on the sublanes, and takes
+``dv += p do``, ``dk += ds q`` and ``dq[query tile] += ds^T k`` from them, ``p`` and
+``ds`` rounded to the operands' type once, as operands of those products. ``dk`` and
+``dv`` accumulate in a ``[tile, dh]`` float32 scratch for the key tile's walk. ``dq``
+cannot: a query tile's contributions arrive one a key tile, across the outer axis. Its
+float32 accumulator holds the whole sequence of one (batch row, KV head), ``[T, G * dh]``
+(32 MiB at 8,192 rows and a group of 8), in VMEM from that pair's first grid step, where
+it is zeroed, to its last; both tile axes of the grid are ``"arbitrary"`` for that. In all
+four forms a query tile's own key tile is the last that holds a key of it and the first
+step of that key tile's walk, so there ``dq`` of the tile is complete, and it is scaled,
+rounded and written out then, a tile at a time. A tile's contributions arrive in ascending
+key tile, the order in which the kernel that walked a query tile's keys added them, each
+the float32 product of the same rounded ``ds`` and ``k``: ``dq`` is that kernel's to the
+order of a float32 sum inside the products. Under a selection a third kernel gives a
+second result, as ``models/pattern.py:_attend_summed`` does: the probabilities
+``exp(score - lse)`` of every head summed in float32, ``[B, T, T]`` (one more ``QK^T`` a
+tile, from the forward's log-sum-exp; zero off the selection and in the tiles past the
+diagonal), which passes no gradient.
 
 Layout: q, the output and their cotangents stay ``[B, T, H * dh]`` in HBM; a block is
 ``[tile, G * dh]``, the columns of one KV head's group, and a head is a lane-aligned
 slice of it. Nothing is transposed on the way in or out. Row statistics are
 ``[B, Hkv, T, G]`` (a query row's ``G`` values on the lanes) where the scores have the
-queries on the sublanes, and ``[B, Hkv, G, T]`` where the dK/dV kernel has them on the
-lanes; that kernel reads the selection key by query, from a transposed copy made once
-a call.
+queries on the sublanes, and ``[B, Hkv, G, T]`` in the backward kernel, which has them on
+the lanes and reads the selection key by query, from a transposed copy made once a call.
 
 Off the TPU the same kernels run under the Pallas interpreter, which is what the tier-1
 tests compare with plain masked attention.
@@ -210,9 +224,9 @@ def _lanes(x, width: int):
 
 
 def _query_walk(i, j, tile, window, reach, noised):
-    """Step ``j`` of query tile ``i`` in the forward and the dQ kernel, as three functions
-    of no argument, each traced where the kernel calls it: whether the step is taken,
-    whether its tile is whole, and the tile's mask with the queries on the sublanes."""
+    """Step ``j`` of query tile ``i`` in the forward kernel, as three functions of no
+    argument, each traced where the kernel calls it: whether the step is taken, whether
+    its tile is whole, and the tile's mask with the queries on the sublanes."""
     if noised is None:
         kj = jnp.maximum(i - reach, 0) + j
         return (lambda: kj <= i, lambda: _uncut(i, kj, tile, window),
@@ -224,15 +238,15 @@ def _query_walk(i, j, tile, window, reach, noised):
 
 
 def _key_walk(j, i, tile, window, n, noised):
-    """Step ``i`` of key tile ``j`` in the dK/dV kernel, as :func:`_query_walk`'s three
-    functions, the masks with the queries on the lanes."""
+    """Step ``i`` of key tile ``j`` in the backward kernel: the query tile, then
+    :func:`_query_walk`'s three functions, the masks with the queries on the lanes."""
     if noised is None:
         qi = j + i  # the first query tile that sees key tile j is the one on the diagonal
-        return (lambda: qi < n, lambda: _uncut(qi, j, tile, window),
+        return (qi, lambda: qi < n, lambda: _uncut(qi, j, tile, window),
                 lambda: _keep(qi, j, tile, window, 1))
     block, half = noised
     qi, taken = _noised_query_tile(j, i, half)
-    return (lambda: taken, lambda: _noised_uncut(qi, j, half),
+    return (qi, lambda: taken, lambda: _noised_uncut(qi, j, half),
             lambda: _noised_keep(qi, j, tile, block, half, 1))
 
 
@@ -281,9 +295,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, groups, dh, tile, window, scale, rea
             lse_ref[:, g:g + 1] = (m_scr[g] + jnp.log(l))[:, :1]
 
 
-def _params():
+def _params(outer_tiles: str = "parallel"):
+    """The grid's axes are (batch row, KV head, outer tile, inner tile): scratch lives
+    across the inner tiles, and in the backward kernel across the outer ones too."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", outer_tiles, "arbitrary"),
         vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
@@ -424,47 +440,19 @@ def _probs(q, k, lse, selected):
 
 # -- backward ---------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-               groups, dh, tile, window, scale, reach, noised=None):
-    *sel, dq_ref, dq_scr = rest
-    i, j = pl.program_id(2), pl.program_id(3)
-    seen, uncut, keep = _query_walk(i, j, tile, window, reach, noised)
+_TN = (((0,), (0,)), ((), ()))  # [n, m] x [n, d] -> [m, d]
 
-    @pl.when(j == 0)
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                groups, dh, tile, window, scale, reach, n, noised=None):
+    *sel, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+    j, i = pl.program_id(2), pl.program_id(3)
+    qi, seen, uncut, keep = _key_walk(j, i, tile, window, n, noised)
+    last = reach if noised is None else n - 1  # the grid's last step for a key tile
+
+    @pl.when((j == 0) & (i == 0))
     def _():
         dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    def tile_body(mask):
-        k, v = k_ref[...], v_ref[...]
-        keep = mask() if mask else None
-        for g in range(groups):
-            head = slice(g * dh, (g + 1) * dh)
-            s = jax.lax.dot_general(q_ref[:, head], k, _NT,
-                                    preferred_element_type=jnp.float32) * scale
-            if mask:
-                s = jnp.where(keep, s, MASKED)
-            p = jnp.exp(s - lse_ref[:, g:g + 1])
-            dp = jax.lax.dot_general(do_ref[:, head], v, _NT,
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[:, g:g + 1])
-            dq_scr[:, head] += jnp.dot(ds.astype(k.dtype), k,
-                                       preferred_element_type=jnp.float32)
-
-    @pl.when(seen())
-    def _():
-        _visit(sel, uncut(), keep, tile_body)
-
-    @pl.when(j == reach)
-    def _():
-        dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                groups, dh, tile, window, scale, reach, n, noised=None):
-    *sel, dk_ref, dv_ref, dk_scr, dv_scr = rest
-    j, i = pl.program_id(2), pl.program_id(3)
-    seen, uncut, keep = _key_walk(j, i, tile, window, n, noised)
-    last = reach if noised is None else n - 1  # the grid's last step for a key tile
 
     @pl.when(i == 0)
     def _():
@@ -484,12 +472,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             p = jnp.exp(s - lse_ref[g:g + 1, :])
             dv_scr[...] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[g:g + 1, :])
-            dk_scr[...] += jnp.dot(ds.astype(q.dtype), q, preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[g:g + 1, :])).astype(q.dtype)
+            dk_scr[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+            # ds^T k: the keys are the rows of both, and Mosaic turns the block for the MXU
+            dq_scr[qi, :, head] += jax.lax.dot_general(
+                ds, k, _TN, preferred_element_type=jnp.float32)
 
     @pl.when(seen())
     def _():
         _visit(sel, uncut(), keep, tile_body)
+
+    @pl.when(i == 0)  # query tile j has taken its own key tile, the last that holds a key of it
+    def _():
+        dq_ref[...] = (dq_scr[j] * scale).astype(dq_ref.dtype)
 
     @pl.when(i == last)
     def _():
@@ -497,47 +492,62 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _backward(q, k, v, out, lse, do, window, selected=None, noised=None):
-    p = _plan(q, k, window, noised)
-    # each row's sum of (output x its cotangent): what the softmax's backward subtracts
-    delta = jnp.sum((out.astype(jnp.float32) * do.astype(jnp.float32))
-                    .reshape(p.b, p.t, p.hkv, p.groups, p.dh), axis=-1).transpose(0, 2, 1, 3)
-    operands = p.flat(q, k, v, do)
-    stats = pl.BlockSpec((None, None, p.tile, p.groups), lambda b, h, i, j: (b, h, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **p.static),
-        grid=p.grid,
-        in_specs=[p.q_block(), p.kv_block(), p.kv_block(), p.q_block(), stats, stats,
-                  *p.sel_blocks(selected)],
-        out_specs=p.q_block(),
-        out_shape=jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((p.tile, p.groups * p.dh), jnp.float32)],
-        compiler_params=_params(), interpret=_interpret(), name="blocked_attention_dq",
-    )(*operands, lse, delta, *_given(selected))
-
-    # the dK/dV kernel's grid is (B, Hkv, key tile, query tile that sees it); its scores
-    # have the queries on the lanes, so the row statistics go in with the sequence last,
-    # and a selection key by query. In the block-diffusion form a clean key tile is seen
-    # by up to all the query tiles of both halves
+def _backward_call(p: _Plan, dtype, selection: bool):
+    """The backward kernel's call for plan ``p`` over operands of ``dtype``: (the grid, the
+    blocks, the results and the scratch as ``pallas_call`` takes them, the dtype of each
+    operand's block). The grid is (B, Hkv, key tile, query tile that sees it); the scores
+    have the queries on the lanes, so the row statistics go in with the sequence last, and a
+    selection key by query. In the block-diffusion form a clean key tile is seen by up to
+    all the query tiles of both halves."""
     def seen(j, i):
         if p.noised is not None:
             return _noised_query_tile(j, i, p.noised[1])[0]
         return jnp.minimum(j + i, p.n - 1)
 
-    q_seen = pl.BlockSpec((None, p.tile, p.groups * p.dh), lambda b, h, j, i: (b, seen(j, i), h))
+    width = p.groups * p.dh
+    q_seen = pl.BlockSpec((None, p.tile, width), lambda b, h, j, i: (b, seen(j, i), h))
+    q_own = pl.BlockSpec((None, p.tile, width), lambda b, h, j, i: (b, j, h))
     kv_own = pl.BlockSpec((None, p.tile, p.dh), lambda b, h, j, i: (b, j, h))
     stats = pl.BlockSpec((None, None, p.groups, p.tile), lambda b, h, j, i: (b, h, 0, seen(j, i)))
-    sel_seen = [] if selected is None else [
+    sel_seen = [] if not selection else [
         pl.BlockSpec((None, p.tile, p.tile), lambda b, h, j, i: (b, j, seen(j, i)))]
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, n=p.n, **p.static),
+    call = dict(
         grid=p.grid if p.noised is None else (p.b, p.hkv, p.n, p.n),
         in_specs=[q_seen, kv_own, kv_own, q_seen, stats, stats, *sel_seen],
-        out_specs=[kv_own, kv_own],
-        out_shape=[jax.ShapeDtypeStruct(operands[1].shape, k.dtype)] * 2,
-        scratch_shapes=[pltpu.VMEM((p.tile, p.dh), jnp.float32)] * 2,
-        compiler_params=_params(), interpret=_interpret(), name="blocked_attention_dkv",
-    )(*operands, lse.swapaxes(2, 3), delta.swapaxes(2, 3),
+        out_specs=[q_own, kv_own, kv_own],
+        out_shape=[jax.ShapeDtypeStruct((p.b, p.t, p.hkv * width), dtype),
+                   *[jax.ShapeDtypeStruct((p.b, p.t, p.hkv * p.dh), dtype)] * 2],
+        scratch_shapes=[pltpu.VMEM((p.n, p.tile, width), jnp.float32),
+                        *[pltpu.VMEM((p.tile, p.dh), jnp.float32)] * 2])
+    return call, [dtype] * 4 + [jnp.float32] * 2 + [jnp.int8] * len(sel_seen)
+
+
+def backward_vmem_bytes(q, k, *, window: Optional[int] = None, selection: bool = False,
+                        noised=None) -> int:
+    """What the backward kernel's call asks of VMEM for q and k of these shapes and dtype
+    (arrays or ``jax.ShapeDtypeStruct``): its scratch, and two buffers (one in use, one in
+    flight) of every operand's and every result's block. Mosaic's own temporaries (a tile's
+    ``[tile, tile]`` float32 scores and their like) come on top."""
+    call, dtypes = _backward_call(_plan(q, k, window, noised), q.dtype, selection)
+    dtypes += [out.dtype for out in call["out_shape"]]
+    blocks = sum(2 * int(np.prod([d for d in spec.block_shape if d is not None]))
+                 * jnp.dtype(dtype).itemsize
+                 for spec, dtype in zip(call["in_specs"] + call["out_specs"], dtypes))
+    return blocks + sum(int(np.prod(s.shape)) * jnp.dtype(s.dtype).itemsize
+                        for s in call["scratch_shapes"])
+
+
+def _backward(q, k, v, out, lse, do, window, selected=None, noised=None):
+    p = _plan(q, k, window, noised)
+    # each row's sum of (output x its cotangent): what the softmax's backward subtracts
+    delta = jnp.sum((out.astype(jnp.float32) * do.astype(jnp.float32))
+                    .reshape(p.b, p.t, p.hkv, p.groups, p.dh), axis=-1).transpose(0, 2, 3, 1)
+    call, _ = _backward_call(p, q.dtype, selected is not None)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, n=p.n, **p.static), **call,
+        compiler_params=_params("arbitrary"), interpret=_interpret(),
+        name="blocked_attention_bwd",
+    )(*p.flat(q, k, v, do), lse.swapaxes(2, 3), delta,
       *(s.swapaxes(1, 2) for s in _given(selected)))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
@@ -612,7 +622,12 @@ def blocked_attention(q, k, v, *, window: Optional[int] = None, selected=None, n
     With ``noised = (block, clean)`` the ``T = 2 clean`` rows are a doubled stream, the
     clean copy of a sequence beside its noised copy, under the block-diffusion mask of the
     module's docstring, from positions alone; it takes no window and no selection, and the
-    shapes have to tile (:func:`applies_noised`)."""
+    shapes have to tile (:func:`applies_noised`).
+
+    Every form differentiates through one backward kernel, which gives dQ, dK and dV from
+    one making of each tile's scores and holds the float32 dQ of a (batch row, KV head)'s
+    whole sequence in VMEM meanwhile (:func:`backward_vmem_bytes`; the module's docstring
+    says why that leaves dQ's order of summation as it was)."""
     t, dh = q.shape[1], q.shape[3]
     if noised is not None:
         if (window is not None or selected is not None or q.shape[2] % k.shape[2]
